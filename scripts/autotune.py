@@ -629,17 +629,10 @@ def run_search(
 
 def main(argv=None):
     args = parse_args(argv)
-    # some images pre-import jax with a platform baked into jax.config,
-    # where the JAX_PLATFORMS env var alone is a silent no-op (see
-    # serve_loadgen.py) — re-assert it through the config
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-
-        try:
-            jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-        except RuntimeError:
-            pass  # backend already initialized (e.g. under pytest)
     from zero_transformer_tpu.analysis import autotune as at
+    from zero_transformer_tpu.utils import compile_cache
+
+    compile_cache.configure()
 
     target = args.target
     wl_path = Path(

@@ -1601,18 +1601,9 @@ def run_tenant_flood_bench(args) -> dict:
 
 def main(argv=None) -> dict:
     args = parse_args(argv)
-    # some images pre-import jax with a platform baked into jax.config,
-    # where the JAX_PLATFORMS env var alone is a silent no-op — re-assert
-    # it through the config so "CPU run" means CPU
-    import os
+    from zero_transformer_tpu.utils import compile_cache
 
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-
-        try:
-            jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-        except RuntimeError:
-            pass  # backend already initialized (e.g. under pytest)
+    compile_cache.configure()
     if args.workload and (
         args.router or args.long_prompt_flood or args.sawtooth
         or args.capacity_sweep or args.tenant_flood

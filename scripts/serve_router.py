@@ -416,16 +416,9 @@ class StubReplica:
 
 def run_replica_worker(args) -> None:
     """A real single-replica serving process on the test zoo model —
-    the SIGKILL target of the fleet chaos tests."""
-    import os
-
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-
-        try:
-            jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-        except RuntimeError:
-            pass
+    the SIGKILL target of the fleet chaos tests. CPU-only: a fleet is
+    several jax processes on one host and a chip belongs to one process,
+    so whoever spawns replicas pins them with ``JAX_PLATFORMS=cpu``."""
     import jax
     import jax.numpy as jnp
 

@@ -39,7 +39,6 @@ from zero_transformer_tpu.config import (
 from zero_transformer_tpu.parallel import sharding as shd
 from zero_transformer_tpu.parallel.mesh import make_mesh
 from zero_transformer_tpu.training.trainer import Trainer, remap_loader_state
-from zero_transformer_tpu.utils.jax_compat import HAS_AMBIENT_MESH
 
 
 def tiny_config(directory, total_steps=8, zero_stage=1, batch_size=8):
@@ -311,10 +310,6 @@ def test_pp_schedule_relayout_restore_bitwise(tmp_path, devices):
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not HAS_AMBIENT_MESH,
-    reason="old-jax shard_map cannot trace the pipeline engine",
-)
 def test_elastic_resume_across_pp_schedule_change(tmp_path, devices):
     """Full trainer roundtrip: train 4 steps under gpipe, resume under
     interleaved — the loader position is in global batches so the token

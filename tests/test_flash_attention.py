@@ -227,3 +227,74 @@ def test_gate_serving_decisions(monkeypatch):
     if jax.default_backend() != "tpu":
         assert not fa.supported(q, k, v, causal=True, q_offset=offs,
                                 segment_ids=seg)
+
+
+# ------------------------------------------------------------------ on a mesh
+
+
+def test_kernels_run_per_device_on_a_mesh(monkeypatch, devices):
+    """GSPMD cannot partition a Mosaic call, so on a mesh the dispatch-site
+    entries run the kernel per device (``shard_kernel``): batch over the
+    data axes, heads — and their ALiBi slopes — over the tensor axis. Same
+    numbers as the unsharded kernel, forward and gradients."""
+    from zero_transformer_tpu.config import MeshConfig
+    from zero_transformer_tpu.ops.attention import dot_product_attention
+    from zero_transformer_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setenv("ZT_PALLAS_INTERPRET", "1")
+    B, T, H, D = 8, 64, 4, 16
+    q, k, v = (
+        jax.random.normal(jax.random.PRNGKey(i), (B, T, H, D), jnp.float32)
+        for i in range(3)
+    )
+    ids = jnp.repeat(jnp.arange(2), T // 2)[None].repeat(B, 0)
+
+    def loss(q, k, v):
+        out = dot_product_attention(
+            q, k, v, causal=True, alibi=True, doc_ids=ids, impl="flash"
+        )
+        return jnp.sum(out * out), out
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True))
+    ref_g, ref_out = grad(q, k, v)
+    mesh = make_mesh(MeshConfig(data=4, tensor=2))
+    with jax.set_mesh(mesh):
+        got_g, got_out = jax.jit(
+            jax.grad(loss, argnums=(0, 1, 2), has_aux=True)
+        )(q, k, v)
+    np.testing.assert_allclose(got_out, ref_out, rtol=1e-6, atol=1e-6)
+    for a, b in zip(got_g, ref_g):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+def test_gate_declines_what_the_mesh_does_not_divide(monkeypatch, caplog, devices):
+    """A batch the data axes do not divide: ``auto`` takes the XLA path and
+    says so ONCE, ``flash`` raises — never a silent per-device recompute of
+    the whole batch."""
+    import logging
+
+    from zero_transformer_tpu.config import MeshConfig
+    from zero_transformer_tpu.ops.attention import dot_product_attention
+    from zero_transformer_tpu.ops.pallas import kernel_traces
+    from zero_transformer_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setenv("ZT_PALLAS_INTERPRET", "1")
+    mesh = make_mesh(MeshConfig(data=8))
+    q = jax.random.normal(jax.random.PRNGKey(0), (12, 64, 4, 16), jnp.float32)
+
+    def run(impl, q):
+        with jax.set_mesh(mesh):
+            return jax.jit(
+                lambda q: dot_product_attention(q, q, q, causal=True, impl=impl)
+            )(q)
+
+    before = kernel_traces["flash_fwd"]
+    with caplog.at_level(logging.WARNING, logger="zero_transformer_tpu"):
+        run("auto", q)
+        run("auto", q * 2)
+    assert kernel_traces["flash_fwd"] == before  # XLA path both times
+    assert sum("does not divide" in r.message for r in caplog.records) == 1
+    with pytest.raises(NotImplementedError):
+        run("flash", q)
+    run("auto", q[:8])  # divisible: the kernel is in the program
+    assert kernel_traces["flash_fwd"] > before

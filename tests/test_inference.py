@@ -410,9 +410,7 @@ def test_tp2_prefill_logits_close(devices):
 
     mesh = serve_mesh(2)
     sharded = shard_for_inference(model, params, mesh)
-    from zero_transformer_tpu.utils.jax_compat import set_mesh
-
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits_tp, _ = prefill(
             model, sharded, prompt, init_cache(model, 2, mesh=mesh)
         )

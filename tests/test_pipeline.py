@@ -23,16 +23,7 @@ from zero_transformer_tpu.parallel import (
 from zero_transformer_tpu.parallel.mesh import PIPE_AXIS
 from zero_transformer_tpu.parallel.pipeline import bubble_fraction, interleaved_slot
 from zero_transformer_tpu.training.optimizer import make_optimizer, make_schedule
-from zero_transformer_tpu.utils.jax_compat import HAS_AMBIENT_MESH
 
-# The pipe engines' shard_map programs don't trace/compile on this image's
-# pre-ambient-mesh jax (the known old-jax failure set); NEW interleaved
-# execution coverage is gated so the set doesn't grow — the schedule's
-# dataflow itself is proven everywhere by the concrete-int simulation below.
-requires_modern_shard_map = pytest.mark.skipif(
-    not HAS_AMBIENT_MESH,
-    reason="old-jax shard_map cannot trace the pipeline engine",
-)
 
 CFG = ModelConfig(
     name="t", vocab_size=256, d_model=64, n_heads=4, n_layers=4, max_seq_len=32,
@@ -471,7 +462,6 @@ def _setup_interleaved(pp_interleave=2, zero_stage=1):
     return mesh, state, step
 
 
-@requires_modern_shard_map
 def test_pp_interleaved_matches_gpipe_and_dp(devices):
     """Interleaved runs the same per-layer math on a different wavefront:
     the trajectory must track GPipe and plain DP at the suite's pipeline
@@ -490,7 +480,6 @@ def test_pp_interleaved_matches_gpipe_and_dp(devices):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5)
 
 
-@requires_modern_shard_map
 def test_pp_interleaved_zero2_matches_dp(devices):
     _, s_il, step_il = _setup_interleaved(zero_stage=2)
     _, s_dp, step_dp = _setup(MeshConfig(), zero_stage=2)
@@ -503,7 +492,6 @@ def test_pp_interleaved_zero2_matches_dp(devices):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5)
 
 
-@requires_modern_shard_map
 def test_pp_interleaved_rejects_indivisible_microbatches(devices):
     """M % P != 0 breaks the just-in-time wrap-around hop — refused when
     the wavefront traces, not silently mis-scheduled."""

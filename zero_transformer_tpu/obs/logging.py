@@ -27,13 +27,15 @@ from typing import Any, Dict, Optional
 
 import jax
 
-# bf16 peak FLOP/s per chip by TPU generation (public spec sheets)
+# bf16 peak FLOP/s of ONE jax device, keyed by its exact ``device_kind``
+# (Google Cloud TPU documentation, per-generation system architecture
+# pages; a v3 jax device is one of a chip's two cores)
 TPU_PEAK_FLOPS = {
-    "v3": 123e12 / 2,  # per chip (2 cores): 61.5 TF/core… v3 chip = 123 TF bf16
-    "v4": 275e12,
-    "v5e": 197e12,
-    "v5p": 459e12,
-    "v6e": 918e12,
+    "TPU v3": 123e12 / 2,
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,  # v5e
+    "TPU v5": 459e12,  # v5p
+    "TPU v6 lite": 918e12,  # v6e
 }
 
 
@@ -49,13 +51,18 @@ def model_flops_per_token(
 
 
 def device_peak_flops() -> Optional[float]:
-    kind = jax.devices()[0].device_kind.lower()
-    for key, val in TPU_PEAK_FLOPS.items():
-        if key in kind.replace(" ", "").replace("tpu", ""):
-            return val
-    if "v5lite" in kind.replace(" ", "") or "lite" in kind:
-        return TPU_PEAK_FLOPS["v5e"]
-    return None
+    """Peak bf16 FLOP/s of device 0: ``None`` off the TPU (no utilisation is
+    ever reported for a CPU run); a TPU kind missing from the table is an
+    error, not a default — add it with its source."""
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        return None
+    if device.device_kind not in TPU_PEAK_FLOPS:
+        raise KeyError(
+            f"no peak FLOP/s recorded for device_kind {device.device_kind!r}; "
+            f"known: {sorted(TPU_PEAK_FLOPS)}"
+        )
+    return TPU_PEAK_FLOPS[device.device_kind]
 
 
 def mfu(

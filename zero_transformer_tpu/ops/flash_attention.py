@@ -15,13 +15,21 @@ index — chunked prefill windows, spec-verify blocks) and a ``[B, S]``
 - non-TPU backends, unless ``ZT_PALLAS_INTERPRET=1`` opts into Pallas
   interpret mode (how this CPU image exercises the kernels' numerics);
 - shapes without a sublane-aligned block decomposition, head widths the
-  MXU lane layout cannot take, f16, packed doc masks on cache shapes.
+  MXU lane layout cannot take, f16, packed doc masks on cache shapes;
+- a mesh whose data axes do not divide the batch, or whose tensor axis
+  does not divide the heads: the kernel runs per device under a shard_map
+  (``parallel.sharding.shard_kernel`` — GSPMD cannot partition a Mosaic
+  call), and an undivided dim would have every device compute all of it.
+  Logged once.
 
 The gate and the wrapper share ONE keyword surface — every kwarg
 ``supported`` inspects, ``flash_attention`` threads to the kernel (pinned
 by test: the gate may never advertise a distinction it then drops).
 """
 from __future__ import annotations
+
+import functools
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +41,7 @@ from zero_transformer_tpu.ops.pallas.flash import (
     flash_serving as _pallas_serving,
     pick_block,
 )
+from zero_transformer_tpu.parallel.sharding import kernel_shardable
 
 
 def interpret_enabled() -> bool:
@@ -46,6 +55,15 @@ def interpret_enabled() -> bool:
     )
 
     return interpret_requested()
+
+
+@functools.cache
+def _log_unshardable(B: int, H: int, KVH: int, mesh_shape: tuple) -> None:
+    logging.getLogger(__name__).warning(
+        "flash attention: mesh %s does not divide batch=%d / heads=%d / "
+        "kv_heads=%d; attention_impl=auto takes the XLA path here",
+        dict(mesh_shape), B, H, KVH,
+    )
 
 
 def _is_training_call(q_offset, segment_ids) -> bool:
@@ -96,6 +114,11 @@ def supported(
         floor = 16 if q.dtype == jnp.bfloat16 else 8
         if bq % floor or bk % floor:
             return False
+    if not kernel_shardable(batch=B, heads=H, kvheads=KVH):
+        _log_unshardable(
+            B, H, KVH, tuple(jax.sharding.get_abstract_mesh().shape.items())
+        )
+        return False
     return True
 
 

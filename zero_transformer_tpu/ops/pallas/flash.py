@@ -33,13 +33,11 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu is importable on CPU builds too; guard anyway
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
+from zero_transformer_tpu.ops.pallas import kernel_traces
 from zero_transformer_tpu.ops.positions import NEG_INF, alibi_slopes
+from zero_transformer_tpu.parallel.sharding import shard_kernel
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
@@ -271,7 +269,7 @@ def _offsets_arg(q_offset, kv_offset, B: int) -> jax.Array:
 
 
 def _smem_spec():
-    return pl.BlockSpec(memory_space=pltpu.SMEM if pltpu else None)
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _ids_args(q_ids, k_ids, B, T, S):
@@ -308,6 +306,7 @@ def _fwd(q, k, v, causal, alibi, scale, block_q, block_k, interpret,
 
     if slopes is None:
         slopes = _slopes_arg(H, alibi)
+    kernel_traces["flash_fwd"] += 1
     q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
     kv_spec = pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h // G, j, 0))
     qid_spec = pl.BlockSpec((1, 1, block_q), lambda b, h, i, j: (b, 0, i))
@@ -357,6 +356,7 @@ def _bwd(q, k, v, o, lse, do, causal, alibi, scale, block_q, block_k, interpret,
     if slopes is None:
         slopes = _slopes_arg(H, alibi)
     offs = _offsets_arg(q_offset, kv_offset, B)
+    kernel_traces["flash_bwd"] += 1
     q_spec_iq = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
     kv_spec_iq = pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h // G, j, 0))
     row_spec_iq = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0))
@@ -418,28 +418,79 @@ def _bwd(q, k, v, o, lse, do, causal, alibi, scale, block_q, block_k, interpret,
     return dq, dk, dv
 
 
+# logical activation names of the kernels' operands, for shard_kernel
+_Q = ("batch", None, "heads", None)  # q / o / do / dq   [B, T, H, D]
+_KV = ("batch", None, "kvheads", None)  # k / v / dk / dv  [B, S, KVH, D]
+_LSE = ("batch", "heads", None, None)  # [B, H, T, 1]
+_ROWS = ("batch", None)  # doc ids [B, T], kv validity [B, S]
+_SLOPES = ("heads", None)  # [H, 1]
+
+
+def _mesh_fwd(q, k, v, slopes, doc_ids, segment_ids, q_offset,
+              causal, alibi, scale, block_q, block_k, interpret):
+    """``_fwd`` for the dispatch-site entries (``flash_attention``,
+    ``flash_serving``), under ``shard_kernel``: on a mesh each device runs
+    the kernel on its own batch rows and heads. The ALiBi slope table is an
+    explicit operand so a head shard gets ITS heads' slopes; offsets go
+    per-row so they split with the batch. The context-parallel engines call
+    ``flash_partial``/``flash_grads`` from inside their own shard_maps and
+    do not come through here."""
+    B, _, H, _ = q.shape
+    if slopes is None:
+        slopes = _slopes_arg(H, alibi)
+    offs = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32).reshape(-1), (B,))
+    rows = [x for x in (doc_ids, segment_ids) if x is not None]
+
+    def local(q, k, v, slopes, offs, *rows):
+        rows = iter(rows)
+        ids = None if doc_ids is None else next(rows)
+        seg = None if segment_ids is None else next(rows)
+        return _fwd(q, k, v, causal, alibi, scale, block_q, block_k, interpret,
+                    q_offset=offs, slopes=slopes, q_ids=ids, k_ids=ids,
+                    segment_ids=seg)
+
+    return shard_kernel(
+        local,
+        (_Q, _KV, _KV, _SLOPES, ("batch",), *(_ROWS for _ in rows)),
+        (_Q, _LSE),
+    )(q, k, v, slopes, offs, *rows)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, doc_ids, slopes, causal, alibi, scale, block_q, block_k, interpret):
     # doc_ids: [B, T] float32 (or None) — f32 so its zero cotangent below is
     # a plain zeros_like rather than float0 plumbing. slopes: [H, 1] f32 (or
     # None) overriding the ALiBi table for head-sharded callers (ulysses/TP).
-    o, _ = _fwd(q, k, v, causal, alibi, scale, block_q, block_k, interpret,
-                slopes=slopes, q_ids=doc_ids, k_ids=doc_ids)
+    o, _ = _mesh_fwd(q, k, v, slopes, doc_ids, None, 0,
+                     causal, alibi, scale, block_q, block_k, interpret)
     return o
 
 
 def _flash_fwd(q, k, v, doc_ids, slopes, causal, alibi, scale, block_q, block_k, interpret):
-    o, lse = _fwd(q, k, v, causal, alibi, scale, block_q, block_k, interpret,
-                  slopes=slopes, q_ids=doc_ids, k_ids=doc_ids)
+    o, lse = _mesh_fwd(q, k, v, slopes, doc_ids, None, 0,
+                       causal, alibi, scale, block_q, block_k, interpret)
     return o, (q, k, v, doc_ids, slopes, o, lse)
 
 
 def _flash_bwd(causal, alibi, scale, block_q, block_k, interpret, res, do):
     q, k, v, doc_ids, slopes, o, lse = res
-    dq, dk, dv = _bwd(
-        q, k, v, o, lse, do, causal, alibi, scale, block_q, block_k, interpret,
-        slopes=slopes, q_ids=doc_ids, k_ids=doc_ids,
-    )
+    docs = () if doc_ids is None else (doc_ids,)
+    table = _slopes_arg(q.shape[2], alibi) if slopes is None else slopes
+
+    def local(q, k, v, o, lse, do, slopes, *docs):
+        ids = docs[0] if docs else None
+        return _bwd(
+            q, k, v, o, lse, do, causal, alibi, scale, block_q, block_k,
+            interpret, slopes=slopes, q_ids=ids, k_ids=ids,
+        )
+
+    # the backward kernels get their OWN shard_map (same specs as the
+    # forward's) — jax never transposes one
+    dq, dk, dv = shard_kernel(
+        local,
+        (_Q, _KV, _KV, _Q, _LSE, _Q, _SLOPES, *(_ROWS for _ in docs)),
+        (_Q, _KV, _KV),
+    )(q, k, v, o, lse, do, table, *docs)
     d_ids = None if doc_ids is None else jnp.zeros_like(doc_ids)
     d_slopes = None if slopes is None else jnp.zeros_like(slopes)
     return dq, dk, dv, d_ids, d_slopes
@@ -540,10 +591,9 @@ def flash_serving(
         slopes = jax.lax.stop_gradient(slopes).reshape(-1, 1).astype(jnp.float32)
     block_q, block_k = _resolve_blocks(T, S, block, None, None)
     scale = softmax_scale if softmax_scale is not None else 1.0 / (D**0.5)
-    o, _ = _fwd(
-        q, k, v, causal, alibi, float(scale), block_q, block_k, interpret,
-        q_offset=q_offset, kv_offset=0, slopes=slopes,
-        segment_ids=segment_ids,
+    o, _ = _mesh_fwd(
+        q, k, v, slopes, None, segment_ids, q_offset,
+        causal, alibi, float(scale), block_q, block_k, interpret,
     )
     return o
 

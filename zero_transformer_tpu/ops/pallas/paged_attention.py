@@ -16,20 +16,30 @@ the page walk, and no slab view ever exists. int8 KV pages dequantize
 in-register (per-page scale blocks ride the same index map) on their way
 into the VMEM K/V scratch.
 
-Bit-exactness contract: the kernel computes, per (row, kv-head), the exact
-op sequence of the gather path (``jnp.take`` + ``ops.attention.xla_attention``
-per-row branch) — same dot shapes per contraction, same f32 bias add order,
-same ``jax.nn.softmax`` reduction, same output-dot dtypes — so its output is
-bit-identical to the gather path on the same backend (pinned by
-``tests/test_paged_kernel.py`` across page sizes, ragged tables, trash-page
-rows, and int8 scales). Swapping the read path can therefore never change a
-served token.
+Exactness contract. The kernel computes, per row, the op sequence of the
+gather path (``jnp.take`` + ``ops.attention.xla_attention`` per-row
+branch): scores in f32, the scalar scale multiply, one bias add (ALiBi with
+the causal mask folded in), a second validity add, ``jax.nn.softmax`` in
+f32, weights rounded to the compute dtype, the output matmul accumulated in
+f32 and rounded once. What the two paths may NOT share is the order in
+which a backend sums a contraction or a softmax row: the kernel's layouts
+are the ones Mosaic lowers (kv-head-batched 3-D matmuls over a head-major
+scratch), not the gather path's 5-D einsums, which the chip's compiler
+refuses. So the bar is:
 
-VMEM note: the whole row's K/V lands in a ``[cache_len, D]`` scratch pair
-per (row, head) — at D=128 bf16 that is 0.5 MB per 1k cache positions, so
-decode contexts to ~8k fit comfortably; past that, a production variant
-would switch to an online-softmax page walk (and forfeit the bitwise
-contract vs the full-softmax slab path).
+- interpret mode (CPU): output within 1 ulp (bf16) / 4 ulp (f32; observed
+  1-2) of the gather path at the output's scale, and a served token stream
+  byte-identical to the gather engine's (``tests/test_paged_kernel.py``);
+- on the chip: greedy token streams identical to ``attention_impl: xla``
+  and logits within bf16 rounding of it — checked by ``chip_smoke.py``,
+  the only place the Mosaic-compiled kernel's numbers exist.
+
+VMEM note: the whole row's K/V lands in a ``[KVH, cache_len, D]`` scratch
+pair — 8 MiB at 16 heads x 1k positions x D=128 bf16 — beside f32
+score-sized temporaries. The wrapper asks Mosaic for that much scoped VMEM
+and the gate declines what would not fit the core's 128 MiB with room to
+spare; past that, a production variant would switch to an online-softmax
+page walk.
 """
 from __future__ import annotations
 
@@ -41,12 +51,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu imports on CPU builds too; guard anyway
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
+from zero_transformer_tpu.ops.pallas import kernel_traces
 from zero_transformer_tpu.ops.positions import NEG_INF, alibi_slopes
+from zero_transformer_tpu.parallel.sharding import kernel_shardable, shard_kernel
 
 # decode window ceiling: 1 (plain decode) .. 1 + draft_k (spec verify).
 # Larger query windows belong to the flash kernel's chunked-prefill path.
@@ -61,11 +70,28 @@ def interpret_requested() -> bool:
     return os.environ.get("ZT_PALLAS_INTERPRET", "") == "1"
 
 
+# scoped-VMEM budget the gate admits (v5e has 128 MiB per core; leave
+# headroom for the pipelined page blocks and Mosaic's own stack)
+VMEM_CEILING = 96 << 20
+
+
+def vmem_bytes(*, T: int, H: int, KVH: int, D: int, S: int, dtype) -> int:
+    """Scoped VMEM one grid step needs: the K/V scratch pair plus the f32
+    score-shaped temporaries (scores, bias, exp, weights — rows pad to the
+    8-sublane tile) and a fixed allowance for the double-buffered blocks."""
+    rows = -(-(T * (H // KVH)) // 8) * 8
+    scratch = 2 * KVH * S * D * jnp.dtype(dtype).itemsize
+    return scratch + 6 * KVH * rows * S * 4 + (8 << 20)
+
+
 def supported(
     impl: str,
     *,
     T: int,
+    H: int,
+    KVH: int,
     D: int,
+    S: int,
     page_size: int,
     dtype,
     interpret: bool = False,
@@ -77,6 +103,7 @@ def supported(
     "supported" and "will actually run" can never disagree. ``impl`` is
     ``cfg.attention_impl``; ``xla`` always declines (the gather path is the
     reference), ``auto``/``flash`` accept on TPU or under interpret mode.
+    ``S`` is the cache length (``n_blocks * page_size``).
     """
     if impl not in ("auto", "flash"):
         return False
@@ -85,6 +112,8 @@ def supported(
         return False
     if T < 1 or T > MAX_DECODE_T:
         return False  # decode/spec-verify windows only
+    if dtype not in (jnp.bfloat16, jnp.float32):
+        return False
     if on_tpu:
         # Mosaic lowering constraints — interpret mode (the CPU parity
         # lane) has no tiling and accepts any structurally valid shape
@@ -92,86 +121,96 @@ def supported(
             return False  # lane-dim alignment for the MXU
         if page_size % 8:
             return False  # sublane-aligned page copies into the K/V scratch
-    if dtype not in (jnp.bfloat16, jnp.float32):
-        return False
-    return True
+        if vmem_bytes(T=T, H=H, KVH=KVH, D=D, S=S, dtype=dtype) > VMEM_CEILING:
+            return False
+    # per-device call under shard_kernel: the tensor axis must divide heads
+    return kernel_shardable(heads=H, kvheads=KVH)
 
 
 def _kernel(
     # scalar-prefetch refs
     table_ref, offs_ref,
     # operands
-    slope_ref, q_ref, k_ref, v_ref, *args,
-    T: int, H: int, KVH: int, page: int, n_blocks: int, scale: float,
+    tq_ref, slope_ref, q_ref, k_ref, v_ref, *args,
+    T: int, KVH: int, D: int, page: int, n_blocks: int, scale: float,
     causal: bool, alibi: bool, int8: bool,
 ):
     """One row's attention over its paged K/V, ALL heads per grid step.
 
     Grid (B, n_blocks): step j copies page ``table[b, j]``'s block —
-    already pipelined into VMEM by the index map — into the K/V scratch at
-    its logical position (dequantized when int8); the final step runs the
-    full-softmax attention with the gather path's exact einsum subscripts.
-    Keeping the kv-head axis INSIDE the contraction (a batch dim of the
-    einsum, not a grid dim) is load-bearing for the bitwise contract: XLA
-    lowers a per-head 2-D dot through a different gemm path than the
-    reference's batched einsum, and the two differ by ulps at M=1."""
+    already pipelined into VMEM by the index map — into the head-major
+    K/V scratch at its logical position (dequantized when int8); the final
+    step runs the full-softmax attention as ONE kv-head-batched matmul
+    pair. Keeping the kv-head axis a BATCH dim of the contraction (not a
+    grid dim or a Python loop of 2-D dots) is load-bearing twice: it is
+    the batched-matmul form Mosaic lowers to the MXU, and on the CPU
+    interpret lane XLA sends a per-head 2-D dot through a different gemm
+    path than the reference's batched einsum (ulps apart at M=1).
+
+    Layouts (all chosen so every slice the kernel takes is a static lane
+    slice or a page-aligned sublane window): q/out ``[KVH, M, D]`` with
+    row ``m = t * G + g``; a pool block ``[page, KVH * D]``; an int8 scale
+    block ``[page, KVH]``; ``tq`` ``[M, 1]`` the window position ``t`` of
+    row m; ``slope`` ``[KVH, M, 1]`` the ALiBi slope of (head, row)."""
     # arg order: remaining inputs (int8 scale blocks), the output ref,
     # then the scratch buffers
-    G = H // KVH
     if int8:
-        ks_ref, vs_ref = args[0], args[1]
-        o_scr, k_scr, v_scr = args[2], args[3], args[4]
+        ks_ref, vs_ref, o_ref, k_scr, v_scr = args
     else:
-        o_scr, k_scr, v_scr = args[0], args[1], args[2]
+        o_ref, k_scr, v_scr = args
     b, j = pl.program_id(0), pl.program_id(1)
     S = n_blocks * page
+    M = q_ref.shape[2]
+    rows = pl.ds(pl.multiple_of(j * page, page), page)
 
-    kb = k_ref[0]  # [page, KVH, D]
-    vb = v_ref[0]
-    if int8:
-        # exact mirror of the gather path's dequant:
-        # (int8 -> f32) * f32 scale -> compute dtype, elementwise
-        kb = (kb.astype(jnp.float32) * ks_ref[0]).astype(k_scr.dtype)
-        vb = (vb.astype(jnp.float32) * vs_ref[0]).astype(v_scr.dtype)
-    k_scr[pl.ds(j * page, page), :, :] = kb.astype(k_scr.dtype)
-    v_scr[pl.ds(j * page, page), :, :] = vb.astype(v_scr.dtype)
+    for h in range(KVH):
+        lanes = slice(h * D, (h + 1) * D)
+        kb = k_ref[0, :, lanes]  # [page, D]
+        vb = v_ref[0, :, lanes]
+        if int8:
+            # exact mirror of the gather path's dequant:
+            # (int8 -> f32) * f32 scale -> compute dtype, elementwise
+            kb = kb.astype(jnp.float32) * ks_ref[0, :, h:h + 1]
+            vb = vb.astype(jnp.float32) * vs_ref[0, :, h:h + 1]
+        k_scr[h, rows, :] = kb.astype(k_scr.dtype)
+        v_scr[h, rows, :] = vb.astype(v_scr.dtype)
 
     @pl.when(j == n_blocks - 1)
     def _compute():
         off = offs_ref[b]
-        qg = q_ref[0]  # [T, KVH, G, D]
-        # scores einsum with the REFERENCE's subscripts (kvh stays a batch
-        # dim), in f32, THEN the scalar scale multiply — xla_attention's
+        # scores in f32, THEN the scalar scale multiply — xla_attention's
         # exact order
         s = jnp.einsum(
-            "tkgd,skd->kgts", qg, k_scr[:],
+            "hmd,hsd->hms", q_ref[0], k_scr[...],
             preferred_element_type=jnp.float32,
         )
-        s = s * jnp.float32(scale)  # [KVH, G, T, S]
-        q_pos = off + jax.lax.broadcasted_iota(jnp.int32, (T, S), 0)
-        kv_pos = jax.lax.broadcasted_iota(jnp.int32, (T, S), 1)
+        s = s * jnp.float32(scale)  # [KVH, M, S]
+        q_pos = off + tq_ref[...]  # [M, 1]
+        kv_pos = jax.lax.broadcasted_iota(jnp.int32, (M, S), 1)
         if alibi:
             # xla per-row branch: bias = -slope*dist (+ causal NEG_INF
             # folded into the SAME bias tensor), ONE add onto the scores
-            dist = jnp.maximum(q_pos - kv_pos, 0).astype(jnp.float32)  # [T, S]
-            sl = jnp.stack(
-                [slope_ref[i, 0] for i in range(H)]
-            ).reshape(KVH, G)
-            bias = -sl[:, :, None, None] * dist[None, None, :, :]
+            dist = jnp.maximum(q_pos - kv_pos, 0).astype(jnp.float32)  # [M, S]
+            bias = -slope_ref[...] * dist[None]  # [KVH, M, S]
             if causal:
                 visible = kv_pos <= q_pos
-                bias = bias + jnp.where(visible, 0.0, NEG_INF)[None, None, :, :]
+                bias = bias + jnp.where(visible, 0.0, NEG_INF)[None]
             s = s + bias
         elif causal:
             visible = kv_pos <= q_pos
-            s = s + jnp.where(visible, 0.0, NEG_INF)[None, None, :, :]
+            s = s + jnp.where(visible, 0.0, NEG_INF)[None]
         # validity pad is its own SECOND add, exactly like the xla path's
         # segment_ids term (order matters for the bitwise contract)
         valid = kv_pos[:1, :] < off + T  # [1, S]
-        s = s + jnp.where(valid, 0.0, NEG_INF)[None, None, :, :]
-        w = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(k_scr.dtype)
-        out = jnp.einsum("kgts,skd->tkgd", w, v_scr[:])
-        o_scr[0] = out.astype(o_scr.dtype)
+        s = s + jnp.where(valid, 0.0, NEG_INF)[None]
+        w = jax.nn.softmax(s, axis=-1).astype(v_scr.dtype)
+        # f32 accumulation (the MXU has no narrower accumulator), rounded
+        # ONCE into the compute dtype
+        out = jnp.einsum(
+            "hms,hsd->hmd", w, v_scr[...],
+            preferred_element_type=jnp.float32,
+        )
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 # graftlint: hot-path
@@ -200,41 +239,86 @@ def paged_attention(
     position ``q_offset[r]``, and positions ``>= q_offset[r] + T`` are
     masked invalid, the gather path's ``kv_valid``.
 
-    Forward-only (the decode path never differentiates). Output is
-    bit-identical to gather-to-slab + ``xla_attention`` on the same
-    backend — see the module docstring for why that holds by construction.
+    Forward-only (the decode path never differentiates). How close the
+    output is to gather-to-slab + ``xla_attention`` is the module
+    docstring's exactness contract.
     """
     B, T, H, D = q.shape
-    n_pages, page, KVH, _ = k_pool.shape
+    KVH = k_pool.shape[2]
     if H % KVH:
         raise ValueError(f"query heads {H} not divisible by kv heads {KVH}")
-    G = H // KVH
-    _, n_blocks = block_table.shape
-    S = n_blocks * page
     int8 = k_pool.dtype == jnp.int8
     if int8 and (k_scale is None or v_scale is None):
         raise ValueError("int8 pools need k_scale/v_scale pools")
     scale = softmax_scale if softmax_scale is not None else 1.0 / (D**0.5)
-    dtype = q.dtype
-
     offs = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32).reshape(-1), (B,))
     if slopes is None:
         slopes = alibi_slopes(H) if alibi else jnp.zeros((H,), jnp.float32)
-    slopes = slopes.reshape(H, 1).astype(jnp.float32)
-    q5 = q.reshape(B, T, KVH, G, D)
+    interpret = interpret or (
+        jax.default_backend() != "tpu" and interpret_requested()
+    )
+    scales = (k_scale, v_scale) if int8 else ()
+
+    local = functools.partial(
+        _paged_local, scale=float(scale), causal=causal, alibi=alibi,
+        interpret=interpret,
+    )
+    # on a mesh (``serve --tensor N``) each device walks ITS kv heads' pages
+    q_names = ("batch", None, "heads", None)
+    pool = (None, None, "kvheads", None)
+    (out,) = shard_kernel(
+        lambda *operands: (local(*operands),),
+        (q_names, pool, pool, ("batch", None), ("batch",), ("heads",),
+         *(pool for _ in scales)),
+        (q_names,),
+    )(q, k_pool, v_pool, block_table.astype(jnp.int32), offs,
+      slopes.reshape(H).astype(jnp.float32), *scales)
+    return out
+
+
+def _paged_local(q, k_pool, v_pool, block_table, offs, slopes,
+                 k_scale=None, v_scale=None, *, scale, causal, alibi,
+                 interpret):
+    """One device's kernel call on its rows and heads (see
+    ``paged_attention``)."""
+    B, T, H, D = q.shape
+    n_pages, page, KVH, _ = k_pool.shape
+    G = H // KVH
+    _, n_blocks = block_table.shape
+    S = n_blocks * page
+    int8 = k_pool.dtype == jnp.int8
+    dtype = q.dtype
+    kernel_traces["paged_attention"] += 1
+    # kernel layouts (see _kernel): q rows m = t * G + g under each kv head;
+    # the pool's (KVH, D) minor dims merge into one lane axis (a free
+    # reshape — the pool is never copied)
+    M = T * G
+    qk = q.reshape(B, T, KVH, G, D).transpose(0, 2, 1, 3, 4).reshape(B, KVH, M, D)
+    tq = jnp.repeat(jnp.arange(T, dtype=jnp.int32), G).reshape(M, 1)
+    slope = jnp.broadcast_to(
+        slopes.reshape(KVH, 1, G), (KVH, T, G)
+    ).reshape(KVH, M, 1)
+    k_pool = k_pool.reshape(n_pages, page, KVH * D)
+    v_pool = v_pool.reshape(n_pages, page, KVH * D)
 
     # index maps receive the scalar-prefetch refs (table, offsets) last;
     # the page axis of every pool operand resolves through the table — the
     # pipelined block fetch IS the page walk
-    qo_spec = pl.BlockSpec((1, T, KVH, G, D), lambda b, j, tbl, off: (b, 0, 0, 0, 0))
-    kv_spec = pl.BlockSpec((1, page, KVH, D), lambda b, j, tbl, off: (tbl[b, j], 0, 0, 0))
-    sc_spec = pl.BlockSpec((1, page, KVH, 1), lambda b, j, tbl, off: (tbl[b, j], 0, 0, 0))
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM if pltpu else None)
-    in_specs = [smem, qo_spec, kv_spec, kv_spec]
-    operands = [slopes, q5, k_pool, v_pool]
+    qo_spec = pl.BlockSpec((1, KVH, M, D), lambda b, j, tbl, off: (b, 0, 0, 0))
+    kv_spec = pl.BlockSpec((1, page, KVH * D), lambda b, j, tbl, off: (tbl[b, j], 0, 0))
+    sc_spec = pl.BlockSpec((1, page, KVH), lambda b, j, tbl, off: (tbl[b, j], 0, 0))
+    in_specs = [
+        pl.BlockSpec((M, 1), lambda b, j, tbl, off: (0, 0)),
+        pl.BlockSpec((KVH, M, 1), lambda b, j, tbl, off: (0, 0, 0)),
+        qo_spec, kv_spec, kv_spec,
+    ]
+    operands = [tq, slope, qk, k_pool, v_pool]
     if int8:
         in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
+        operands += [
+            k_scale.reshape(n_pages, page, KVH),
+            v_scale.reshape(n_pages, page, KVH),
+        ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -242,17 +326,23 @@ def paged_attention(
         in_specs=in_specs,
         out_specs=qo_spec,
         scratch_shapes=[
-            pltpu.VMEM((S, KVH, D), dtype),
-            pltpu.VMEM((S, KVH, D), dtype),
+            pltpu.VMEM((KVH, S, D), dtype),
+            pltpu.VMEM((KVH, S, D), dtype),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _kernel, T=T, H=H, KVH=KVH, page=page, n_blocks=n_blocks,
-            scale=float(scale), causal=causal, alibi=alibi, int8=int8,
+            _kernel, T=T, KVH=KVH, D=D, page=page, n_blocks=n_blocks,
+            scale=scale, causal=causal, alibi=alibi, int8=int8,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T, KVH, G, D), dtype),
-        interpret=interpret or (jax.default_backend() != "tpu" and interpret_requested()),
-    )(block_table.astype(jnp.int32), offs, *operands)
-    return out.reshape(B, T, H, D)
+        out_shape=jax.ShapeDtypeStruct((B, KVH, M, D), dtype),
+        name="paged_attention",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_bytes(T=T, H=H, KVH=KVH, D=D, S=S, dtype=dtype)
+        ),
+        interpret=interpret,
+    )(block_table, offs, *operands)
+    return (
+        out.reshape(B, KVH, T, G, D).transpose(0, 2, 1, 3, 4).reshape(B, T, H, D)
+    )

@@ -11,18 +11,15 @@ Cases:
   and gradients, few-ulp vs ``xla_attention``;
 - ``flash_serving_offsets_mask`` — the serving entry (per-row offsets +
   kv-validity mask), few-ulp;
-- ``paged_decode_vs_gather`` — the paged decode kernel, BITWISE vs the
+- ``paged_decode_vs_gather`` — the paged decode kernel, few-ulp vs the
   gather-to-slab path it replaces. Both sides run under jit with the
-  gather INSIDE the reference program: the engine's fused step computes
-  take + attention in one compiled program, and that is the program the
-  bitwise contract is defined against (different jit boundaries fuse
-  differently).
+  gather INSIDE the reference program, as the engine's fused step computes
+  take + attention in one compiled program.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 FWD_TOL = 3e-5
 BWD_TOL = 3e-4
@@ -78,7 +75,8 @@ def interpret_parity_report() -> dict:
         "max_abs_diff_fwd": sdiff, "ok": sdiff < FWD_TOL,
     })
 
-    # paged decode kernel: BITWISE vs the gather-to-slab path it replaces
+    # paged decode kernel vs the gather-to-slab path it replaces: f32
+    # few-ulp (summation order only — the kernel module's exactness contract)
     page, n_blocks = 16, 4
     n_pages = 12
     S = page * n_blocks
@@ -100,10 +98,10 @@ def interpret_parity_report() -> dict:
     out = jax.jit(lambda q, kp, vp, t, o: paged_attention(
         q, kp, vp, t, o, causal=False, alibi=True, interpret=True,
     ))(q[:, :1], kp, vp, table, doff)
-    bitwise = bool(np.array_equal(np.asarray(ref), np.asarray(out)))
+    pdiff = float(jnp.max(jnp.abs(ref - out)))
     cases.append({
         "case": "paged_decode_vs_gather", "shape": [B, 1, H, D],
-        "page_size": page, "bitwise": bitwise, "ok": bitwise,
+        "page_size": page, "max_abs_diff_fwd": pdiff, "ok": pdiff < FWD_TOL,
     })
 
     return {

@@ -36,7 +36,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from zero_transformer_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from zero_transformer_tpu.ops.positions import NEG_INF, alibi_slopes
@@ -82,12 +82,10 @@ def _engine_ctx(mesh: Mesh, specs: tuple):
     ambient ABSTRACT mesh, whose axis types record what is already manual
     (a concrete all-Auto mesh is rejected inside the region).
     """
-    from zero_transformer_tpu.utils.jax_compat import get_abstract_mesh
-
-    amesh = get_abstract_mesh()
+    amesh = jax.sharding.get_abstract_mesh()
     ctx_manual: set = set()
     mesh_arg = mesh
-    if amesh is not None and amesh.axis_names and dict(amesh.shape) == dict(mesh.shape):
+    if amesh.axis_names and dict(amesh.shape) == dict(mesh.shape):
         ctx_manual = {
             name for name, t in zip(amesh.axis_names, amesh.axis_types)
             if t == jax.sharding.AxisType.Manual

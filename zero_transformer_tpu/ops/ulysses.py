@@ -37,7 +37,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from zero_transformer_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from zero_transformer_tpu.ops.attention import xla_attention

@@ -61,7 +61,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from zero_transformer_tpu.config import resolve_dtype
 from zero_transformer_tpu.ops.losses import chunked_next_token_loss, next_token_loss
 from zero_transformer_tpu.parallel import sharding as shd
-from zero_transformer_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 @dataclasses.dataclass(frozen=True)

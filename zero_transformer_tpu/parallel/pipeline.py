@@ -35,7 +35,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import optax
-from zero_transformer_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from zero_transformer_tpu.config import resolve_dtype

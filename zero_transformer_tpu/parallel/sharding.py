@@ -377,10 +377,8 @@ def constrain_activation(x: jax.Array, *names: Optional[str]) -> jax.Array:
     This is the Megatron "other half": without activation constraints GSPMD
     alone chooses TP activation layouts (round-3 VERDICT weak #3).
     """
-    from zero_transformer_tpu.utils.jax_compat import get_abstract_mesh
-
-    amesh = get_abstract_mesh()
-    if amesh is None or not amesh.axis_names:
+    amesh = jax.sharding.get_abstract_mesh()
+    if not amesh.axis_names:
         return x
     auto = {
         n for n, t in zip(amesh.axis_names, amesh.axis_types)
@@ -402,6 +400,85 @@ def constrain_activation(x: jax.Array, *names: Optional[str]) -> jax.Array:
     return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
+def _ambient_auto_axes() -> tuple:
+    """(abstract mesh, its Auto axis names) of the current trace scope —
+    ``(None, ())`` without an ambient mesh or inside a fully-manual one."""
+    amesh = jax.sharding.get_abstract_mesh()
+    auto = tuple(
+        n for n, t in zip(amesh.axis_names, amesh.axis_types)
+        if t == jax.sharding.AxisType.Auto
+    )
+    return (amesh, auto) if auto else (None, ())
+
+
+def _kernel_axes(name: Optional[str], size: int, amesh, auto) -> tuple:
+    """(the Auto mesh axes of size > 1 the activation dim ``name`` splits
+    over, whether they divide a dim of ``size``)."""
+    axes = ACTIVATION_RULES.get(name) if name else None
+    axes = (axes,) if isinstance(axes, str) else (axes or ())
+    axes = tuple(a for a in axes if a in auto and amesh.shape[a] > 1)
+    return axes, size % math.prod(amesh.shape[a] for a in axes) == 0
+
+
+def kernel_shardable(**dims: int) -> bool:
+    """Do the ambient mesh's axes divide dims of these sizes (keyed by
+    logical activation name, e.g. ``batch=8, heads=16``)? True without a
+    mesh. ``shard_kernel`` runs a dim they do not divide WHOLE on every
+    device — correct, but every device then computes all of it — so the
+    kernel gates ask this first: ``auto`` takes the XLA path there (logged
+    once) and ``flash`` raises."""
+    amesh, auto = _ambient_auto_axes()
+    return all(
+        _kernel_axes(name, size, amesh, auto)[1] for name, size in dims.items()
+    )
+
+
+def shard_kernel(fn, in_names, out_names):
+    """Wrap a Pallas-kernel call in ``shard_map`` over the ambient mesh.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned") and refuses one that merely sits under Auto
+    mesh axes — size-1 axes included, which is how the explicit ZeRO-2/3
+    core (manual data/fsdp, auto everything else) failed on a single chip.
+    So every Auto axis of the ambient mesh becomes manual around the call:
+    operands split by their logical activation names (``in_names`` /
+    ``out_names``: one tuple of names per positional array argument / per
+    element of the tuple ``fn`` returns, ``ACTIVATION_RULES`` vocabulary —
+    batch over data/fsdp, heads over tensor) and are replicated over every
+    other axis; a dim its axes do not divide stays whole. Axes already
+    manual (the ZeRO core's) arrive pre-sliced and are left alone. Returns
+    ``fn`` itself when there is no ambient mesh or nothing is left to
+    manualize.
+
+    Keep this INSIDE any custom VJP: forward and backward kernels each get
+    their own shard_map, so jax never has to transpose one."""
+    amesh, auto = _ambient_auto_axes()
+    if not auto:
+        return fn
+
+    def call(*operands):
+        split = {}  # name -> spec entry, decided by the first dim that carries it
+        for names, x in zip(in_names, operands):
+            for name, size in zip(names, x.shape):
+                axes, divides = _kernel_axes(name, size, amesh, auto)
+                split.setdefault(name, (axes or None) if divides else None)
+
+        def spec(names):
+            return P(*(split.get(n) for n in names))
+
+        return jax.shard_map(
+            fn,
+            in_specs=tuple(spec(n) for n in in_names),
+            out_specs=tuple(spec(n) for n in out_names),
+            # ALL axes, the already-manual ones included: Mosaic's lowering
+            # wants the innermost shard_map itself to name every mesh axis
+            axis_names=frozenset(amesh.axis_names),
+            check_vma=False,
+        )(*operands)
+
+    return call
+
+
 def replicate_activation(x: jax.Array) -> jax.Array:
     """Constrain ``x`` to full replication over the ambient auto mesh.
 
@@ -411,10 +488,8 @@ def replicate_activation(x: jax.Array) -> jax.Array:
     where one up-front all-gather beats the involuntary full
     rematerialization GSPMD otherwise inserts on the gather output. No-op
     without an ambient mesh."""
-    from zero_transformer_tpu.utils.jax_compat import get_abstract_mesh
-
-    amesh = get_abstract_mesh()
-    if amesh is None or not amesh.axis_names:
+    amesh = jax.sharding.get_abstract_mesh()
+    if not amesh.axis_names:
         return x
     return jax.lax.with_sharding_constraint(x, P(*(None,) * x.ndim))
 

@@ -653,6 +653,9 @@ def main(argv=None) -> None:
                         "finish, then are force-finished and the process "
                         "exits 0")
     args = _resolve_tuned_args(p.parse_args(argv))
+    from zero_transformer_tpu.utils import compile_cache
+
+    compile_cache.configure()
 
     gen = _build_generator(args)
     if args.server:

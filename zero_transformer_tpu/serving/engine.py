@@ -1035,7 +1035,10 @@ class ServingEngine:
         self._paged_kernel = kv_layout == "paged" and _pa.supported(
             cfg.attention_impl,
             T=1 + self.draft_k if self.draft_k else 1,
+            H=cfg.n_heads,
+            KVH=cfg.kv_heads,
             D=cfg.head_width,
+            S=self.cache_len,
             page_size=self.page_size,
             dtype=resolve_dtype(cfg.compute_dtype),
         )

@@ -1,103 +1,21 @@
-"""Version tolerance for the handful of new-jax APIs this repo leans on.
+"""Two small helpers around jax's ambient-mesh and donation seams.
 
-The codebase targets the modern ambient-mesh jax surface (``jax.shard_map``,
-``jax.set_mesh``, ``jax.sharding.use_abstract_mesh``); some deployment images
-pin an older jax (0.4.x) where those names either live elsewhere
-(``jax.experimental.shard_map``) or do not exist at all (the ambient-mesh
-machinery). Import sites go through this module so one place owns the
-translation:
-
-- ``shard_map``: the new keyword surface (``axis_names`` = the MANUAL axes,
-  ``check_vma``) translated to the experimental API's complement form
-  (``auto`` = the axes left automatic, ``check_rep``) when needed;
-- ``set_mesh``: falls back to the legacy ``with mesh:`` context — on old jax
-  that is what resolves bare-PartitionSpec ``with_sharding_constraint`` calls;
-- ``use_abstract_mesh`` / ``clear_abstract_mesh``: no-ops on old jax, where
-  there is no ambient abstract mesh to leak into flax's param boxing;
-- ``get_abstract_mesh``: returns None on old jax, which callers treat as
-  "no ambient mesh" (``parallel.sharding.constrain_activation`` no-ops).
-
-Nothing here changes behavior on a modern jax: every symbol resolves to the
-real API when it exists.
+The codebase calls the installed jax (0.9) directly — ``jax.shard_map``,
+``jax.set_mesh``, ``jax.sharding.get_abstract_mesh``; what lives here is
+what is not a one-liner: ``clear_abstract_mesh`` and the donation-safety
+copy ``ensure_donatable``.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
 
-HAS_AMBIENT_MESH = hasattr(jax, "set_mesh") and hasattr(
-    jax.sharding, "use_abstract_mesh"
-)
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
+def clear_abstract_mesh():
+    """Context clearing the ambient mesh (see ``inference.generate``:
+    flax boxing must not read logical axis names as mesh axes)."""
+    from jax.sharding import AbstractMesh
 
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=None, **kwargs):
-        """New-surface ``jax.shard_map`` on the experimental implementation.
-
-        ``axis_names`` (manual axes) becomes ``auto`` (its complement);
-        ``check_vma`` maps onto ``check_rep``.
-        """
-        if axis_names is not None:
-            kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if check_vma is not None:
-            kwargs["check_rep"] = bool(check_vma)
-        return _exp_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-
-
-if hasattr(jax, "set_mesh"):
-    set_mesh = jax.set_mesh
-else:
-
-    @contextlib.contextmanager
-    def set_mesh(mesh):
-        # legacy ambient mesh: the Mesh context manager is what pre-ambient
-        # jax used to resolve unqualified sharding constraints
-        with mesh:
-            yield mesh
-
-
-if HAS_AMBIENT_MESH:
-    use_abstract_mesh = jax.sharding.use_abstract_mesh
-
-    def clear_abstract_mesh():
-        """Context clearing the ambient mesh (see ``inference.generate``:
-        flax boxing must not read logical axis names as mesh axes)."""
-        from jax.sharding import AbstractMesh
-
-        return jax.sharding.use_abstract_mesh(AbstractMesh((), ()))
-
-    get_abstract_mesh = jax.sharding.get_abstract_mesh
-else:
-
-    @contextlib.contextmanager
-    def use_abstract_mesh(mesh):
-        yield mesh
-
-    @contextlib.contextmanager
-    def clear_abstract_mesh():
-        # old jax has no abstract mesh, but the hazard this guards against
-        # (flax boxing reading LOGICAL axis names as mesh axes during an
-        # eval_shape init) exists all the same under the legacy ``with mesh:``
-        # context that our ``set_mesh`` fallback enters — clear the legacy
-        # thread-resources mesh for the duration instead
-        from jax._src import mesh as _mesh_lib
-
-        prev = _mesh_lib.thread_resources.env
-        _mesh_lib.thread_resources.env = _mesh_lib.EMPTY_ENV
-        try:
-            yield
-        finally:
-            _mesh_lib.thread_resources.env = prev
-
-    def get_abstract_mesh():
-        return None
+    return jax.sharding.use_abstract_mesh(AbstractMesh((), ()))
 
 
 def ensure_donatable(tree):
@@ -105,10 +23,10 @@ def ensure_donatable(tree):
 
     ``jax.device_put`` from host numpy and orbax restores can hand back
     arrays whose buffers the runtime does NOT own (zero-copy views of host
-    memory). The train step donates its input state, and on jax 0.4.37's
-    CPU backend donating such a foreign buffer lets XLA recycle memory it
-    never owned — the state silently turns to garbage within a step or two
-    and glibc aborts with heap corruption. An eager add-0 per leaf runs a
+    memory). The train step donates its input state, and donating such a
+    foreign buffer lets XLA recycle memory it never owned — observed on the
+    CPU backend as state that silently turns to garbage within a step or two
+    and a glibc heap-corruption abort. An eager add-0 per leaf runs a
     real XLA computation, so every output buffer is freshly allocated and
     runtime-owned (shardings are preserved: eager ops follow their committed
     operands). Call this on ANY state that flows into a donating jit from

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from zero_transformer_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def _allreduce_count(devices) -> float:
