@@ -3,7 +3,7 @@
 points, on one TPU v5e chip — the quickest proof the system still starts
 there.
 
-    python chip_smoke.py             # one chip: train -> extract -> serve x3
+    python chip_smoke.py             # one chip: train -> extract -> kernels -> serve x3
     python chip_smoke.py --chips 4   # four chips: ZeRO-1/2 on data=4 vs one chip
 
 One process per chip: this parent never imports jax. It runs each phase as
@@ -22,6 +22,10 @@ Phases (one chip), all on the ``1_3b`` model at full width and depth:
   the checkpoint is restored once with digest verification and the compiled
   step is checked for the flash kernel (lowered text + trace counter).
 - extract: ``python -m zero_transformer_tpu.export extract`` on it.
+- kernels: the Mosaic-compiled paged decode kernel against the gather path
+  it replaces, at the server's shapes (``1_3b`` heads, page 16, cache 1024,
+  4 slots), decode and spec-verify windows, bf16 and int8 pages, random K/V
+  from the seed — the kernel module's on-chip bar (``PAGED_ULPS``).
 - serve (three servers, one after another): ``serve --server`` in bf16 with
   the user defaults (paged KV, attention-impl auto, chunked prefill, fused
   tail) plus ``--tokenizer bytes --greedy``: (a) ``--draft-k 4``,
@@ -29,6 +33,13 @@ Phases (one chip), all on the ``1_3b`` model at full width and depth:
   the same concurrent shared-prefix requests; /healthz must reach 200,
   /metrics must show the kernels and counters, SIGTERM must drain to exit 0,
   and every request's tokens must be identical across the three.
+
+Four chips (``--chips 4``), the recipe family with depth cut: the recipe's
+own adafactor at its 64k tokens a step on one chip, then ZeRO-1 and ZeRO-2 on
+``data=4`` (per-step losses compared, loss decreasing; adafactor's factored
+statistics are replicated by design, so they are held to "tiny" instead of
+"a quarter each"), then an adamw leg whose param-shaped state must sit a
+quarter on each chip.
 
 Everything is generated from a seed; output goes under ``chip_smoke_out/``
 (git-ignored, too big for ``chiprun_out/``); nothing untracked is read.
@@ -64,35 +75,47 @@ TRAIN_SETS = [
     "optimizer.warmup_steps=1",
 ]
 SERVE_MODEL = "1_3b"
+DRAFT_K = 4
 SERVE_ARGS = [
     "--tokenizer", "bytes", "--dtype", "bfloat16", "--greedy",
     "--repetition-penalty", "1.0", "--cache-len", "1024", "--slots", "4",
 ]
 SERVE_VARIANTS = {
-    "spec": ["--draft-k", "4"],
+    "spec": ["--draft-k", str(DRAFT_K)],
     "plain": ["--draft-k", "0"],
-    "xla": ["--attention-impl", "xla", "--draft-k", "4"],
+    "xla": ["--attention-impl", "xla", "--draft-k", str(DRAFT_K)],
 }
 N_REQUESTS = 7
 MAX_NEW_TOKENS = 24
+# the paged decode kernel's on-chip bar (ops/pallas/paged_attention.py): its
+# output within this many bf16 ulps, at the output's scale, of the gather
+# path's (the order of a sum alone accounts for up to 1)
+PAGED_ULPS = 2.0
 
-# ---- the four-chip run: same recipe family, depth cut, global batch 8.
-# adamw, not the recipe's adafactor: ZeRO shards optimizer state that is
-# shaped like the params (adam's mu/nu); adafactor's factored row/column
-# statistics are not, and ``opt_state_sharding`` replicates them — a first
-# four-chip run showed every device holding all of that (tiny) state. At 6
-# layers adamw's 12 bytes/param fit one chip, so the 1/4 check means something.
+# ---- the four-chip run: the recipe family (its own adafactor, its 64k
+# tokens a step as batch 8 x accum 8), depth cut to 6 layers
 ZERO_LAYERS = 6
 ZERO_STEPS = 6
 ZERO_SETS = TRAIN_SETS + [
     f"model.n_layers={ZERO_LAYERS}",
-    "optimizer.optimizer=adamw",
     "training.batch_size=8",
-    "training.gradient_accumulation_steps=2",
+    "training.gradient_accumulation_steps=8",
 ]
 # per-step |loss(4 chips) - loss(1 chip)|: same batch, seed and bf16 math,
 # a different reduction order across the data axis
 ZERO_LOSS_TOL = 0.02
+# adafactor keeps factored row/column statistics, O(rows + cols) a matrix,
+# replicated on every device BY DESIGN (training/optimizer.py: what ZeRO
+# shards there is the work, not the storage) — held to this share of the
+# params' bytes
+FACTORED_STATE_MAX = 0.02
+# what ZeRO does scatter is state shaped like the params: an adamw leg (mu
+# and nu, 8 bytes a param — fits four chips, not one) must sit 1/4 on each
+ZERO_ADAMW_SETS = ZERO_SETS[:-1] + [
+    "optimizer.optimizer=adamw",
+    "training.gradient_accumulation_steps=2",
+]
+ZERO_ADAMW_STEPS = 2
 
 
 def emit(obj: dict) -> None:
@@ -278,6 +301,39 @@ def extract_phase(out: Path, require_tpu: bool = True) -> dict:
             "seconds": round(time.perf_counter() - t0, 1)}
 
 
+def kernels_phase(model: str = SERVE_MODEL, slots: int = 4,
+                  cache_len: int = 1024, require_tpu: bool = True) -> dict:
+    """The paged decode kernel as the chip's compiler built it, against the
+    gather path it replaces, at the shapes the servers below decode with —
+    greedy tokens alone cannot tell a wrong mask from a right one on a
+    random-weight model that emits one token."""
+    device = device_or_exit(require_tpu)
+    import jax.numpy as jnp
+
+    from zero_transformer_tpu.config import ServingConfig, model_config
+    from zero_transformer_tpu.ops.pallas.parity import paged_vs_gather
+
+    cfg = model_config(model)
+    page = ServingConfig().page_size
+    cases = [
+        paged_vs_gather(
+            B=slots, T=T, H=cfg.n_heads, KVH=cfg.kv_heads, D=cfg.head_width,
+            page=page, n_blocks=cache_len // page, dtype=jnp.bfloat16,
+            int8=int8, alibi=cfg.position == "alibi", seed=SEED,
+            interpret=device["platform"] != "tpu",
+        )
+        for T in (1, 1 + DRAFT_K) for int8 in (False, True)
+    ]
+    for case in cases:
+        if not (case["finite"] and case["ulps"] <= PAGED_ULPS < case["control_ulps"]):
+            raise RuntimeError(
+                f"paged kernel outside {PAGED_ULPS} bf16 ulps of the gather "
+                f"path (or the control inside them): {case}"
+            )
+    return {"phase": "kernels", "ok": True, "device": device, "model": model,
+            "paged_ulps_bar": PAGED_ULPS, "paged_vs_gather": cases}
+
+
 def serve_child(model: str, params: Path, port: int, extra: list,
                 require_tpu: bool = True) -> None:
     """The real server, in this process, until SIGTERM drains it."""
@@ -294,10 +350,11 @@ def serve_child(model: str, params: Path, port: int, extra: list,
 
 
 def zero_phase(out: Path, cfg_path: str = TRAIN_CFG, sets=ZERO_SETS,
-               steps: int = ZERO_STEPS, n_chips: int = 4,
+               steps: int = ZERO_STEPS, adamw_sets=ZERO_ADAMW_SETS,
+               adamw_steps: int = ZERO_ADAMW_STEPS, n_chips: int = 4,
                require_tpu: bool = True) -> dict:
     """ZeRO-1 and ZeRO-2 on a data=n mesh against the one-chip run of the
-    same global batch and seed, all in this one process."""
+    same global batch and seed, then the adamw leg, all in this one process."""
     device = device_or_exit(require_tpu)
     cache = cache_state()
     compiles = watch_compiles()
@@ -312,13 +369,11 @@ def zero_phase(out: Path, cfg_path: str = TRAIN_CFG, sets=ZERO_SETS,
     from zero_transformer_tpu.parallel.mesh import make_mesh
     from zero_transformer_tpu.training.trainer import Trainer
 
-    def run(name: str, mesh_sets: list, mesh=None) -> dict:
+    def run(name: str, sets: list, steps: int, decreasing: bool, mesh=None) -> dict:
         ckpt = out / name
         cfg = train.apply_overrides(
             load_config(ROOT / cfg_path),
-            train.parse_overrides(
-                [*sets, *mesh_sets, f"checkpoint.directory={ckpt}"]
-            ),
+            train.parse_overrides([*sets, f"checkpoint.directory={ckpt}"]),
         )
         before = dict(kernel_traces)
         trainer = Trainer(cfg, mesh=mesh)
@@ -327,27 +382,36 @@ def zero_phase(out: Path, cfg_path: str = TRAIN_CFG, sets=ZERO_SETS,
             state = trainer.train(max_steps=steps)
             wall = time.perf_counter() - t0
             opt = jax.tree.leaves(state.opt_state)
-            total = sum(x.nbytes for x in opt)
             per_device = {}
             for leaf in opt:
                 for shard in leaf.addressable_shards:
                     per_device[shard.device.id] = (
                         per_device.get(shard.device.id, 0) + shard.data.nbytes
                     )
+            got = {
+                "opt_state_bytes": sum(x.nbytes for x in opt),
+                "param_bytes": sum(x.nbytes for x in jax.tree.leaves(state.params)),
+                "opt_state_bytes_per_device": per_device,
+            }
         finally:
             trainer.close()
-        # 16k random tokens a step: the loss wanders by more than four
-        # steps of learning move it, so the bar here is the COMPARISON
-        losses = check_losses(train_rows(ckpt), steps, decreasing=False)
+        losses = check_losses(train_rows(ckpt), steps, decreasing)
         traced = {k: v - before.get(k, 0) for k, v in kernel_traces.items()}
         if not (traced.get("flash_fwd") and traced.get("flash_bwd")):
             raise RuntimeError(f"{name}: flash kernel not traced: {traced}")
-        return {"loss": losses, "wall_s": round(wall, 1),
-                "opt_state_bytes": total,
-                "opt_state_bytes_per_device": per_device,
+        return {"loss": losses, "wall_s": round(wall, 1), **got,
                 "kernel_traces": traced}
 
-    one = run("one_chip", ["mesh.data=1"], mesh=make_mesh(
+    def on_mesh(name: str, stage: int, sets: list, steps: int, decreasing: bool) -> dict:
+        got = run(name, [*sets, f"mesh.data={n_chips}", f"mesh.zero_stage={stage}"],
+                  steps, decreasing)
+        per = got["opt_state_bytes_per_device"]
+        if len(per) != n_chips:
+            raise RuntimeError(f"{name}: state on devices {sorted(per)} only")
+        got["max_opt_state_share"] = max(per.values()) / got["opt_state_bytes"]
+        return got
+
+    one = run("one_chip", [*sets, "mesh.data=1"], steps, True, mesh=make_mesh(
         MeshConfig(data=1), devices=jax.devices()[:1]
     ))
     result = {"phase": "zero4", "ok": True, "device": device,
@@ -355,26 +419,35 @@ def zero_phase(out: Path, cfg_path: str = TRAIN_CFG, sets=ZERO_SETS,
               "n_layers": ZERO_LAYERS, "steps": steps,
               "loss_tol": ZERO_LOSS_TOL, "one_chip": one, **cache}
     for stage in (1, 2):
-        got = run(f"zero{stage}", [f"mesh.data={n_chips}",
-                                   f"mesh.zero_stage={stage}"])
+        got = on_mesh(f"zero{stage}", stage, sets, steps, True)
         diffs = [abs(a - b) for a, b in zip(got["loss"], one["loss"])]
         if max(diffs) > ZERO_LOSS_TOL:
             raise RuntimeError(
                 f"ZeRO-{stage} losses {got['loss']} differ from one chip's "
                 f"{one['loss']} by more than {ZERO_LOSS_TOL}"
             )
-        per = got["opt_state_bytes_per_device"]
-        if len(per) != n_chips:
-            raise RuntimeError(f"ZeRO-{stage}: state on devices {sorted(per)} only")
-        share = max(per.values()) / got["opt_state_bytes"]
-        if share > 1.25 / n_chips:
-            raise RuntimeError(
-                f"ZeRO-{stage}: a device holds {share:.2f} of the optimizer "
-                f"state, expected about 1/{n_chips}"
-            )
         got["max_loss_diff"] = max(diffs)
-        got["max_opt_state_share"] = share
+        got["opt_state_of_params"] = got["opt_state_bytes"] / got["param_bytes"]
+        if got["opt_state_of_params"] > FACTORED_STATE_MAX:
+            raise RuntimeError(
+                f"ZeRO-{stage}: replicated factored state is "
+                f"{got['opt_state_of_params']:.3f} of the params' bytes"
+            )
+        got["opt_state"] = "factored statistics, replicated on every device by design"
         result[f"zero{stage}"] = got
+    for stage in (1, 2):
+        got = on_mesh(f"adamw_zero{stage}", stage, adamw_sets, adamw_steps, False)
+        if got["max_opt_state_share"] > 1.25 / n_chips:
+            raise RuntimeError(
+                f"adamw ZeRO-{stage}: a device holds "
+                f"{got['max_opt_state_share']:.2f} of the optimizer state, "
+                f"expected about 1/{n_chips}"
+            )
+        result[f"adamw_zero{stage}"] = got
+    diffs = [abs(a - b) for a, b in zip(result["adamw_zero1"]["loss"],
+                                        result["adamw_zero2"]["loss"])]
+    if max(diffs) > ZERO_LOSS_TOL:
+        raise RuntimeError(f"adamw ZeRO-1 and ZeRO-2 losses differ: {result}")
     return {**result, **compiles}
 
 
@@ -599,7 +672,8 @@ def main() -> None:
                    help="4: only ZeRO-1/2 on a data=4 mesh and the one-chip "
                         "run they are compared with")
     # internal: what the parent starts its children with
-    p.add_argument("--phase", choices=("train", "extract", "serve", "zero4"),
+    p.add_argument("--phase",
+                   choices=("train", "extract", "kernels", "serve", "zero4"),
                    help=argparse.SUPPRESS)
     p.add_argument("--variant", choices=tuple(SERVE_VARIANTS), help=argparse.SUPPRESS)
     p.add_argument("--port", type=int, help=argparse.SUPPRESS)
@@ -609,6 +683,8 @@ def main() -> None:
         emit(train_phase(OUT))
     elif args.phase == "extract":
         emit(extract_phase(OUT))
+    elif args.phase == "kernels":
+        emit(kernels_phase())
     elif args.phase == "serve":
         serve_child(SERVE_MODEL, OUT / "params.msgpack", args.port,
                     SERVE_ARGS + SERVE_VARIANTS[args.variant])
@@ -622,6 +698,7 @@ def main() -> None:
         else:
             run_child("train", child_cmd("--phase", "train"))
             run_child("extract", child_cmd("--phase", "extract"))
+            run_child("kernels", child_cmd("--phase", "kernels"))
             last = serve_phase(
                 OUT, SERVE_MODEL, SERVE_VARIANTS,
                 lambda variant, extra, port: child_cmd(

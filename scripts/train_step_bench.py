@@ -325,61 +325,6 @@ def attention_interpret_parity() -> dict:
     return interpret_parity_report()
 
 
-def mfu_projection_v5e() -> dict:
-    """Assumption-labeled v5e MFU projection for flash-by-default on the
-    1.3B north-star config. Baseline input: 0.528 MFU, a 2026-07-31 on-chip
-    reading of a tree that predates PRs 1-18 (its record is no longer in
-    the repo) — NOT measured on current code, so this whole block is an
-    assumption-labeled estimate. The XLA attention materializes the
-    [B, H, T, T] f32 score/weight tensors and round-trips them through HBM
-    several times per layer per step (write scores, softmax read+write,
-    out-matmul read, and the mirror passes in backward); the flash kernel
-    keeps that traffic in VMEM. The projection removes exactly that HBM
-    time from the measured step and re-derives MFU — every input is a
-    field so the arithmetic can be audited from the artifact alone."""
-    from zero_transformer_tpu.config import model_config
-
-    cfg = model_config("1_3b")
-    measured_mfu = 0.5281  # 2026-07-31 reading, stale: see the docstring
-    n_chips = 8
-    tokens_per_step = 64 * 1024
-    hbm_gbps = 819.0  # v5e HBM bandwidth per chip
-    score_passes = 6  # fwd: write + softmax rw + read; bwd: mirror passes
-    T = cfg.max_seq_len
-    B_chip = tokens_per_step // T // n_chips
-    n_params = cfg.num_params
-    useful_flops = 6.0 * n_params * tokens_per_step
-    step_s = useful_flops / (n_chips * V5E_PEAK_FLOPS * measured_mfu)
-    score_bytes_chip = (
-        B_chip * cfg.n_heads * T * T * 4 * score_passes * cfg.n_layers
-    )
-    saved_s = score_bytes_chip / (hbm_gbps * 1e9)
-    projected = measured_mfu * step_s / max(step_s - saved_s, 1e-9)
-    return {
-        "platform": "tpu_v5e_projected",
-        "model": "1_3b",
-        "baseline_mfu_measured": measured_mfu,
-        "assumptions": {
-            "n_chips": n_chips,
-            "tokens_per_step": tokens_per_step,
-            "peak_flops": V5E_PEAK_FLOPS,
-            "hbm_gbps": hbm_gbps,
-            "score_hbm_passes": score_passes,
-            "n_params": int(n_params),
-        },
-        "step_s_at_measured_mfu": round(step_s, 4),
-        "score_traffic_s_per_step": round(saved_s, 4),
-        "projected_mfu": round(projected, 4),
-        "target": 0.60,
-        "method": (
-            "remove the XLA path's [B,H,T,T] f32 score/weight HBM round "
-            "trips (bytes/bandwidth) from the measured step time and "
-            "re-derive MFU = useful_flops / (peak * new_step_time); "
-            "flash keeps those tensors blockwise in VMEM"
-        ),
-    }
-
-
 def attention_microbench(args) -> dict:
     """Per-op flash-vs-XLA attention, fwd+bwd (ROADMAP 5(a)): the kernel is
     Pallas/TPU — off TPU the flash column says WHY it is absent (timed
@@ -470,7 +415,6 @@ def main() -> None:
         "device_kind": jax.devices()[0].device_kind,
         **ab,
         "projection": projection,
-        "mfu_projection": mfu_projection_v5e(),
         "bubble": bubble_table(args),
         "attention_microbench": attention_microbench(args),
         "note": (

@@ -24,9 +24,9 @@ import jax  # noqa: E402
 # CPU compiles of 8-device programs that are identical run-to-run (round-3
 # VERDICT weak #6). Shared across workers and runs; xdist workers hit the
 # same directory safely (orbax-style atomic renames inside jax's cache).
-# Resolution (JAX_COMPILATION_CACHE_DIR wins, else the fixed fingerprinted
-# directory — tests/_compile_cache.py) is shared with the standalone
-# multihost workers, which recompute it from the same env.
+# Placement (JAX_COMPILATION_CACHE_DIR wins, else the fingerprinted
+# directory under <checkout>/.jax_cache — tests/_compile_cache.py) is shared
+# with the standalone multihost workers, which recompute it from the same env.
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import _compile_cache  # noqa: E402
 

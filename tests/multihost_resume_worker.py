@@ -43,9 +43,8 @@ jax.config.update("jax_platforms", "cpu")
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=2"
 )
-# persistent compile cache, resolved by the SAME base+fingerprint rule as
-# tests/conftest.py (shared helper) — suite-spawned and standalone runs both
-# land in the host-correct directory. Three phases x four processes compile
+# persistent compile cache, placed by the same helper as tests/conftest.py —
+# suite-spawned and standalone runs both land in the host-correct directory. Three phases x four processes compile
 # the SAME programs — without this the test's wall-clock is ~12 identical
 # XLA compiles
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))

@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from zero_transformer_tpu.ops import flash_attention as dispatch
 from zero_transformer_tpu.ops.pallas import flash, paged_attention as pa
 
 
@@ -87,9 +88,9 @@ def test_paged_decode_kernel_compiles(one_chip, H, D, T, int8):
     ) == 1
 
 
-def _flash_grads(docs: bool):
+def _flash_grads(docs: bool, entry=flash.flash_attention):
     def loss(q, k, v, ids):
-        out = flash.flash_attention(
+        out = entry(
             q, k, v, causal=True, alibi=True, doc_ids=ids if docs else None
         )
         return jnp.sum(out.astype(jnp.float32))
@@ -134,13 +135,14 @@ def test_flash_compiles_under_a_four_device_data_mesh(data_mesh, docs):
     """ZeRO data-parallel training's attention call: batch sharded over a
     4-device ``data`` mesh, the default ``attention_impl``. Refused on the
     parent commit — "Mosaic kernels cannot be automatically partitioned" —
-    now each device runs the kernel on its batch rows (``shard_kernel``)
-    and no collective is needed."""
+    now the dispatch site (``ops.flash_attention``) has each device run the
+    kernel on its batch rows (``shard_kernel``) and no collective is needed."""
     B, T, H, D = 8, 1024, 16, 128
     rows = NamedSharding(data_mesh, P("data"))
     qkv = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=rows)
     ids = jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=rows)
     with jax.set_mesh(data_mesh):
-        text = jax.jit(_flash_grads(docs)).lower(qkv, qkv, qkv, ids).compile().as_text()
+        grads = _flash_grads(docs, entry=dispatch.flash_attention)
+        text = jax.jit(grads).lower(qkv, qkv, qkv, ids).compile().as_text()
     assert text.count("tpu_custom_call") == 3
     assert "all-gather" not in text and "all-reduce" not in text

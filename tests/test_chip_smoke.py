@@ -27,14 +27,14 @@ from zero_transformer_tpu.utils import compile_cache  # noqa: E402
 
 @pytest.fixture
 def rehearsal(tmp_path, monkeypatch):
-    """Interpret-mode kernels, the suite's own compile cache (so the entry
-    points' ``compile_cache.configure()`` leaves this process's jax config
-    alone), and a learnable corpus: the phases assert a DECREASING loss,
-    which uniform random tokens cannot give a 2-layer model in four steps."""
+    """Interpret-mode kernels and a learnable corpus: the phases assert a
+    DECREASING loss, which uniform random tokens cannot give a 2-layer model
+    in four steps. (The compile cache stays the suite's own: conftest
+    exported its directory, so the entry points' ``compile_cache.configure()``
+    leaves this process's jax config alone.)"""
     from zero_transformer_tpu.data import write_memmap
 
     monkeypatch.setenv("ZT_PALLAS_INTERPRET", "1")
-    monkeypatch.setenv(compile_cache.ENV_VAR, _compile_cache.resolve_cache_dir())
     corpus = tmp_path / "train.bin"
     write_memmap(np.tile(np.arange(64, dtype=np.int32), 2048), str(corpus))
     sets = [
@@ -84,24 +84,50 @@ def test_train_extract_serve_phases_at_test_size(rehearsal):
     assert all(s["completed"] == chip_smoke.N_REQUESTS for s in (spec, plain, xla))
 
 
+def test_kernels_phase_holds_the_paged_kernel_to_its_bar(monkeypatch):
+    """The on-chip numerics check, rehearsed in interpret mode at the
+    ``test`` model's head shapes: every case inside the bar, its off-by-one
+    control far outside — and a bar the control would pass is refused."""
+    got = chip_smoke.kernels_phase("test", slots=2, cache_len=64, require_tpu=False)
+    assert got["ok"] and len(got["paged_vs_gather"]) == 4
+    assert {(c["shape"]["T"], c["int8_pages"]) for c in got["paged_vs_gather"]} == {
+        (1, False), (1, True), (5, False), (5, True)
+    }
+    for case in got["paged_vs_gather"]:
+        assert case["ulps"] <= chip_smoke.PAGED_ULPS < case["control_ulps"]
+    monkeypatch.setattr(chip_smoke, "PAGED_ULPS", 1e9)
+    with pytest.raises(RuntimeError, match="paged kernel outside"):
+        chip_smoke.kernels_phase("test", slots=2, cache_len=64, require_tpu=False)
+
+
 def test_zero_phase_on_the_virtual_mesh(rehearsal, devices):
-    """Rehearsal 2: ZeRO-1 and ZeRO-2 on a ``data=8`` virtual mesh against
-    the one-device run of the same global batch and seed — the flash kernel
-    traced under ``shard_kernel`` (plain jit and the explicit ZeRO-2 core),
-    losses within the stated tolerance, optimizer state spread 1/8 each."""
+    """Rehearsal 2: the recipe's adafactor under ZeRO-1 and ZeRO-2 on a
+    ``data=8`` virtual mesh against the one-device run of the same global
+    batch and seed — the flash kernel traced under ``shard_kernel`` (plain
+    jit and the explicit ZeRO-2 core), losses decreasing and within the
+    stated tolerance, factored state tiny and on every device — then the
+    adamw leg, its param-shaped state spread 1/8 each. d_model 128, the
+    narrowest width adafactor factors."""
     out, sets = rehearsal
+    sets = sets + ["model.d_model=128", "training.batch_size=8",
+                   "training.gradient_accumulation_steps=2"]
     got = chip_smoke.zero_phase(
         out, "configs/train_test.yaml",
-        sets + ["optimizer.optimizer=adamw", "training.batch_size=8",
-                "training.gradient_accumulation_steps=2"],
-        steps=4, n_chips=len(devices), require_tpu=False,
+        sets + ["optimizer.optimizer=adafactor"], steps=4,
+        adamw_sets=sets + ["optimizer.optimizer=adamw"], adamw_steps=2,
+        n_chips=len(devices), require_tpu=False,
     )
     assert got["ok"] and got["device"]["count"] == 8
+    assert got["one_chip"]["loss"][-1] < got["one_chip"]["loss"][0]
     for stage in ("zero1", "zero2"):
         assert got[stage]["max_loss_diff"] <= chip_smoke.ZERO_LOSS_TOL
-        assert len(got[stage]["opt_state_bytes_per_device"]) == 8
+        assert got[stage]["opt_state_of_params"] <= chip_smoke.FACTORED_STATE_MAX
+        assert got[stage]["max_opt_state_share"] == 1.0  # replicated by design
+    for stage in ("adamw_zero1", "adamw_zero2"):
         assert got[stage]["max_opt_state_share"] < 1.25 / 8
-        assert got[stage]["kernel_traces"]["flash_fwd"]
+    for leg in ("zero1", "zero2", "adamw_zero1", "adamw_zero2"):
+        assert len(got[leg]["opt_state_bytes_per_device"]) == 8
+        assert got[leg]["kernel_traces"]["flash_fwd"]
 
 
 def _run_main(monkeypatch, capsys, tmp_path, argv, device):
@@ -125,7 +151,7 @@ def _run_main(monkeypatch, capsys, tmp_path, argv, device):
 
 
 @pytest.mark.parametrize("argv,count,expected", [
-    ([], 1, ["train", "extract", "serve"]),
+    ([], 1, ["train", "extract", "kernels", "serve"]),
     (["--chips", "4"], 4, ["zero4"]),
 ])
 def test_last_line_is_exactly_the_contract(
@@ -183,11 +209,14 @@ def test_cache_helper_honours_the_env_var(monkeypatch, tmp_path):
     monkeypatch.delenv(compile_cache.ENV_VAR)
     assert compile_cache.configure() == str(REPO / ".jax_cache")
     assert calls == [("jax_compilation_cache_dir", str(REPO / ".jax_cache"))]
-    # the tests' own helper follows the same rule
-    monkeypatch.setenv(_compile_cache.ENV_VAR, str(tmp_path))
-    assert _compile_cache.resolve_cache_dir() == str(tmp_path)
-    monkeypatch.delenv(_compile_cache.ENV_VAR)
-    assert _compile_cache.resolve_cache_dir().startswith(_compile_cache.FIXED_BASE)
+    # the tests' own cache is placed by the same helper, inside the checkout
+    tests_dir = str(REPO / ".jax_cache" / "tests" / _compile_cache.cpu_fingerprint())
+    assert _compile_cache.configure(jax) == tests_dir
+    assert ("jax_compilation_cache_dir", tests_dir) in calls
+    n_calls = len(calls)
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    assert _compile_cache.configure(jax) == str(tmp_path)
+    assert all(c[0] != "jax_compilation_cache_dir" for c in calls[n_calls:])
 
 
 @pytest.mark.parametrize("kind,expected", [

@@ -95,9 +95,12 @@ def test_parity_vs_gather_page_sizes(page, n_blocks, alibi):
     _assert_contract(ref, out)
 
 
-def test_parity_bf16_and_gqa():
+def test_bitwise_bf16_and_gqa():
+    """bf16 (the serving dtype) with grouped queries: here the f32 partial
+    sums round to the same bf16 values in either order, so this case still
+    holds the original bit-equal contract."""
     ref, out = _case(2, 1, 8, 2, 64, 8, 4, jnp.bfloat16, True)
-    _assert_contract(ref, out)
+    np.testing.assert_array_equal(out, ref)
 
 
 def test_parity_mha_single_token():

@@ -25,9 +25,6 @@ REQUIRED_KEYS = {
     "measured_reduction", "parity",
     # the assumption-labeled projection (null on TPU where it's measured)
     "projection",
-    # kernel-lane MFU projection (ISSUE 11): flash-by-default vs the
-    # measured 0.53 baseline, assumption-labeled, targeting >= 0.60
-    "mfu_projection",
     # bubble table + attention microbench satellites
     "bubble", "attention_microbench",
     "note", "best_of", "measured_at_utc",
@@ -125,21 +122,6 @@ def test_step_artifact_interpret_parity(artifact):
             "paged_decode_vs_gather"} <= names
     paged = next(c for c in parity["cases"] if c["case"] == "paged_decode_vs_gather")
     assert paged["ok"] is True
-
-
-def test_step_artifact_mfu_projection(artifact):
-    """ISSUE 11 acceptance: the assumption-labeled v5e MFU projection for
-    flash-by-default must carry its inputs and clear the 0.60 target from
-    the measured 0.53 baseline."""
-    proj = artifact["mfu_projection"]
-    assert proj["assumptions"].keys() >= {
-        "n_chips", "tokens_per_step", "peak_flops", "hbm_gbps",
-        "score_hbm_passes", "n_params",
-    }
-    assert 0.5 < proj["baseline_mfu_measured"] < 0.6
-    assert proj["projected_mfu"] >= proj["target"] == 0.60
-    # the projection must be re-derivable from its own fields
-    assert proj["step_s_at_measured_mfu"] > proj["score_traffic_s_per_step"] > 0
 
 
 # -- guard semantics on synthetic artifacts ----------------------------------

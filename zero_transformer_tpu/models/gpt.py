@@ -33,7 +33,11 @@ from zero_transformer_tpu.parallel.sharding import (
     constrain_activation,
     replicate_activation,
 )
-from zero_transformer_tpu.ops.attention import dot_product_attention
+from zero_transformer_tpu.ops.attention import (
+    dot_product_attention,
+    paged_decode_attention,
+    paged_kernel_supported,
+)
 from zero_transformer_tpu.ops.losses import chunked_next_token_loss, next_token_loss
 from zero_transformer_tpu.ops.positions import apply_rope
 
@@ -345,15 +349,15 @@ class Attention(nn.Module):
             if per_slot:
                 overflow = overflow[:, None, None, None]
             q = jnp.where(overflow, jnp.nan, 1.0).astype(q.dtype) * q
-            from zero_transformer_tpu.ops.pallas import paged_attention as pa
+            from zero_transformer_tpu.ops.pallas.paged_attention import MAX_DECODE_T
 
-            use_kernel = paged and pa.supported(
+            use_kernel = paged and paged_kernel_supported(
                 impl, T=T, H=H, KVH=KVH, D=D, S=max_len_b,
                 page_size=self.kv_pages[1], dtype=dtype,
             )
             if (
                 paged and not use_kernel
-                and cfg.attention_impl == "flash" and T <= pa.MAX_DECODE_T
+                and cfg.attention_impl == "flash" and T <= MAX_DECODE_T
             ):
                 # flash-or-raise holds on the paged decode path too: an
                 # explicit kernel request never gets the gather fallback
@@ -369,7 +373,7 @@ class Attention(nn.Module):
                 # gather-pages-to-slab view below never materializes; how
                 # close it stays to that gather path is the kernel
                 # module's exactness contract
-                out = pa.paged_attention(
+                out = paged_decode_attention(
                     q, ck.value, cv.value, bt.value, offset,
                     causal=T > 1,
                     alibi=cfg.position == "alibi",

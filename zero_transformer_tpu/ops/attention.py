@@ -152,3 +152,53 @@ def dot_product_attention(
         segment_ids=segment_ids,
         doc_ids=doc_ids,
     )
+
+
+def paged_kernel_supported(impl: str, *, H: int, KVH: int, **shape) -> bool:
+    """Does the paged decode kernel take this dispatch? ONE gate consulted
+    by both the model's paged read path (``models/gpt.py``) and the engine's
+    dispatch-site bookkeeping, so "supported" and "will actually run" can
+    never disagree: the kernel's own shape gate, and — the kernel runs per
+    device on a mesh — a tensor axis that divides the heads."""
+    from zero_transformer_tpu.ops.pallas import paged_attention as pa
+    from zero_transformer_tpu.parallel.sharding import kernel_shardable
+
+    return pa.supported(impl, H=H, KVH=KVH, **shape) and kernel_shardable(
+        heads=H, kvheads=KVH
+    )
+
+
+# graftlint: hot-path
+def paged_decode_attention(
+    q, k_pool, v_pool, block_table, q_offset, *, causal: bool,
+    alibi: bool = False, k_scale=None, v_scale=None,
+) -> jax.Array:
+    """The paged decode kernel (``ops.pallas.paged_attention``) at its
+    dispatch site: on a mesh (``serve --tensor N``) each device walks ITS
+    kv heads' pages under ``parallel.sharding.shard_kernel`` — GSPMD cannot
+    partition a Mosaic call — with the heads' ALiBi slopes and the per-row
+    offsets as explicit operands."""
+    from zero_transformer_tpu.ops.pallas import paged_attention as pa
+    from zero_transformer_tpu.parallel.sharding import shard_kernel
+
+    B, _, H, _ = q.shape
+    offs = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32).reshape(-1), (B,))
+    slopes = alibi_slopes(H) if alibi else jnp.zeros((H,), jnp.float32)
+    scales = () if k_scale is None else (k_scale, v_scale)
+
+    def local(q, k_pool, v_pool, block_table, offs, slopes, *scales):
+        k_sc, v_sc = scales or (None, None)
+        return (pa.paged_attention(
+            q, k_pool, v_pool, block_table, offs, causal=causal, alibi=alibi,
+            k_scale=k_sc, v_scale=v_sc, slopes=slopes,
+        ),)
+
+    q_names = ("batch", None, "heads", None)
+    pool = (None, None, "kvheads", None)
+    (out,) = shard_kernel(
+        local,
+        (q_names, pool, pool, ("batch", None), ("batch",), ("heads",),
+         *(pool for _ in scales)),
+        (q_names,),
+    )(q, k_pool, v_pool, block_table, offs, slopes.reshape(H), *scales)
+    return out

@@ -37,7 +37,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from zero_transformer_tpu.ops.pallas import kernel_traces
 from zero_transformer_tpu.ops.positions import NEG_INF, alibi_slopes
-from zero_transformer_tpu.parallel.sharding import shard_kernel
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
@@ -418,79 +417,28 @@ def _bwd(q, k, v, o, lse, do, causal, alibi, scale, block_q, block_k, interpret,
     return dq, dk, dv
 
 
-# logical activation names of the kernels' operands, for shard_kernel
-_Q = ("batch", None, "heads", None)  # q / o / do / dq   [B, T, H, D]
-_KV = ("batch", None, "kvheads", None)  # k / v / dk / dv  [B, S, KVH, D]
-_LSE = ("batch", "heads", None, None)  # [B, H, T, 1]
-_ROWS = ("batch", None)  # doc ids [B, T], kv validity [B, S]
-_SLOPES = ("heads", None)  # [H, 1]
-
-
-def _mesh_fwd(q, k, v, slopes, doc_ids, segment_ids, q_offset,
-              causal, alibi, scale, block_q, block_k, interpret):
-    """``_fwd`` for the dispatch-site entries (``flash_attention``,
-    ``flash_serving``), under ``shard_kernel``: on a mesh each device runs
-    the kernel on its own batch rows and heads. The ALiBi slope table is an
-    explicit operand so a head shard gets ITS heads' slopes; offsets go
-    per-row so they split with the batch. The context-parallel engines call
-    ``flash_partial``/``flash_grads`` from inside their own shard_maps and
-    do not come through here."""
-    B, _, H, _ = q.shape
-    if slopes is None:
-        slopes = _slopes_arg(H, alibi)
-    offs = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32).reshape(-1), (B,))
-    rows = [x for x in (doc_ids, segment_ids) if x is not None]
-
-    def local(q, k, v, slopes, offs, *rows):
-        rows = iter(rows)
-        ids = None if doc_ids is None else next(rows)
-        seg = None if segment_ids is None else next(rows)
-        return _fwd(q, k, v, causal, alibi, scale, block_q, block_k, interpret,
-                    q_offset=offs, slopes=slopes, q_ids=ids, k_ids=ids,
-                    segment_ids=seg)
-
-    return shard_kernel(
-        local,
-        (_Q, _KV, _KV, _SLOPES, ("batch",), *(_ROWS for _ in rows)),
-        (_Q, _LSE),
-    )(q, k, v, slopes, offs, *rows)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, doc_ids, slopes, causal, alibi, scale, block_q, block_k, interpret):
     # doc_ids: [B, T] float32 (or None) — f32 so its zero cotangent below is
     # a plain zeros_like rather than float0 plumbing. slopes: [H, 1] f32 (or
     # None) overriding the ALiBi table for head-sharded callers (ulysses/TP).
-    o, _ = _mesh_fwd(q, k, v, slopes, doc_ids, None, 0,
-                     causal, alibi, scale, block_q, block_k, interpret)
+    o, _ = _fwd(q, k, v, causal, alibi, scale, block_q, block_k, interpret,
+                slopes=slopes, q_ids=doc_ids, k_ids=doc_ids)
     return o
 
 
 def _flash_fwd(q, k, v, doc_ids, slopes, causal, alibi, scale, block_q, block_k, interpret):
-    o, lse = _mesh_fwd(q, k, v, slopes, doc_ids, None, 0,
-                       causal, alibi, scale, block_q, block_k, interpret)
+    o, lse = _fwd(q, k, v, causal, alibi, scale, block_q, block_k, interpret,
+                  slopes=slopes, q_ids=doc_ids, k_ids=doc_ids)
     return o, (q, k, v, doc_ids, slopes, o, lse)
 
 
 def _flash_bwd(causal, alibi, scale, block_q, block_k, interpret, res, do):
     q, k, v, doc_ids, slopes, o, lse = res
-    docs = () if doc_ids is None else (doc_ids,)
-    table = _slopes_arg(q.shape[2], alibi) if slopes is None else slopes
-
-    def local(q, k, v, o, lse, do, slopes, *docs):
-        ids = docs[0] if docs else None
-        return _bwd(
-            q, k, v, o, lse, do, causal, alibi, scale, block_q, block_k,
-            interpret, slopes=slopes, q_ids=ids, k_ids=ids,
-        )
-
-    # the backward kernels get their OWN shard_map (same specs as the
-    # forward's) — jax never transposes one
-    dq, dk, dv = shard_kernel(
-        local,
-        (_Q, _KV, _KV, _Q, _LSE, _Q, _SLOPES, *(_ROWS for _ in docs)),
-        (_Q, _KV, _KV),
-    )(q, k, v, o, lse, do, table, *docs)
+    dq, dk, dv = _bwd(
+        q, k, v, o, lse, do, causal, alibi, scale, block_q, block_k, interpret,
+        slopes=slopes, q_ids=doc_ids, k_ids=doc_ids,
+    )
     d_ids = None if doc_ids is None else jnp.zeros_like(doc_ids)
     d_slopes = None if slopes is None else jnp.zeros_like(slopes)
     return dq, dk, dv, d_ids, d_slopes
@@ -591,26 +539,29 @@ def flash_serving(
         slopes = jax.lax.stop_gradient(slopes).reshape(-1, 1).astype(jnp.float32)
     block_q, block_k = _resolve_blocks(T, S, block, None, None)
     scale = softmax_scale if softmax_scale is not None else 1.0 / (D**0.5)
-    o, _ = _mesh_fwd(
-        q, k, v, slopes, None, segment_ids, q_offset,
-        causal, alibi, float(scale), block_q, block_k, interpret,
+    o, _ = _fwd(
+        q, k, v, causal, alibi, float(scale), block_q, block_k, interpret,
+        q_offset=q_offset, kv_offset=0, slopes=slopes,
+        segment_ids=segment_ids,
     )
     return o
 
 
 def flash_partial(
     q, k, v, *, causal, alibi, softmax_scale, q_offset, kv_offset,
-    slopes=None, q_ids=None, k_ids=None,
+    slopes=None, q_ids=None, k_ids=None, out_dtype=jnp.float32,
     block: Optional[int] = None, interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Forward-only: (out [B,T,H,D], lse [B,H,T,1]) at global offsets.
 
     ``out`` is normalized by the LOCAL softmax sum; merge across kv shards
-    with the lse (ring attention does this). ``slopes`` overrides the ALiBi
-    slope table for head-sharded (TP) calls; ``q_ids``/``k_ids`` are this
-    shard's document ids (ring packing — the kv ids rotate with the kv
+    with the lse (ring attention does this — hence the f32 ``out_dtype``
+    default: merged, and rounded once, by the caller). ``slopes`` overrides
+    the ALiBi slope table for head-sharded calls; ``q_ids``/``k_ids`` are
+    this shard's document ids (ring packing — the kv ids rotate with the kv
     shard). NOT differentiable — pair with ``flash_grads`` under a custom
-    VJP.
+    VJP (ring attention's, or the mesh dispatch's in
+    ``ops.flash_attention``).
     """
     B, T, H, D = q.shape
     _, S, KVH, _ = k.shape
@@ -619,19 +570,19 @@ def flash_partial(
     return _fwd(
         q, k, v, causal, alibi, float(scale), block_q, block_k, interpret,
         q_offset=q_offset, kv_offset=kv_offset, slopes=slopes,
-        out_dtype=jnp.float32,  # merged (and rounded once) by the caller
-        q_ids=q_ids, k_ids=k_ids,
+        out_dtype=out_dtype, q_ids=q_ids, k_ids=k_ids,
     )
 
 
 def flash_grads(
     q, k, v, o, lse, do, *, causal, alibi, softmax_scale, q_offset, kv_offset,
-    slopes=None, delta=None, q_ids=None, k_ids=None,
+    slopes=None, delta=None, q_ids=None, k_ids=None, grad_dtype=jnp.float32,
     block: Optional[int] = None, interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """(dq, dk, dv) given the GLOBAL (out, lse) of the merged softmax —
     the flash backward identity p = exp(s - lse_global) makes per-shard
-    backward passes independent (ring attention sums them)."""
+    backward passes independent (ring attention sums them across ring
+    steps, hence the f32 ``grad_dtype`` default; None = the operands')."""
     B, T, H, D = q.shape
     _, S, KVH, _ = k.shape
     block_q, block_k = _resolve_blocks(T, S, block, None, None)
@@ -639,6 +590,5 @@ def flash_grads(
     return _bwd(
         q, k, v, o, lse, do, causal, alibi, float(scale), block_q, block_k,
         interpret, q_offset=q_offset, kv_offset=kv_offset, slopes=slopes,
-        grad_dtype=jnp.float32,  # summed across ring steps by the caller
-        delta=delta, q_ids=q_ids, k_ids=k_ids,
+        grad_dtype=grad_dtype, delta=delta, q_ids=q_ids, k_ids=k_ids,
     )

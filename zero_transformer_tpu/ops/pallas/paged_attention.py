@@ -28,11 +28,15 @@ scratch), not the gather path's 5-D einsums, which the chip's compiler
 refuses. So the bar is:
 
 - interpret mode (CPU): output within 1 ulp (bf16) / 4 ulp (f32; observed
-  1-2) of the gather path at the output's scale, and a served token stream
-  byte-identical to the gather engine's (``tests/test_paged_kernel.py``);
-- on the chip: greedy token streams identical to ``attention_impl: xla``
-  and logits within bf16 rounding of it — checked by ``chip_smoke.py``,
-  the only place the Mosaic-compiled kernel's numbers exist.
+  1-2) of the gather path at the output's scale — the bf16 GQA case is
+  still bit-equal — and a served token stream byte-identical to the gather
+  engine's (``tests/test_paged_kernel.py``);
+- on the chip: output within 2 bf16 ulps of the gather path at the
+  output's scale, bf16 and int8 pages, decode and spec-verify windows, at
+  the server's shapes (``ops.pallas.parity.paged_vs_gather``, held by
+  ``chip_smoke.py``'s ``kernels`` phase — the only place the
+  Mosaic-compiled kernel's numbers exist), and greedy token streams
+  identical to ``attention_impl: xla`` (its ``serve`` phase).
 
 VMEM note: the whole row's K/V lands in a ``[KVH, cache_len, D]`` scratch
 pair — 8 MiB at 16 heads x 1k positions x D=128 bf16 — beside f32
@@ -55,7 +59,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from zero_transformer_tpu.ops.pallas import kernel_traces
 from zero_transformer_tpu.ops.positions import NEG_INF, alibi_slopes
-from zero_transformer_tpu.parallel.sharding import kernel_shardable, shard_kernel
 
 # decode window ceiling: 1 (plain decode) .. 1 + draft_k (spec verify).
 # Larger query windows belong to the flash kernel's chunked-prefill path.
@@ -96,11 +99,9 @@ def supported(
     dtype,
     interpret: bool = False,
 ) -> bool:
-    """Gate: does the paged kernel handle this decode dispatch?
-
-    ONE function consulted by both the model's paged read path
-    (``models/gpt.py``) and the engine's dispatch-site bookkeeping, so
-    "supported" and "will actually run" can never disagree. ``impl`` is
+    """Shape gate: can the paged kernel take this decode dispatch on one
+    device? (The dispatch site, ``ops.attention.paged_kernel_supported``,
+    adds what the mesh has to say.) ``impl`` is
     ``cfg.attention_impl``; ``xla`` always declines (the gather path is the
     reference), ``auto``/``flash`` accept on TPU or under interpret mode.
     ``S`` is the cache length (``n_blocks * page_size``).
@@ -123,8 +124,7 @@ def supported(
             return False  # sublane-aligned page copies into the K/V scratch
         if vmem_bytes(T=T, H=H, KVH=KVH, D=D, S=S, dtype=dtype) > VMEM_CEILING:
             return False
-    # per-device call under shard_kernel: the tensor axis must divide heads
-    return kernel_shardable(heads=H, kvheads=KVH)
+    return True
 
 
 def _kernel(
@@ -237,56 +237,33 @@ def paged_attention(
     ``[B, n_blocks]`` int32 (zeros = the serving layer's trash page);
     ``q_offset`` ``[B]`` (or scalar) — row r's query block starts at
     position ``q_offset[r]``, and positions ``>= q_offset[r] + T`` are
-    masked invalid, the gather path's ``kv_valid``.
+    masked invalid, the gather path's ``kv_valid``. ``slopes`` ``[H]`` f32
+    overrides the ALiBi slope table for a caller holding a shard of the
+    heads.
 
     Forward-only (the decode path never differentiates). How close the
     output is to gather-to-slab + ``xla_attention`` is the module
     docstring's exactness contract.
     """
     B, T, H, D = q.shape
-    KVH = k_pool.shape[2]
+    n_pages, page, KVH, _ = k_pool.shape
     if H % KVH:
         raise ValueError(f"query heads {H} not divisible by kv heads {KVH}")
     int8 = k_pool.dtype == jnp.int8
     if int8 and (k_scale is None or v_scale is None):
         raise ValueError("int8 pools need k_scale/v_scale pools")
-    scale = softmax_scale if softmax_scale is not None else 1.0 / (D**0.5)
+    scale = float(softmax_scale if softmax_scale is not None else 1.0 / (D**0.5))
     offs = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32).reshape(-1), (B,))
     if slopes is None:
         slopes = alibi_slopes(H) if alibi else jnp.zeros((H,), jnp.float32)
+    slopes = slopes.reshape(H).astype(jnp.float32)
     interpret = interpret or (
         jax.default_backend() != "tpu" and interpret_requested()
     )
-    scales = (k_scale, v_scale) if int8 else ()
-
-    local = functools.partial(
-        _paged_local, scale=float(scale), causal=causal, alibi=alibi,
-        interpret=interpret,
-    )
-    # on a mesh (``serve --tensor N``) each device walks ITS kv heads' pages
-    q_names = ("batch", None, "heads", None)
-    pool = (None, None, "kvheads", None)
-    (out,) = shard_kernel(
-        lambda *operands: (local(*operands),),
-        (q_names, pool, pool, ("batch", None), ("batch",), ("heads",),
-         *(pool for _ in scales)),
-        (q_names,),
-    )(q, k_pool, v_pool, block_table.astype(jnp.int32), offs,
-      slopes.reshape(H).astype(jnp.float32), *scales)
-    return out
-
-
-def _paged_local(q, k_pool, v_pool, block_table, offs, slopes,
-                 k_scale=None, v_scale=None, *, scale, causal, alibi,
-                 interpret):
-    """One device's kernel call on its rows and heads (see
-    ``paged_attention``)."""
-    B, T, H, D = q.shape
-    n_pages, page, KVH, _ = k_pool.shape
+    block_table = block_table.astype(jnp.int32)
     G = H // KVH
     _, n_blocks = block_table.shape
     S = n_blocks * page
-    int8 = k_pool.dtype == jnp.int8
     dtype = q.dtype
     kernel_traces["paged_attention"] += 1
     # kernel layouts (see _kernel): q rows m = t * G + g under each kv head;

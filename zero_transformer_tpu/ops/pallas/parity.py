@@ -77,31 +77,14 @@ def interpret_parity_report() -> dict:
 
     # paged decode kernel vs the gather-to-slab path it replaces: f32
     # few-ulp (summation order only — the kernel module's exactness contract)
-    page, n_blocks = 16, 4
-    n_pages = 12
-    S = page * n_blocks
-    kp = jax.random.normal(jax.random.PRNGKey(5), (n_pages, page, H, D), jnp.float32)
-    vp = jax.random.normal(jax.random.PRNGKey(6), (n_pages, page, H, D), jnp.float32)
-    table = jax.random.randint(
-        jax.random.PRNGKey(7), (B, n_blocks), 1, n_pages, jnp.int32
+    paged = paged_vs_gather(
+        B=B, T=1, H=H, KVH=H, D=D, page=16, n_blocks=4, dtype=jnp.float32,
+        int8=False, interpret=True,
     )
-    doff = jnp.asarray([17, 42], jnp.int32)
-
-    def _gather_ref(q, kp, vp, tbl, o):
-        gk = jnp.take(kp, tbl, axis=0).reshape(B, S, H, D)
-        gv = jnp.take(vp, tbl, axis=0).reshape(B, S, H, D)
-        s = (jnp.arange(S)[None, :] < (o[:, None] + 1)).astype(jnp.int32)
-        return xla_attention(q, gk, gv, causal=False, alibi=True,
-                             q_offset=o, segment_ids=s)
-
-    ref = jax.jit(_gather_ref)(q[:, :1], kp, vp, table, doff)
-    out = jax.jit(lambda q, kp, vp, t, o: paged_attention(
-        q, kp, vp, t, o, causal=False, alibi=True, interpret=True,
-    ))(q[:, :1], kp, vp, table, doff)
-    pdiff = float(jnp.max(jnp.abs(ref - out)))
     cases.append({
         "case": "paged_decode_vs_gather", "shape": [B, 1, H, D],
-        "page_size": page, "max_abs_diff_fwd": pdiff, "ok": pdiff < FWD_TOL,
+        "page_size": 16, "max_abs_diff_fwd": paged["max_abs_diff"],
+        "ok": paged["max_abs_diff"] < FWD_TOL,
     })
 
     return {
@@ -109,4 +92,88 @@ def interpret_parity_report() -> dict:
         "platform": jax.default_backend(),
         "cases": cases,
         "ok": all(c["ok"] for c in cases),
+    }
+
+
+def paged_vs_gather(
+    *, B: int, T: int, H: int, KVH: int, D: int, page: int, n_blocks: int,
+    dtype, int8: bool, alibi: bool = True, seed: int = 0,
+    interpret: bool = False,
+) -> dict:
+    """The paged decode kernel against the gather-to-slab path it replaces
+    (``jnp.take(pool, table)`` + ``xla_attention``'s per-row branch), on
+    random q / pools / scales from ``seed``, a block table that is a random
+    permutation of distinct pages, and per-row offsets that include a page
+    boundary, one before it, and a full cache. ``interpret=False`` on a TPU
+    is the Mosaic-compiled kernel — how ``chip_smoke.py`` holds it to the
+    on-chip bar of the kernel module's exactness contract.
+
+    Differences are in units of one ``dtype`` ulp at the output's scale
+    (``eps * max|ref|``). ``control_ulps`` is the same measure for a
+    reference whose offsets are ONE position off — a bar means something
+    only while that control is far above it."""
+    from zero_transformer_tpu.ops.attention import xla_attention
+    from zero_transformer_tpu.ops.pallas.paged_attention import paged_attention
+
+    S = page * n_blocks
+    n_pages = B * n_blocks + 1  # page 0: the serving layer's trash page
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    q = jax.random.normal(ks[0], (B, T, H, D), dtype)
+    if int8:
+        k_pool, v_pool = (
+            jax.random.randint(k, (n_pages, page, KVH, D), -127, 128, jnp.int32)
+            .astype(jnp.int8) for k in ks[1:3]
+        )
+        scales = tuple(
+            jax.random.uniform(k, (n_pages, page, KVH, 1), jnp.float32, 1e-3, 2e-2)
+            for k in ks[3:5]
+        )
+    else:
+        k_pool, v_pool = (
+            jax.random.normal(k, (n_pages, page, KVH, D), dtype) for k in ks[1:3]
+        )
+        scales = ()
+    table = (1 + jax.random.permutation(ks[5], n_pages - 1)).reshape(B, n_blocks)
+    table = table.astype(jnp.int32)
+    offsets = jax.random.randint(ks[6], (B,), 0, S - T + 1, jnp.int32)
+    edges = jnp.asarray([S - T, (n_blocks // 2) * page, (n_blocks // 2) * page - 1])
+    offsets = offsets.at[: min(B, 3)].set(edges[: min(B, 3)].astype(jnp.int32))
+
+    def gather(pool, scale, tbl):
+        x = jnp.take(pool, tbl, axis=0)
+        if scale is not None:  # the engine's dequant, verbatim
+            x = (x.astype(jnp.float32) * jnp.take(scale, tbl, axis=0)).astype(dtype)
+        return x.reshape(B, S, KVH, D)
+
+    @jax.jit
+    def reference(q, k_pool, v_pool, tbl, off, *scales):
+        k_sc, v_sc = scales or (None, None)
+        valid = (jnp.arange(S)[None, :] < (off[:, None] + T)).astype(jnp.int32)
+        return xla_attention(
+            q, gather(k_pool, k_sc, tbl), gather(v_pool, v_sc, tbl),
+            causal=T > 1, alibi=alibi, q_offset=off, segment_ids=valid,
+        )
+
+    @jax.jit
+    def kernel(q, k_pool, v_pool, tbl, off, *scales):
+        k_sc, v_sc = scales or (None, None)
+        return paged_attention(
+            q, k_pool, v_pool, tbl, off, causal=T > 1, alibi=alibi,
+            k_scale=k_sc, v_scale=v_sc, interpret=interpret,
+        )
+
+    args = (q, k_pool, v_pool, table)
+    ref = reference(*args, offsets, *scales).astype(jnp.float32)
+    out = kernel(*args, offsets, *scales).astype(jnp.float32)
+    off_by_one = jnp.where(offsets > 0, offsets - 1, offsets + 1)
+    control = reference(*args, off_by_one, *scales).astype(jnp.float32)
+    ulp = float(jnp.finfo(dtype).eps) * float(jnp.max(jnp.abs(ref)))
+    diff = float(jnp.max(jnp.abs(out - ref)))
+    return {
+        "shape": {"B": B, "T": T, "H": H, "KVH": KVH, "D": D, "page": page,
+                  "cache_len": S},
+        "dtype": jnp.dtype(dtype).name, "int8_pages": int8,
+        "finite": bool(jnp.all(jnp.isfinite(out))),
+        "max_abs_diff": diff, "ulps": diff / ulp,
+        "control_ulps": float(jnp.max(jnp.abs(control - ref))) / ulp,
     }

@@ -1028,11 +1028,11 @@ class ServingEngine:
         self._ds_sample = bounded_dispatch("engine.sample_tail", 1)
         self._ds_paged = bounded_dispatch("engine.paged_attention", 1)
         # is the paged-attention kernel compiled into the decode program?
-        # Same gate the model consults (ops.pallas.paged_attention), so the
-        # exported gauge can never disagree with what actually traced.
-        from zero_transformer_tpu.ops.pallas import paged_attention as _pa
+        # Same gate the model consults, so the exported gauge can never
+        # disagree with what actually traced.
+        from zero_transformer_tpu.ops.attention import paged_kernel_supported
 
-        self._paged_kernel = kv_layout == "paged" and _pa.supported(
+        self._paged_kernel = kv_layout == "paged" and paged_kernel_supported(
             cfg.attention_impl,
             T=1 + self.draft_k if self.draft_k else 1,
             H=cfg.n_heads,
