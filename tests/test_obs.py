@@ -471,12 +471,11 @@ def test_parse_profile_window():
             obs.parse_profile_window(bad)
 
 
-@pytest.mark.slow
 def test_profile_capture_over_http_and_draining_409(cfg, params, tmp_path):
-    """Slow lane (a real jax.profiler capture serializes xplane protos for
-    ~20s on CPU): the full 202 -> capture -> on-disk artifact -> drain-409
-    lifecycle. Tier-1 covers the staging/conflict/draining refusals in
-    test_profile_request_refusals without touching the profiler."""
+    """The full 202 -> capture -> on-disk artifact -> drain-409 lifecycle,
+    with a real jax.profiler capture. It was the slow lane's while the
+    window opened with Python tracing on (~20 s on CPU); through
+    ``obs.profiling.start_trace`` it takes two (PR 24)."""
     engine = make_engine(cfg, params, obs_dir=str(tmp_path), prefill_chunk=8)
     server = run_server(engine, ByteTok(), port=0, background=True)
     try:

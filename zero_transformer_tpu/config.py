@@ -345,9 +345,8 @@ class TrainingConfig:
     # (scripts/train_step_bench.py) measured for this config's platform.
     # When set, the trainer's obs track reports train/exposed_comm_frac
     # from the artifact's measured overlap A/B alongside the analytic
-    # train/bubble_frac gauge, and emits per-window grads_compute /
-    # comm_exposed / bubble_wait estimate spans. "" = bubble_frac only
-    # (it is analytic — exact for the configured schedule).
+    # train/bubble_frac gauge. "" = bubble_frac only (it is analytic —
+    # exact for the configured schedule).
     step_bench_artifact: str = ""
 
     def __post_init__(self):

@@ -27,6 +27,8 @@ from typing import Any, Dict, Optional
 
 import jax
 
+from zero_transformer_tpu.obs.profiling import start_trace
+
 # bf16 peak FLOP/s of ONE jax device, keyed by its exact ``device_kind``
 # (Google Cloud TPU documentation, per-generation system architecture
 # pages; a v3 jax device is one of a chip's two cores)
@@ -220,11 +222,12 @@ class StepTimer:
 
 @contextlib.contextmanager
 def profile(log_dir: str | Path, enabled: bool = True):
-    """Capture a jax.profiler trace viewable in TensorBoard/XProf."""
+    """Capture a jax.profiler trace viewable in TensorBoard/XProf (Python
+    tracing off, live spans on the host plane: ``obs/profiling.start_trace``)."""
     if not enabled:
         yield
         return
-    jax.profiler.start_trace(str(log_dir))
+    start_trace(log_dir)
     try:
         yield
     finally:

@@ -11,7 +11,9 @@ Two surfaces share this module:
   window [START, START+LEN) — ``parse_profile_window`` is the flag parser.
 
 Traces land under ``<run dir>/profiles/<name>`` next to the flight-recorder
-dumps, viewable in TensorBoard/XProf or ``xprof``.
+dumps, viewable in TensorBoard/XProf or ``xprof``. Every capture the program
+opens goes through ``start_trace`` here: Python tracing off, the program's
+live spans on the host plane as ``"<track>/<name>"`` annotations.
 """
 from __future__ import annotations
 
@@ -37,6 +39,23 @@ def parse_profile_window(spec: str) -> Tuple[int, int]:
             f"--profile-window START and LEN must be >= 1, got {spec!r}"
         )
     return start, length
+
+
+def start_trace(directory) -> None:
+    """Open a ``jax.profiler`` capture an operator can use. Python tracing is
+    off: the default options trace every Python call, which slows a
+    host-bound loop to a fraction of its speed and buries the capture under
+    millions of host events. The host tracer stays at the level that keeps
+    ``TraceAnnotation``s, so the program's live spans (``obs/spans.py``)
+    land beside the device's programs. Close with
+    ``jax.profiler.stop_trace()``."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    Path(directory).mkdir(parents=True, exist_ok=True)
+    jax.profiler.start_trace(str(directory), profiler_options=options)
 
 
 class ProfileWindow:
@@ -97,10 +116,7 @@ class ProfileWindow:
             ticks, path = self._pending
             self._pending = None
             try:
-                import jax
-
-                Path(path).mkdir(parents=True, exist_ok=True)
-                jax.profiler.start_trace(path)
+                start_trace(path)
             except Exception:
                 log.exception("profiler: start_trace failed (capture skipped)")
                 self._busy = False

@@ -11,9 +11,15 @@ dispatch, device sync, checkpoint save, replica audit) both record into one
   window and the overflow is *counted*, never silently unbounded.
 - **clock**: timestamps are caller-supplied floats on ONE monotonic clock
   (the engine's ``now()`` / ``time.monotonic``). Spans recorded at finish
-  time from timestamps captured earlier are first-class — the request
-  lifecycle is emitted as one batch when the request reaches a terminal
-  state, so the hot emit path allocates nothing per token.
+  time from timestamps captured earlier (``add``) are first-class — the
+  request lifecycle is emitted as one batch when the request reaches a
+  terminal state, so the hot emit path allocates nothing per token.
+- **two clocks at once**: a LIVE host phase (``with tracer.span(...)``: the
+  engine's tick tree, the trainer's loop) is also a
+  ``jax.profiler.TraceAnnotation`` named ``"<track>/<name>"`` for its
+  life, so an open profiler capture holds the same span on the profiler's
+  clock, beside the device's programs. With no capture open that costs
+  about half a microsecond a span.
 - **export**: ``chrome_trace()`` renders Perfetto/Chrome ``traceEvents``
   JSON (complete "X" events, one ``tid`` per track); ``write_jsonl``
   appends newly finished spans to a ``spans.jsonl`` beside
@@ -26,7 +32,6 @@ and every phase span is a sub-interval of it.
 """
 from __future__ import annotations
 
-import contextlib
 import itertools
 import json
 import logging
@@ -36,11 +41,70 @@ from collections import deque
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 log = logging.getLogger("zero_transformer_tpu")
 
 # span record layout (fixed tuple, index-addressed):
 # (seq, track, name, t0_s, t1_s, attrs_or_None)
 SEQ, TRACK, NAME, T0, T1, ATTRS = range(6)
+
+
+class LiveSpan:
+    """One ``Tracer.span``. ``note`` adds attributes learned inside the body
+    to the ring record (the annotation took its stats at entry); ``discard``
+    leaves the ring unwritten — an annotation once entered cannot be
+    withdrawn, so the profiler's side keeps it."""
+
+    __slots__ = ("_tracer", "_name", "_track", "_attrs", "_t0", "_ann", "_keep")
+
+    def __init__(self, tracer: "Tracer", name: str, track: str, attrs: dict):
+        self._tracer, self._name, self._track = tracer, name, track
+        self._attrs = attrs
+        self._keep = True
+
+    # graftlint: hot-path
+    def __enter__(self) -> "LiveSpan":
+        self._ann = TraceAnnotation(f"{self._track}/{self._name}", **self._attrs)
+        self._ann.__enter__()
+        self._t0 = self._tracer.clock()
+        return self
+
+    # graftlint: hot-path
+    def __exit__(self, *exc) -> bool:
+        t1 = self._tracer.clock()
+        self._ann.__exit__(*exc)
+        if self._keep:
+            self._tracer.add(self._name, self._track, self._t0, t1,
+                             self._attrs or None)
+        return False
+
+    def note(self, **attrs) -> None:
+        self._attrs.update(attrs)
+
+    def discard(self) -> None:
+        self._keep = False
+
+
+class _NullSpan:
+    """What a disabled tracer hands out: no ring record, no annotation."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def note(self, **attrs):
+        pass
+
+    def discard(self):
+        pass
+
+
+_NULL_SPAN = _NullSpan()
 
 
 class Tracer:
@@ -108,19 +172,18 @@ class Tracer:
         ts = self.clock() if t is None else t
         self.add(name, track, ts, ts, attrs)
 
-    @contextlib.contextmanager
-    def span(self, name: str, track: str = "main", **attrs):
-        """Context-manager form for host-side phases. The span is recorded
-        even when the body raises — a fault's timeline is the one that
-        matters most."""
+    def span(self, name: str, track: str = "main", **attrs) -> "LiveSpan":
+        """A live host phase, on two clocks at once: ``with tracer.span(...)``
+        appends the fixed tuple to the ring on this tracer's clock when the
+        body ends, and for the body's life holds a
+        ``jax.profiler.TraceAnnotation`` named ``"<track>/<name>"`` (``attrs``
+        as its stats), so that an open ``jax.profiler`` capture shows the
+        same span on the host plane, on the profiler's clock, on the thread
+        that ran it. The span is recorded even when the body raises — a
+        fault's timeline is the one that matters most. Disabled: neither."""
         if not self.enabled:
-            yield
-            return
-        t0 = self.clock()
-        try:
-            yield
-        finally:
-            self.add(name, track, t0, self.clock(), attrs or None)
+            return _NULL_SPAN
+        return LiveSpan(self, name, track, attrs)
 
     # --------------------------------------------------------------- reading
 

@@ -334,6 +334,7 @@ def _fwd(q, k, v, causal, alibi, scale, block_q, block_k, interpret,
             pltpu.VMEM((block_q, D), jnp.float32),  # acc
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(slopes, _offsets_arg(q_offset, kv_offset, B), *id_args, *seg_args, q, k, v)
     return jnp.swapaxes(o, 1, 2), lse
 
@@ -376,6 +377,7 @@ def _bwd(q, k, v, o, lse, do, causal, alibi, scale, block_q, block_k, interpret,
         out_shape=jax.ShapeDtypeStruct(q.shape, grad_dtype or q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(slopes, offs, *id_args, q, k, v, do, lse, delta)
 
     # k-block-major grid; q walked innermost. dk/dv computed per *query* head
@@ -406,6 +408,7 @@ def _bwd(q, k, v, o, lse, do, causal, alibi, scale, block_q, block_k, interpret,
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(slopes, offs, *id_args, q, k, v, do, lse, delta)
 
     dq = jnp.swapaxes(dq, 1, 2)

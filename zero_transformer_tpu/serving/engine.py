@@ -1959,40 +1959,43 @@ class ServingEngine:
             self._event("page_preemption", slots=len(faulted), phase="prefill")
             if not self._prefilling:
                 return True
-        t_chunk = self.now() if self.tracer.enabled else 0.0
         try:
-            if self._chaos is not None:
-                self._chaos.on_prefill_chunk(self._tick)
-            if paged:
-                chunk_args = (
-                    self.model,
-                    self.params,
-                    self.slots.cache,
-                    jnp.asarray(tokens, jnp.int32),
-                    jnp.asarray(starts, jnp.int32),
-                    jnp.asarray(lens, jnp.int32),
-                    jnp.asarray(active, jnp.bool_),
-                    jnp.asarray(self.slots.table),
-                    jnp.asarray(self._index_after(starts, lens, active), jnp.int32),
-                )
-                # observe skips model+params (engine-lifetime constants):
-                # the describe walk stays O(per-tick args), not O(params)
-                self._ds_prefill.observe(*chunk_args[2:])
-                cache, last = _in_mesh(self.mesh, self._paged_chunk, *chunk_args)
-            else:
-                chunk_args = (
-                    self.model,
-                    self.slots.axes_items,
-                    self.params,
-                    self.slots.cache,
-                    jnp.asarray(tokens, jnp.int32),
-                    jnp.asarray(starts, jnp.int32),
-                    jnp.asarray(lens, jnp.int32),
-                    jnp.asarray(active, jnp.bool_),
-                )
-                # skip model (0) + params (2); axes_items are cache statics
-                self._ds_prefill.observe(chunk_args[1], *chunk_args[3:])
-                cache, last = _in_mesh(self.mesh, self._chunk_fused, *chunk_args)
+            # prefill_chunk covers an asynchronous dispatch: the chunk
+            # program's device time is waited for in this tick's decode_step
+            with self.tracer.span("prefill_chunk", "engine", tick=self._tick,
+                                  slots=sum(active)):
+                if self._chaos is not None:
+                    self._chaos.on_prefill_chunk(self._tick)
+                if paged:
+                    chunk_args = (
+                        self.model,
+                        self.params,
+                        self.slots.cache,
+                        jnp.asarray(tokens, jnp.int32),
+                        jnp.asarray(starts, jnp.int32),
+                        jnp.asarray(lens, jnp.int32),
+                        jnp.asarray(active, jnp.bool_),
+                        jnp.asarray(self.slots.table),
+                        jnp.asarray(self._index_after(starts, lens, active), jnp.int32),
+                    )
+                    # observe skips model+params (engine-lifetime constants):
+                    # the describe walk stays O(per-tick args), not O(params)
+                    self._ds_prefill.observe(*chunk_args[2:])
+                    cache, last = _in_mesh(self.mesh, self._paged_chunk, *chunk_args)
+                else:
+                    chunk_args = (
+                        self.model,
+                        self.slots.axes_items,
+                        self.params,
+                        self.slots.cache,
+                        jnp.asarray(tokens, jnp.int32),
+                        jnp.asarray(starts, jnp.int32),
+                        jnp.asarray(lens, jnp.int32),
+                        jnp.asarray(active, jnp.bool_),
+                    )
+                    # skip model (0) + params (2); axes_items are cache statics
+                    self._ds_prefill.observe(chunk_args[1], *chunk_args[3:])
+                    cache, last = _in_mesh(self.mesh, self._chunk_fused, *chunk_args)
         except CompileFamilyExceeded:
             # strict-mode sanitizer trip: the whole point is the readable
             # signature listing — it must reach the test harness, not be
@@ -2001,11 +2004,6 @@ class ServingEngine:
         except Exception as exc:
             self._on_prefill_fault(exc)
             return True
-        if self.tracer.enabled:
-            self.tracer.add(
-                "prefill_chunk", "engine", t_chunk, self.now(),
-                {"tick": self._tick, "slots": sum(active)},
-            )
         self.slots.cache = cache
         self.stats["prefill_chunks"] += sum(active)
         completed = []
@@ -2300,19 +2298,39 @@ class ServingEngine:
         # counter (self._tick), so "capture N ticks" means N ticks of real
         # work, not N idle spins of the scheduler loop
         self._profiler.poll(self._tick)
+        # the tick's span tree (live spans: the ring on the engine's clock,
+        # "engine/<name>" annotations on the profiler's while a capture is
+        # open):  tick > schedule, prefill > prefill_chunk, grow_pages,
+        # decode_step > dispatch + device_wait, emit. What the children do
+        # not cover is the tick's self time.
+        with self.tracer.span("tick", "engine", tick=self._tick) as tick_span:
+            return self._run_tick(tick_span)
+
+    # graftlint: hot-path
+    # graftlint: supervised-seam
+    def _run_tick(self, tick_span) -> bool:
         tr = self.tracer
         tick_idx = self._tick
-        t_tick = self.now() if tr.enabled else 0.0
-        self._swap_pending_params()
-        self._sweep_queue()
-        self._sweep_active()
-        self._service_migrations()
-        self._service_imports()
-        self._prefill_work = False
-        self._admit()
-        ran_prefill = self._prefill_tick() if self.prefill_chunk else False
-        if self.kv_layout == "paged":
-            self._grow_decode_pages()
+        with tr.span("schedule", "engine", tick=tick_idx) as sched_span:
+            self._swap_pending_params()
+            self._sweep_queue()
+            self._sweep_active()
+            self._service_migrations()
+            self._service_imports()
+            self._prefill_work = False
+            self._admit()
+            if not (self._prefilling or self.active_count or self._breaker.open):
+                # nothing admitted, nothing running: this spin will record
+                # no tick, and a parked engine spins a thousand times a
+                # second — keep its schedule out of the ring too
+                sched_span.discard()
+        ran_prefill = False
+        if self.prefill_chunk and self._prefilling:
+            with tr.span("prefill", "engine", tick=tick_idx):
+                ran_prefill = self._prefill_tick()
+        if self.kv_layout == "paged" and self.active_count:
+            with tr.span("grow_pages", "engine", tick=tick_idx):
+                self._grow_decode_pages()
         # an idle DEGRADED engine still runs the fused step as a self-probe
         # (all rows parked, outputs discarded): without it, a load balancer
         # honoring the 503 starves the engine of the clean tick it needs to
@@ -2322,71 +2340,80 @@ class ServingEngine:
             if ran_prefill:
                 # prefill-only tick: nothing decodes yet, but the tick did
                 # real work and the loop must not sleep
-                if tr.enabled:
-                    tr.add("tick", "engine", t_tick, self.now(),
-                           {"tick": tick_idx, "phase": "prefill_only"})
+                tick_span.note(phase="prefill_only")
                 self.flight.tick({
                     "tick": tick_idx, "prefilling": len(self._prefilling),
                     "active": 0, "queued": len(self._queue), "emitted": 0,
                 })
                 self._tick += 1
                 return True
+            # a spin that found nothing to do is no tick of work: the ring
+            # stays unwritten (serve_mfu sums every ``tick`` as work)
+            tick_span.discard()
             return False
 
         # -- supervised region: a fault here poisons AT MOST this tick's
         # active slots, never the scheduler thread (run() stays alive and
         # queued requests admit on the next tick)
-        t_dec = self.now() if tr.enabled else 0.0
         try:
-            if self._chaos is not None:
-                self._chaos.on_tick(self._tick)
-            if self.kv_layout == "paged":
-                # one batched push of every block-table change this tick
-                # (admissions, growth, retirements) before the fused step
-                # reads the device tables
-                self.slots.flush_tables()
-            if self.draft_k and self._spec_enabled:
-                blocks, n_emits, bad_rows = self._dispatch_spec()
-            else:
-                if self.fused_tail:
-                    fused_args = (
-                        self.model,
-                        self.sampling,
-                        self.params,
-                        self._last_logits,
-                        self.slots.cache,
-                        self._gen_mask,
-                        self._rngs,
-                    )
-                    # skip model (0) + params (2) — engine-lifetime
-                    # constants; sampling statics + cache/logits/mask/rng
-                    # shapes remain
-                    self._ds_decode.observe(fused_args[1], *fused_args[3:])
-                    if self._paged_kernel:
-                        # the paged kernel's compiled family is selected by
-                        # the table/pool shapes inside the cache tree plus
-                        # the decode window — pin them at bound 1
-                        self._ds_paged.observe(
-                            fused_args[4], 1 + self.draft_k
-                        )
-                    token, self._last_logits, self.slots.cache, self._gen_mask, self._rngs, bad = _in_mesh(
-                        self.mesh, self._fused, *fused_args
-                    )
-                else:
-                    token, bad = self._dispatch_defused()
+            # decode_step is dispatch plus the host's wait for everything
+            # the device still owes this tick: the decode program AND the
+            # prefill program that prefill_chunk only dispatched. It is
+            # host time; the programs' own durations are in a capture's
+            # "XLA Modules" line.
+            with tr.span("decode_step", "engine", tick=tick_idx,
+                         active=self.active_count, spec=bool(self.draft_k)):
                 if self._chaos is not None:
-                    # injected NaNs land AFTER the step, so re-run the same
-                    # predicate over the poisoned logits — injected and organic
-                    # NaNs are judged by the identical criterion (the extra
-                    # dispatch is chaos-only; the healthy path stays at one)
-                    self._last_logits = self._chaos.poison_logits(
-                        self._tick, self._last_logits
-                    )
-                    bad = _in_mesh(self.mesh, nonfinite_rows, self._last_logits)
-                # graftlint: allow[host-sync-in-hot-path] reason=THE designed per-tick sync — one coalesced device_get of token + poison mask (PR 2's one-sync budget); every other read rides it
-                tokens, bad_rows = jax.device_get((token, bad))
-                blocks = [[int(t)] for t in tokens.tolist()]
-                n_emits = [1] * self.n_slots
+                    self._chaos.on_tick(self._tick)
+                if self.kv_layout == "paged":
+                    # one batched push of every block-table change this tick
+                    # (admissions, growth, retirements) before the fused step
+                    # reads the device tables
+                    self.slots.flush_tables()
+                if self.draft_k and self._spec_enabled:
+                    blocks, n_emits, bad_rows = self._dispatch_spec(tick_idx)
+                else:
+                    with tr.span("dispatch", "engine", tick=tick_idx):
+                        if self.fused_tail:
+                            fused_args = (
+                                self.model,
+                                self.sampling,
+                                self.params,
+                                self._last_logits,
+                                self.slots.cache,
+                                self._gen_mask,
+                                self._rngs,
+                            )
+                            # skip model (0) + params (2) — engine-lifetime
+                            # constants; sampling statics + cache/logits/mask/rng
+                            # shapes remain
+                            self._ds_decode.observe(fused_args[1], *fused_args[3:])
+                            if self._paged_kernel:
+                                # the paged kernel's compiled family is selected by
+                                # the table/pool shapes inside the cache tree plus
+                                # the decode window — pin them at bound 1
+                                self._ds_paged.observe(
+                                    fused_args[4], 1 + self.draft_k
+                                )
+                            token, self._last_logits, self.slots.cache, self._gen_mask, self._rngs, bad = _in_mesh(
+                                self.mesh, self._fused, *fused_args
+                            )
+                        else:
+                            token, bad = self._dispatch_defused()
+                        if self._chaos is not None:
+                            # injected NaNs land AFTER the step, so re-run the same
+                            # predicate over the poisoned logits — injected and organic
+                            # NaNs are judged by the identical criterion (the extra
+                            # dispatch is chaos-only; the healthy path stays at one)
+                            self._last_logits = self._chaos.poison_logits(
+                                self._tick, self._last_logits
+                            )
+                            bad = _in_mesh(self.mesh, nonfinite_rows, self._last_logits)
+                    with tr.span("device_wait", "engine", tick=tick_idx):
+                        # graftlint: allow[host-sync-in-hot-path] reason=THE designed per-tick sync — one coalesced device_get of token + poison mask (PR 2's one-sync budget); every other read rides it
+                        tokens, bad_rows = jax.device_get((token, bad))
+                    blocks = [[int(t)] for t in tokens.tolist()]
+                    n_emits = [1] * self.n_slots
         except CompileFamilyExceeded:
             # strict-mode sanitizer trip: surface the signature listing to
             # the test harness instead of feeding it to the breaker as an
@@ -2395,6 +2422,7 @@ class ServingEngine:
         except Exception as exc:
             # ring entry FIRST: a breaker trip inside _on_tick_fault dumps
             # the recorder, and the dump must contain the tick that tripped
+            tick_span.note(fault=True)
             self.flight.tick({
                 "tick": tick_idx, "fault": True, "error": repr(exc),
                 "queued": len(self._queue),
@@ -2402,144 +2430,136 @@ class ServingEngine:
             self._on_tick_fault(exc)
             self._tick += 1
             return True
-        if tr.enabled:
-            # decode_step covers dispatch + the device_get sync — the
-            # on-device milliseconds of this tick
-            tr.add("decode_step", "engine", t_dec, self.now(),
-                   {"tick": tick_idx, "active": self.active_count,
-                    "spec": bool(self.draft_k)})
         if self._breaker.record_clean():
             self._rebuilds_since_recovery = 0
             if not self.draining:
                 self.lifecycle.to(READY, reason="breaker closed after clean tick")
             self._event("breaker_closed")
 
-        now = self.now()
-        finished: List[int] = []
-        poisoned: List[int] = []
-        ttft_new: List[tuple] = []  # (sample_s, qos_class)
-        itl_new: List[tuple] = []
-        tokens_before = self.stats["tokens_out"]
-        paged_ledger = self.kv_layout == "paged"
-        for slot, act in enumerate(self._active):
-            if act is None:
-                continue
-            qos_cls = self.qos.normalize(act.handle.request.qos)
-            toks = blocks[slot][: n_emits[slot]]
-            # cost ledger: one decode tick held, at this slot's current KV
-            # page footprint (pages x ticks is the capacity-time integral a
-            # tenant actually consumed; slab slots have no page unit — 0)
-            act.handle.ledger["decode_ticks"] += 1
-            if paged_ledger:
-                act.handle.ledger["pages_held_ticks"] += (
-                    self.slots.alloc_blocks[slot]
-                )
-            if act.emitted == 0:
-                ttft_new.append((now - act.handle.submitted_at, qos_cls))
-            elif act.last_emit_at is not None:
-                # a speculative tick delivers its accepted block in one
-                # burst; one AMORTIZED sample per token keeps the ITL
-                # percentiles honest about per-token latency (n_emit = 1
-                # degenerates to the classic one-sample-per-tick)
-                gap = now - act.last_emit_at
-                itl_new.extend([(gap / len(toks), qos_cls)] * len(toks))
-            # the block's first token was sampled from the PREVIOUS (finite)
-            # logits, so it is valid even when the new logits went bad —
-            # emit it, then retire the poisoned slot with a retryable error
-            # (a bad row's n_emit is already clamped to that first token:
-            # drafts "verified" by garbage logits are never emitted)
-            done_now = False
-            for t in toks:
-                act.handle._emit(int(t), now)
-                act.emitted += 1
-                act.last_emit_at = now
-                self.stats["tokens_out"] += 1
-                act.handle.ledger["tokens_out"] += 1
-                hit_eos = (
-                    self.eos_token_id is not None and int(t) == self.eos_token_id
-                )
-                if hit_eos or act.emitted >= act.handle.request.max_new_tokens:
-                    # completion outranks the poison flag: the tokens
-                    # emitted so far all trace to finite logits, so a
-                    # request finishing now delivered a fully valid output
-                    act.handle._finish(DONE, now)
-                    self.stats["completed"] += 1
+        with tr.span("emit", "engine", tick=tick_idx) as emit_span:
+            now = self.now()
+            finished: List[int] = []
+            poisoned: List[int] = []
+            ttft_new: List[tuple] = []  # (sample_s, qos_class)
+            itl_new: List[tuple] = []
+            tokens_before = self.stats["tokens_out"]
+            paged_ledger = self.kv_layout == "paged"
+            for slot, act in enumerate(self._active):
+                if act is None:
+                    continue
+                qos_cls = self.qos.normalize(act.handle.request.qos)
+                toks = blocks[slot][: n_emits[slot]]
+                # cost ledger: one decode tick held, at this slot's current KV
+                # page footprint (pages x ticks is the capacity-time integral a
+                # tenant actually consumed; slab slots have no page unit — 0)
+                act.handle.ledger["decode_ticks"] += 1
+                if paged_ledger:
+                    act.handle.ledger["pages_held_ticks"] += (
+                        self.slots.alloc_blocks[slot]
+                    )
+                if act.emitted == 0:
+                    ttft_new.append((now - act.handle.submitted_at, qos_cls))
+                elif act.last_emit_at is not None:
+                    # a speculative tick delivers its accepted block in one
+                    # burst; one AMORTIZED sample per token keeps the ITL
+                    # percentiles honest about per-token latency (n_emit = 1
+                    # degenerates to the classic one-sample-per-tick)
+                    gap = now - act.last_emit_at
+                    itl_new.extend([(gap / len(toks), qos_cls)] * len(toks))
+                # the block's first token was sampled from the PREVIOUS (finite)
+                # logits, so it is valid even when the new logits went bad —
+                # emit it, then retire the poisoned slot with a retryable error
+                # (a bad row's n_emit is already clamped to that first token:
+                # drafts "verified" by garbage logits are never emitted)
+                done_now = False
+                for t in toks:
+                    act.handle._emit(int(t), now)
+                    act.emitted += 1
+                    act.last_emit_at = now
+                    self.stats["tokens_out"] += 1
+                    act.handle.ledger["tokens_out"] += 1
+                    hit_eos = (
+                        self.eos_token_id is not None and int(t) == self.eos_token_id
+                    )
+                    if hit_eos or act.emitted >= act.handle.request.max_new_tokens:
+                        # completion outranks the poison flag: the tokens
+                        # emitted so far all trace to finite logits, so a
+                        # request finishing now delivered a fully valid output
+                        act.handle._finish(DONE, now)
+                        self.stats["completed"] += 1
+                        finished.append(slot)
+                        done_now = True
+                        break
+                if not done_now and bool(bad_rows[slot]):
+                    act.handle._finish(
+                        FAILED, now,
+                        error="non-finite logits in decode (retryable)",
+                        retryable=True,
+                    )
+                    self.stats["poisoned_slots"] += 1
+                    poisoned.append(slot)
                     finished.append(slot)
-                    done_now = True
-                    break
-            if not done_now and bool(bad_rows[slot]):
-                act.handle._finish(
-                    FAILED, now,
-                    error="non-finite logits in decode (retryable)",
-                    retryable=True,
-                )
-                self.stats["poisoned_slots"] += 1
-                poisoned.append(slot)
-                finished.append(slot)
-            elif not done_now and act.handle.overflowed:
-                # the STREAMING consumer stopped draining past the emit
-                # buffer bound: stop paying slot/page capacity for a
-                # reader that went away. Retryable — the done event always
-                # delivers, so a recovered client re-submits cleanly.
-                act.handle._finish(
-                    FAILED, now,
-                    error=(
-                        "client stalled mid-stream; emit buffer "
-                        "overflowed (retryable)"
-                    ),
-                    retryable=True,
-                )
-                self.stats["stalled_streams"] += 1
-                finished.append(slot)
-                self._event("stalled_stream", request_id=act.handle.rid)
-        if any(bad_rows):
-            # zero EVERY bad row (poisoned-and-retired or finished-anyway)
-            # so a parked slot never feeds NaN back into the next tick's
-            # sample — retirement alone leaves the row in place
-            keep = jnp.asarray([not b for b in bad_rows], jnp.bool_)
-            self._last_logits = jnp.where(keep[:, None], self._last_logits, 0.0)
-        if poisoned:
-            self._event("poisoned_slots", slots=len(poisoned))
-        # histograms carry their own micro-locks — no scheduler lock, and a
-        # concurrent /metrics scrape reads bucket counts, never a sample list
-        for sample, cls in ttft_new:
-            self._h_ttft.observe(sample)
-            self._h_ttft_class[cls].observe(sample)
-        for sample, cls in itl_new:
-            self._h_itl.observe(sample)
-            self._h_itl_class[cls].observe(sample)
-            if not self._prefill_work:
-                # per-phase attribution: this tick ran no prefill work
-                # (chunk, span copy, or one-shot admission), so these
-                # samples are the pure-decode ITL floor
-                self._h_itl_decode.observe(sample)
-            self._itl_ewma.update(sample)
-        self._retire(finished)
-
-        emitted_total = self.stats["tokens_out"] - tokens_before
-        if tr.enabled:
-            tr.add("emit", "engine", now, self.now(),
-                   {"tick": tick_idx, "finished": len(finished)})
-            tr.add("tick", "engine", t_tick, self.now(), {"tick": tick_idx})
-        self.flight.tick({
-            "tick": tick_idx, "active": self.active_count,
-            "prefilling": len(self._prefilling), "queued": len(self._queue),
-            "emitted": emitted_total, "finished": len(finished),
-            "poisoned": len(poisoned),
-        })
-        self._tick += 1
-        if (
-            self.metrics is not None
-            and self.metrics_interval
-            and self._tick % self.metrics_interval == 0
-        ):
-            self.metrics.log(self.metrics_snapshot(), step=self._tick, prefix="serve")
+                elif not done_now and act.handle.overflowed:
+                    # the STREAMING consumer stopped draining past the emit
+                    # buffer bound: stop paying slot/page capacity for a
+                    # reader that went away. Retryable — the done event always
+                    # delivers, so a recovered client re-submits cleanly.
+                    act.handle._finish(
+                        FAILED, now,
+                        error=(
+                            "client stalled mid-stream; emit buffer "
+                            "overflowed (retryable)"
+                        ),
+                        retryable=True,
+                    )
+                    self.stats["stalled_streams"] += 1
+                    finished.append(slot)
+                    self._event("stalled_stream", request_id=act.handle.rid)
+            if any(bad_rows):
+                # zero EVERY bad row (poisoned-and-retired or finished-anyway)
+                # so a parked slot never feeds NaN back into the next tick's
+                # sample — retirement alone leaves the row in place
+                keep = jnp.asarray([not b for b in bad_rows], jnp.bool_)
+                self._last_logits = jnp.where(keep[:, None], self._last_logits, 0.0)
+            if poisoned:
+                self._event("poisoned_slots", slots=len(poisoned))
+            # histograms carry their own micro-locks — no scheduler lock, and a
+            # concurrent /metrics scrape reads bucket counts, never a sample list
+            for sample, cls in ttft_new:
+                self._h_ttft.observe(sample)
+                self._h_ttft_class[cls].observe(sample)
+            for sample, cls in itl_new:
+                self._h_itl.observe(sample)
+                self._h_itl_class[cls].observe(sample)
+                if not self._prefill_work:
+                    # per-phase attribution: this tick ran no prefill work
+                    # (chunk, span copy, or one-shot admission), so these
+                    # samples are the pure-decode ITL floor
+                    self._h_itl_decode.observe(sample)
+                self._itl_ewma.update(sample)
+            self._retire(finished)
+            emit_span.note(finished=len(finished))
+            # the tick's own record keeping closes the phase, so that what
+            # ``tick`` holds beyond its children is span overhead alone
+            self.flight.tick({
+                "tick": tick_idx, "active": self.active_count,
+                "prefilling": len(self._prefilling), "queued": len(self._queue),
+                "emitted": self.stats["tokens_out"] - tokens_before,
+                "finished": len(finished), "poisoned": len(poisoned),
+            })
+            self._tick += 1
+            if (
+                self.metrics is not None
+                and self.metrics_interval
+                and self._tick % self.metrics_interval == 0
+            ):
+                self.metrics.log(self.metrics_snapshot(), step=self._tick, prefix="serve")
         return not probe
 
     # --------------------------------------------------- speculative decode
 
     # graftlint: hot-path
-    def _dispatch_spec(self):
+    def _dispatch_spec(self, tick_idx: int):
         """Run the speculative fused step for this tick: host-propose K
         draft tokens per decoding slot (prompt-lookup over the slot's own
         prompt + emitted history, or the engine's pluggable ``draft_fn``),
@@ -2547,45 +2567,48 @@ class ServingEngine:
         blocks. A row whose verify logits went non-finite is clamped to its
         first token (sampled from the previous, finite distribution) — the
         plain step's exact poison semantics."""
-        K, S = self.draft_k, self.n_slots
-        V = self.cfg.vocab_size
-        drafts = [[0] * K for _ in range(S)]
-        active = [a is not None for a in self._active]
-        for slot, act in enumerate(self._active):
-            if act is None:
-                continue
-            hist = list(act.handle.request.prompt) + act.handle.tokens
-            d = [int(t) for t in self.draft_fn(hist, K)]
-            # clamp a misbehaving custom draft_fn: wrong-length or
-            # out-of-vocab drafts must degrade acceptance, not crash a tick
-            drafts[slot] = [t % V for t in d[:K]] + [0] * (K - len(d))
-        spec_args = (
-            self.model,
-            self.sampling,
-            K,
-            self.params,
-            self._last_logits,
-            self.slots.cache,
-            self._gen_mask,
-            self._rngs,
-            jnp.asarray(drafts, jnp.int32),
-            self._veto,
-            jnp.asarray(active, jnp.bool_),
-        )
-        # skip model (0) + params (3) — engine-lifetime constants
-        self._ds_spec.observe(*spec_args[1:3], *spec_args[4:])
-        if self._paged_kernel:
-            self._ds_paged.observe(spec_args[5], 1 + K)
-        x, n_acc, self._last_logits, self.slots.cache, self._gen_mask, self._rngs, self._veto, bad = _in_mesh(
-            self.mesh, self._spec, *spec_args
-        )
-        if self._chaos is not None:
-            self._last_logits = self._chaos.poison_logits(
-                self._tick, self._last_logits
+        tr = self.tracer
+        with tr.span("dispatch", "engine", tick=tick_idx):
+            K, S = self.draft_k, self.n_slots
+            V = self.cfg.vocab_size
+            drafts = [[0] * K for _ in range(S)]
+            active = [a is not None for a in self._active]
+            for slot, act in enumerate(self._active):
+                if act is None:
+                    continue
+                hist = list(act.handle.request.prompt) + act.handle.tokens
+                d = [int(t) for t in self.draft_fn(hist, K)]
+                # clamp a misbehaving custom draft_fn: wrong-length or
+                # out-of-vocab drafts must degrade acceptance, not crash a tick
+                drafts[slot] = [t % V for t in d[:K]] + [0] * (K - len(d))
+            spec_args = (
+                self.model,
+                self.sampling,
+                K,
+                self.params,
+                self._last_logits,
+                self.slots.cache,
+                self._gen_mask,
+                self._rngs,
+                jnp.asarray(drafts, jnp.int32),
+                self._veto,
+                jnp.asarray(active, jnp.bool_),
             )
-            bad = bad | _in_mesh(self.mesh, nonfinite_rows, self._last_logits)
-        # graftlint: allow[host-sync-in-hot-path] reason=THE designed per-tick sync of the speculative path — one coalesced device_get of the accepted block + counts + poison mask
-        xs, n_accs, bad_rows = jax.device_get((x, n_acc, bad))
+            # skip model (0) + params (3) — engine-lifetime constants
+            self._ds_spec.observe(*spec_args[1:3], *spec_args[4:])
+            if self._paged_kernel:
+                self._ds_paged.observe(spec_args[5], 1 + K)
+            x, n_acc, self._last_logits, self.slots.cache, self._gen_mask, self._rngs, self._veto, bad = _in_mesh(
+                self.mesh, self._spec, *spec_args
+            )
+            if self._chaos is not None:
+                self._last_logits = self._chaos.poison_logits(
+                    self._tick, self._last_logits
+                )
+                bad = bad | _in_mesh(self.mesh, nonfinite_rows, self._last_logits)
+        with tr.span("device_wait", "engine", tick=tick_idx):
+            # graftlint: allow[host-sync-in-hot-path] reason=THE designed per-tick sync of the speculative path — one coalesced device_get of the accepted block + counts + poison mask
+            xs, n_accs, bad_rows = jax.device_get((x, n_acc, bad))
         self.stats["spec_ticks"] += 1
         blocks = [row.tolist() for row in xs]
         n_emits = [1] * S
@@ -3507,7 +3530,12 @@ class ServingEngine:
             if self.draining and self.poll_drain():
                 return  # drained clean: nothing queued or active remains
             if not busy:
-                time.sleep(idle_sleep)
+                # every instant of this thread is under ``tick`` or ``idle``
+                # on the profiler's side; the ring keeps no idle spans (a
+                # parked engine would fill it at a thousand a second)
+                with self.tracer.span("idle", "engine") as idle_span:
+                    idle_span.discard()
+                    time.sleep(idle_sleep)
         # graceful stop: anything still queued or mid-decode will never get
         # another tick — finish it as failed so blocked consumers unblock
         self._abort("engine stopped")

@@ -34,6 +34,7 @@ from zero_transformer_tpu.parallel.zero import (
 )
 from zero_transformer_tpu.training.optimizer import make_optimizer, make_schedule
 from zero_transformer_tpu.obs import FlightRecorder, Tracer
+from zero_transformer_tpu.obs.profiling import start_trace
 from zero_transformer_tpu.utils import monitoring
 from zero_transformer_tpu.utils.jax_compat import ensure_donatable
 
@@ -810,33 +811,30 @@ class Trainer:
         try:
             while step < end:
                 if profile_stop and not profiling and step == profile_trigger:
-                    jax.profiler.start_trace(profile_dir)
+                    start_trace(profile_dir)
                     profiling = True
                     log.info("profiler: tracing %d steps to %s", cfg.profile_steps, profile_dir)
-                t_fetch = tr.clock()
-                local = next(it)
-                batch = device_put_batch(local, self.batch_sharding)
-                t_disp = tr.clock()
-                if tr.enabled:
-                    tr.add("data_fetch", "train", t_fetch, t_disp,
-                           {"step": step + 1})
-                # observe only the axes that can vary mid-run (batch
-                # geometry, rng layout, guard carry) — state shapes are
-                # fixed at build time and threaded through step_fn, and
-                # describing the whole param tree would cost O(params)
-                # per step for no added detection
-                if guard is not None:
-                    self.dispatch_site.observe(batch, self.rng, carry)
-                    state, metrics, carry = step_fn(state, batch, self.rng, carry)
-                else:
-                    self.dispatch_site.observe(batch, self.rng)
-                    state, metrics = step_fn(state, batch, self.rng)
-                if tr.enabled:
-                    # dispatch, not compute: jax returns futures — the
-                    # device milliseconds show up in device_sync at the
-                    # next log point (and in a --profile-window capture)
-                    tr.add("dispatch", "train", t_disp, tr.clock(),
-                           {"step": step + 1})
+                # live spans (obs/spans.py): the ring on the tracer's clock
+                # and, while a capture is open, "train/<name>" annotations
+                # beside the device's programs on the profiler's
+                with tr.span("data_fetch", "train", step=step + 1):
+                    local = next(it)
+                    batch = device_put_batch(local, self.batch_sharding)
+                # dispatch, not compute: jax returns futures — the device
+                # milliseconds show up in device_sync at the next log point
+                # (and in a --profile-window capture)
+                with tr.span("dispatch", "train", step=step + 1):
+                    # observe only the axes that can vary mid-run (batch
+                    # geometry, rng layout, guard carry) — state shapes are
+                    # fixed at build time and threaded through step_fn, and
+                    # describing the whole param tree would cost O(params)
+                    # per step for no added detection
+                    if guard is not None:
+                        self.dispatch_site.observe(batch, self.rng, carry)
+                        state, metrics, carry = step_fn(state, batch, self.rng, carry)
+                    else:
+                        self.dispatch_site.observe(batch, self.rng)
+                        state, metrics = step_fn(state, batch, self.rng)
                 step += 1
                 self.last_step = step
                 self._live = (step, state)
@@ -852,14 +850,11 @@ class Trainer:
 
                 paused = False
                 if step % cfg.log_frequency == 0 or step == end:
-                    t_sync = tr.clock()
-                    # graftlint: allow[host-sync-in-hot-path] reason=THE designed log-point sync (every log_frequency steps, not per step) — the device_sync span right below measures exactly this wait
-                    loss = float(metrics["loss"])  # device sync point
-                    if tr.enabled:
-                        # host-blocked time waiting on the device: the gap
-                        # between dispatch rate and compute rate
-                        tr.add("device_sync", "train", t_sync, tr.clock(),
-                               {"step": step})
+                    # host-blocked time waiting on the device: the gap
+                    # between dispatch rate and compute rate
+                    with tr.span("device_sync", "train", step=step):
+                        # graftlint: allow[host-sync-in-hot-path] reason=THE designed log-point sync (every log_frequency steps, not per step) — the device_sync span around it measures exactly this wait
+                        loss = float(metrics["loss"])  # device sync point
                     if (
                         cfg.halt_on_nan
                         and not jnp.isfinite(loss)
@@ -905,32 +900,17 @@ class Trainer:
                         if util is not None:
                             payload["mfu"] = util
                         # step-time decomposition (PR 8): analytic bubble +
-                        # bench-measured exposed comm, as metric keys and as
-                        # estimate spans subdividing this logging window —
-                        # the same fractions the train_bubble_frac /
-                        # train_exposed_comm_frac gauges export on /metrics
+                        # bench-measured exposed comm, as metric keys — the
+                        # same fractions the train_bubble_frac /
+                        # train_exposed_comm_frac gauges export on /metrics.
+                        # They are an operator's reading, not a measurement:
+                        # the span timeline holds measured spans only
                         if self._bubble_frac > 0:
                             payload["bubble_frac"] = self._bubble_frac
                         if self._exposed_comm_frac is not None:
                             payload["exposed_comm_frac"] = (
                                 self._exposed_comm_frac
                             )
-                        if tr.enabled:
-                            comm = self._exposed_comm_frac or 0.0
-                            bub = self._bubble_frac
-                            t_phase = tr.clock() - dt
-                            for name, frac in (
-                                ("grads_compute", max(0.0, 1.0 - comm - bub)),
-                                ("comm_exposed", comm),
-                                ("bubble_wait", bub),
-                            ):
-                                if frac > 0:
-                                    tr.add(
-                                        name, "train", t_phase,
-                                        t_phase + dt * frac,
-                                        {"step": step, "estimate": True},
-                                    )
-                                    t_phase += dt * frac
                     hbm = monitoring.hbm_device_stats()
                     if hbm is not None:
                         # max across local devices (the OOM-relevant number;
@@ -991,61 +971,58 @@ class Trainer:
                         )
                     tick_step = step
                     if guard is not None:
-                        t_audit = tr.clock()
-                        state, carry, rolled = self._handle_replica_divergence(
-                            new_audit, state, carry, guard, snapshot,
-                            rollbacks, step,
-                        )
-                        if rolled:
-                            # audit rollback reset the carry; both counters
-                            # restart from zero at the next read
-                            anom_seen = 0
-                            audit_seen = 0
-                        else:
-                            audit_seen = stats.audit_failures
-                            state, carry, rolled = self._handle_anomalies(
-                                stats, new_anoms, state, carry, guard, snapshot,
+                        # guard-carry read + divergence/anomaly escalation
+                        # + snapshot refresh, as one phase
+                        with tr.span("replica_audit", "train", step=step) as audit_span:
+                            state, carry, rolled = self._handle_replica_divergence(
+                                new_audit, state, carry, guard, snapshot,
                                 rollbacks, step,
                             )
-                            anom_seen = 0 if rolled else stats.count
                             if rolled:
+                                # audit rollback reset the carry; both counters
+                                # restart from zero at the next read
+                                anom_seen = 0
                                 audit_seen = 0
-                        if rolled:
-                            rollbacks += 1
-                            self.resilience_report["rollbacks"] = rollbacks
-                            paused = True  # exclude rollback time from timing
-                        # mirror a known-good state to host RAM on schedule.
-                        # With the replica audit active, "known-good" also
-                        # requires a CLEAN audit to have run since the last
-                        # capture: otherwise a desync that happened between
-                        # audits could be captured and later re-replicated
-                        # by the "heal" rollback, baking the corruption into
-                        # every replica. (Residual window: corruption in the
-                        # <= audit_frequency steps since the last clean
-                        # audit can still slip in — the audit bounds it.)
-                        audit_vouched = (
-                            getattr(guard, "_audit", None) is None
-                            or (
-                                new_audit == 0
-                                and step // res.audit_frequency
-                                > last_snap_step // res.audit_frequency
+                            else:
+                                audit_seen = stats.audit_failures
+                                state, carry, rolled = self._handle_anomalies(
+                                    stats, new_anoms, state, carry, guard, snapshot,
+                                    rollbacks, step,
+                                )
+                                anom_seen = 0 if rolled else stats.count
+                                if rolled:
+                                    audit_seen = 0
+                            if rolled:
+                                rollbacks += 1
+                                self.resilience_report["rollbacks"] = rollbacks
+                                paused = True  # exclude rollback time from timing
+                            # mirror a known-good state to host RAM on schedule.
+                            # With the replica audit active, "known-good" also
+                            # requires a CLEAN audit to have run since the last
+                            # capture: otherwise a desync that happened between
+                            # audits could be captured and later re-replicated
+                            # by the "heal" rollback, baking the corruption into
+                            # every replica. (Residual window: corruption in the
+                            # <= audit_frequency steps since the last clean
+                            # audit can still slip in — the audit bounds it.)
+                            audit_vouched = (
+                                getattr(guard, "_audit", None) is None
+                                or (
+                                    new_audit == 0
+                                    and step // res.audit_frequency
+                                    > last_snap_step // res.audit_frequency
+                                )
                             )
-                        )
-                        if (
-                            snapshot is not None
-                            and stats.streak == 0
-                            and not rolled
-                            and audit_vouched
-                            and step - last_snap_step >= res.snapshot_frequency
-                        ):
-                            snapshot.capture(state)
-                            last_snap_step = step
-                        if tr.enabled:
-                            # guard-carry read + divergence/anomaly
-                            # escalation + snapshot refresh, as one phase
-                            tr.add("replica_audit", "train", t_audit,
-                                   tr.clock(), {"step": step,
-                                                "rolled": rolled})
+                            if (
+                                snapshot is not None
+                                and stats.streak == 0
+                                and not rolled
+                                and audit_vouched
+                                and step - last_snap_step >= res.snapshot_frequency
+                            ):
+                                snapshot.capture(state)
+                                last_snap_step = step
+                            audit_span.note(rolled=rolled)
 
                 if cfg.evaluation_frequency and step % cfg.evaluation_frequency == 0:
                     with tr.span("evaluate", "train", step=step):
@@ -1054,11 +1031,11 @@ class Trainer:
                         )
                     paused = True
 
-                t_save = tr.clock()
-                if self.ckpt.save(step, state, meta=self._save_meta()):
-                    if tr.enabled:
-                        tr.add("checkpoint_save", "train", t_save, tr.clock(),
-                               {"step": step})
+                with tr.span("checkpoint_save", "train", step=step) as save_span:
+                    saved = self.ckpt.save(step, state, meta=self._save_meta())
+                    if not saved:
+                        save_span.discard()  # not a save tick: no span
+                if saved:
                     paused = True
                 if paused:
                     # exclude eval/checkpoint wall time from the throughput window
