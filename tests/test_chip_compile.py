@@ -72,8 +72,8 @@ def test_paged_decode_kernel_compiles(one_chip, H, D, T, int8):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = sds((n_pages, page, H, D), jnp.int8 if int8 else jnp.bfloat16)
-    scales = (sds((n_pages, page, H, 1), jnp.float32),) * 2 if int8 else ()
+    pool = sds((n_pages, page, H * D), jnp.int8 if int8 else jnp.bfloat16)
+    scales = (sds((n_pages, page, H), jnp.float32),) * 2 if int8 else ()
 
     def step(q, k_pool, v_pool, table, offsets, *scales):
         k_scale, v_scale = scales or (None, None)
@@ -86,6 +86,182 @@ def test_paged_decode_kernel_compiles(one_chip, H, D, T, int8):
         step, sds((B, T, H, D), jnp.bfloat16), pool, pool,
         sds((B, n_blocks), jnp.int32), sds((B,), jnp.int32), *scales,
     ) == 1
+
+
+# ---- the engine's real programs: where does the KV page pool go? -----------
+
+N_SLOTS, PAGE, CACHE_LEN, CHUNK, DEPTH = 16, 16, 2048, 64, 2
+N_PAGES = N_SLOTS * CACHE_LEN // PAGE + 1  # 2049: in no other shape here
+
+
+LANES = 12 * 128  # one K/V pool row; the int8 scale pools have 12 lanes
+
+# what the compiler's own prefetch into the core's fast memory ("S(1)" in a
+# layout) is made of; a `copy` / `copy-start` whose destination is there
+# belongs to it, one that lands in HBM is a copy of the pool
+PREFETCH = {"slice-start", "slice-done", "ConcatBitcast", "copy-done"}
+
+
+def _pool_ops(hlo: str):
+    """Every operation of an optimised HLO module that MATERIALIZES a
+    pool-sized value (a result whose element count is a multiple of
+    pages x page size: 2049 = 3 x 683 divides no other shape here), as
+    ``(opcode, name, kv, in_loop)``: the instructions of the entry, loop
+    and branch computations, and for a fusion the pool-sized operations
+    inside it (a fusion is one kernel writing its result once; what it is
+    made of says whether that is an in-place scatter or a copy). ``kv``
+    tells a K/V pool from an int8 scale pool, ``in_loop`` the layer loop's
+    body from the program's edge. Parameters, tuples and bitcasts move no
+    byte and are left out."""
+    import math
+    import re
+
+    comps, fused, entry, name = {}, set(), None, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if head and not line.startswith(" "):
+            name = head.group(2)
+            comps[name] = []
+            entry = name if head.group(1) else entry
+        elif name and " = " in line:
+            comps[name].append(line)
+            fused.update(re.findall(r" fusion\(.*calls=%?([\w.\-]+)", line))
+    free = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+            "conditional", "call", "constant", "broadcast", "iota", "reshape"}
+
+    def pool_sized(comp):
+        for line in comps[comp]:
+            m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(", line)
+            if not m:
+                continue
+            op_name, result, opcode = m.groups()
+            shapes = re.findall(r"[a-z0-9]+\[([\d,]+)\](\{[^}]*\})?", result)
+            sizes = [math.prod(int(d) for d in dims.split(",")) for dims, _ in shapes]
+            sizes = [n for n in sizes if n % (N_PAGES * PAGE) == 0]
+            if not sizes:
+                continue
+            if opcode == "custom-call":
+                opcode = re.search(r'custom_call_target="(\w+)"', line).group(1)
+            if opcode in ("copy", "copy-start") and "S(1)" in shapes[0][1]:
+                opcode = "copy-done"  # destination in fast memory: prefetch
+            kv = any(n % (N_PAGES * PAGE * LANES) == 0 for n in sizes)
+            if opcode == "fusion":
+                inner = re.search(r"calls=%?([\w.\-]+)", line).group(1)
+                for op, _, _ in pool_sized(inner):
+                    yield op, op_name, kv
+            elif opcode not in free:
+                yield opcode, op_name, kv
+
+    return [
+        (op, op_name, kv, comp != entry)
+        for comp in comps if comp not in fused
+        for op, op_name, kv in pool_sized(comp)
+    ]
+
+
+def _serving_programs(one_chip, monkeypatch, int8: bool, scan: bool):
+    """The 580M serving model's structure (d 1536, 12 heads of 128, float32
+    weights, bf16 compute; depth cut to keep the compile in seconds) at the
+    benchmark cell's engine shapes — 16 slots x 2048, page 16, chunk 64 —
+    as the engine's own jitted decode step and paged chunk prefill,
+    compiled for the described chip. Returns their optimised HLO and the
+    number of pool leaves."""
+    from zero_transformer_tpu.config import ModelConfig
+    from zero_transformer_tpu.inference.generate import decode_model
+    from zero_transformer_tpu.inference.sampling import SamplingConfig
+    from zero_transformer_tpu.serving import engine as eng
+    from zero_transformer_tpu.serving.slots import (
+        POOL_LEAVES, _cache_struct, _leaf_name, vectorize_index,
+    )
+
+    # the kernel gates ask the backend, and the backend here is the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("ZT_PALLAS_INTERPRET", raising=False)
+    cfg = ModelConfig(
+        name="serve_580m_cut", d_model=1536, n_layers=DEPTH, n_heads=12,
+        head_dim=128, d_ff=6144, vocab_size=50304, max_seq_len=CACHE_LEN,
+        position="alibi", norm="layernorm", activation="gelu",
+        tie_embeddings=True, param_dtype="float32", compute_dtype="bfloat16",
+        dropout=0.0, attention_impl="auto", scan_layers=scan,
+        kv_cache_dtype="int8" if int8 else "auto",
+    )
+    model = decode_model(cfg, CACHE_LEN, kv_pages=(N_PAGES, PAGE))
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree
+        )
+
+    shapes = jax.eval_shape(
+        lambda r: model.init(r, jnp.zeros((N_SLOTS, 1), jnp.int32)),
+        jax.random.PRNGKey(0),
+    )
+    from zero_transformer_tpu.parallel.sharding import unbox
+
+    params = on_chip(unbox(shapes["params"]))
+    cache = on_chip(jax.eval_shape(
+        lambda: vectorize_index(
+            jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                         _cache_struct(model, N_SLOTS)), N_SLOTS)
+    ))
+    n_pools = sum(
+        _leaf_name(p) in POOL_LEAVES
+        for p, _ in jax.tree_util.tree_leaves_with_path(cache)
+    )
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    V = cfg.vocab_size
+    decode = eng._jit_fused_step().lower(
+        model, SamplingConfig(greedy=True, repetition_penalty=1.0), params,
+        sds((N_SLOTS, V), jnp.float32), cache, sds((N_SLOTS, V), jnp.bool_),
+        sds((N_SLOTS, 2), jnp.uint32),
+    ).compile().as_text()
+    rows = sds((N_SLOTS,), jnp.int32)
+    prefill = jax.jit(eng._paged_chunk_prefill_impl, static_argnums=(0,)).lower(
+        model, params, cache, sds((N_SLOTS, CHUNK), jnp.int32), rows, rows,
+        sds((N_SLOTS,), jnp.bool_), sds((N_SLOTS, CACHE_LEN // PAGE), jnp.int32),
+        rows,
+    ).compile().as_text()
+    return decode, prefill, n_pools
+
+
+@pytest.mark.parametrize("scan", [True, False], ids=["scan", "unrolled"])
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch, int8, scan):
+    """The counter of "the pool has ONE layout from allocation to kernel and
+    every program updates it in place". In the donated decode program the
+    only operations with a K/V-pool-sized result are the in-place scatters,
+    and every pool leaf aliases its input; the chunk-prefill program, NOT
+    donated by design (a prefill fault keeps the pre-chunk pool), holds
+    exactly the one whole-pool copy per K/V leaf that this forces. Neither
+    slices a pool out of anything, and the layer loop's body holds scatters
+    alone. On the parent commit (pool scanned over as xs/ys, declared
+    [.., KVH, D]) the decode program held 8 copies, 2 re-layouts and 2
+    slices of the pool per layer.
+
+    The int8 scale pools (12 lanes of f32) are held to "never sliced"
+    alone: the chip's default layout for so narrow an array is not the
+    row-major one a Mosaic call takes, so each program re-lays them out on
+    the way in and on the way out (at 18 layers, at its edge; at this cut
+    depth they fit the core's fast memory and the compiler also moves them
+    there and back)."""
+    decode, prefill, n_pools = _serving_programs(one_chip, monkeypatch, int8, scan)
+    n_kv = n_pools // 2 if int8 else n_pools
+    assert decode.count("tpu_custom_call") >= 1  # the paged kernel is on the path
+    aliased = decode.split("input_output_alias={", 1)[1].split("}, entry", 1)[0]
+    assert aliased.count("-alias") >= n_pools
+
+    for ops in (_pool_ops(decode), _pool_ops(prefill)):
+        sliced = {"dynamic-slice", "dynamic-update-slice", "gather", "AllocateBuffer"}
+        assert not [o for o in ops if o[0] in sliced], ops
+        assert {op for op, _, kv, in_loop in ops if kv and in_loop} <= {"scatter"}, ops
+    on_kv = [op for op, _, kv, _ in _pool_ops(decode) if kv]
+    assert set(on_kv) == {"scatter"}, on_kv
+    on_kv = [op for op, _, kv, _ in _pool_ops(prefill) if kv and op not in PREFETCH]
+    assert set(on_kv) <= {"scatter", "copy", "copy-start"}, on_kv
+    assert len(on_kv) - on_kv.count("scatter") == n_kv, on_kv
 
 
 def _flash_grads(docs: bool, entry=flash.flash_attention):

@@ -26,8 +26,10 @@ CACHE_LEN = 48
 
 
 def _case(B, T, H, KVH, D, page, n_blocks, dtype, alibi, int8=False, seed=0,
-          offsets=None, table=None):
-    """Build (q, pools, table, offsets) and both attention paths."""
+          offsets=None, table=None, layer=None):
+    """Build (q, pools, table, offsets) and both attention paths. With
+    ``layer``, the kernel reads a stacked pool at that layer index, and the
+    third value returned is what a WRONG layer index reads."""
     n_pages = B * n_blocks + 4
     S = page * n_blocks
     ks = jax.random.split(jax.random.PRNGKey(seed), 8)
@@ -69,13 +71,35 @@ def _case(B, T, H, KVH, D, page, n_blocks, dtype, alibi, int8=False, seed=0,
         )
 
     ref = jax.jit(reference)(q, k_pool, v_pool, table, offsets)
-    out = jax.jit(
-        lambda q, kp, vp, tbl, off: pa.paged_attention(
-            q, kp, vp, tbl, off, causal=T > 1, alibi=alibi,
-            k_scale=k_sc, v_scale=v_sc, interpret=True,
+
+    def lanes(pool):
+        """The engine's pool layout: heads merged into the lane axis."""
+        return None if pool is None else pool.reshape(n_pages, page, -1)
+
+    pools = [lanes(p) for p in (k_pool, v_pool, k_sc, v_sc)]
+    if layer is not None:
+        # the stacked entry: layer `layer` of three, the others other bytes
+        pools = [
+            None if p is None else jnp.stack(
+                [p if l == layer else jnp.flip(p, axis=0) for l in range(3)]
+            )
+            for p in pools
+        ]
+
+    @jax.jit
+    def kernel(q, kp, vp, ks, vs, tbl, off, lyr):
+        return pa.paged_attention(
+            q, kp, vp, tbl, off, causal=T > 1, alibi=alibi, layer=lyr,
+            k_scale=ks, v_scale=vs, interpret=True,
         )
-    )(q, k_pool, v_pool, table, offsets)
-    return np.asarray(ref), np.asarray(out)
+
+    def run(lyr):
+        lyr = None if lyr is None else jnp.int32(lyr)
+        return np.asarray(kernel(q, *pools, table, offsets, lyr))
+
+    if layer is None:
+        return np.asarray(ref), run(None)
+    return np.asarray(ref), run(layer), run((layer + 1) % 3)
 
 
 def _assert_contract(ref, out):
@@ -146,6 +170,23 @@ def test_parity_ragged_tables_and_trash_rows():
         offsets=offsets, table=table,
     )
     _assert_contract(ref, out)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("layer", [1, 2])
+def test_stacked_pool_reads_its_layer(layer, int8):
+    """The scanned stack's entry: the pool is ``[n_layers, n_pages, page,
+    KVH * D]`` and the layer index a scalar-prefetch operand of the page
+    fetch. A non-zero layer reads exactly what the unstacked kernel reads
+    from that layer's pool; another layer's index reads other pages."""
+    ref, out, wrong = _case(
+        2, 1, 4, 2, 64, 8, 4, jnp.float32, True, int8=int8, seed=3,
+        layer=layer,
+    )
+    _assert_contract(ref, out)
+    flat = _case(2, 1, 4, 2, 64, 8, 4, jnp.float32, True, int8=int8, seed=3)[1]
+    np.testing.assert_array_equal(out, flat)
+    assert np.abs(wrong - ref).max() > 1e-2
 
 
 def test_gate_decisions():
@@ -226,6 +267,55 @@ def test_engine_kernel_parity_and_one_signature(monkeypatch):
         assert snap["dispatch_paged_attention_violations"] == 0
         assert snap["dispatch_decode_step_violations"] == 0
         assert snap["dispatch_spec_verify_violations"] == 0
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+def test_engine_kernel_under_a_tensor_mesh(devices, monkeypatch, int8):
+    """``serve --tensor 2``: the pools are allocated sharded on their merged
+    lane axis (heads are contiguous in it), each device's kernel walks its
+    own kv heads' lanes, and the streams are the single-device engine's."""
+    from zero_transformer_tpu.config import model_config
+    from zero_transformer_tpu.inference import serve_mesh, shard_for_inference
+    from zero_transformer_tpu.inference.generate import decode_model
+    from zero_transformer_tpu.inference.sampling import SamplingConfig
+    from zero_transformer_tpu.models import Transformer
+    from zero_transformer_tpu.serving import ServingEngine
+
+    monkeypatch.setenv("ZT_PALLAS_INTERPRET", "1")
+    cfg = model_config(
+        "test", dropout=0.0, compute_dtype="float32",
+        kv_cache_dtype="int8" if int8 else "auto",
+    )
+    params = Transformer(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    prompts = [[(3 + i + j) % 250 + 1 for j in range(n)]
+               for i, n in enumerate((3, 11))]
+
+    def run(mesh):
+        p = params if mesh is None else shard_for_inference(
+            decode_model(cfg, CACHE_LEN), params, mesh
+        )
+        engine = ServingEngine(
+            cfg, p, n_slots=2, cache_len=CACHE_LEN, prefill_chunk=8,
+            sampling=SamplingConfig(greedy=True), kv_layout="paged",
+            page_size=8, mesh=mesh,
+        )
+        handles = [
+            engine.submit(p, max_new_tokens=8, seed=i)
+            for i, p in enumerate(prompts)
+        ]
+        engine.run_until_idle()
+        assert all(h.status == "done" for h in handles)
+        assert engine.metrics_snapshot()["kernel_paged_attention"] == 1
+        return [h.tokens for h in handles], engine
+
+    single, _ = run(None)
+    sharded, engine = run(serve_mesh(2))
+    assert sharded == single
+    for name in ("cached_key", "key_scale") if int8 else ("cached_key",):
+        pool = engine.slots.cache[name]  # [L, n_pages, page, lanes]
+        assert tuple(pool.sharding.spec) == (None, None, None, "tensor")
 
 
 def test_engine_fused_tail_control_parity():

@@ -506,7 +506,10 @@ def test_slow_client_chaos_bounds_emit_buffer(cfg, params, reference):
         # 2 delivered events; client 1 streams unperturbed alongside
         t0 = threading.Thread(target=client, args=(0,))
         t0.start()
-        _wait(lambda: engine.stats["submitted"] >= 1, msg="first admit")
+        # ... and the second only once the one-shot fault has fired on the
+        # first: admitted in the same tick, the two pumps race for it, and
+        # on a loaded machine the wrong stream stalled one run in three
+        _wait(lambda: chaos.fired_log, msg="the stall on client 0")
         t1 = threading.Thread(target=client, args=(1,))
         t1.start()
         t0.join(60)

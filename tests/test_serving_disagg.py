@@ -125,15 +125,10 @@ def _synthetic_payload(kv, n_blocks, rng):
     """A random page-span payload matching ``kv``'s pool leaf geometry —
     roundtrip fidelity without paying a model forward."""
     leaves = {}
-    for path, leaf in jax.tree_util.tree_leaves_with_path(kv.cache):
-        name = str(path[-1].key if hasattr(path[-1], "key") else path[-1])
-        if name not in ("cached_key", "cached_value", "key_scale",
-                        "value_scale"):
-            continue
-        key = jax.tree_util.keystr(path)
-        ax = leaf.ndim - 4
-        per_page = tuple(d for i, d in enumerate(leaf.shape) if i != ax)
-        dt = np.dtype(leaf.dtype)
+    # the WIRE geometry: per page [(L,) page, KVH, D | 1] under the leaf's
+    # per-layer Attention path, whatever layout the pool itself has
+    for key, (_, per_page, dtype) in kv.wire_leaves.items():
+        dt = np.dtype(dtype)
         if dt.kind == "f":
             arr = rng.standard_normal((n_blocks,) + per_page).astype(dt)
         elif dt.kind == "V":

@@ -351,6 +351,132 @@ def test_spec_requires_no_repetition_penalty(cfg, params):
         )
 
 
+# ------------------------------------------------- pool layout and identity
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["gather", "kernel"])
+def test_paged_parity_unrolled_layers(monkeypatch, kernel):
+    """``scan_layers: False``: every layer owns its ``[n_pages, page,
+    KVH * D]`` pool leaf and runs the stacked path's code with no layer
+    index — token-identical to generate(), through the gather path and
+    through the kernel (interpret mode)."""
+    if kernel:
+        monkeypatch.setenv("ZT_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("ZT_PALLAS_INTERPRET", raising=False)
+    ucfg = model_config(
+        "test", dropout=0.0, compute_dtype="float32", scan_layers=False
+    )
+    uparams = Transformer(ucfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    prompts = [_prompt(n, offset=i) for i, n in enumerate((5, 17))]
+    model = decode_model(ucfg, CACHE_LEN)
+    engine = make_engine(ucfg, uparams)
+    pools = [
+        leaf.shape for path, leaf in
+        jax.tree_util.tree_leaves_with_path(engine.slots.cache)
+        if path[-1].key == "cached_key"
+    ]
+    assert pools == [(engine.slots.n_pages, 4, 4 * 16)] * ucfg.n_layers
+    handles = [
+        engine.submit(p, max_new_tokens=8, seed=i) for i, p in enumerate(prompts)
+    ]
+    engine.run_until_idle()
+    assert engine.metrics_snapshot()["kernel_paged_attention"] == int(kernel)
+    for i, (p, h) in enumerate(zip(prompts, handles)):
+        ref = generate(
+            model, uparams, jnp.asarray([p], jnp.int32), 8,
+            jax.random.PRNGKey(i), SAMPLING,
+        )
+        assert h.status == "done"
+        assert h.tokens == jax.device_get(ref)[0].tolist()
+
+
+@pytest.mark.parametrize("scan", [True, False], ids=["scan", "unrolled"])
+def test_index_updates_leave_the_pools_where_they_are(scan):
+    """Retiring a slot and installing a migrated cursor touch the int32
+    cursors only: the programs take and return the INDEX_LEAVES, and every
+    pool leaf of the cache is afterwards the SAME array — not a copy the
+    program made because it was handed the tree."""
+    from zero_transformer_tpu.serving.slots import (
+        INDEX_LEAVES, POOL_LEAVES, PagedKVCache, _leaf_name,
+    )
+
+    pcfg = model_config("test", dropout=0.0, scan_layers=scan)
+    kv = PagedKVCache(decode_model(pcfg, 16, kv_pages=(9, 4)), n_slots=2)
+
+    def leaves(names):
+        return [
+            leaf for path, leaf in jax.tree_util.tree_leaves_with_path(kv.cache)
+            if _leaf_name(path) in names
+        ]
+
+    pools = leaves(POOL_LEAVES)
+    where = [p.unsafe_buffer_pointer() for p in pools]
+    assert len(pools) == (2 if scan else 2 * pcfg.n_layers)
+    a, b = kv.acquire(), kv.acquire()
+    kv.set_cursor(a, 7)
+    kv.set_cursor(b, 3)
+    assert all(bool((i[..., a] == 7).all() and (i[..., b] == 3).all())
+               for i in leaves(INDEX_LEAVES))
+    kv.release([a])
+    assert all(bool((i[..., a] == 0).all() and (i[..., b] == 3).all())
+               for i in leaves(INDEX_LEAVES))
+    after = leaves(POOL_LEAVES)
+    assert all(x is y for x, y in zip(after, pools))
+    assert [p.unsafe_buffer_pointer() for p in after] == where
+
+
+@pytest.mark.parametrize(
+    "tag,kw",
+    [("scan_bf16", {}), ("scan_int8", {"kv_cache_dtype": "int8"}),
+     ("unrolled_f32", {"scan_layers": False, "compute_dtype": "float32"})],
+)
+def test_page_span_from_before_the_lane_merged_pool_imports_bit_exactly(tag, kw):
+    """The wire format is not the pool's layout. ``tests/fixtures/
+    page_span_pr24_*.bin`` were exported by the commit BEFORE the pool was
+    re-laid-out (pools ``[(L,) n_pages, page, KVH, D]``, the stacked ones
+    under ``['blocks']['attn']``): they import, land head ``h`` of layer
+    ``l`` in lanes ``[h * D, (h + 1) * D)`` of that layer's pool, and
+    export again to the same bytes."""
+    import pathlib
+
+    import numpy as np
+
+    from zero_transformer_tpu.serving.slots import (
+        PagedKVCache, page_span_from_wire, page_span_to_wire,
+    )
+
+    blob = (
+        pathlib.Path(__file__).parent / "fixtures" / f"page_span_pr24_{tag}.bin"
+    ).read_bytes()
+    payload = page_span_from_wire(blob)
+    pcfg = model_config("test", dropout=0.0, **kw)
+    kv = PagedKVCache(decode_model(pcfg, 16, kv_pages=(9, 4)), n_slots=2)
+    slot = kv.acquire()
+    assert kv.import_page_span(slot, payload)
+    assert page_span_to_wire(kv.export_page_span(slot, payload["n_tokens"])) == blob
+
+    pages = kv.table[slot, : payload["n_blocks"]]
+    wire = payload["leaves"]
+    if pcfg.scan_layers:
+        sent = wire["['blocks']['attn']['cached_key']"]  # [blocks, L, page, KVH, D]
+        pool = np.asarray(kv.cache["cached_key"])  # [L, n_pages, page, KVH * D]
+        for l in range(pcfg.n_layers):
+            np.testing.assert_array_equal(
+                pool[l, pages].reshape(sent[:, l].shape), sent[:, l]
+            )
+    else:
+        sent = wire["['block_1']['attn']['cached_value']"]  # [blocks, page, KVH, D]
+        pool = np.asarray(kv.cache["block_1"]["attn"]["cached_value"])
+        np.testing.assert_array_equal(pool[pages].reshape(sent.shape), sent)
+        h, D = 2, pcfg.head_width
+        np.testing.assert_array_equal(
+            pool[pages][:, :, h * D:(h + 1) * D], sent[:, :, h]
+        )
+
+
 # ---------------------------------------------------------------- allocator
 
 

@@ -99,11 +99,12 @@ def init_cache(model: Transformer, batch: int, rng=None, mesh=None) -> Any:
     models); the cache contents are genuinely zeros + zero indices, which is
     exactly what a fresh init produces.
 
-    With ``mesh``, K/V buffers (and int8 scales), shaped [..., KVH, D] with
-    per-layer [B, T] leading dims (plus a layer axis under the scanned
-    stack), are laid out sharded over the tensor axis on the KV-heads dim —
-    committed up front so the decode loop's cache carry never round-trips
-    through a GSPMD-guessed layout."""
+    With ``mesh``, K/V buffers (and int8 scales) — slab [..., KVH, D] with
+    per-layer [B, T] leading dims, paged pools [..., n_pages, page,
+    KVH * D], plus a layer axis under the scanned stack — are laid out
+    sharded over the tensor axis on the KV heads — committed up front so
+    the decode loop's cache carry never round-trips through a GSPMD-guessed
+    layout."""
     rng = rng if rng is not None else jax.random.PRNGKey(0)
     # shape derivation runs with the AMBIENT mesh cleared: under
     # jax.set_mesh, flax's with_partitioning boxing would interpret the
@@ -123,36 +124,39 @@ def init_cache(model: Transformer, batch: int, rng=None, mesh=None) -> Any:
     from zero_transformer_tpu.parallel.mesh import TENSOR_AXIS
 
     tp = mesh.shape[TENSOR_AXIS]
-    # KV buffers end [..., KVH, D] and their int8 scales [..., KVH, 1]
+    # Slab KV buffers end [..., KVH, D] and their int8 scales [..., KVH, 1]
     # (leading dims: [B, T] per layer, plus a layer axis up front under the
-    # scanned stack) — KVH is dim -2 in EVERY layout; indexing it from the
-    # front silently sharded the cache's sequence dim on scanned models.
+    # scanned stack) — KVH is dim -2 in EVERY slab layout; indexing it from
+    # the front silently sharded the cache's sequence dim on scanned models.
+    # Paged pools keep the heads merged into the LAST axis ([..., KVH * D],
+    # scales [..., KVH]: models.gpt.kv_pool_leaves) — heads are contiguous
+    # in it, so a tensor shard of that axis is a shard of the heads.
     # Keyed by LEAF NAME, not shape-sniffing — a future cache entry with a
     # different layout must not be silently mis-sharded.
     kv_leaves = {"cached_key", "cached_value", "key_scale", "value_scale"}
-    if tp > 1:
-        kvh = {s.shape[-2] for p, s in jax.tree_util.tree_leaves_with_path(shapes)
-               if str(p[-1].key if hasattr(p[-1], "key") else p[-1]) in kv_leaves}
-        bad = {h for h in kvh if h % tp != 0}
-        if bad:
-            # the params ARE tensor-sharded in this configuration, so a
-            # replicated cache silently forfeits the HBM win the mesh was
-            # requested for — make the GQA/tensor mismatch visible
-            import warnings
+    heads_axis = -2 if model.kv_pages is None else -1
+    kvh = model.cfg.kv_heads
+    if tp > 1 and kvh % tp:
+        # the params ARE tensor-sharded in this configuration, so a
+        # replicated cache silently forfeits the HBM win the mesh was
+        # requested for — make the GQA/tensor mismatch visible
+        import warnings
 
-            warnings.warn(
-                f"KV cache stays REPLICATED: kv head count(s) {sorted(bad)} "
-                f"not divisible by tensor={tp}; each chip holds the full "
-                "cache while params are sharded. Pick tensor dividing the "
-                "KV-head count (GQA) to shard the cache.",
-                stacklevel=2,
-            )
+        warnings.warn(
+            f"KV cache stays REPLICATED: kv head count {kvh} "
+            f"not divisible by tensor={tp}; each chip holds the full "
+            "cache while params are sharded. Pick tensor dividing the "
+            "KV-head count (GQA) to shard the cache.",
+            stacklevel=2,
+        )
 
     def place(path, s):
         leaf = str(path[-1].key if hasattr(path[-1], "key") else path[-1])
         spec = P()
-        if leaf in kv_leaves and tp > 1 and s.shape[-2] % tp == 0:
-            spec = P(*([None] * (s.ndim - 2)), TENSOR_AXIS, None)
+        if leaf in kv_leaves and tp > 1 and kvh % tp == 0:
+            dims = [None] * s.ndim
+            dims[heads_axis] = TENSOR_AXIS
+            spec = P(*dims)
         return jax.device_put(
             jnp.zeros(s.shape, s.dtype), NamedSharding(mesh, spec)
         )

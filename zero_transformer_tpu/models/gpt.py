@@ -96,6 +96,27 @@ def _quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return q, scale
 
 
+def kv_pool_leaves(cfg: ModelConfig, kv_pages: Tuple[int, int], dtype) -> dict:
+    """``{leaf name: (shape, dtype)}`` of ONE layer's paged K/V pool, in the
+    layout the paged kernel's ``BlockSpec`` reads: K/V ``[n_pages, page,
+    KVH * D]`` and, for int8 pages, f32 scales ``[n_pages, page, KVH]``.
+    The heads are merged into the lane axis at allocation: with
+    ``[..., KVH, D]`` minor dims a TPU tile pads 12 heads to 16, XLA picks
+    another physical layout for the pool than the Mosaic call and the
+    scatter want, and converts between them with pool-sized copies in
+    every layer of every tick. One layout from allocation to kernel, so
+    no program re-lays-out a pool-sized value."""
+    n_pages, page = kv_pages
+    KVH, D = cfg.kv_heads, cfg.head_width
+    int8 = cfg.kv_cache_dtype == "int8"
+    kv = ((n_pages, page, KVH * D), jnp.int8 if int8 else dtype)
+    leaves = {"cached_key": kv, "cached_value": kv}
+    if int8:
+        scale = ((n_pages, page, KVH), jnp.float32)
+        leaves.update(key_scale=scale, value_scale=scale)
+    return leaves
+
+
 def resolve_remat_policy(cfg: ModelConfig):
     """cfg.remat_policy → jax.checkpoint saveable-policy.
 
@@ -163,17 +184,27 @@ class Attention(nn.Module):
 
     ``kv_pages=(n_pages, page_size)`` switches the decode cache to a PAGED
     layout (vLLM-style, Kwon et al. 2309.06180): K/V live in a global page
-    pool ``[n_pages, page_size, KVH, D]`` shared by every row, and each row
-    owns an int32 ``block_table`` ``[B, cache_len // page_size]`` mapping
-    its logical sequence blocks to pool pages. Reads gather the row's pages
-    back into the same ``[B, cache_len, KVH, D]`` view the slab path
-    attends over; writes scatter each token's K/V to
-    ``pool[table[b, pos // P], pos % P]``. Position math, validity masks,
+    pool ``[n_pages, page_size, KVH * D]`` shared by every row (heads merged
+    into the lane axis — ``kv_pool_leaves`` says why), and each row owns an
+    int32 ``block_table`` ``[B, cache_len // page_size]`` mapping its
+    logical sequence blocks to pool pages. Reads gather the row's pages and
+    reshape the GATHERED view to the ``[B, cache_len, KVH, D]`` the slab
+    path attends over; writes scatter each token's K/V to
+    ``pool[table[b, pos // P], pos % P]``. Nothing reshapes or transposes
+    the pool itself. Position math, validity masks,
     the int8 path, and the overflow poison guard are IDENTICAL to the slab
     cache — paging only changes where the bytes live, so paged decode is
     bit-exact vs slab decode (tested). Page 0 is the serving layer's trash
     page: a zeroed block table routes writes somewhere harmless, which is
-    how parked rows ride along in fixed-shape dispatches."""
+    how parked rows ride along in fixed-shape dispatches.
+
+    Called with ``pools`` (the layer-STACKED ``[n_layers, ...]`` pool leaves
+    the scanned ``Transformer`` carries through its layer loop) and this
+    layer's index, the module indexes the stack at ``layer`` in the scatter,
+    the gather and the kernel's page fetch, and returns ``(out, pools)``:
+    the pool is updated in place through the loop, never sliced out of it.
+    Without ``pools`` it owns per-layer pool leaves itself — same code, no
+    layer index."""
 
     cfg: ModelConfig
     deterministic: bool = True
@@ -184,7 +215,13 @@ class Attention(nn.Module):
     kv_pages: Optional[Tuple[int, int]] = None  # (n_pages, page_size)
 
     @nn.compact
-    def __call__(self, x: jax.Array, doc_ids: Optional[jax.Array] = None) -> jax.Array:
+    def __call__(
+        self,
+        x: jax.Array,
+        doc_ids: Optional[jax.Array] = None,
+        pools: Optional[dict] = None,
+        layer: Optional[jax.Array] = None,
+    ):
         cfg = self.cfg
         dtype = x.dtype
         param_dtype = resolve_dtype(cfg.param_dtype)
@@ -224,8 +261,7 @@ class Attention(nn.Module):
         bt = None
         if self.decode:
             max_len = self.cache_len or cfg.max_seq_len
-            is_init = not self.has_variable("cache", "cached_key")
-            cache_dtype = jnp.int8 if int8_cache else dtype
+            is_init = not self.has_variable("cache", "cache_index")
             if paged:
                 n_pages, page = self.kv_pages
                 if max_len % page:
@@ -234,22 +270,27 @@ class Attention(nn.Module):
                         f"page_size ({page}) for the paged KV cache"
                     )
                 n_blocks = max_len // page
-                ck = self.variable("cache", "cached_key", jnp.zeros, (n_pages, page, KVH, D), cache_dtype)
-                cv = self.variable("cache", "cached_value", jnp.zeros, (n_pages, page, KVH, D), cache_dtype)
-                if int8_cache:
-                    ksc = self.variable("cache", "key_scale", jnp.zeros, (n_pages, page, KVH, 1), jnp.float32)
-                    vsc = self.variable("cache", "value_scale", jnp.zeros, (n_pages, page, KVH, 1), jnp.float32)
+                leaves = kv_pool_leaves(cfg, self.kv_pages, dtype)
                 bt = self.variable(
                     "cache", "block_table", jnp.zeros, (B, n_blocks), jnp.int32
                 )
             else:
-                ck = self.variable("cache", "cached_key", jnp.zeros, (B, max_len, KVH, D), cache_dtype)
-                cv = self.variable("cache", "cached_value", jnp.zeros, (B, max_len, KVH, D), cache_dtype)
+                slab = ((B, max_len, KVH, D), jnp.int8 if int8_cache else dtype)
+                leaves = {"cached_key": slab, "cached_value": slab}
                 if int8_cache:
                     # per-(token, head) symmetric scales; f32 so tiny magnitudes
                     # don't underflow the dequant product
-                    ksc = self.variable("cache", "key_scale", jnp.zeros, (B, max_len, KVH, 1), jnp.float32)
-                    vsc = self.variable("cache", "value_scale", jnp.zeros, (B, max_len, KVH, 1), jnp.float32)
+                    scale = ((B, max_len, KVH, 1), jnp.float32)
+                    leaves.update(key_scale=scale, value_scale=scale)
+            # K/V leaves: this module's own variables, or — a stacked pool
+            # riding the layer loop's carry — the caller's
+            own = None
+            if pools is None:
+                own = {
+                    name: self.variable("cache", name, jnp.zeros, shape, dt)
+                    for name, (shape, dt) in leaves.items()
+                }
+            kv = dict(pools) if own is None else {n: v.value for n, v in own.items()}
             idx = self.variable("cache", "cache_index", lambda: jnp.zeros((), jnp.int32))
             use_cache = not is_init
             if use_cache:
@@ -290,15 +331,23 @@ class Attention(nn.Module):
                     bt.value, jnp.clip(pos // page, 0, n_blocks - 1), axis=1
                 )  # [B, T]
                 in_page = pos % page
+                # a stacked pool is indexed by the layer in the SAME scatter
+                # / gather — `pool[layer]` first would slice a pool-sized
+                # value out of the loop's carry every layer
+                at_layer = () if layer is None else (layer,)
 
                 def write(buf, upd):
-                    return buf.at[page_ids, in_page].set(upd.astype(buf.dtype))
+                    # upd [B, T, KVH, D | 1] -> the pool's merged lane axis
+                    return buf.at[at_layer + (page_ids, in_page)].set(
+                        upd.reshape(B, T, -1).astype(buf.dtype)
+                    )
 
                 def gather(buf):
-                    # [n_pages, page, ...] -> the row-major [B, cache_len,
-                    # ...] view the slab path attends over
-                    g = jnp.take(buf, bt.value, axis=0)  # [B, n_blocks, page, ...]
-                    return g.reshape((B, n_blocks * page) + buf.shape[2:])
+                    # pool pages -> the row-major [B, cache_len, KVH, D | 1]
+                    # view the slab path attends over; only the GATHERED
+                    # view is reshaped, never the pool
+                    g = buf[at_layer + (bt.value,)]  # [B, n_blocks, page, lanes]
+                    return g.reshape(B, n_blocks * page, KVH, -1)
 
             else:
                 if per_slot:
@@ -322,13 +371,16 @@ class Attention(nn.Module):
             if int8_cache:
                 kq, k_scale = _quantize_kv(k)
                 vq, v_scale = _quantize_kv(v)
-                ck.value = write(ck.value, kq)
-                cv.value = write(cv.value, vq)
-                ksc.value = write(ksc.value, k_scale)
-                vsc.value = write(vsc.value, v_scale)
+                kv["cached_key"] = write(kv["cached_key"], kq)
+                kv["cached_value"] = write(kv["cached_value"], vq)
+                kv["key_scale"] = write(kv["key_scale"], k_scale)
+                kv["value_scale"] = write(kv["value_scale"], v_scale)
             else:
-                ck.value = write(ck.value, k)
-                cv.value = write(cv.value, v)
+                kv["cached_key"] = write(kv["cached_key"], k)
+                kv["cached_value"] = write(kv["cached_value"], v)
+            if own is not None:
+                for name, var in own.items():
+                    var.value = kv[name]
             idx.value = offset + T
             max_len_b = self.cache_len or cfg.max_seq_len
             if per_slot:
@@ -374,11 +426,12 @@ class Attention(nn.Module):
                 # close it stays to that gather path is the kernel
                 # module's exactness contract
                 out = paged_decode_attention(
-                    q, ck.value, cv.value, bt.value, offset,
+                    q, kv["cached_key"], kv["cached_value"], bt.value, offset,
+                    layer=layer,
                     causal=T > 1,
                     alibi=cfg.position == "alibi",
-                    k_scale=ksc.value if int8_cache else None,
-                    v_scale=vsc.value if int8_cache else None,
+                    k_scale=kv.get("key_scale"),
+                    v_scale=kv.get("value_scale"),
                 )
             else:
                 if int8_cache:
@@ -389,10 +442,10 @@ class Attention(nn.Module):
                     # the gather moves int8 bytes + scales, dequant happens
                     # on the gathered view) multiply in f32 (scales are
                     # stored f32 for exactly this), round once at the end
-                    k_all = (gather(ck.value).astype(jnp.float32) * gather(ksc.value)).astype(dtype)
-                    v_all = (gather(cv.value).astype(jnp.float32) * gather(vsc.value)).astype(dtype)
+                    k_all = (gather(kv["cached_key"]).astype(jnp.float32) * gather(kv["key_scale"])).astype(dtype)
+                    v_all = (gather(kv["cached_value"]).astype(jnp.float32) * gather(kv["value_scale"])).astype(dtype)
                 else:
-                    k_all, v_all = gather(ck.value), gather(cv.value)
+                    k_all, v_all = gather(kv["cached_key"]), gather(kv["cached_value"])
                 # dispatching entry point: chunked-prefill / spec-verify
                 # windows route to the flash kernel where the gate accepts
                 # them (TPU or interpret mode); single-token decode and CPU
@@ -428,7 +481,11 @@ class Attention(nn.Module):
 
         out = out.reshape(B, T, H * D)
         out = _dense(cfg.d_model, ("qheads", "embed"), resid_std, dtype, param_dtype, "out", quant)(out)
-        return nn.Dropout(cfg.dropout, deterministic=self.deterministic)(out)
+        out = nn.Dropout(cfg.dropout, deterministic=self.deterministic)(out)
+        if pools is None:
+            return out
+        # init traces (no cache yet) hand the stack back untouched
+        return out, (kv if use_cache else pools)
 
 
 class MLP(nn.Module):
@@ -468,7 +525,9 @@ class Block(nn.Module):
 
     Carry is ``(x, aux)``: MoE blocks add their router auxiliary loss to
     ``aux`` as it threads through the layer scan; dense blocks pass it
-    through unchanged."""
+    through unchanged. Called with a ``layer`` index (the scanned paged
+    decode stack), the carry's third element is the stacked K/V pool, which
+    ``Attention`` updates in place at that layer."""
 
     cfg: ModelConfig
     deterministic: bool = True
@@ -478,23 +537,28 @@ class Block(nn.Module):
     kv_pages: Optional[Tuple[int, int]] = None
 
     @nn.compact
-    def __call__(self, carry, _=None):
+    def __call__(self, carry, layer=None):
         cfg = self.cfg
         # packed-sequence models thread the document ids as a third carry
         # element (constant through the layer scan); the decode path never
-        # packs, so its carry stays (x, aux)
+        # packs, so its carry stays (x, aux) — plus the pool when stacked
         packed = cfg.doc_sep_token is not None and not self.decode
+        doc_ids = pools = None
         if packed:
             x, aux, doc_ids = carry
+        elif layer is not None:
+            x, aux, pools = carry
         else:
             x, aux = carry
-            doc_ids = None
-        x = x + Attention(
+        attn = Attention(
             cfg, self.deterministic, self.decode, self.cache_len, self.mesh,
             self.kv_pages, name="attn"
         )(
-            _norm(cfg, x.dtype, "ln_attn")(x), doc_ids
+            _norm(cfg, x.dtype, "ln_attn")(x), doc_ids, pools, layer
         )
+        if pools is not None:
+            attn, pools = attn
+        x = x + attn
         # pin the residual stream: batch/seq sharded, replicated over tensor
         # (Megatron layout) — GSPMD must not invent another layout for it
         x = constrain_activation(x, "batch", "seq", "embed")
@@ -509,7 +573,9 @@ class Block(nn.Module):
                 _norm(cfg, x.dtype, "ln_mlp")(x)
             )
         x = constrain_activation(x, "batch", "seq", "embed")
-        return ((x, aux, doc_ids) if packed else (x, aux)), None
+        if packed:
+            return (x, aux, doc_ids), None
+        return ((x, aux) if pools is None else (x, aux, pools)), None
 
 
 class Transformer(nn.Module):
@@ -523,7 +589,10 @@ class Transformer(nn.Module):
     mesh: Optional[Any] = None
     # (n_pages, page_size): paged KV cache for the serving engine — K/V in
     # a global page pool addressed through per-row block tables (see
-    # Attention). None = the classic [B, cache_len] slab.
+    # Attention). None = the classic [B, cache_len] slab. Under
+    # ``scan_layers`` the pool leaves are declared HERE, stacked
+    # [n_layers, n_pages, page, KVH * D], and carried through the layer
+    # loop; unrolled layers each own theirs.
     kv_pages: Optional[Tuple[int, int]] = None
 
     @nn.compact
@@ -636,6 +705,24 @@ class Transformer(nn.Module):
             # ppermute ring)
             doc_ids = doc_ids_from_tokens(x, cfg.doc_sep_token)
         carry = (h, aux, doc_ids) if packed else (h, aux)
+        layers = pool_vars = None
+        if cfg.scan_layers and self.decode and self.kv_pages is not None:
+            # the paged K/V pool rides the layer loop's CARRY, stacked
+            # [n_layers, ...] and indexed by the layer inside Attention.
+            # Scanned over like the rest of the cache (`variable_axes`), it
+            # would enter the loop as one buffer and leave as another:
+            # every layer would slice its pool out of the first and copy
+            # it into the second, whatever the caller donates.
+            pool_vars = {
+                name: self.variable(
+                    "cache", name, jnp.zeros, (cfg.n_layers,) + shape, dt
+                )
+                for name, (shape, dt) in kv_pool_leaves(
+                    cfg, self.kv_pages, dtype
+                ).items()
+            }
+            layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+            carry = carry + ({n: v.value for n, v in pool_vars.items()},)
         if cfg.scan_layers:
             stack = nn.scan(
                 block_cls,
@@ -645,7 +732,10 @@ class Transformer(nn.Module):
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(cfg, not train, self.decode, self.cache_len, self.mesh,
               self.kv_pages, name="blocks")
-            carry, _ = stack(carry, None)
+            carry, _ = stack(carry, layers)
+            if pool_vars is not None:
+                for name, var in pool_vars.items():
+                    var.value = carry[2][name]
         else:
             for i in range(cfg.n_layers):
                 carry, _ = block_cls(

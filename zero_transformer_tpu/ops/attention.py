@@ -171,34 +171,40 @@ def paged_kernel_supported(impl: str, *, H: int, KVH: int, **shape) -> bool:
 # graftlint: hot-path
 def paged_decode_attention(
     q, k_pool, v_pool, block_table, q_offset, *, causal: bool,
-    alibi: bool = False, k_scale=None, v_scale=None,
+    layer=None, alibi: bool = False, k_scale=None, v_scale=None,
 ) -> jax.Array:
     """The paged decode kernel (``ops.pallas.paged_attention``) at its
     dispatch site: on a mesh (``serve --tensor N``) each device walks ITS
     kv heads' pages under ``parallel.sharding.shard_kernel`` — GSPMD cannot
     partition a Mosaic call — with the heads' ALiBi slopes and the per-row
-    offsets as explicit operands."""
+    offsets as explicit operands. The pools arrive in the kernel's own
+    layout (``[..., n_pages, page, KVH * D]``, stacked over layers when
+    ``layer`` is given); a device's shard of the merged lane axis is its
+    kv heads' lanes, heads being contiguous in it."""
     from zero_transformer_tpu.ops.pallas import paged_attention as pa
     from zero_transformer_tpu.parallel.sharding import shard_kernel
 
     B, _, H, _ = q.shape
     offs = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32).reshape(-1), (B,))
     slopes = alibi_slopes(H) if alibi else jnp.zeros((H,), jnp.float32)
-    scales = () if k_scale is None else (k_scale, v_scale)
+    pools = (k_pool, v_pool) + (() if k_scale is None else (k_scale, v_scale))
+    if layer is None:  # an unstacked pool is a stack of one: moves no byte
+        layer, pools = 0, tuple(p[None] for p in pools)
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def local(q, k_pool, v_pool, block_table, offs, slopes, *scales):
+    def local(q, block_table, offs, slopes, lyr, k_pool, v_pool, *scales):
         k_sc, v_sc = scales or (None, None)
         return (pa.paged_attention(
             q, k_pool, v_pool, block_table, offs, causal=causal, alibi=alibi,
-            k_scale=k_sc, v_scale=v_sc, slopes=slopes,
+            layer=lyr[0], k_scale=k_sc, v_scale=v_sc, slopes=slopes,
         ),)
 
     q_names = ("batch", None, "heads", None)
-    pool = (None, None, "kvheads", None)
+    pool = (None, None, None, "kvheads")
     (out,) = shard_kernel(
         local,
-        (q_names, pool, pool, ("batch", None), ("batch",), ("heads",),
-         *(pool for _ in scales)),
+        (q_names, ("batch", None), ("batch",), ("heads",), (None,),
+         *(pool for _ in pools)),
         (q_names,),
-    )(q, k_pool, v_pool, block_table, offs, slopes.reshape(H), *scales)
+    )(q, block_table, offs, slopes.reshape(H), lyr, *pools)
     return out

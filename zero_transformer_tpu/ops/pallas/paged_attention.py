@@ -1,20 +1,33 @@
 """Paged-attention decode kernel: block tables read INSIDE the kernel grid.
 
 The serving engine's paged KV cache (``serving/slots.py``, PR 6) stores K/V
-in a global page pool ``[n_pages, page, KVH, D]`` addressed through per-row
-int32 block tables. Until this kernel, every decode/spec-verify dispatch
-first materialized a gather-to-slab view — ``jnp.take(pool, table)`` builds
-a fresh ``[B, cache_len, KVH, D]`` copy of every live row's K/V per token —
-and then ran the slab attention over it. That gather is pure HBM traffic
-the math never needed: attention only has to *read* each page once.
+in a global page pool addressed through per-row int32 block tables. Until
+this kernel, every decode/spec-verify dispatch first materialized a
+gather-to-slab view — ``jnp.take(pool, table)`` builds a fresh
+``[B, cache_len, KVH, D]`` copy of every live row's K/V per token — and
+then ran the slab attention over it. That gather is pure HBM traffic the
+math never needed: attention only has to *read* each page once.
 
 This kernel walks the block table inside the Pallas grid instead: grid
-``(B, KVH, n_blocks)``, with the page axis resolved per grid step through a
+``(B, n_blocks)``, with the page axis resolved per grid step through a
 scalar-prefetched table (``PrefetchScalarGridSpec``) so the BlockSpec index
-map fetches ``pool[table[b, j]]`` directly — the pipelined HBM→VMEM copy IS
-the page walk, and no slab view ever exists. int8 KV pages dequantize
-in-register (per-page scale blocks ride the same index map) on their way
-into the VMEM K/V scratch.
+map fetches ``pool[layer, table[b, j]]`` directly — the pipelined HBM→VMEM
+copy IS the page walk, and no slab view ever exists. int8 KV pages
+dequantize in-register (per-page scale blocks ride the same index map) on
+their way into the VMEM K/V scratch.
+
+Pool layout. The pool is ALLOCATED in the shape this kernel's ``BlockSpec``
+reads — K/V ``[n_pages, page, KVH * D]``, int8 scales ``[n_pages, page,
+KVH]``, under the scanned layer stack one more leading ``n_layers`` axis
+with the layer index a third scalar-prefetch operand — and the wrapper
+hands it to Mosaic as it is. It used to be declared ``[n_pages, page, KVH,
+D]`` and merged here with a reshape that reads as free and on a TPU is
+not: a ``(KVH, D)`` minor pair tiles with 12 heads padded to 16, so XLA
+kept the pool in another physical layout than the row-major operand a
+Mosaic call insists on, and converted between them with pool-sized copies,
+per layer, per tick (54 of a 66 ms decode tick at 580M: ledger, PR 24).
+Nothing between allocation and this ``pallas_call`` may reshape, transpose
+or slice a pool-sized value (``tests/test_chip_compile.py`` counts them).
 
 Exactness contract. The kernel computes, per row, the op sequence of the
 gather path (``jnp.take`` + ``ops.attention.xla_attention`` per-row
@@ -129,7 +142,7 @@ def supported(
 
 def _kernel(
     # scalar-prefetch refs
-    table_ref, offs_ref,
+    table_ref, offs_ref, layer_ref,
     # operands
     tq_ref, slope_ref, q_ref, k_ref, v_ref, *args,
     T: int, KVH: int, D: int, page: int, n_blocks: int, scale: float,
@@ -149,9 +162,10 @@ def _kernel(
 
     Layouts (all chosen so every slice the kernel takes is a static lane
     slice or a page-aligned sublane window): q/out ``[KVH, M, D]`` with
-    row ``m = t * G + g``; a pool block ``[page, KVH * D]``; an int8 scale
-    block ``[page, KVH]``; ``tq`` ``[M, 1]`` the window position ``t`` of
-    row m; ``slope`` ``[KVH, M, 1]`` the ALiBi slope of (head, row)."""
+    row ``m = t * G + g``; a pool block ``[page, KVH * D]`` (the index map
+    picked its layer and page); an int8 scale block ``[page, KVH]``; ``tq``
+    ``[M, 1]`` the window position ``t`` of row m; ``slope`` ``[KVH, M, 1]``
+    the ALiBi slope of (head, row)."""
     # arg order: remaining inputs (int8 scale blocks), the output ref,
     # then the scratch buffers
     if int8:
@@ -165,13 +179,13 @@ def _kernel(
 
     for h in range(KVH):
         lanes = slice(h * D, (h + 1) * D)
-        kb = k_ref[0, :, lanes]  # [page, D]
-        vb = v_ref[0, :, lanes]
+        kb = k_ref[0, 0, :, lanes]  # [page, D]
+        vb = v_ref[0, 0, :, lanes]
         if int8:
             # exact mirror of the gather path's dequant:
             # (int8 -> f32) * f32 scale -> compute dtype, elementwise
-            kb = kb.astype(jnp.float32) * ks_ref[0, :, h:h + 1]
-            vb = vb.astype(jnp.float32) * vs_ref[0, :, h:h + 1]
+            kb = kb.astype(jnp.float32) * ks_ref[0, 0, :, h:h + 1]
+            vb = vb.astype(jnp.float32) * vs_ref[0, 0, :, h:h + 1]
         k_scr[h, rows, :] = kb.astype(k_scr.dtype)
         v_scr[h, rows, :] = vb.astype(v_scr.dtype)
 
@@ -222,6 +236,7 @@ def paged_attention(
     q_offset: jax.Array,
     *,
     causal: bool,
+    layer: Optional[jax.Array] = None,
     alibi: bool = False,
     softmax_scale: Optional[float] = None,
     k_scale: Optional[jax.Array] = None,
@@ -232,8 +247,11 @@ def paged_attention(
     """Decode attention straight off the page pool. q ``[B, T, H, D]``
     (T = 1 decode, 1+K spec verify; RoPE already applied, overflow rows
     already NaN-poisoned by the caller); ``k_pool``/``v_pool``
-    ``[n_pages, page, KVH, D]`` (int8 with ``k_scale``/``v_scale``
-    ``[n_pages, page, KVH, 1]`` f32, or the compute dtype); ``block_table``
+    ``[n_pages, page, KVH * D]`` (int8 with ``k_scale``/``v_scale``
+    ``[n_pages, page, KVH]`` f32, or the compute dtype) — or, with
+    ``layer`` (an int32 scalar, traced under the layer scan), the stacked
+    ``[n_layers, n_pages, page, ...]`` pools, of which only layer
+    ``layer``'s pages are fetched; ``block_table``
     ``[B, n_blocks]`` int32 (zeros = the serving layer's trash page);
     ``q_offset`` ``[B]`` (or scalar) — row r's query block starts at
     position ``q_offset[r]``, and positions ``>= q_offset[r] + T`` are
@@ -246,9 +264,18 @@ def paged_attention(
     docstring's exactness contract.
     """
     B, T, H, D = q.shape
-    n_pages, page, KVH, _ = k_pool.shape
-    if H % KVH:
-        raise ValueError(f"query heads {H} not divisible by kv heads {KVH}")
+    if k_pool.ndim != (3 if layer is None else 4):
+        raise ValueError(
+            f"pool {k_pool.shape}: expected [n_pages, page, KVH * D], or "
+            "[n_layers, n_pages, page, KVH * D] with a layer index"
+        )
+    page, lanes = k_pool.shape[-2:]
+    KVH = lanes // D
+    if lanes % D or H % KVH:
+        raise ValueError(
+            f"pool lanes {lanes} are not kv heads of width {D} dividing "
+            f"the {H} query heads"
+        )
     int8 = k_pool.dtype == jnp.int8
     if int8 and (k_scale is None or v_scale is None):
         raise ValueError("int8 pools need k_scale/v_scale pools")
@@ -266,39 +293,42 @@ def paged_attention(
     S = n_blocks * page
     dtype = q.dtype
     kernel_traces["paged_attention"] += 1
-    # kernel layouts (see _kernel): q rows m = t * G + g under each kv head;
-    # the pool's (KVH, D) minor dims merge into one lane axis (a free
-    # reshape — the pool is never copied)
+    # kernel layouts (see _kernel): q rows m = t * G + g under each kv head.
+    # The pools go in as allocated (module docstring): an unstacked pool
+    # only gains a unit layer axis, which moves no byte.
     M = T * G
     qk = q.reshape(B, T, KVH, G, D).transpose(0, 2, 1, 3, 4).reshape(B, KVH, M, D)
     tq = jnp.repeat(jnp.arange(T, dtype=jnp.int32), G).reshape(M, 1)
     slope = jnp.broadcast_to(
         slopes.reshape(KVH, 1, G), (KVH, T, G)
     ).reshape(KVH, M, 1)
-    k_pool = k_pool.reshape(n_pages, page, KVH * D)
-    v_pool = v_pool.reshape(n_pages, page, KVH * D)
+    pools = [k_pool, v_pool] + ([k_scale, v_scale] if int8 else [])
+    if layer is None:
+        layer = 0
+        pools = [p[None] for p in pools]
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    # index maps receive the scalar-prefetch refs (table, offsets) last;
-    # the page axis of every pool operand resolves through the table — the
-    # pipelined block fetch IS the page walk
-    qo_spec = pl.BlockSpec((1, KVH, M, D), lambda b, j, tbl, off: (b, 0, 0, 0))
-    kv_spec = pl.BlockSpec((1, page, KVH * D), lambda b, j, tbl, off: (tbl[b, j], 0, 0))
-    sc_spec = pl.BlockSpec((1, page, KVH), lambda b, j, tbl, off: (tbl[b, j], 0, 0))
+    # index maps receive the scalar-prefetch refs (table, offsets, layer)
+    # last; the layer and page axes of every pool operand resolve through
+    # them — the pipelined block fetch IS the page walk
+    qo_spec = pl.BlockSpec((1, KVH, M, D), lambda b, j, tbl, off, lyr: (b, 0, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, page, lanes), lambda b, j, tbl, off, lyr: (lyr[0], tbl[b, j], 0, 0)
+    )
+    sc_spec = pl.BlockSpec(
+        (1, 1, page, KVH), lambda b, j, tbl, off, lyr: (lyr[0], tbl[b, j], 0, 0)
+    )
     in_specs = [
-        pl.BlockSpec((M, 1), lambda b, j, tbl, off: (0, 0)),
-        pl.BlockSpec((KVH, M, 1), lambda b, j, tbl, off: (0, 0, 0)),
+        pl.BlockSpec((M, 1), lambda b, j, tbl, off, lyr: (0, 0)),
+        pl.BlockSpec((KVH, M, 1), lambda b, j, tbl, off, lyr: (0, 0, 0)),
         qo_spec, kv_spec, kv_spec,
     ]
-    operands = [tq, slope, qk, k_pool, v_pool]
     if int8:
         in_specs += [sc_spec, sc_spec]
-        operands += [
-            k_scale.reshape(n_pages, page, KVH),
-            v_scale.reshape(n_pages, page, KVH),
-        ]
+    operands = [tq, slope, qk, *pools]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, n_blocks),
         in_specs=in_specs,
         out_specs=qo_spec,
@@ -319,7 +349,7 @@ def paged_attention(
             vmem_limit_bytes=vmem_bytes(T=T, H=H, KVH=KVH, D=D, S=S, dtype=dtype)
         ),
         interpret=interpret,
-    )(block_table, offs, *operands)
+    )(block_table, offs, lyr, *operands)
     return (
         out.reshape(B, KVH, T, G, D).transpose(0, 2, 1, 3, 4).reshape(B, T, H, D)
     )

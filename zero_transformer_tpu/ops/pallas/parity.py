@@ -162,9 +162,15 @@ def paged_vs_gather(
             k_scale=k_sc, v_scale=v_sc, interpret=interpret,
         )
 
+    def lanes(pool):
+        # the engine's pool layout: heads merged into the lane axis
+        return pool.reshape(n_pages, page, -1)
+
     args = (q, k_pool, v_pool, table)
     ref = reference(*args, offsets, *scales).astype(jnp.float32)
-    out = kernel(*args, offsets, *scales).astype(jnp.float32)
+    out = kernel(
+        q, lanes(k_pool), lanes(v_pool), table, offsets, *map(lanes, scales)
+    ).astype(jnp.float32)
     off_by_one = jnp.where(offsets > 0, offsets - 1, offsets + 1)
     control = reference(*args, off_by_one, *scales).astype(jnp.float32)
     ulp = float(jnp.finfo(dtype).eps) * float(jnp.max(jnp.abs(ref)))

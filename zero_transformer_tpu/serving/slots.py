@@ -35,10 +35,15 @@ from zero_transformer_tpu.inference.generate import init_cache
 # position table offset at the Transformer level.)
 INDEX_LEAVES = ("cache_index", "decode_pos")
 
-# K/V byte-holding leaves of the PAGED cache ([n_pages, page, ...] pools);
-# the int32 per-row page map is its own leaf
+# K/V byte-holding leaves of the PAGED cache: pools in the paged kernel's
+# own layout (``models.gpt.kv_pool_leaves``) — K/V [n_pages, page, KVH * D],
+# int8 scales [n_pages, page, KVH], and under the scanned stack ONE stacked
+# [n_layers, n_pages, page, ...] leaf per pool at the top of the cache tree
+# (it rides the layer loop's carry). The page axis is ``ndim - 3`` either
+# way. The int32 per-row page map is its own leaf.
 POOL_LEAVES = ("cached_key", "cached_value", "key_scale", "value_scale")
 TABLE_LEAF = "block_table"
+_PAGE_AXIS_FROM_END = 3
 
 
 def _leaf_name(path) -> str:
@@ -136,18 +141,27 @@ def _write_spans_impl(axes_items, cache, spans, slot):
     return jax.tree_util.tree_map_with_path(put, cache)
 
 
+def _update_index(cache: Any, update, *args) -> Any:
+    """Run the jitted ``update(index_leaves, *args)`` over the cache's
+    ``INDEX_LEAVES`` and graft the result back into the tree. Every other
+    leaf stays the SAME array: a program that does not need the K/V does
+    not get it — a jit that takes the whole tree and hands it back writes
+    a fresh copy of every leaf it was not donated, the pools included."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(cache)
+    at = [i for i, (path, _) in enumerate(leaves) if _leaf_name(path) in INDEX_LEAVES]
+    out = [leaf for _, leaf in leaves]
+    for i, leaf in zip(at, update([out[i] for i in at], *args)):
+        out[i] = leaf
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
 @jax.jit
-def _reset_index(cache: Any, keep: jax.Array) -> Any:
+def _reset_index(index_leaves: List[jax.Array], keep: jax.Array) -> List[jax.Array]:
     """Zero the positions of retired slots (``keep`` [n_slots] bool). K/V
     rows are left in place — the validity mask (positions < index) already
     excludes them, and the next insert overwrites the row."""
-
-    def reset(path, leaf):
-        if _leaf_name(path) in INDEX_LEAVES:
-            return jnp.where(keep, leaf, 0)  # keep broadcasts from the right
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(reset, cache)
+    # keep broadcasts from the right
+    return [jnp.where(keep, leaf, 0) for leaf in index_leaves]
 
 
 class SlotKVCache:
@@ -311,7 +325,7 @@ class SlotKVCache:
         keep = jnp.asarray(
             [s not in self._free for s in range(self.n_slots)], jnp.bool_
         )
-        self.cache = _reset_index(self.cache, keep)
+        self.cache = _update_index(self.cache, _reset_index, keep)
 
 
 # ---- paged KV cache (block tables over a global page pool) -----------------
@@ -339,6 +353,15 @@ class SlotKVCache:
 # garbage into page 0, which nothing ever reads).
 
 _WIRE_MAGIC = b"ZTPG1"
+
+
+def _wire_key(path) -> str:
+    """A pool leaf's name in a page span: its path under the per-layer
+    ``Attention`` module, as it has been since the format shipped. The
+    scanned stack declares its stacked pools at the top of the cache tree
+    (``models.gpt.Transformer``) but keeps that name on the wire."""
+    key = jax.tree_util.keystr(path)
+    return key if len(path) > 1 else "['blocks']['attn']" + key
 
 
 def _dtype_token(dt) -> str:
@@ -371,17 +394,18 @@ def _gather_pages_impl(cache, page_ids):
     """Pull pool pages out of every K/V pool leaf in ONE dispatch:
     {leaf path -> [len(page_ids), ...per-page]} with the page axis moved
     to the front so row ``i`` is page ``page_ids[i]`` whatever the pool
-    layout (per-layer [n_pages, page, KVH, D] or scanned
-    [L, n_pages, ...]). The compile family is keyed by ``page_ids``'s
+    layout (per-layer [n_pages, page, lanes] or stacked
+    [L, n_pages, page, lanes]) — gathered first, and only the gathered
+    rows transposed. The compile family is keyed by ``page_ids``'s
     (quantized) length — the caller pads to a power of two."""
     out: Dict[str, jax.Array] = {}
 
     def grab(path, leaf):
         if _leaf_name(path) not in POOL_LEAVES:
             return
-        ax = leaf.ndim - 4
-        v = jnp.moveaxis(leaf, ax, 0)
-        out[jax.tree_util.keystr(path)] = jnp.take(v, page_ids, axis=0)
+        ax = leaf.ndim - _PAGE_AXIS_FROM_END
+        rows = jnp.take(leaf, page_ids, axis=ax)
+        out[jax.tree_util.keystr(path)] = jnp.moveaxis(rows, ax, 0)
 
     jax.tree_util.tree_map_with_path(grab, cache)
     return out
@@ -397,28 +421,29 @@ def _scatter_pages_impl(cache, page_ids, spans):
         key = jax.tree_util.keystr(path)
         if key not in spans:
             return leaf
-        ax = leaf.ndim - 4
-        v = jnp.moveaxis(leaf, ax, 0)
-        v = v.at[page_ids].set(spans[key].astype(v.dtype))
-        return jnp.moveaxis(v, 0, ax)
+        # rows back to the pool's axis order, then ONE in-place scatter on
+        # the page axis — the (donated) pool itself is never transposed
+        ax = leaf.ndim - _PAGE_AXIS_FROM_END
+        rows = jnp.moveaxis(spans[key].astype(leaf.dtype), 0, ax)
+        return leaf.at[(slice(None),) * ax + (page_ids,)].set(rows)
 
     return jax.tree_util.tree_map_with_path(put, cache)
 
 
 @jax.jit
-def _set_index_slot(cache: Any, slot: jax.Array, value: jax.Array) -> Any:
+def _set_index_slot(
+    index_leaves: List[jax.Array], slot: jax.Array, value: jax.Array
+) -> List[jax.Array]:
     """Set ONE slot's fill cursor in every index leaf (migration import:
     the destination's cursor is host-known — prompt + emitted — and the
     imported pages already hold the K/V at [0, cursor))."""
 
-    def upd(path, leaf):
-        if _leaf_name(path) not in INDEX_LEAVES:
-            return leaf
+    def upd(leaf):
         block = jnp.full(leaf.shape[:-1] + (1,), value, leaf.dtype)
         starts = (0,) * (leaf.ndim - 1) + (slot,)
         return jax.lax.dynamic_update_slice(leaf, block, starts)
 
-    return jax.tree_util.tree_map_with_path(upd, cache)
+    return [upd(leaf) for leaf in index_leaves]
 
 
 def page_span_to_wire(payload: Dict[str, Any]) -> bytes:
@@ -478,13 +503,13 @@ def page_span_from_wire(blob: bytes) -> Dict[str, Any]:
 def _copy_page(cache: Any, src: jax.Array, dst: jax.Array) -> Any:
     """Copy pool page ``src`` onto ``dst`` in every K/V pool leaf, one
     dispatch — the copy-on-write primitive. The page axis sits at
-    ``ndim - 4`` in every pool layout this repo produces (per-layer
-    [n_pages, page, KVH, D|1], scanned [L, n_pages, page, KVH, D|1])."""
+    ``ndim - 3`` in every pool layout this repo produces (per-layer
+    [n_pages, page, lanes], stacked [L, n_pages, page, lanes])."""
 
     def one(path, leaf):
         if _leaf_name(path) not in POOL_LEAVES:
             return leaf
-        ax = leaf.ndim - 4
+        ax = leaf.ndim - _PAGE_AXIS_FROM_END
         row = jax.lax.dynamic_slice_in_dim(leaf, src, 1, axis=ax)
         return jax.lax.dynamic_update_slice_in_dim(leaf, row, dst, axis=ax)
 
@@ -596,6 +621,21 @@ class PagedKVCache:
         )
         self._free: List[int] = list(range(n_slots))
         self.cow_copies = 0
+        # page-span geometry: {wire key: (cache key, per-page wire shape,
+        # dtype)}. On the wire a page is [(L,) page, KVH, D | 1] — the heads
+        # split back out of the pool's merged lane axis, on HOST arrays
+        # (free), so replicas keep exchanging the payloads they always have
+        kvh = model.cfg.kv_heads
+        self.wire_leaves: Dict[str, Tuple[str, Tuple[int, ...], Any]] = {}
+        for path, leaf in jax.tree_util.tree_leaves_with_path(self.cache):
+            if _leaf_name(path) in POOL_LEAVES:
+                ax = leaf.ndim - _PAGE_AXIS_FROM_END
+                per_page = leaf.shape[:ax] + leaf.shape[ax + 1:-1] + (
+                    kvh, leaf.shape[-1] // kvh
+                )
+                self.wire_leaves[_wire_key(path)] = (
+                    jax.tree_util.keystr(path), per_page, leaf.dtype
+                )
 
     # ---- device sync -----------------------------------------------------
 
@@ -603,12 +643,15 @@ class PagedKVCache:
         """Push the host block-table mirror into every ``block_table`` leaf
         (per-layer copies under the scanned stack broadcast the same
         values). Tiny int32 traffic; ``flush_tables`` below batches the
-        pushes to one per tick."""
-        dev = jnp.asarray(self.table)
+        pushes to one per tick. Every leaf gets a buffer of its OWN: the
+        decode step donates the cache, and unrolled layers' tables sharing
+        one array would be one buffer donated twice."""
 
         def one(path, leaf):
             if _leaf_name(path) == TABLE_LEAF:
-                return jnp.broadcast_to(dev, leaf.shape).astype(leaf.dtype)
+                return jnp.asarray(
+                    np.broadcast_to(self.table, leaf.shape), leaf.dtype
+                )
             return leaf
 
         self.cache = jax.tree_util.tree_map_with_path(one, self.cache)
@@ -743,7 +786,10 @@ class PagedKVCache:
             "page_size": self.page_size,
             "n_blocks": n_blocks,
             "n_tokens": int(n_tokens),
-            "leaves": {k: v[:n_blocks] for k, v in host.items()},
+            "leaves": {
+                wire: host[key][:n_blocks].reshape((n_blocks,) + per_page)
+                for wire, (key, per_page, _) in self.wire_leaves.items()
+            },
         }
 
     # graftlint: hot-path
@@ -774,27 +820,18 @@ class PagedKVCache:
                 f"{self.n_blocks}"
             )
         leaves = payload["leaves"]
-        expect = {}
-        for path, leaf in jax.tree_util.tree_leaves_with_path(self.cache):
-            if _leaf_name(path) in POOL_LEAVES:
-                key = jax.tree_util.keystr(path)
-                ax = leaf.ndim - 4
-                shape = tuple(
-                    d for i, d in enumerate(leaf.shape) if i != ax
-                )
-                expect[key] = (shape, leaf.dtype)
-        if set(leaves) != set(expect):
+        if set(leaves) != set(self.wire_leaves):
             raise ValueError(
                 f"page-span leaves {sorted(leaves)} != pool leaves "
-                f"{sorted(expect)}"
+                f"{sorted(self.wire_leaves)}"
             )
-        for key, arr in leaves.items():
-            shape, dtype = expect[key]
+        for wire, arr in leaves.items():
+            _, shape, dtype = self.wire_leaves[wire]
             if tuple(arr.shape) != (n_blocks,) + shape or np.dtype(
                 arr.dtype
             ) != np.dtype(dtype):
                 raise ValueError(
-                    f"page-span leaf {key} is {arr.dtype}{arr.shape}; "
+                    f"page-span leaf {wire} is {arr.dtype}{arr.shape}; "
                     f"pool expects {np.dtype(dtype).str}[{n_blocks}]+{shape}"
                 )
         fresh: List[int] = []
@@ -807,11 +844,15 @@ class PagedKVCache:
         padded = self._quantized_blocks(n_blocks)
         ids = fresh + [PagePool.TRASH] * (padded - n_blocks)
         spans = {}
-        for key, arr in leaves.items():
+        for wire, arr in leaves.items():
+            # heads back into the pool's lane axis (a view of a host array)
+            arr = arr.reshape(arr.shape[:-2] + (-1,))
             pad = np.zeros(
                 (padded - n_blocks,) + arr.shape[1:], dtype=arr.dtype
             )
-            spans[key] = jnp.asarray(np.concatenate([arr, pad], axis=0))
+            spans[self.wire_leaves[wire][0]] = jnp.asarray(
+                np.concatenate([arr, pad], axis=0)
+            )
         self.cache = _scatter_pages_impl(
             self.cache, jnp.asarray(ids, jnp.int32), spans
         )
@@ -824,8 +865,8 @@ class PagedKVCache:
     def set_cursor(self, slot: int, value: int) -> None:
         """Set the slot's fill cursor in every index leaf (import install:
         the host knows the migrated stream's exact position)."""
-        self.cache = _set_index_slot(
-            self.cache, jnp.int32(slot), jnp.int32(value)
+        self.cache = _update_index(
+            self.cache, _set_index_slot, jnp.int32(slot), jnp.int32(value)
         )
 
     def reset_slot_pages(self, slot: int) -> None:
@@ -877,4 +918,4 @@ class PagedKVCache:
         keep = jnp.asarray(
             [s not in self._free for s in range(self.n_slots)], jnp.bool_
         )
-        self.cache = _reset_index(self.cache, keep)
+        self.cache = _update_index(self.cache, _reset_index, keep)
