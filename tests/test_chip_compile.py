@@ -102,7 +102,7 @@ LANES = 12 * 128  # one K/V pool row; the int8 scale pools have 12 lanes
 PREFETCH = {"slice-start", "slice-done", "ConcatBitcast", "copy-done"}
 
 
-def _pool_ops(hlo: str):
+def _pool_ops(hlo: str, n_pages: int = N_PAGES, page: int = PAGE, lanes: int = LANES):
     """Every operation of an optimised HLO module that MATERIALIZES a
     pool-sized value (a result whose element count is a multiple of
     pages x page size: 2049 = 3 x 683 divides no other shape here), as
@@ -137,14 +137,14 @@ def _pool_ops(hlo: str):
             op_name, result, opcode = m.groups()
             shapes = re.findall(r"[a-z0-9]+\[([\d,]+)\](\{[^}]*\})?", result)
             sizes = [math.prod(int(d) for d in dims.split(",")) for dims, _ in shapes]
-            sizes = [n for n in sizes if n % (N_PAGES * PAGE) == 0]
+            sizes = [n for n in sizes if n % (n_pages * page) == 0]
             if not sizes:
                 continue
             if opcode == "custom-call":
                 opcode = re.search(r'custom_call_target="(\w+)"', line).group(1)
             if opcode in ("copy", "copy-start") and "S(1)" in shapes[0][1]:
                 opcode = "copy-done"  # destination in fast memory: prefetch
-            kv = any(n % (N_PAGES * PAGE * LANES) == 0 for n in sizes)
+            kv = any(n % (n_pages * page * lanes) == 0 for n in sizes)
             if opcode == "fusion":
                 inner = re.search(r"calls=%?([\w.\-]+)", line).group(1)
                 for op, _, _ in pool_sized(inner):
@@ -159,14 +159,26 @@ def _pool_ops(hlo: str):
     ]
 
 
-def _serving_programs(one_chip, monkeypatch, int8: bool, scan: bool):
+def _cfg_580m_cut(int8: bool, scan: bool):
     """The 580M serving model's structure (d 1536, 12 heads of 128, float32
-    weights, bf16 compute; depth cut to keep the compile in seconds) at the
-    benchmark cell's engine shapes — 16 slots x 2048, page 16, chunk 64 —
-    as the engine's own jitted decode step and paged chunk prefill,
-    compiled for the described chip. Returns their optimised HLO and the
-    number of pool leaves."""
+    weights, bf16 compute; depth cut to keep the compile in seconds)."""
     from zero_transformer_tpu.config import ModelConfig
+
+    return ModelConfig(
+        name="serve_580m_cut", d_model=1536, n_layers=DEPTH, n_heads=12,
+        head_dim=128, d_ff=6144, vocab_size=50304, max_seq_len=CACHE_LEN,
+        position="alibi", norm="layernorm", activation="gelu",
+        tie_embeddings=True, param_dtype="float32", compute_dtype="bfloat16",
+        dropout=0.0, attention_impl="auto", scan_layers=scan,
+        kv_cache_dtype="int8" if int8 else "auto",
+    )
+
+
+def _serving_programs(one_chip, monkeypatch, cfg, cache_len=CACHE_LEN, n_pages=N_PAGES):
+    """``cfg`` at a benchmark cell's engine shapes — 16 slots, page 16,
+    chunk 64, the cell's cache length and pool — as the engine's own jitted
+    decode step and paged chunk prefill, compiled for the described chip.
+    Returns their optimised HLO and the number of pool leaves."""
     from zero_transformer_tpu.inference.generate import decode_model
     from zero_transformer_tpu.inference.sampling import SamplingConfig
     from zero_transformer_tpu.serving import engine as eng
@@ -177,15 +189,7 @@ def _serving_programs(one_chip, monkeypatch, int8: bool, scan: bool):
     # the kernel gates ask the backend, and the backend here is the CPU
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.delenv("ZT_PALLAS_INTERPRET", raising=False)
-    cfg = ModelConfig(
-        name="serve_580m_cut", d_model=1536, n_layers=DEPTH, n_heads=12,
-        head_dim=128, d_ff=6144, vocab_size=50304, max_seq_len=CACHE_LEN,
-        position="alibi", norm="layernorm", activation="gelu",
-        tie_embeddings=True, param_dtype="float32", compute_dtype="bfloat16",
-        dropout=0.0, attention_impl="auto", scan_layers=scan,
-        kv_cache_dtype="int8" if int8 else "auto",
-    )
-    model = decode_model(cfg, CACHE_LEN, kv_pages=(N_PAGES, PAGE))
+    model = decode_model(cfg, cache_len, kv_pages=(n_pages, PAGE))
 
     def on_chip(tree):
         return jax.tree.map(
@@ -221,7 +225,7 @@ def _serving_programs(one_chip, monkeypatch, int8: bool, scan: bool):
     rows = sds((N_SLOTS,), jnp.int32)
     prefill = jax.jit(eng._paged_chunk_prefill_impl, static_argnums=(0,)).lower(
         model, params, cache, sds((N_SLOTS, CHUNK), jnp.int32), rows, rows,
-        sds((N_SLOTS,), jnp.bool_), sds((N_SLOTS, CACHE_LEN // PAGE), jnp.int32),
+        sds((N_SLOTS,), jnp.bool_), sds((N_SLOTS, cache_len // PAGE), jnp.int32),
         rows,
     ).compile().as_text()
     return decode, prefill, n_pools
@@ -247,7 +251,8 @@ def test_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch, int8, 
     the way in and on the way out (at 18 layers, at its edge; at this cut
     depth they fit the core's fast memory and the compiler also moves them
     there and back)."""
-    decode, prefill, n_pools = _serving_programs(one_chip, monkeypatch, int8, scan)
+    decode, prefill, n_pools = _serving_programs(
+        one_chip, monkeypatch, _cfg_580m_cut(int8, scan))
     n_kv = n_pools // 2 if int8 else n_pools
     assert decode.count("tpu_custom_call") >= 1  # the paged kernel is on the path
     aliased = decode.split("input_output_alias={", 1)[1].split("}, entry", 1)[0]
@@ -262,6 +267,61 @@ def test_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch, int8, 
     on_kv = [op for op, _, kv, _ in _pool_ops(prefill) if kv and op not in PREFETCH]
     assert set(on_kv) <= {"scatter", "copy", "copy-start"}, on_kv
     assert len(on_kv) - on_kv.count("scatter") == n_kv, on_kv
+
+
+LOOP_CACHE_LEN, LOOP_POOL_TOKENS = 512, 2560
+LOOP_N_PAGES = LOOP_POOL_TOKENS // PAGE + 1  # 161 = 7 x 23: in no other shape there
+
+
+@pytest.mark.parametrize("scan", [True, False], ids=["scan", "unrolled"])
+def test_looped_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch, scan):
+    """The looped 2.6B cell's structure (d 2048, 16 heads of 128, SwiGLU
+    5632, sandwich norms, 4 passes, the exit gate, bfloat16 weights; depth
+    cut to 2 layers, so 8 K/V entries) at its engine shapes: 16 slots x 512
+    over a 2,560-token pool. The pass axis changes nothing of what the 580M
+    case allows: the stacked pool [n_loops * n_layers, ...] (unrolled: each
+    layer's own [n_loops, ...]) rides the layer loops, every pass's scatter
+    is in place, decode aliases every pool leaf and holds nothing else
+    pool-sized, prefill the one copy a pool that not donating forces."""
+    from zero_transformer_tpu.config import ModelConfig
+
+    cfg = ModelConfig(
+        name="serve_looped_cut", d_model=2048, n_layers=DEPTH, n_loops=4,
+        n_heads=16, n_kv_heads=16, head_dim=128, d_ff=5632, vocab_size=49152,
+        max_seq_len=LOOP_CACHE_LEN, position="rope", rope_theta=1e6,
+        norm="rmsnorm", activation="swiglu", tie_embeddings=False,
+        post_norm=True, exit_gate=True, exit_threshold=1.0,
+        param_dtype="bfloat16", compute_dtype="bfloat16", dropout=0.0,
+        attention_impl="auto", scan_layers=scan,
+    )
+    decode, prefill, n_pools = _serving_programs(
+        one_chip, monkeypatch, cfg, LOOP_CACHE_LEN, LOOP_N_PAGES)
+    assert n_pools == (2 if scan else 2 * DEPTH)
+    # the paged kernel is on the path once a (pass, layer) unless the passes'
+    # layer loops stay rolled: at least once a pass
+    assert decode.count("tpu_custom_call") >= 4
+    aliased = decode.split("input_output_alias={", 1)[1].split("}, entry", 1)[0]
+    assert aliased.count("-alias") >= n_pools
+
+    def ops(hlo):
+        return _pool_ops(hlo, LOOP_N_PAGES, PAGE, 16 * 128)
+
+    for found in (ops(decode), ops(prefill)):
+        sliced = {"dynamic-slice", "dynamic-update-slice", "gather", "AllocateBuffer"}
+        assert not [o for o in found if o[0] in sliced], found
+        assert {op for op, _, kv, in_loop in found if kv and in_loop} <= {"scatter"}, found
+    if not scan:
+        # an unrolled layer's own pool [n_loops, 161, 16, 2048] is 42 MB and
+        # fits the core's fast memory: the compiler stages it there around
+        # every scatter and writes it back (slice-start .. copy-start), at
+        # any depth. That is its prefetch, not a copy the program asked for;
+        # the count below is held on the stacked pool the cell runs.
+        return
+    on_kv = [op for op, _, kv, _ in ops(decode) if kv]
+    assert set(on_kv) == {"scatter"}, on_kv
+    on_kv = [op for op, _, kv, _ in ops(prefill) if kv and op not in PREFETCH]
+    assert set(on_kv) <= {"scatter", "copy", "copy-start"}, on_kv
+    assert len(on_kv) - on_kv.count("scatter") == n_pools, on_kv
 
 
 def _flash_grads(docs: bool, entry=flash.flash_attention):

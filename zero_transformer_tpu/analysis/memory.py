@@ -50,6 +50,20 @@ def _dtype_bytes(name: str) -> int:
     return jnp.dtype(resolve_dtype(name)).itemsize
 
 
+def kv_bytes_per_token(m) -> int:
+    """Bytes ONE cached position holds in the decode K/V cache of model
+    config ``m``: K and V in every entry — one per (pass, layer), so a
+    looped stack pays ``n_loops`` times a plain one — at the compute dtype,
+    or int8 values plus one float32 scale per (position, head). What a
+    serving pool is sized from: ``page_pool_tokens`` x this, beside the
+    weights."""
+    per_head = (
+        m.head_width + 4 if m.kv_cache_dtype == "int8"
+        else m.head_width * _dtype_bytes(m.compute_dtype)
+    )
+    return 2 * m.kv_entries * m.kv_heads * per_head
+
+
 def analytic_memory(
     cfg, accum: Optional[int] = None, n_devices: Optional[int] = None
 ) -> Dict[str, Any]:

@@ -55,6 +55,23 @@ class ModelConfig:
     activation: str = "gelu"  # "gelu" | "swiglu"
     norm: str = "layernorm"  # "layernorm" | "rmsnorm"
     tie_embeddings: bool = True
+    # Looped ("universal") stack: the n_layers blocks run n_loops times over
+    # the same weights, the final norm closing every pass, and the KV cache
+    # keeps one entry per (pass, layer) — n_loops * n_layers in all, entry
+    # t * n_layers + l — while a token's position advances once. 1 = the
+    # plain stack.
+    n_loops: int = 1
+    # "sandwich" norms: a second norm on each sublayer's OUTPUT, before the
+    # residual add (ln_attn_post / ln_mlp_post beside ln_attn / ln_mlp)
+    post_norm: bool = False
+    # Exit gate of a looped stack: a Dense(1, bias) on each pass's normed
+    # state. lam_t = sigmoid(gate), p_t = lam_t * prod_{j<t}(1 - lam_j), the
+    # last pass taking what is left; a position decodes from the first pass
+    # whose cumulative p reaches exit_threshold, else from the last. Every
+    # pass always runs (the cache stays whole): the gate only selects the
+    # state the head reads.
+    exit_gate: bool = False
+    exit_threshold: float = 1.0
     # Compilation shape: scan over layers gives O(1) compile time in depth and a
     # stacked [n_layers, ...] param layout that ZeRO shards cleanly.
     scan_layers: bool = True
@@ -130,18 +147,38 @@ class ModelConfig:
         return 4 * self.d_model
 
     @property
-    def num_params(self) -> int:
-        """Approximate parameter count (embedding included once when tied)."""
-        d, f, L, v = self.d_model, self.ff_dim, self.n_layers, self.vocab_size
+    def layer_params(self) -> int:
+        """Parameters of ONE block (matrices and norm scales)."""
+        d, f = self.d_model, self.ff_dim
         h, kv, hd = self.n_heads, self.kv_heads, self.head_width
         attn = d * h * hd + 2 * d * kv * hd + h * hd * d
         mlp = (3 if self.activation == "swiglu" else 2) * d * f
         if self.n_experts > 0:
             mlp = self.n_experts * mlp + d * self.n_experts  # experts + router
-        norms = 2 * d
-        per_layer = attn + mlp + norms
+        norms = (4 if self.post_norm else 2) * d
+        return attn + mlp + norms
+
+    @property
+    def num_params(self) -> int:
+        """Approximate parameter count (embedding included once when tied; a
+        looped stack's shared layers once)."""
+        d, v = self.d_model, self.vocab_size
         embed = v * d * (1 if self.tie_embeddings else 2)
-        return L * per_layer + embed + d
+        gate = d + 1 if self.exit_gate else 0
+        return self.n_layers * self.layer_params + embed + d + gate
+
+    @property
+    def params_per_token(self) -> int:
+        """Parameters a token is multiplied through: ``num_params`` with a
+        looped stack's shared layers counted once a PASS. What FLOP
+        arithmetic wants where ``num_params`` is what memory holds; the
+        same number for a plain stack."""
+        return self.num_params + (self.n_loops - 1) * self.n_layers * self.layer_params
+
+    @property
+    def kv_entries(self) -> int:
+        """K/V cache entries a token keeps: one per (pass, layer)."""
+        return self.n_loops * self.n_layers
 
     def __post_init__(self):
         if self.d_model % self.n_heads and self.head_dim is None:
@@ -170,6 +207,12 @@ class ModelConfig:
                 f"[0, {self.vocab_size}): the separator could never appear, "
                 "silently disabling document masking"
             )
+        if self.n_loops < 1:
+            raise ValueError("n_loops must be >= 1")
+        if self.exit_gate and self.n_loops == 1:
+            raise ValueError("exit_gate needs a looped stack (n_loops > 1)")
+        if not 0.0 < self.exit_threshold <= 1.0:
+            raise ValueError("exit_threshold must lie in (0, 1]")
         if self.loss_chunk is not None and self.loss_chunk <= 0:
             raise ValueError("loss_chunk must be a positive chunk size or None")
         if self.n_experts < 0:

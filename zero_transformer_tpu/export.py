@@ -283,6 +283,30 @@ def _cmd_import_reference(args) -> None:
     print(f"converted {n:,} reference params ({args.model}) -> {args.out}")
 
 
+def check_exportable(cfg) -> None:
+    """Exit unless ``cfg`` is of the reference's family. The layout alone
+    cannot tell: an RMSNorm stores one ``scale`` like a LayerNorm, and a
+    looped stack (``n_loops`` > 1) has the plain stack's tree — written out,
+    it would load and compute a one-pass model."""
+    bad = [
+        f"{field}={got!r} (reference: {want!r})"
+        for field, got, want in (
+            ("norm", cfg.norm, "layernorm"),
+            ("position", cfg.position, "alibi"),
+            ("activation", cfg.activation, "gelu"),
+            ("tie_embeddings", cfg.tie_embeddings, True),
+            ("n_loops", cfg.n_loops, 1),
+            ("post_norm", cfg.post_norm, False),
+            ("exit_gate", cfg.exit_gate, False),
+        )
+        if got != want
+    ]
+    if bad:
+        raise SystemExit(
+            f"{cfg.name} is outside the reference family: {'; '.join(bad)}"
+        )
+
+
 def _cmd_to_reference(args) -> None:
     from flax.serialization import msgpack_serialize
 
@@ -292,21 +316,7 @@ def _cmd_to_reference(args) -> None:
     if args.model:
         from zero_transformer_tpu.config import model_config
 
-        cfg = model_config(args.model)
-        bad = [
-            f"{field}={got!r} (reference: {want!r})"
-            for field, got, want in (
-                ("norm", cfg.norm, "layernorm"),
-                ("position", cfg.position, "alibi"),
-                ("activation", cfg.activation, "gelu"),
-                ("tie_embeddings", cfg.tie_embeddings, True),
-            )
-            if got != want
-        ]
-        if bad:
-            raise SystemExit(
-                f"{args.model} is outside the reference family: {'; '.join(bad)}"
-            )
+        check_exportable(model_config(args.model))
     # unwrap once HERE: the converter tolerates an outer "params" wrapper,
     # so the layout detection and round-trip comparison below must see the
     # same unwrapped tree it converts

@@ -19,6 +19,7 @@ returns logits or (logits, loss) (reference ``GPT.py:67-113``).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Optional, Tuple, Union
 
 import flax.linen as nn
@@ -97,9 +98,11 @@ def _quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 
 def kv_pool_leaves(cfg: ModelConfig, kv_pages: Tuple[int, int], dtype) -> dict:
-    """``{leaf name: (shape, dtype)}`` of ONE layer's paged K/V pool, in the
-    layout the paged kernel's ``BlockSpec`` reads: K/V ``[n_pages, page,
-    KVH * D]`` and, for int8 pages, f32 scales ``[n_pages, page, KVH]``.
+    """``{leaf name: (shape, dtype)}`` of ONE entry of the paged K/V pool
+    (a layer's; a looped stack keeps ``n_loops`` a layer, on a leading
+    axis), in the layout the paged kernel's ``BlockSpec`` reads: K/V
+    ``[n_pages, page, KVH * D]`` and, for int8 pages, f32 scales
+    ``[n_pages, page, KVH]``.
     The heads are merged into the lane axis at allocation: with
     ``[..., KVH, D]`` minor dims a TPU tile pads 12 heads to 16, XLA picks
     another physical layout for the pool than the Mosaic call and the
@@ -154,6 +157,29 @@ def _norm(cfg: ModelConfig, dtype, name: str):
     return nn.LayerNorm(use_bias=False, **kwargs)
 
 
+def _select_exit(states, gates, threshold: float) -> jax.Array:
+    """The state each position decodes from, of a looped stack's per-pass
+    normed states ``[B, T, d]`` and gate values ``[B, T]`` (float32).
+
+    ``lam_t = sigmoid(g_t)``; pass ``t`` is the exit with probability
+    ``p_t = lam_t * prod_{j<t}(1 - lam_j)``, the last pass with what is
+    left. A position exits at the first pass whose CUMULATIVE ``p`` reaches
+    ``threshold``, else at the last: at threshold 1 that is the last unless
+    a gate saturates."""
+    out = states[-1]
+    remaining = jnp.ones_like(gates[0])
+    cum = jnp.zeros_like(gates[0])
+    open_ = jnp.ones(gates[0].shape, jnp.bool_)  # no earlier pass was chosen
+    for state, g in zip(states[:-1], gates[:-1]):
+        lam = jax.nn.sigmoid(g)
+        cum = cum + lam * remaining
+        remaining = remaining * (1.0 - lam)
+        take = open_ & (cum >= threshold)
+        out = jnp.where(take[..., None], state, out)
+        open_ = open_ & ~take
+    return out
+
+
 class LMHead(nn.Module):
     """Untied output projection: a bias-free Dense whose kernel is ALSO
     directly readable (``head.kernel`` — the chunked-loss path projects the
@@ -204,7 +230,16 @@ class Attention(nn.Module):
     the gather and the kernel's page fetch, and returns ``(out, pools)``:
     the pool is updated in place through the loop, never sliced out of it.
     Without ``pools`` it owns per-layer pool leaves itself — same code, no
-    layer index."""
+    layer index.
+
+    ``step`` is the pass of a looped stack (``cfg.n_loops > 1``; None
+    otherwise). The projections are the same at every pass, the K/V are
+    not: every K/V leaf gains an entry axis in front — the stack's
+    ``[n_loops * n_layers, ...]``, entry ``step * n_layers + layer``, or
+    this module's own ``[n_loops, ...]`` — and this call reads and writes
+    entry ``step`` alone. ``cache_index`` and ``block_table`` have no such
+    axis: a token sits at ONE position and in one page, whatever the pass,
+    and the index advances at the last pass."""
 
     cfg: ModelConfig
     deterministic: bool = True
@@ -221,6 +256,7 @@ class Attention(nn.Module):
         doc_ids: Optional[jax.Array] = None,
         pools: Optional[dict] = None,
         layer: Optional[jax.Array] = None,
+        step=None,
     ):
         cfg = self.cfg
         dtype = x.dtype
@@ -261,7 +297,11 @@ class Attention(nn.Module):
         bt = None
         if self.decode:
             max_len = self.cache_len or cfg.max_seq_len
-            is_init = not self.has_variable("cache", "cache_index")
+            # (a looped stack calls this module once a pass: every call of
+            # an init trace declares, none writes)
+            is_init = self.is_initializing() or not self.has_variable(
+                "cache", "cache_index"
+            )
             if paged:
                 n_pages, page = self.kv_pages
                 if max_len % page:
@@ -285,12 +325,28 @@ class Attention(nn.Module):
             # K/V leaves: this module's own variables, or — a stacked pool
             # riding the layer loop's carry — the caller's
             own = None
+            entry = layer
             if pools is None:
+                passes = () if step is None else (cfg.n_loops,)
                 own = {
-                    name: self.variable("cache", name, jnp.zeros, shape, dt)
+                    name: self.variable(
+                        "cache", name, jnp.zeros, passes + shape, dt
+                    )
                     for name, (shape, dt) in leaves.items()
                 }
+                entry = step
+            elif step is not None:
+                entry = step * cfg.n_layers + layer
             kv = dict(pools) if own is None else {n: v.value for n, v in own.items()}
+            if entry is not None and not paged:
+                # a slab with a pass axis: this pass's entry is taken out,
+                # written and attended over as the plain slab is, and put
+                # back below
+                slabs = kv
+                kv = {
+                    n: jax.lax.dynamic_index_in_dim(v, entry, 0, keepdims=False)
+                    for n, v in slabs.items()
+                }
             idx = self.variable("cache", "cache_index", lambda: jnp.zeros((), jnp.int32))
             use_cache = not is_init
             if use_cache:
@@ -331,14 +387,14 @@ class Attention(nn.Module):
                     bt.value, jnp.clip(pos // page, 0, n_blocks - 1), axis=1
                 )  # [B, T]
                 in_page = pos % page
-                # a stacked pool is indexed by the layer in the SAME scatter
-                # / gather — `pool[layer]` first would slice a pool-sized
+                # a stacked pool is indexed by its entry in the SAME scatter
+                # / gather — `pool[entry]` first would slice a pool-sized
                 # value out of the loop's carry every layer
-                at_layer = () if layer is None else (layer,)
+                at_entry = () if entry is None else (entry,)
 
                 def write(buf, upd):
                     # upd [B, T, KVH, D | 1] -> the pool's merged lane axis
-                    return buf.at[at_layer + (page_ids, in_page)].set(
+                    return buf.at[at_entry + (page_ids, in_page)].set(
                         upd.reshape(B, T, -1).astype(buf.dtype)
                     )
 
@@ -346,7 +402,7 @@ class Attention(nn.Module):
                     # pool pages -> the row-major [B, cache_len, KVH, D | 1]
                     # view the slab path attends over; only the GATHERED
                     # view is reshaped, never the pool
-                    g = buf[at_layer + (bt.value,)]  # [B, n_blocks, page, lanes]
+                    g = buf[at_entry + (bt.value,)]  # [B, n_blocks, page, lanes]
                     return g.reshape(B, n_blocks * page, KVH, -1)
 
             else:
@@ -380,8 +436,14 @@ class Attention(nn.Module):
                 kv["cached_value"] = write(kv["cached_value"], v)
             if own is not None:
                 for name, var in own.items():
-                    var.value = kv[name]
-            idx.value = offset + T
+                    var.value = kv[name] if paged or entry is None else (
+                        jax.lax.dynamic_update_index_in_dim(
+                            slabs[name], kv[name], entry, 0
+                        )
+                    )
+            idx.value = offset + (
+                T if step is None else jnp.where(step == cfg.n_loops - 1, T, 0)
+            )
             max_len_b = self.cache_len or cfg.max_seq_len
             if per_slot:
                 kv_valid = (
@@ -427,7 +489,7 @@ class Attention(nn.Module):
                 # module's exactness contract
                 out = paged_decode_attention(
                     q, kv["cached_key"], kv["cached_value"], bt.value, offset,
-                    layer=layer,
+                    layer=entry,
                     causal=T > 1,
                     alibi=cfg.position == "alibi",
                     k_scale=kv.get("key_scale"),
@@ -527,7 +589,9 @@ class Block(nn.Module):
     ``aux`` as it threads through the layer scan; dense blocks pass it
     through unchanged. Called with a ``layer`` index (the scanned paged
     decode stack), the carry's third element is the stacked K/V pool, which
-    ``Attention`` updates in place at that layer."""
+    ``Attention`` updates in place at that layer. ``step`` is the pass of a
+    looped stack (see ``Attention``). With ``cfg.post_norm`` each sublayer's
+    output is normed once more before it joins the residual stream."""
 
     cfg: ModelConfig
     deterministic: bool = True
@@ -537,7 +601,7 @@ class Block(nn.Module):
     kv_pages: Optional[Tuple[int, int]] = None
 
     @nn.compact
-    def __call__(self, carry, layer=None):
+    def __call__(self, carry, layer=None, step=None):
         cfg = self.cfg
         # packed-sequence models thread the document ids as a third carry
         # element (constant through the layer scan); the decode path never
@@ -554,10 +618,12 @@ class Block(nn.Module):
             cfg, self.deterministic, self.decode, self.cache_len, self.mesh,
             self.kv_pages, name="attn"
         )(
-            _norm(cfg, x.dtype, "ln_attn")(x), doc_ids, pools, layer
+            _norm(cfg, x.dtype, "ln_attn")(x), doc_ids, pools, layer, step
         )
         if pools is not None:
             attn, pools = attn
+        if cfg.post_norm:
+            attn = _norm(cfg, x.dtype, "ln_attn_post")(attn)
         x = x + attn
         # pin the residual stream: batch/seq sharded, replicated over tensor
         # (Megatron layout) — GSPMD must not invent another layout for it
@@ -566,12 +632,16 @@ class Block(nn.Module):
             mo, layer_aux = MoEMLP(cfg, self.deterministic, name="moe")(
                 _norm(cfg, x.dtype, "ln_mlp")(x)
             )
-            x = x + mo
-            aux = aux + layer_aux
         else:
-            x = x + MLP(cfg, self.deterministic, name="mlp")(
+            layer_aux = None
+            mo = MLP(cfg, self.deterministic, name="mlp")(
                 _norm(cfg, x.dtype, "ln_mlp")(x)
             )
+        if cfg.post_norm:
+            mo = _norm(cfg, x.dtype, "ln_mlp_post")(mo)
+        x = x + mo
+        if layer_aux is not None:
+            aux = aux + layer_aux
         x = constrain_activation(x, "batch", "seq", "embed")
         if packed:
             return (x, aux, doc_ids), None
@@ -708,14 +778,15 @@ class Transformer(nn.Module):
         layers = pool_vars = None
         if cfg.scan_layers and self.decode and self.kv_pages is not None:
             # the paged K/V pool rides the layer loop's CARRY, stacked
-            # [n_layers, ...] and indexed by the layer inside Attention.
+            # [n_loops * n_layers, ...] and indexed by its entry inside
+            # Attention.
             # Scanned over like the rest of the cache (`variable_axes`), it
             # would enter the loop as one buffer and leave as another:
             # every layer would slice its pool out of the first and copy
             # it into the second, whatever the caller donates.
             pool_vars = {
                 name: self.variable(
-                    "cache", name, jnp.zeros, (cfg.n_layers,) + shape, dt
+                    "cache", name, jnp.zeros, (cfg.kv_entries,) + shape, dt
                 )
                 for name, (shape, dt) in kv_pool_leaves(
                     cfg, self.kv_pages, dtype
@@ -732,19 +803,52 @@ class Transformer(nn.Module):
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(cfg, not train, self.decode, self.cache_len, self.mesh,
               self.kv_pages, name="blocks")
-            carry, _ = stack(carry, layers)
-            if pool_vars is not None:
-                for name, var in pool_vars.items():
-                    var.value = carry[2][name]
         else:
-            for i in range(cfg.n_layers):
-                carry, _ = block_cls(
+            blocks = [
+                block_cls(
                     cfg, not train, self.decode, self.cache_len, self.mesh,
                     self.kv_pages, name=f"block_{i}",
-                )(carry, None)
-        h, aux = carry[0], carry[1]
-
-        h = _norm(cfg, h.dtype, "ln_f")(h)
+                )
+                for i in range(cfg.n_layers)
+            ]
+        ln_f = _norm(cfg, h.dtype, "ln_f")
+        # A looped stack runs the SAME blocks n_loops times: one module,
+        # called once a pass, so the weights are shared (and their gradients
+        # sum over the passes) while each pass reads and writes its own K/V
+        # entries. The final norm closes every pass: the next one starts
+        # from the normed state.
+        looped = cfg.n_loops > 1
+        states, gates = [], []
+        if cfg.exit_gate:
+            gate = nn.Dense(
+                1, dtype=jnp.float32, param_dtype=param_dtype,
+                kernel_init=nn.with_partitioning(
+                    initializers.normal(stddev=0.02), ("embed", None)
+                ),
+                name="exit_gate",
+            )
+        for t in range(cfg.n_loops):
+            step = t if looped else None
+            with jax.named_scope("loop_pass") if looped else contextlib.nullcontext():
+                if cfg.scan_layers:
+                    steps = jnp.full((cfg.n_layers,), t, jnp.int32) if looped else None
+                    carry, _ = stack(carry, layers, steps)
+                else:
+                    for block in blocks:
+                        carry, _ = block(carry, None, step)
+                h = ln_f(carry[0])
+            carry = (h,) + carry[1:]
+            if cfg.exit_gate:
+                states.append(h)
+                with jax.named_scope("exit_gate"):
+                    gates.append(gate(h)[..., 0])
+        if pool_vars is not None:
+            for name, var in pool_vars.items():
+                var.value = carry[2][name]
+        aux = carry[1]
+        if cfg.exit_gate:
+            with jax.named_scope("exit_gate"):
+                h = _select_exit(states, gates, cfg.exit_threshold)
 
         if cfg.tie_embeddings:
             head = None

@@ -136,6 +136,19 @@ def _psum_pipe_replicated(grads, pipe_sharded):
     )
 
 
+def check_supported(cfg) -> None:
+    """The stage builder lays ``n_layers`` blocks over the pipe ranks ONCE
+    and applies its own final norm and head: a looped stack (``n_loops`` >
+    1: every pass would have to travel the ranks again) or an exit gate
+    would be dropped in silence and a one-pass model trained. Refuse."""
+    if cfg.n_loops > 1 or cfg.exit_gate:
+        raise NotImplementedError(
+            f"pipeline parallelism runs the layer stack once: n_loops="
+            f"{cfg.n_loops}, exit_gate={cfg.exit_gate} is not supported on a "
+            "mesh with a pipe axis (use data/fsdp/tensor axes for a looped model)"
+        )
+
+
 def make_pp_train_step(
     model,
     tx: optax.GradientTransformation,
@@ -190,6 +203,7 @@ def make_pp_train_step(
     from zero_transformer_tpu.parallel.zero import TrainState, _accum_add, _accum_dtype
 
     cfg = model.cfg
+    check_supported(cfg)
     n_stages = mesh.shape[PIPE_AXIS]
     if pp_schedule not in ("gpipe", "1f1b", "interleaved"):
         # validate at the API boundary too (MeshConfig validates its own

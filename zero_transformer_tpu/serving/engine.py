@@ -104,6 +104,7 @@ from zero_transformer_tpu.analysis.runtime import (
     CompileFamilyExceeded,
     bounded_dispatch,
 )
+from zero_transformer_tpu.analysis.memory import kv_bytes_per_token
 from zero_transformer_tpu.config import resolve_dtype
 from zero_transformer_tpu.obs import (
     LATENCY_BUCKETS,
@@ -1138,6 +1139,12 @@ class ServingEngine:
             "page_faults": 0,
             "pages_reclaimed": 0,
             "preemptions": 0,
+            # admission attempts deferred for PAGES while a slot was free
+            # (the request went back to the queue's head): the pool, not
+            # the slot count, was what admission ran out of
+            "page_waits": 0,
+            # model passes run by decode ticks: ticks x cfg.n_loops
+            "loop_passes": 0,
             # speculation counters: acceptance_rate = accepted / drafted
             "spec_ticks": 0,
             "draft_tokens": 0,
@@ -1759,6 +1766,7 @@ class ServingEngine:
             if paged and not self._paged_admission_fits(handle):
                 # back at the HEAD: admission stays FIFO, and the next
                 # retirement frees the pages this request is waiting for
+                self.stats["page_waits"] += 1
                 with self._lock:
                     self._queue.appendleft(handle)
                 return
@@ -2356,13 +2364,19 @@ class ServingEngine:
         # active slots, never the scheduler thread (run() stays alive and
         # queued requests admit on the next tick)
         try:
+            self.stats["loop_passes"] += self.cfg.n_loops
             # decode_step is dispatch plus the host's wait for everything
             # the device still owes this tick: the decode program AND the
             # prefill program that prefill_chunk only dispatched. It is
             # host time; the programs' own durations are in a capture's
             # "XLA Modules" line.
             with tr.span("decode_step", "engine", tick=tick_idx,
-                         active=self.active_count, spec=bool(self.draft_k)):
+                         active=self.active_count, spec=bool(self.draft_k),
+                         loops=self.cfg.n_loops,
+                         pages_in_use=(
+                             self.slots.pool.in_use
+                             if self.kv_layout == "paged" else 0
+                         )):
                 if self._chaos is not None:
                     self._chaos.on_tick(self._tick)
                 if self.kv_layout == "paged":
@@ -3614,6 +3628,10 @@ class ServingEngine:
             "cow_copies": (
                 self.slots.cow_copies if self.kv_layout == "paged" else 0
             ),
+            # what ONE cached position costs in every K/V entry (a looped
+            # stack keeps n_loops a layer): the number to size
+            # page_pool_tokens with
+            "kv_bytes_per_token": kv_bytes_per_token(self.cfg),
             "acceptance_rate": (
                 self.stats["accepted_tokens"] / self.stats["draft_tokens"]
                 if self.stats["draft_tokens"]
@@ -3666,6 +3684,7 @@ class ServingEngine:
             "prefill_chunks", "prefill_faults", "prefill_bucket_capped",
             "expired_prefilling",
             "page_faults", "pages_reclaimed", "preemptions",
+            "page_waits", "loop_passes",
             "spec_ticks", "draft_tokens", "accepted_tokens",
             "migrations_out", "migrations_in", "migration_failures",
             "prefill_handoffs", "import_replayed_tokens",
@@ -3712,6 +3731,9 @@ class ServingEngine:
             ("page_faults", "Page-pool exhaustions that reclaimed prefix pages"),
             ("pages_reclaimed", "Prefix-cache pages reclaimed under pressure"),
             ("preemptions", "Requests preempted for KV pages (last resort)"),
+            ("page_waits",
+             "Admission attempts deferred for KV pages while a slot was free"),
+            ("loop_passes", "Model passes run by decode ticks (ticks x n_loops)"),
             ("spec_ticks", "Speculative decode ticks"),
             ("draft_tokens", "Draft tokens proposed"),
             ("accepted_tokens", "Draft tokens accepted by verify"),

@@ -38,9 +38,14 @@ INDEX_LEAVES = ("cache_index", "decode_pos")
 # K/V byte-holding leaves of the PAGED cache: pools in the paged kernel's
 # own layout (``models.gpt.kv_pool_leaves``) — K/V [n_pages, page, KVH * D],
 # int8 scales [n_pages, page, KVH], and under the scanned stack ONE stacked
-# [n_layers, n_pages, page, ...] leaf per pool at the top of the cache tree
-# (it rides the layer loop's carry). The page axis is ``ndim - 3`` either
-# way. The int32 per-row page map is its own leaf.
+# [n_loops * n_layers, n_pages, page, ...] leaf per pool at the top of the
+# cache tree (it rides the layer loops' carry; a looped model keeps an entry
+# per (pass, layer), an unrolled one [n_loops, n_pages, ...] per layer). The
+# page axis is ``ndim - 3`` either way, so a page — the unit that is
+# allocated, shared, copied on write, banked by the prefix index and
+# shipped in a span — carries every entry of its positions. The int32
+# per-row page map is its own leaf and has no pass axis: a token sits in
+# one page at one position whatever the pass.
 POOL_LEAVES = ("cached_key", "cached_value", "key_scale", "value_scale")
 TABLE_LEAF = "block_table"
 _PAGE_AXIS_FROM_END = 3
@@ -79,7 +84,8 @@ def vectorize_index(cache: Any, n_slots: int) -> Any:
 # Every K/V leaf (and int8 scale leaf) is laid out [..., n_slots, cache_len,
 # ...]: the sequence axis sits immediately after the slot axis in every
 # layout this repo produces (per-layer [B, L, KVH, D], scanned
-# [n_layers, B, L, KVH, D], scales [..., KVH, 1]) — asserted at SlotKVCache
+# [n_layers, B, L, KVH, D], a looped model's pass axis in front of the slot
+# axis, scales [..., KVH, 1]) — asserted at SlotKVCache
 # construction so a future layout change fails loudly instead of silently
 # copying the wrong axis. ``axes_items`` (the per-leaf slot-axis map as a
 # sorted tuple) is a STATIC argument: one compiled program per cache

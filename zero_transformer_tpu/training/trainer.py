@@ -434,8 +434,8 @@ class Trainer:
         # data window and loss curves are comparable step-to-step
         self._val_window: Optional[dict] = None
         self.flops_per_token = monitoring.model_flops_per_token(
-            cfg.model.num_params,
-            cfg.model.n_layers,
+            cfg.model.params_per_token,
+            cfg.model.kv_entries,  # attention runs once per (pass, layer)
             cfg.model.d_model,
             cfg.training.train_context,
         )
