@@ -301,12 +301,20 @@ def extract_phase(out: Path, require_tpu: bool = True) -> dict:
             "seconds": round(time.perf_counter() - t0, 1)}
 
 
+# (slots, heads, cache_len) of the benchmark's serving cells: 580M and the
+# looped 2.6B, heads of 128 as SERVE_MODEL's
+CELL_SHAPES = ((16, 12, 2048), (16, 16, 512))
+
+
 def kernels_phase(model: str = SERVE_MODEL, slots: int = 4,
-                  cache_len: int = 1024, require_tpu: bool = True) -> dict:
+                  cache_len: int = 1024, ragged_shapes: tuple = CELL_SHAPES,
+                  require_tpu: bool = True) -> dict:
     """The paged decode kernel as the chip's compiler built it, against the
     gather path it replaces, at the shapes the servers below decode with —
     greedy tokens alone cannot tell a wrong mask from a right one on a
-    random-weight model that emits one token."""
+    random-weight model that emits one token — and, the kernel's walk being
+    bounded by each row's own length, at the benchmark cells' shapes with
+    most rows a few pages long."""
     device = device_or_exit(require_tpu)
     import jax.numpy as jnp
 
@@ -315,13 +323,17 @@ def kernels_phase(model: str = SERVE_MODEL, slots: int = 4,
 
     cfg = model_config(model)
     page = ServingConfig().page_size
+    shapes = [(slots, cfg.n_heads, cfg.kv_heads, cache_len, False)] + [
+        (B, H, H, S, True) for B, H, S in ragged_shapes
+    ]
     cases = [
         paged_vs_gather(
-            B=slots, T=T, H=cfg.n_heads, KVH=cfg.kv_heads, D=cfg.head_width,
-            page=page, n_blocks=cache_len // page, dtype=jnp.bfloat16,
-            int8=int8, alibi=cfg.position == "alibi", seed=SEED,
-            interpret=device["platform"] != "tpu",
+            B=B, T=T, H=H, KVH=KVH, D=cfg.head_width, page=page,
+            n_blocks=S // page, dtype=jnp.bfloat16, int8=int8,
+            alibi=cfg.position == "alibi", seed=SEED,
+            interpret=device["platform"] != "tpu", ragged=ragged,
         )
+        for B, H, KVH, S, ragged in shapes
         for T in (1, 1 + DRAFT_K) for int8 in (False, True)
     ]
     for case in cases:

@@ -159,6 +159,46 @@ def _pool_ops(hlo: str, n_pages: int = N_PAGES, page: int = PAGE, lanes: int = L
     ]
 
 
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize(
+    "H,n_blocks,n_pages", [(12, 128, N_PAGES), (16, 32, 161)], ids=["580m", "looped"]
+)
+def test_paged_kernel_takes_the_cells_pools_where_they_lie(one_chip, H, n_blocks, n_pages, int8):
+    """The kernel as the two serving cells call it — 16 slots, page 16,
+    heads of 128, a 2048 / 512 cache, the STACKED pool with a traced layer
+    index — with its pools handed over in ``pl.ANY``: one Mosaic call, and
+    no operation of the program makes a K/V-pool-sized value on the way
+    to it (its own DMAs read the parameter). The int8 scale pools are
+    gathered by row ([16, S, KVH]: not pool-sized) before the call."""
+    B, page, D, L = N_SLOTS, PAGE, 128, 2
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((L, n_pages, page, H * D), jnp.int8 if int8 else jnp.bfloat16)
+    scales = (sds((L, n_pages, page, H), jnp.float32),) * 2 if int8 else ()
+
+    def step(q, k_pool, v_pool, table, offsets, layer, *scales):
+        k_scale, v_scale = scales or (None, None)
+        return pa.paged_attention(
+            q, k_pool, v_pool, table, offsets, causal=False, layer=layer,
+            alibi=True, k_scale=k_scale, v_scale=v_scale,
+        )
+
+    text = jax.jit(step).lower(
+        sds((B, 1, H, D), jnp.bfloat16), pool, pool,
+        sds((B, n_blocks), jnp.int32), sds((B,), jnp.int32), sds((), jnp.int32),
+        *scales,
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    # (a pool small enough for the core's fast memory may be staged there
+    # by the compiler's own prefetch: the looped cell's int8 pool cut to 2
+    # layers is)
+    made = [o for o in _pool_ops(text, n_pages, page, H * D)
+            if o[2] and o[0] not in PREFETCH]
+    assert not made, made
+
+
 def _cfg_580m_cut(int8: bool, scan: bool):
     """The 580M serving model's structure (d 1536, 12 heads of 128, float32
     weights, bf16 compute; depth cut to keep the compile in seconds)."""

@@ -88,16 +88,20 @@ def test_kernels_phase_holds_the_paged_kernel_to_its_bar(monkeypatch):
     """The on-chip numerics check, rehearsed in interpret mode at the
     ``test`` model's head shapes: every case inside the bar, its off-by-one
     control far outside — and a bar the control would pass is refused."""
-    got = chip_smoke.kernels_phase("test", slots=2, cache_len=64, require_tpu=False)
-    assert got["ok"] and len(got["paged_vs_gather"]) == 4
-    assert {(c["shape"]["T"], c["int8_pages"]) for c in got["paged_vs_gather"]} == {
-        (1, False), (1, True), (5, False), (5, True)
+    # ragged at a shape with three buckets and two staged groups (page 16)
+    kw = dict(slots=2, cache_len=64, ragged_shapes=((4, 4, 384),), require_tpu=False)
+    got = chip_smoke.kernels_phase("test", **kw)
+    assert got["ok"] and len(got["paged_vs_gather"]) == 8
+    assert {(c["shape"]["T"], c["int8_pages"], c["ragged"])
+            for c in got["paged_vs_gather"]} == {
+        (T, int8, ragged)
+        for T in (1, 5) for int8 in (False, True) for ragged in (False, True)
     }
     for case in got["paged_vs_gather"]:
         assert case["ulps"] <= chip_smoke.PAGED_ULPS < case["control_ulps"]
     monkeypatch.setattr(chip_smoke, "PAGED_ULPS", 1e9)
     with pytest.raises(RuntimeError, match="paged kernel outside"):
-        chip_smoke.kernels_phase("test", slots=2, cache_len=64, require_tpu=False)
+        chip_smoke.kernels_phase("test", **kw)
 
 
 def test_zero_phase_on_the_virtual_mesh(rehearsal, devices):

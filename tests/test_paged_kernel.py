@@ -189,6 +189,161 @@ def test_stacked_pool_reads_its_layer(layer, int8):
     assert np.abs(wrong - ref).max() > 1e-2
 
 
+# ---- the walk is bounded by each row's own length ---------------------------
+
+R_PAGE, R_BLOCKS = 8, 48  # S = 384: buckets 128 / 256 / 384, two staged groups
+R_S = R_PAGE * R_BLOCKS
+
+
+def _live_lengths(T):
+    """Live lengths (``offset + T``) at every edge the walk has: the window
+    alone, one short of a page edge, on it, one over; each bucket's edge and
+    one over; a staged group's edge; the whole cache."""
+    group = pa.stage_pages(
+        page=R_PAGE, n_blocks=R_BLOCKS, KVH=2, D=64, pool_dtype=jnp.float32
+    )
+    widths = pa.bucket_widths(page=R_PAGE, n_blocks=R_BLOCKS)
+    assert widths == (128, 256, R_S) and 1 < group < R_BLOCKS
+    return {
+        "window": T, "page-1": 3 * R_PAGE - 1, "page": 3 * R_PAGE,
+        "page+1": 3 * R_PAGE + 1, "bucket": widths[0], "bucket+1": widths[0] + 1,
+        "bucket2": widths[1], "group+1": group * R_PAGE + 1, "full": R_S,
+    }
+
+
+def _poisoned_case(lengths, T, H, KVH, dtype, int8, stacked, alibi=True, seed=5):
+    """Rows live up to ``lengths`` positions each, on distinct pages. The
+    reference is the gather path on CLEAN pools. The kernel reads pools in
+    which everything it must not touch is NaN: every dead table entry
+    addresses an all-NaN page, and every live page's positions past the
+    row's live length are NaN (int8 pages carry the NaN in their scales)."""
+    D, B = 64, len(lengths)
+    poison = B * R_BLOCKS + 1  # the all-NaN page; page 0 stays the trash page
+    n_pages = poison + 1
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = jax.random.normal(ks[0], (B, T, H, D), dtype)
+    if int8:
+        k_pool, v_pool = (
+            jax.random.randint(k, (n_pages, R_PAGE, KVH, D), -127, 128, jnp.int32)
+            .astype(jnp.int8) for k in ks[1:3]
+        )
+        k_sc, v_sc = (
+            jax.random.uniform(k, (n_pages, R_PAGE, KVH, 1), jnp.float32, 1e-3, 2e-2)
+            for k in ks[3:5]
+        )
+    else:
+        k_pool, v_pool = (
+            jax.random.normal(k, (n_pages, R_PAGE, KVH, D), dtype) for k in ks[1:3]
+        )
+        k_sc = v_sc = None
+    table = 1 + np.asarray(jax.random.permutation(ks[5], B * R_BLOCKS)).reshape(B, R_BLOCKS)
+    offsets = jnp.asarray([n - T for n in lengths], jnp.int32)
+
+    def gather(pool, scale):
+        x = jnp.take(pool, jnp.asarray(table), axis=0)
+        if scale is not None:
+            x = (x.astype(jnp.float32) * jnp.take(scale, jnp.asarray(table), axis=0)).astype(dtype)
+        return x.reshape(B, R_S, KVH, D)
+
+    kv_valid = (jnp.arange(R_S)[None, :] < (offsets[:, None] + T)).astype(jnp.int32)
+    ref = xla_attention(
+        q, gather(k_pool, k_sc), gather(v_pool, v_sc), causal=T > 1, alibi=alibi,
+        q_offset=offsets, segment_ids=kv_valid,
+    )
+
+    dead = np.zeros((n_pages, R_PAGE), bool)  # (page, position) the kernel must not read
+    dead[poison] = True
+    walked = table.copy()
+    for b, n in enumerate(lengths):
+        live_pages = -(-n // R_PAGE)
+        walked[b, live_pages:] = poison
+        if n % R_PAGE:
+            dead[table[b, live_pages - 1], n % R_PAGE:] = True
+    dead = jnp.asarray(dead)[:, :, None, None]
+    if int8:
+        k_sc, v_sc = (jnp.where(dead, jnp.nan, x) for x in (k_sc, v_sc))
+    else:
+        k_pool, v_pool = (jnp.where(dead, jnp.nan, x).astype(dtype) for x in (k_pool, v_pool))
+    pools = [
+        None if x is None else x.reshape(n_pages, R_PAGE, -1)
+        for x in (k_pool, v_pool, k_sc, v_sc)
+    ]
+    layer = None
+    if stacked:  # layer 1 of three; the others hold NaN throughout
+        layer = jnp.int32(1)
+        pools = [
+            None if x is None else jnp.stack([
+                x if l == 1 else (jnp.full_like(x, jnp.nan) if x.dtype != jnp.int8 else jnp.flip(x, 0))
+                for l in range(3)
+            ])
+            for x in pools
+        ]
+    out = jax.jit(
+        lambda q, kp, vp, ksc, vsc, tbl, off, lyr: pa.paged_attention(
+            q, kp, vp, tbl, off, causal=T > 1, alibi=alibi, layer=lyr,
+            k_scale=ksc, v_scale=vsc, interpret=True,
+        )
+    )(q, *pools, jnp.asarray(walked, jnp.int32), offsets, layer)
+    return np.asarray(ref), np.asarray(out)
+
+
+@pytest.mark.parametrize("T", [1, 5])
+@pytest.mark.parametrize(
+    "edge",
+    ["window", "page-1", "page", "page+1", "bucket", "bucket+1", "bucket2", "group+1", "full"],
+)
+def test_row_walks_its_live_pages_only(edge, T):
+    """One row at each edge of the walk beside an idle row (offset 0) and a
+    full one: what lies past a row's live length — dead table entries, the
+    last live page's tail, the scratch the row before left — is all NaN or
+    another row's, and none of it reaches the output."""
+    n = _live_lengths(T)[edge]
+    ref, out = _poisoned_case([R_S, n, T], T, 4, 2, jnp.float32, False, False)
+    assert np.isfinite(out).all()
+    _assert_contract(ref, out)
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["flat", "stacked"])
+@pytest.mark.parametrize("T", [1, 5])
+@pytest.mark.parametrize("H,KVH", [(4, 4), (4, 2)], ids=["mha", "gqa"])
+@pytest.mark.parametrize(
+    "dtype,int8",
+    [(jnp.float32, False), (jnp.bfloat16, False), (jnp.float32, True), (jnp.bfloat16, True)],
+    ids=["f32", "bf16", "f32-int8", "bf16-int8"],
+)
+def test_mixed_batch_of_every_edge(dtype, int8, H, KVH, T, stacked):
+    """Every edge in ONE batch, short rows after long ones (each row's
+    scratch and staging follow another's), for both dtypes, int8 pages,
+    grouped heads, the spec-verify window and the stacked pool."""
+    edges = _live_lengths(T)
+    lengths = [edges[e] for e in
+               ("full", "window", "bucket+1", "page-1", "bucket2", "page", "group+1", "bucket", "page+1")]
+    ref, out = _poisoned_case(lengths, T, H, KVH, dtype, int8, stacked)
+    assert np.isfinite(out).all()
+    _assert_contract(ref, out)
+
+
+def test_poison_reaches_the_output_when_it_is_live():
+    """The control of the two tests above: one NaN position INSIDE a row's
+    live length does reach that row's output (so the poisoned pools would
+    show a read past it), and no other row's."""
+    ref, out = _poisoned_case([40, 40], 1, 4, 2, jnp.float32, False, False)
+    _assert_contract(ref, out)
+    ref, out = _poisoned_case([40, 41], 1, 4, 2, jnp.float32, False, False)
+    assert np.isfinite(out).all()
+    # row 0 told it is one longer than what its last page holds clean
+    D, B, n_pages = 64, 2, 2 * R_BLOCKS + 2
+    k_pool = jnp.ones((n_pages, R_PAGE, 2 * D)).at[7, 3].set(jnp.nan)
+    table = jnp.zeros((B, R_BLOCKS), jnp.int32).at[0, 0].set(7).at[1, 0].set(8)
+    q = jnp.ones((B, 1, 4, D))
+    run = lambda offs: np.asarray(pa.paged_attention(
+        q, k_pool, k_pool, table, jnp.asarray(offs, jnp.int32), causal=False,
+        alibi=True, interpret=True))
+    assert np.isfinite(run([2, 7])).all()
+    leaked = run([3, 7])
+    assert np.isnan(leaked[0]).all() and np.isfinite(leaked[1]).all()
+
+
 def test_gate_decisions():
     """The ONE gate both the model trace and the engine gauge consult."""
     common = dict(T=1, H=4, KVH=4, D=64, S=64, page_size=16, dtype=jnp.float32)

@@ -480,6 +480,46 @@ def test_page_span_from_before_the_lane_merged_pool_imports_bit_exactly(tag, kw)
 # ---------------------------------------------------------------- allocator
 
 
+def test_kernel_page_counters_add_up(cfg, params):
+    """What the paged decode kernel walks against what it is handed, from
+    the host's own mirrors: ``kernel_pages_table`` is slots x blocks a
+    decode tick (the ``decode_step`` span's ``table_pages``), and
+    ``kernel_pages_live`` the pages of the rows' live extents at each of
+    those ticks, an unmapped row counting as the one page the kernel
+    does for it. Two short requests in three slots of 12 blocks: most of
+    the table is dead at every tick."""
+    engine = make_engine(cfg, params, n_slots=3, sampling=GREEDY)
+    walked = []
+    flush = engine.slots.flush_tables
+
+    def recording_flush():  # called once a decode tick, inside its span
+        walked.append(sum(max(1, n) for n in engine.slots.alloc_blocks))
+        flush()
+
+    engine.slots.flush_tables = recording_flush
+    handles = [engine.submit(_prompt(n, i), max_new_tokens=6, seed=i)
+               for i, n in enumerate((5, 13))]
+    engine.run_until_idle()
+    assert all(h.status == "done" for h in handles)
+    attrs = [a for _, track, name, _, _, a in engine.tracer.spans()
+             if track == "engine" and name == "decode_step"]
+    table = 3 * (CACHE_LEN // 4)
+    assert attrs and all(a["table_pages"] == table for a in attrs)
+    snap = engine.metrics_snapshot()
+    assert snap["kernel_pages_table"] == table * len(attrs)
+    assert snap["kernel_pages_live"] == sum(walked) and len(walked) == len(attrs)
+    # every row is at least one page; 5 + 6 and 13 + 6 tokens are at most
+    # 3 + 5 pages of the 36
+    assert 3 * len(attrs) <= snap["kernel_pages_live"] <= (1 + 3 + 5) * len(attrs)
+    text = engine.prometheus_text()
+    assert "kernel_pages_live" in text and "kernel_pages_table" in text
+    # a slab engine has no table to walk
+    slab = make_engine(cfg, params, kv_layout="slab", sampling=GREEDY)
+    slab.submit(_prompt(5), max_new_tokens=3, seed=0)
+    slab.run_until_idle()
+    assert slab.metrics_snapshot()["kernel_pages_table"] == 0
+
+
 def test_page_pool_unit():
     from zero_transformer_tpu.serving.slots import PagePool
 

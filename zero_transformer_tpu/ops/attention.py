@@ -158,13 +158,17 @@ def paged_kernel_supported(impl: str, *, H: int, KVH: int, **shape) -> bool:
     """Does the paged decode kernel take this dispatch? ONE gate consulted
     by both the model's paged read path (``models/gpt.py``) and the engine's
     dispatch-site bookkeeping, so "supported" and "will actually run" can
-    never disagree: the kernel's own shape gate, and — the kernel runs per
-    device on a mesh — a tensor axis that divides the heads."""
+    never disagree: a tensor axis that divides the heads — the kernel runs
+    per device on a mesh — and the kernel's own shape gate on the heads one
+    device then holds."""
     from zero_transformer_tpu.ops.pallas import paged_attention as pa
-    from zero_transformer_tpu.parallel.sharding import kernel_shardable
+    from zero_transformer_tpu.parallel.sharding import (
+        kernel_local_size, kernel_shardable,
+    )
 
-    return pa.supported(impl, H=H, KVH=KVH, **shape) and kernel_shardable(
-        heads=H, kvheads=KVH
+    return kernel_shardable(heads=H, kvheads=KVH) and pa.supported(
+        impl, H=kernel_local_size("heads", H),
+        KVH=kernel_local_size("kvheads", KVH), **shape
     )
 
 

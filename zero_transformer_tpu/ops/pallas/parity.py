@@ -98,13 +98,17 @@ def interpret_parity_report() -> dict:
 def paged_vs_gather(
     *, B: int, T: int, H: int, KVH: int, D: int, page: int, n_blocks: int,
     dtype, int8: bool, alibi: bool = True, seed: int = 0,
-    interpret: bool = False,
+    interpret: bool = False, ragged: bool = False,
 ) -> dict:
     """The paged decode kernel against the gather-to-slab path it replaces
     (``jnp.take(pool, table)`` + ``xla_attention``'s per-row branch), on
     random q / pools / scales from ``seed``, a block table that is a random
     permutation of distinct pages, and per-row offsets that include a page
-    boundary, one before it, and a full cache. ``interpret=False`` on a TPU
+    boundary, one before it, and a full cache. ``ragged`` is the batch a
+    server at low load hands the kernel, whose walk is bounded by each
+    row's own length: one row full, one at offset 0, the others a few pages
+    long (one ending on a page's last position), and every table entry
+    past a row's live pages the trash page 0. ``interpret=False`` on a TPU
     is the Mosaic-compiled kernel — how ``chip_smoke.py`` holds it to the
     on-chip bar of the kernel module's exactness contract.
 
@@ -135,9 +139,16 @@ def paged_vs_gather(
         scales = ()
     table = (1 + jax.random.permutation(ks[5], n_pages - 1)).reshape(B, n_blocks)
     table = table.astype(jnp.int32)
-    offsets = jax.random.randint(ks[6], (B,), 0, S - T + 1, jnp.int32)
-    edges = jnp.asarray([S - T, (n_blocks // 2) * page, (n_blocks // 2) * page - 1])
+    if ragged:
+        offsets = jax.random.randint(ks[6], (B,), 1, min(S, 6 * page) - T, jnp.int32)
+        edges = jnp.asarray([S - T, 0, 2 * page - T])
+    else:
+        offsets = jax.random.randint(ks[6], (B,), 0, S - T + 1, jnp.int32)
+        edges = jnp.asarray([S - T, (n_blocks // 2) * page, (n_blocks // 2) * page - 1])
     offsets = offsets.at[: min(B, 3)].set(edges[: min(B, 3)].astype(jnp.int32))
+    if ragged:
+        live_pages = (offsets + T + page - 1) // page
+        table = jnp.where(jnp.arange(n_blocks)[None, :] < live_pages[:, None], table, 0)
 
     def gather(pool, scale, tbl):
         x = jnp.take(pool, tbl, axis=0)
@@ -178,7 +189,7 @@ def paged_vs_gather(
     return {
         "shape": {"B": B, "T": T, "H": H, "KVH": KVH, "D": D, "page": page,
                   "cache_len": S},
-        "dtype": jnp.dtype(dtype).name, "int8_pages": int8,
+        "dtype": jnp.dtype(dtype).name, "int8_pages": int8, "ragged": ragged,
         "finite": bool(jnp.all(jnp.isfinite(out))),
         "max_abs_diff": diff, "ulps": diff / ulp,
         "control_ulps": float(jnp.max(jnp.abs(control - ref))) / ulp,

@@ -433,6 +433,15 @@ def kernel_shardable(**dims: int) -> bool:
     )
 
 
+def kernel_local_size(name: str, size: int) -> int:
+    """What one device holds of a dim of ``size`` that ``shard_kernel``
+    splits by the activation name ``name`` (the whole of it where the
+    ambient mesh's axes do not divide it, or without a mesh)."""
+    amesh, auto = _ambient_auto_axes()
+    axes, divides = _kernel_axes(name, size, amesh, auto)
+    return size // math.prod(amesh.shape[a] for a in axes) if divides else size
+
+
 def shard_kernel(fn, in_names, out_names):
     """Wrap a Pallas-kernel call in ``shard_map`` over the ambient mesh.
 

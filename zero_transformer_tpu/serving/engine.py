@@ -1145,6 +1145,12 @@ class ServingEngine:
             "page_waits": 0,
             # model passes run by decode ticks: ticks x cfg.n_loops
             "loop_passes": 0,
+            # what the paged decode kernel walks, summed over decode ticks
+            # from the host's table mirror: the pages of the rows' live
+            # extents (a row that maps none is a one-page row to the
+            # kernel), and the whole table it is handed
+            "kernel_pages_live": 0,
+            "kernel_pages_table": 0,
             # speculation counters: acceptance_rate = accepted / drafted
             "spec_ticks": 0,
             "draft_tokens": 0,
@@ -2365,6 +2371,13 @@ class ServingEngine:
         # queued requests admit on the next tick)
         try:
             self.stats["loop_passes"] += self.cfg.n_loops
+            table_pages = 0
+            if self.kv_layout == "paged":
+                table_pages = self.n_slots * self.slots.n_blocks
+                self.stats["kernel_pages_table"] += table_pages
+                self.stats["kernel_pages_live"] += sum(
+                    max(1, n) for n in self.slots.alloc_blocks
+                )
             # decode_step is dispatch plus the host's wait for everything
             # the device still owes this tick: the decode program AND the
             # prefill program that prefill_chunk only dispatched. It is
@@ -2376,7 +2389,8 @@ class ServingEngine:
                          pages_in_use=(
                              self.slots.pool.in_use
                              if self.kv_layout == "paged" else 0
-                         )):
+                         ),
+                         table_pages=table_pages):
                 if self._chaos is not None:
                     self._chaos.on_tick(self._tick)
                 if self.kv_layout == "paged":
@@ -3685,6 +3699,7 @@ class ServingEngine:
             "expired_prefilling",
             "page_faults", "pages_reclaimed", "preemptions",
             "page_waits", "loop_passes",
+            "kernel_pages_live", "kernel_pages_table",
             "spec_ticks", "draft_tokens", "accepted_tokens",
             "migrations_out", "migrations_in", "migration_failures",
             "prefill_handoffs", "import_replayed_tokens",
@@ -3734,6 +3749,10 @@ class ServingEngine:
             ("page_waits",
              "Admission attempts deferred for KV pages while a slot was free"),
             ("loop_passes", "Model passes run by decode ticks (ticks x n_loops)"),
+            ("kernel_pages_live",
+             "KV pages of the rows' live extents, summed over decode ticks"),
+            ("kernel_pages_table",
+             "Block-table entries handed to decode ticks (slots x blocks)"),
             ("spec_ticks", "Speculative decode ticks"),
             ("draft_tokens", "Draft tokens proposed"),
             ("accepted_tokens", "Draft tokens accepted by verify"),
