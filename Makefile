@@ -135,14 +135,12 @@ bench:
 # Continuous-batching serving bench: 8 concurrent clients against a 2-slot
 # engine on the CPU test model (paged KV cache + chunked prefill by
 # default), every response verified byte-identical to single-request
-# generate(). Four scenarios:
+# generate(). Scenarios:
 #  - headline mixed-length run, SPECULATION ON (greedy so the byte-parity
 #    check stays exact) with an embedded spec-OFF control (no_speculation)
 #    -> BENCH_serve.json — the spec-on/spec-off pair;
 #  - shared-prefix run (N personas x one system prompt; with paging a hit
 #    is a page-refcount bump) -> BENCH_serve_prefix.json;
-#  - capacity sweep: slab vs paged concurrent streams at EQUAL KV budget
-#    -> BENCH_serve_capacity.json (the >=4x concurrency evidence);
 #  - fleet-router scaling: paced stub replicas behind the real router,
 #    aggregate relayed tok/s at 1/2/4 replicas + token-exact mid-stream
 #    failover + rolling reload with zero drops -> BENCH_router.json (the
@@ -155,21 +153,17 @@ bench:
 #    -> BENCH_disagg.json (isolation ratios graded on accelerators only —
 #    on a shared-core CPU box both replicas compete for the same cores).
 # A regression guard compares the fresh runs against the previously
-# committed artifacts (>15% on decode_tok_s / itl p99 / capacity ratio /
-# router scaling fails loudly on matching hardware, skips otherwise).
+# committed artifacts (>15% on decode_tok_s / itl p99 / router scaling
+# fails loudly on matching hardware, skips otherwise).
 # Schema pinned by tests/test_serve_bench.py.
 serve-bench:
 	@cp BENCH_serve.json /tmp/_serve_baseline.json 2>/dev/null || true
-	@cp BENCH_serve_capacity.json /tmp/_serve_cap_baseline.json 2>/dev/null || true
 	@cp BENCH_router.json /tmp/_serve_router_baseline.json 2>/dev/null || true
 	@cp BENCH_disagg.json /tmp/_serve_disagg_baseline.json 2>/dev/null || true
 	JAX_PLATFORMS=cpu $(PY) scripts/serve_loadgen.py --requests 8 --slots 2 \
-		--spec-k 4 --greedy --max-new-tokens 32 --cache-len 64 --obs-ab \
-		--fused-tail-ab
+		--spec-k 4 --greedy --max-new-tokens 32 --cache-len 64 --obs-ab
 	JAX_PLATFORMS=cpu $(PY) scripts/serve_loadgen.py --requests 8 --slots 2 \
 		--shared-prefix --cache-len 64 --out BENCH_serve_prefix.json
-	JAX_PLATFORMS=cpu $(PY) scripts/serve_loadgen.py --capacity-sweep \
-		--cache-len 128 --max-new-tokens 8
 	JAX_PLATFORMS=cpu $(PY) scripts/serve_loadgen.py --router
 	JAX_PLATFORMS=cpu $(PY) scripts/serve_loadgen.py --long-prompt-flood \
 		--sawtooth --cache-len 64 --max-new-tokens 12 --slots 2
@@ -177,11 +171,6 @@ serve-bench:
 		$(PY) scripts/serve_bench_guard.py /tmp/_serve_baseline.json BENCH_serve.json; \
 	else \
 		echo "serve-bench-guard: no committed baseline; skipping"; \
-	fi
-	@if [ -f /tmp/_serve_cap_baseline.json ]; then \
-		$(PY) scripts/serve_bench_guard.py /tmp/_serve_cap_baseline.json BENCH_serve_capacity.json; \
-	else \
-		echo "serve-bench-guard: no committed capacity baseline; skipping"; \
 	fi
 	@if [ -f /tmp/_serve_router_baseline.json ]; then \
 		$(PY) scripts/serve_bench_guard.py /tmp/_serve_router_baseline.json BENCH_router.json; \
@@ -214,7 +203,7 @@ train-bench:
 # Kernel lane (ISSUE 11): interpret-mode parity for the Pallas kernels on
 # THIS box (flash train fwd+bwd and serving offset/mask shapes pinned
 # few-ulp vs the XLA reference; the paged-attention decode kernel pinned
-# few-ulp vs the gather-to-slab path it replaces, int8 scales included)
+# few-ulp vs the gather path it replaces, int8 scales included)
 # plus the shared interpret-mode parity report. Timed kernel numbers are
 # TPU-only (bench.py's flash child refuses to run off the chip).
 # docs/KERNELS.md documents the dispatch-gate decision table.

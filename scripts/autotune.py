@@ -174,26 +174,19 @@ class ServeHarness:
         from zero_transformer_tpu.config import ServingConfig
         from zero_transformer_tpu.serving import ServingEngine
 
-        paged = knobs["kv_layout"] == "paged"
         # prefix cache at its ServingConfig hand default: trials measure
         # the configuration `serve.py --tuned` actually DEPLOYS (the cache
-        # interacts with layout and chunking; a no-cache winner would be
-        # optimal for an engine nobody runs)
-        prefix_chunks = (
-            ServingConfig().prefix_cache_chunks
-            if knobs["prefill_chunk"] else 0
-        )
+        # interacts with chunking; a no-cache winner would be optimal for
+        # an engine nobody runs)
         return ServingEngine(
             self.cfg, self.params, n_slots=self.wl_args.slots,
             cache_len=self.cache_len, sampling=self.sampling,
             max_queue=self.wl_args.max_queue,
             prefill_chunk=knobs["prefill_chunk"],
-            prefix_cache_chunks=prefix_chunks,
-            kv_layout=knobs["kv_layout"],
+            prefix_cache_chunks=ServingConfig().prefix_cache_chunks,
             page_size=knobs["page_size"],
-            page_pool_tokens=knobs["page_pool_tokens"] if paged else 0,
+            page_pool_tokens=knobs["page_pool_tokens"],
             draft_k=knobs["draft_k"],
-            fused_tail=knobs["fused_tail"],
             trace=trace,
         )
 
@@ -416,8 +409,6 @@ def build_space(target, smoke):
         s.register(at.Knob("remat_policy", ("none", "dots"),
                            "model.remat_policy", "train", "BENCH_step"))
     else:
-        s.register(at.Knob("kv_layout", ("paged",), "serving.kv_layout",
-                           "serve", "BENCH_serve"))
         s.register(at.Knob("prefill_chunk", (8,), "serving.prefill_chunk",
                            "serve", "BENCH_serve"))
         s.register(at.Knob("page_size", (4, 6), "serving.page_size",
@@ -427,23 +418,14 @@ def build_space(target, smoke):
                            "BENCH_serve"))
         s.register(at.Knob("draft_k", (0, 4), "serving.draft_k",
                            "serve", "BENCH_serve"))
-        s.register(at.Knob("fused_tail", (True,), "serving.fused_tail",
-                           "serve", "BENCH_serve"))
     return s
 
 
 def build_validators(args, target, space, wl_spec, cache_len=None):
     from zero_transformer_tpu.analysis import autotune as at
-    from zero_transformer_tpu.config import Config, apply_dotted_overrides
+    from zero_transformer_tpu.config import Config
 
     base_cfg = Config()
-    if target == "serve":
-        # tuning engines run the prefix cache off (it is not a searched
-        # knob); left at the shipped default it would mask the REAL refusal
-        # for prefill_chunk=0 points behind its own coupling rule
-        base_cfg = apply_dotted_overrides(
-            base_cfg, {"serving.prefix_cache_chunks": 0}
-        )
     validators = [at.config_validator(space, base_cfg)]
     if target == "train":
         validators.append(at.train_redundancy_validator())
@@ -454,7 +436,6 @@ def build_validators(args, target, space, wl_spec, cache_len=None):
             space, base_cfg, int(args.hbm_budget_gb * (1 << 30)), 8
         ))
     else:
-        validators.append(at.serve_redundancy_validator())
         # the harness' resolved cache_len (workload value or the model's
         # max_seq_len) — the pruner and the measured engines must agree on
         # the geometry or the feasibility rules prune/admit the wrong set

@@ -63,24 +63,20 @@ def parse_args(argv=None):
     p.add_argument("--cache-len", type=int, default=None)
     p.add_argument("--prefill-chunk", type=int, default=8,
                    help="chunked-prefill budget (tokens per tick) for the "
-                        "measured engine; 0 = legacy one-shot prefill")
+                        "measured engine")
     p.add_argument("--prefix-cache", type=int, default=64, metavar="CHUNKS",
-                   help="prefix-cache capacity in chunk entries (0 = off; "
-                        "forced off when --prefill-chunk is 0)")
+                   help="prefix-cache capacity in chunk entries (0 = off)")
     p.add_argument("--shared-prefix", action="store_true",
                    help="shared-prefix workload: every request = one common "
                         "system prompt (>= 2 chunks long) + a short persona "
                         "tail, so prefix-cache hits and the TTFT hit/miss "
                         "split are measured on realistic traffic")
-    p.add_argument("--kv-layout", choices=("slab", "paged"), default="paged",
-                   help="KV cache layout for the measured engine (paged = "
-                        "block-table page pool, the serving default)")
     p.add_argument("--page-size", type=int, default=4,
-                   help="tokens per KV page (paged); must divide "
+                   help="tokens per KV page; must divide "
                         "--prefill-chunk")
     p.add_argument("--page-pool-tokens", type=int, default=0,
-                   help="page-pool capacity in tokens (0 = slab-equivalent "
-                        "slots x cache_len)")
+                   help="page-pool capacity in tokens (0 = slots x "
+                        "cache_len)")
     p.add_argument("--spec-k", type=int, default=0,
                    help="speculative serving draft length (0 = off). The "
                         "run also drives a spec-OFF control engine first "
@@ -89,29 +85,6 @@ def parse_args(argv=None):
                    help="greedy sampling: with --spec-k the engine output "
                         "is bit-identical to plain decode, so the parity "
                         "verification stays byte-exact")
-    p.add_argument("--fused-tail-ab", action="store_true",
-                   help="also drive a DEFUSED-tail control engine "
-                        "(sampling as its own dispatch after the forward, "
-                        "fused_tail=False, speculation off) and embed it "
-                        "as no_fused_tail — the fused-vs-split sampling "
-                        "A/B the kernel lane prices")
-    p.add_argument("--no-fused-tail", action="store_true",
-                   help="run the MEASURED engine with the defused tail "
-                        "(A/B control; byte-identical output, disables "
-                        "--spec-k)")
-    p.add_argument("--capacity-sweep", action="store_true",
-                   help="capacity mode: ramp concurrent streams at mixed "
-                        "prompt lengths against a slab engine and a paged "
-                        "engine at EQUAL KV memory budget, and emit "
-                        "BENCH_serve_capacity.json (slab-vs-paged "
-                        "concurrent-stream A/B) instead of the standard "
-                        "artifact")
-    p.add_argument("--capacity-streams", type=int, default=24,
-                   help="streams offered during --capacity-sweep")
-    p.add_argument("--capacity-slots", type=int, default=16,
-                   help="decode slots for the PAGED engine in the sweep "
-                        "(its concurrency ceiling; the slab engine's slot "
-                        "count is fixed by the memory budget)")
     p.add_argument("--long-prompt-flood", action="store_true",
                    help="disaggregation A/B (-> BENCH_disagg.json): a "
                         "long-prompt flood against a MIXED 2-replica fleet "
@@ -320,32 +293,17 @@ def build(args):
     )["params"]
     sampling = SamplingConfig(temperature=0.9, top_k=20, greedy=args.greedy)
     cache_len = args.cache_len or cfg.max_seq_len
-    kv_layout = args.kv_layout if args.prefill_chunk else "slab"
 
-    def engine(chaos=None, prefix_cache=None, spec_k=None, slots=None,
-               layout=None, pool_tokens=None, trace=True, fused_tail=None):
+    def engine(chaos=None, prefix_cache=None, spec_k=None, trace=True):
         chunks = prefix_cache if prefix_cache is not None else args.prefix_cache
-        lay = layout or kv_layout
-        fused = (
-            fused_tail if fused_tail is not None
-            else not getattr(args, "no_fused_tail", False)
-        )
-        draft = args.spec_k if spec_k is None else spec_k
-        if not fused:
-            draft = 0  # the defused control covers the plain decode path
         return ServingEngine(
-            cfg, params, n_slots=slots or args.slots, cache_len=cache_len,
+            cfg, params, n_slots=args.slots, cache_len=cache_len,
             sampling=sampling, max_queue=args.max_queue, chaos=chaos,
             prefill_chunk=args.prefill_chunk,
-            prefix_cache_chunks=chunks if args.prefill_chunk else 0,
-            kv_layout=lay,
+            prefix_cache_chunks=chunks,
             page_size=args.page_size,
-            page_pool_tokens=(
-                (pool_tokens if pool_tokens is not None else args.page_pool_tokens)
-                if lay == "paged" else 0
-            ),
-            draft_k=draft,
-            fused_tail=fused,
+            page_pool_tokens=args.page_pool_tokens,
+            draft_k=args.spec_k if spec_k is None else spec_k,
             trace=trace,
         )
 
@@ -453,110 +411,6 @@ def run_load(engine, requests, args):
         stop.set()  # fallback: a wedged drain still stops the loop
         scheduler.join(timeout=30)
     return handles, time.monotonic() - started
-
-
-def run_capacity_sweep(args, cfg, cache_len, make_engine) -> dict:
-    """Slab-vs-paged concurrent-stream capacity at EQUAL KV memory budget.
-
-    The budget is what the slab reserves: ``slots x cache_len`` positions.
-    The paged engine gets a page pool of exactly that many positions (plus
-    its block tables — int32 noise) and ``--capacity-slots`` decode rows,
-    then both engines are offered the same ``--capacity-streams`` mixed-
-    length streams. The slab's concurrency is pinned at its slot count
-    whatever the sequence lengths; the paged engine admits as many streams
-    as their ACTUAL worst-case footprints fit (reservation-checked, so
-    nothing preempts mid-decode) — peak occupancy IS the measured capacity,
-    and admission beyond it waits in the queue (the reject/OOM boundary).
-    Emits BENCH_serve_capacity.json.
-    """
-    import jax
-
-    budget_tokens = args.slots * cache_len
-    rng = random.Random(4321)
-    max_prompt = max(2, min(8, cache_len - args.max_new_tokens))
-    streams = [
-        (
-            [rng.randint(1, cfg.vocab_size - 1) for _ in range(rng.randint(2, max_prompt))],
-            args.seed + i,
-        )
-        for i in range(args.capacity_streams)
-    ]
-
-    def drive(engine):
-        handles = [
-            engine.submit(p, max_new_tokens=args.max_new_tokens, seed=s)
-            for p, s in streams
-        ]
-        engine.run_until_idle()
-        snap = engine.metrics_snapshot()
-        ok = sum(1 for h in handles if h.status == "done")
-        return handles, snap, ok
-
-    # warmup both program families (compiles happen off the measured path)
-    for layout, slots, pool in (
-        ("slab", args.slots, None),
-        ("paged", args.capacity_slots, budget_tokens),
-    ):
-        w = make_engine(layout=layout, slots=slots, pool_tokens=pool, spec_k=0)
-        for p, s in streams[: slots + 1]:
-            w.submit(p, max_new_tokens=args.max_new_tokens, seed=s)
-        w.run_until_idle()
-
-    slab = make_engine(layout="slab", slots=args.slots, spec_k=0)
-    _, slab_snap, slab_ok = drive(slab)
-    paged = make_engine(
-        layout="paged", slots=args.capacity_slots, pool_tokens=budget_tokens,
-        spec_k=0,
-    )
-    _, paged_snap, paged_ok = drive(paged)
-
-    ratio = (
-        paged_snap["peak_occupancy"] / slab_snap["peak_occupancy"]
-        if slab_snap["peak_occupancy"]
-        else 0.0
-    )
-    artifact = {
-        "metric": "serve_capacity_streams_ratio",
-        "value": round(ratio, 3),
-        "unit": "paged_streams / slab_streams @ equal KV budget",
-        "model": args.model,
-        "kv_budget_tokens": budget_tokens,
-        "page_size": args.page_size,
-        "prefill_chunk": args.prefill_chunk,
-        "max_new_tokens": args.max_new_tokens,
-        "streams_offered": args.capacity_streams,
-        "slab": {
-            "slots": args.slots,
-            "capacity_streams": slab_snap["peak_occupancy"],
-            "completed": slab_ok,
-        },
-        "paged": {
-            "slots": args.capacity_slots,
-            "capacity_streams": paged_snap["peak_occupancy"],
-            "completed": paged_ok,
-            "page_pool_util": round(
-                paged_snap["page_pool_peak"]
-                / max(1, paged.slots.pool.n_pages - 1),
-                4,
-            ),
-            "page_faults": paged_snap["page_faults"],
-            "preemptions": paged_snap["preemptions"],
-        },
-        "platform": {
-            "backend": jax.default_backend(),
-            "device": getattr(jax.devices()[0], "device_kind", "unknown"),
-        },
-        "measured_at_utc": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
-    }
-    Path(args.out).write_text(json.dumps(artifact, indent=2) + "\n")
-    print(json.dumps(artifact))
-    if slab_ok != len(streams) or paged_ok != len(streams):
-        raise SystemExit(
-            f"CAPACITY SWEEP FAILED: slab completed {slab_ok}, paged "
-            f"completed {paged_ok} of {len(streams)} (a capacity claim over "
-            "dropped streams is not a capacity claim)"
-        )
-    return artifact
 
 
 # ------------------------------------------------------- fleet router bench
@@ -1018,7 +872,7 @@ def _run_flood_arm(cfg, params, sampling, cache_len, args, roles, label):
         engine = ServingEngine(
             cfg, params, n_slots=args.slots, cache_len=cache_len,
             sampling=sampling, prefill_chunk=args.prefill_chunk,
-            prefix_cache_chunks=0, kv_layout="paged",
+            prefix_cache_chunks=0,
             page_size=args.page_size, role=role,
         )
         server = ServingServer(engine, _IdTokenizer(), port=0)
@@ -1393,7 +1247,7 @@ def _run_tenant_arm(cfg, params, sampling, cache_len, args, flood, label):
         engine = ServingEngine(
             cfg, params, n_slots=args.slots, cache_len=cache_len,
             sampling=sampling, prefill_chunk=args.prefill_chunk,
-            prefix_cache_chunks=0, kv_layout="paged",
+            prefix_cache_chunks=0,
             page_size=args.page_size, qos=qos,
         )
         server = ServingServer(engine, _IdTokenizer(), port=0)
@@ -1606,11 +1460,11 @@ def main(argv=None) -> dict:
     compile_cache.configure()
     if args.workload and (
         args.router or args.long_prompt_flood or args.sawtooth
-        or args.capacity_sweep or args.tenant_flood
+        or args.tenant_flood
     ):
         raise SystemExit(
             "--workload pins the standard engine-driving workload; the "
-            "router/disagg/capacity scenarios generate their own traffic"
+            "router/disagg scenarios generate their own traffic"
         )
     wl_name, wl_spec, wl_hash = resolve_workload(args)
     if args.router:
@@ -1626,25 +1480,7 @@ def main(argv=None) -> dict:
             args.out = str(REPO / "BENCH_tenant.json")
         return run_tenant_flood_bench(args)
     cfg, params, sampling, cache_len, make_engine = build(args)
-    if args.capacity_sweep:
-        if args.out == str(REPO / "BENCH_serve.json"):  # untouched default
-            args.out = str(REPO / "BENCH_serve_capacity.json")
-        return run_capacity_sweep(args, cfg, cache_len, make_engine)
     requests = make_requests(args, cfg.vocab_size, cache_len)
-
-    if args.spec_k and args.no_fused_tail:
-        # mirror serve.py's loud handling of the same flag combination: the
-        # defused control covers the plain decode path only. Zeroing
-        # args.spec_k HERE (not just in the engine closure) also stops the
-        # spec warmup arms and the no_speculation control, which would
-        # otherwise compare the measured engine against itself
-        print(
-            "serve_loadgen: --no-fused-tail (the fused-tail A/B control) "
-            "covers the plain decode path only; speculation DISABLED for "
-            "this run",
-            file=sys.stderr,
-        )
-        args.spec_k = 0
 
     if args.spec_k and not args.greedy and not args.no_verify:
         # stochastic speculation preserves the DISTRIBUTION (rejection
@@ -1664,19 +1500,14 @@ def main(argv=None) -> dict:
             cfg, params, sampling, cache_len, requests, args.max_new_tokens
         )
 
-    # warmup engine: pay prefill-bucket + fused-step compiles outside the
+    # warmup engine: pay chunk-prefill + fused-step compiles outside the
     # measured run (jit caches are shared across engines — the model and
     # sampling statics compare structurally equal). With --spec-k both
     # program families get warmed: the spec-OFF control below must not pay
     # the plain step's compile inside ITS measured window
     warm_specs = (args.spec_k, 0) if args.spec_k else (args.spec_k,)
-    warm_arms = [(k, True) for k in warm_specs]
-    if args.fused_tail_ab or args.no_fused_tail:
-        # the defused control's two programs (standalone sample + forward-
-        # only) must be warm before ITS measured window too
-        warm_arms.append((0, False))
-    for k, fused in warm_arms:
-        warm = make_engine(spec_k=k, fused_tail=fused)
+    for k in warm_specs:
+        warm = make_engine(spec_k=k)
         for prompt, seed in requests[: min(len(requests), args.slots + 1)]:
             warm.submit(prompt, max_new_tokens=args.max_new_tokens, seed=seed)
         warm.run_until_idle()
@@ -1715,27 +1546,6 @@ def main(argv=None) -> dict:
                 3,
             ),
             "itl_ms_p50": round(csnap["itl_ms_p50"], 3),
-        }
-
-    # DEFUSED-tail control for the fused-sampling A/B (same ordering
-    # discipline: runs before the measured engine so both are equally warm
-    # and the delta isolates the extra dispatch + [S]-token round trip of
-    # the split tail). Speculation off in the control — its comparison
-    # partner is no_speculation (the fused plain-decode arm), not the
-    # spec-on headline.
-    no_fused = None
-    if args.fused_tail_ab:
-        control = make_engine(spec_k=0, fused_tail=False)
-        control_handles, control_wall = run_load(control, requests, args)
-        csnap = control.metrics_snapshot()
-        no_fused = {
-            "decode_tok_s": round(
-                sum(len(h.tokens) for h in control_handles if h is not None)
-                / control_wall,
-                3,
-            ),
-            "itl_ms_p50": round(csnap["itl_ms_p50"], 3),
-            "itl_ms_decode_only_p99": round(csnap["itl_decode_ms_p99"], 3),
         }
 
     # tracing-overhead A/B: alternate OFF/ON arms on the same workload and
@@ -1830,31 +1640,23 @@ def main(argv=None) -> dict:
         "prefill_ms_hit_p50": prefill_p50(handles, lambda h: h.prefix_hit_tokens > 0),
         "prefill_ms_miss_p50": prefill_p50(handles, lambda h: h.prefix_hit_tokens == 0),
         "no_prefix_cache": no_cache,
-        # paged-KV + speculation evidence (ISSUE 6): layout, pool pressure,
-        # and the draft-and-verify acceptance economics, plus the spec-OFF
-        # control for the same workload
-        "kv_layout": engine.kv_layout,
-        "page_size": engine.page_size if engine.kv_layout == "paged" else 0,
+        # paged-KV + speculation evidence (ISSUE 6): pool pressure and the
+        # draft-and-verify acceptance economics, plus the spec-OFF control
+        # for the same workload
+        "page_size": engine.page_size,
         "page_faults": snap["page_faults"],
         "pages_reclaimed": snap["pages_reclaimed"],
         "preemptions": snap["preemptions"],
         "page_pool_util": round(
             snap["page_pool_peak"]
             / max(1, engine.slots.pool.n_pages - 1), 4
-        )
-        if engine.kv_layout == "paged"
-        else 0.0,
+        ),
         "cow_copies": snap["cow_copies"],
         "draft_k": engine.draft_k,
         "acceptance_rate": round(snap["acceptance_rate"], 4),
         "spec_ticks": snap["spec_ticks"],
         "no_speculation": no_spec,
-        # fused-sampling-tail evidence (PR 11): is the measured engine's
-        # sampling inside the single decode program, and the defused
-        # control (None unless --fused-tail-ab measured it)
-        "fused_tail": bool(engine.fused_tail),
         "kernel_paged_attention": bool(snap["kernel_paged_attention"]),
-        "no_fused_tail": no_fused,
         # observability evidence (ISSUE 7): the tracing-cost A/B (None
         # unless --obs-ab measured it) and the Perfetto span artifact every
         # run saves next to the JSON

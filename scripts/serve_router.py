@@ -438,8 +438,7 @@ def run_replica_worker(args) -> None:
         cfg, params, n_slots=args.slots,
         cache_len=args.cache_len or cfg.max_seq_len, sampling=sampling,
         prefill_chunk=args.prefill_chunk,
-        prefix_cache_chunks=args.prefix_cache if args.prefill_chunk else 0,
-        kv_layout="paged" if args.prefill_chunk else "slab",
+        prefix_cache_chunks=args.prefix_cache,
         page_size=args.page_size,
         role=args.role,
     )

@@ -31,12 +31,6 @@ def _bench_common():
     return _file_module("bench_common", REPO / "scripts" / "bench_common.py")
 
 
-def _serve_base_cfg():
-    # the tuner's serve base: prefix cache off (not a searched knob) so the
-    # oracle's refusals name the searched knobs, not the cache coupling
-    return apply_dotted_overrides(Config(), {"serving.prefix_cache_chunks": 0})
-
-
 # ------------------------------------------------------------ space basics
 
 
@@ -76,7 +70,7 @@ def test_validity_sweep_every_invalid_point_names_a_knob(target):
     config validation is what keeps invalid points out of measured trials,
     so an anonymous refusal would make the prune trace unauditable."""
     space = at.train_space() if target == "train" else at.serve_space()
-    base = Config() if target == "train" else _serve_base_cfg()
+    base = Config()
     knob_tokens = [k.field.rsplit(".", 1)[1] for k in space.knobs] + [
         k.name for k in space.knobs
     ]
@@ -95,27 +89,30 @@ def test_validity_sweep_every_invalid_point_names_a_knob(target):
 
 @pytest.mark.parametrize("target", ["train", "serve"])
 def test_pruning_majority_reasons_and_valid_survivors(target):
-    """Analytic pre-pruning must eliminate >= 50% of the enumerated space
-    with every pruned point's (rule, reason) recorded, and every survivor
-    must construct a valid Config — no measured trial ever runs an invalid
+    """Analytic pre-pruning must eliminate the points it can name a reason
+    for — >= 50% of the training space, whose inert and unbuildable
+    combinations are most of it; in the serving space, which no longer has
+    an inert dimension, the page sizes that do not divide the chunk — with
+    every pruned point's (rule, reason) recorded, and every survivor must
+    construct a valid Config: no measured trial ever runs an invalid
     point."""
+    base = Config()
     if target == "train":
-        space, base = at.train_space(), Config()
+        space, least = at.train_space(), 0.5
         validators = [
             at.config_validator(space, base),
             at.train_redundancy_validator(),
         ]
     else:
-        space, base = at.serve_space(), _serve_base_cfg()
+        space, least = at.serve_space(), 1 / 6  # page 16 against chunk 8
         validators = [
             at.config_validator(space, base),
-            at.serve_redundancy_validator(),
             at.serve_feasibility_validator(64),
         ]
     points = space.points()
     survivors, pruned = at.prune_points(points, validators)
     assert len(survivors) + len(pruned) == len(points)
-    assert len(pruned) / len(points) >= 0.5, (
+    assert len(pruned) / len(points) >= least, (
         f"only {len(pruned)}/{len(points)} pruned analytically"
     )
     for p in pruned:
@@ -128,13 +125,9 @@ def test_pruning_majority_reasons_and_valid_survivors(target):
 def test_serve_feasibility_rules():
     check = dict([at.serve_feasibility_validator(64)])
     fn = at.serve_feasibility_validator(64)[1]
-    assert fn({"kv_layout": "slab", "page_size": 7}) is None
-    assert "divide" in fn({"kv_layout": "paged", "page_size": 7,
-                           "page_pool_tokens": 0})
-    assert "worst-case" in fn({"kv_layout": "paged", "page_size": 4,
-                               "page_pool_tokens": 32})
-    assert fn({"kv_layout": "paged", "page_size": 4,
-               "page_pool_tokens": 0}) is None
+    assert "divide" in fn({"page_size": 7, "page_pool_tokens": 0})
+    assert "worst-case" in fn({"page_size": 4, "page_pool_tokens": 32})
+    assert fn({"page_size": 4, "page_pool_tokens": 0}) is None
     assert check  # the validator is (rule, fn) shaped
 
 
@@ -302,13 +295,22 @@ def test_tune_artifact_workload_hash_rederivable(tune_artifact):
 def test_tune_artifact_winner_overrides_apply_cleanly(tune_artifact):
     """The winner must load back through the SAME validated path --tuned
     uses (a committed artifact that train.py would refuse at apply time
-    is worse than none)."""
-    base = (
-        Config() if tune_artifact["target"] == "train" else _serve_base_cfg()
-    )
+    is worse than none). ``serve --tuned`` takes the winner's knobs by name
+    (``_TUNED_KNOBS``), so the committed serve artifact, measured while the
+    space still held the slab layout and the defused tail, is held to the
+    knobs the server still has."""
     overrides = at.winner_overrides(tune_artifact)
     assert overrides  # non-empty
-    apply_dotted_overrides(base, overrides)  # must not raise
+    if tune_artifact["target"] == "serve":
+        from zero_transformer_tpu.serve import _TUNED_KNOBS
+
+        overrides = {
+            f"serving.{k}": v
+            for k, v in tune_artifact["winner"]["knobs"].items()
+            if k in _TUNED_KNOBS
+        }
+        assert overrides
+    apply_dotted_overrides(Config(), overrides)  # must not raise
 
 
 def test_winner_overrides_fall_back_to_space_mapping():
@@ -421,8 +423,7 @@ def test_serve_resolve_tuned_args(tmp_path):
 
     def args(tuned=None, **explicit):
         ns = SimpleNamespace(
-            model="test", tuned=tuned, no_fused_tail=None,
-            repetition_penalty=1.0,
+            model="test", tuned=tuned, repetition_penalty=1.0,
             **{k: None for k in _TUNED_KNOBS},
         )
         for k, v in explicit.items():
@@ -433,11 +434,12 @@ def test_serve_resolve_tuned_args(tmp_path):
     a = _resolve_tuned_args(args())
     assert a.page_size == defaults.page_size
     assert a.draft_k == defaults.draft_k
-    assert a.no_fused_tail is (not defaults.fused_tail)
     # matching artifact: winner knobs become the defaults...
     art = _tuned_artifact(target="serve")
+    # (knobs of an artifact from before the slab layout and the defused
+    # tail were removed name nothing any more and are passed over)
     art["winner"] = {"knobs": {"draft_k": 4, "page_size": 8,
-                               "fused_tail": True}}
+                               "kv_layout": "slab", "fused_tail": True}}
     path = tmp_path / "TUNE_serve.json"
     path.write_text(json.dumps(art))
     a = _resolve_tuned_args(args(tuned=str(path)))

@@ -85,7 +85,6 @@ def make_engine(cfg, params, **kw):
     kw.setdefault("cache_len", CACHE_LEN)
     kw.setdefault("sampling", SAMPLING)
     kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("page_size", 4)
     return ServingEngine(cfg, params, **kw)
 

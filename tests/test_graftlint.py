@@ -637,6 +637,7 @@ def test_engine_dispatch_sites_stay_within_bounds(strict_sites):
         n_slots=2,
         cache_len=32,
         prefill_chunk=8,
+        page_size=8,
         sampling=SamplingConfig(temperature=0.9, top_k=20),
     )
     first = [

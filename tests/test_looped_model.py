@@ -5,7 +5,7 @@ d 64, 3 layers, 3 passes, 4 heads of 16, vocab 256, float32 compute.
 
 Every path that serves or trains the model is held to the SAME reference:
 the full forward, chunked prefill then paged decode through
-``ServingEngine``, the slab cache ``generate()`` uses, an exit threshold
+``ServingEngine``, the contiguous cache ``generate()`` uses, an exit threshold
 under 1, and ``Trainer``'s first loss.
 
 The tolerance on logits, ``TOL``: program and reference both compute in
@@ -127,12 +127,12 @@ def test_the_tree_is_the_references_leaf_table_and_counts_agree():
     assert REF.active_params(MODEL) == 9 * matrices + 64 * 256
 
 
-# ------------------------------------------------------- caches: slab, paged
+# ------------------------------- caches: the model's own (slab), the page pool
 
 
 def _slab_logits(cfg, params, prompt_len):
-    """Prefill then token-by-token decode through the slab cache: the two
-    programs ``generate()`` is made of. Returns logits at positions
+    """Prefill then token-by-token decode through the model's own
+    contiguous [batch, cache_len] cache: the two programs ``generate()`` is made of. Returns logits at positions
     prompt_len-1 .. T-1."""
     toks = jnp.asarray(TOKENS, jnp.int32)
     model = decode_model(cfg, 64)
@@ -164,10 +164,8 @@ def test_slab_prefill_and_decode_match_the_reference(scan):
 
 
 def _engine(cfg, params, **kw):
-    kw = {"kv_layout": "paged", "prefix_cache_chunks": 8, "prefill_chunk": 16,
+    kw = {"prefix_cache_chunks": 8, "prefill_chunk": 16,
           "page_pool_tokens": 192, **kw}
-    if kw["kv_layout"] == "slab":
-        kw.pop("page_pool_tokens")
     return ServingEngine(
         cfg, params, n_slots=4, cache_len=64, page_size=8,
         sampling=SamplingConfig(greedy=True, repetition_penalty=1.0),
@@ -229,18 +227,25 @@ def test_engine_chunked_prefill_then_paged_decode_match_the_reference(scan):
 
 def test_paged_engine_and_slab_generate_serve_the_same_tokens_bit_for_bit():
     """Greedy tokens through the paged engine (two requests interleaved, one
-    of them retired and its slot reused) equal ``generate()``'s through the
-    slab cache: paging and the pass axis change where bytes live, nothing
-    else. The released slot's pages return to the pool."""
+    of them retired and its slot reused, then a request that HITS the
+    prefix index on the first one's two whole chunks) equal ``generate()``'s
+    through the model's contiguous cache: paging and the pass axis change
+    where bytes live, nothing else. The released slots' pages return to the
+    pool, but for the three whole chunks the index holds (two of the first
+    prompt, one of the third)."""
     params = seeded_params()
     cfg = cfg_of()
     engine = _engine(cfg, params)
-    prompts = [TOKENS[0, :21].tolist(), TOKENS[1, :9].tolist(), TOKENS[1, 5:30].tolist()]
+    prompts = [TOKENS[0].tolist(), TOKENS[1, :9].tolist(), TOKENS[1, 5:30].tolist(),
+               TOKENS[0, :33].tolist()]
     handles = [engine.submit(p, max_new_tokens=10, seed=i) for i, p in enumerate(prompts[:2])]
     for _ in range(6):
         engine.step()
     handles.append(engine.submit(prompts[2], max_new_tokens=10, seed=2))
     engine.run_until_idle()
+    handles.append(engine.submit(prompts[3], max_new_tokens=10, seed=3))
+    engine.run_until_idle()
+    assert handles[3].prefix_hit_tokens == 32
     slab = decode_model(cfg, 64)
     for p, h in zip(prompts, handles):
         want = generate(slab, params, jnp.asarray([p], jnp.int32), 10,
@@ -248,26 +253,7 @@ def test_paged_engine_and_slab_generate_serve_the_same_tokens_bit_for_bit():
                         SamplingConfig(greedy=True, repetition_penalty=1.0))
         assert h.status == "done" and h.tokens == np.asarray(want)[0].tolist()
     assert engine.slots.free_count == 4 and sum(engine.slots.alloc_blocks) == 0
-
-
-@pytest.mark.parametrize("path", ["chunked", "oneshot"])
-def test_slab_engine_serves_generates_tokens(path):
-    """The engine's slab layout (the pass axis sits in front of the slot
-    axis, so the span ops' [slot, position] adjacency holds): chunked
-    prefill with a prefix-cache hit on the second request, and the one-shot
-    bucketed insert, both against ``generate()``."""
-    params = seeded_params()
-    cfg = cfg_of()
-    kw = dict(prefill_chunk=0, prefix_cache_chunks=0) if path == "oneshot" else {}
-    engine = _engine(cfg, params, kv_layout="slab", **kw)
-    prompts = [TOKENS[0].tolist(), TOKENS[0, :33].tolist()]
-    handles = [engine.submit(p, max_new_tokens=8, seed=i) for i, p in enumerate(prompts)]
-    engine.run_until_idle()
-    slab = decode_model(cfg, 64)
-    for p, h in zip(prompts, handles):
-        want = generate(slab, params, jnp.asarray([p], jnp.int32), 8, jax.random.PRNGKey(0),
-                        SamplingConfig(greedy=True, repetition_penalty=1.0))
-        assert h.status == "done" and h.tokens == np.asarray(want)[0].tolist()
+    assert engine.slots.pool.in_use == 3 * 16 // 8  # the three whole chunks the index holds
 
 
 def test_admission_waits_for_pages_while_slots_are_free():

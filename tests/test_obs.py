@@ -57,6 +57,7 @@ def params(cfg):
 def make_engine(cfg, params, **kw):
     kw.setdefault("n_slots", 2)
     kw.setdefault("cache_len", CACHE_LEN)
+    kw.setdefault("page_size", 8)  # its tests pass chunks of 8
     kw.setdefault("sampling", SAMPLING)
     return ServingEngine(cfg, params, **kw)
 

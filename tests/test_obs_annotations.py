@@ -38,6 +38,7 @@ def make_engine(cfg, params, **kw):
     kw.setdefault("n_slots", 2)
     kw.setdefault("cache_len", 32)
     kw.setdefault("prefill_chunk", 8)
+    kw.setdefault("page_size", 8)
     kw.setdefault("sampling", SamplingConfig(temperature=0.9, top_k=20))
     return ServingEngine(cfg, params, **kw)
 
@@ -137,8 +138,7 @@ def test_capture_holds_the_tick_tree_on_both_clocks(cfg, params, tmp_path):
     ``tick`` values, and every tick's children cover >= 95% of it (what is
     left is span overhead and the release of the step's arrays at return:
     some 40 us, so the ticks here are made a few milliseconds long)."""
-    engine = make_engine(cfg, params, n_slots=8, cache_len=1024,
-                         kv_layout="paged", page_size=8)
+    engine = make_engine(cfg, params, n_slots=8, cache_len=1024)
     drive(engine, n=1)  # compile outside the capture
     engine.tracer = obs.Tracer(capacity=65536, clock=engine.now)
     t_open = time.monotonic()

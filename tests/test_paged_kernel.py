@@ -392,7 +392,7 @@ def test_engine_kernel_parity_and_one_signature(monkeypatch):
         )
         engine = ServingEngine(
             cfg, params, n_slots=2, cache_len=CACHE_LEN, sampling=sampling,
-            prefill_chunk=8, kv_layout="paged", page_size=8, draft_k=draft_k,
+            prefill_chunk=8, page_size=8, draft_k=draft_k,
         )
         handles = [
             engine.submit(p, max_new_tokens=8, seed=i)
@@ -453,8 +453,7 @@ def test_engine_kernel_under_a_tensor_mesh(devices, monkeypatch, int8):
         )
         engine = ServingEngine(
             cfg, p, n_slots=2, cache_len=CACHE_LEN, prefill_chunk=8,
-            sampling=SamplingConfig(greedy=True), kv_layout="paged",
-            page_size=8, mesh=mesh,
+            sampling=SamplingConfig(greedy=True), page_size=8, mesh=mesh,
         )
         handles = [
             engine.submit(p, max_new_tokens=8, seed=i)
@@ -471,54 +470,6 @@ def test_engine_kernel_under_a_tensor_mesh(devices, monkeypatch, int8):
     for name in ("cached_key", "key_scale") if int8 else ("cached_key",):
         pool = engine.slots.cache[name]  # [L, n_pages, page, lanes]
         assert tuple(pool.sharding.spec) == (None, None, None, "tensor")
-
-
-def test_engine_fused_tail_control_parity():
-    """fused_tail=False (the A/B control: sampling as its own dispatch)
-    emits byte-identical trajectories to the fused path, and its sample
-    site stays at one signature."""
-    from zero_transformer_tpu.config import model_config
-    from zero_transformer_tpu.inference.sampling import SamplingConfig
-    from zero_transformer_tpu.models import Transformer
-    from zero_transformer_tpu.serving import ServingEngine
-
-    cfg = model_config("test", dropout=0.0, compute_dtype="float32")
-    params = Transformer(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
-    )["params"]
-    prompts = [[(5 + i + j) % 250 + 1 for j in range(n)]
-               for i, n in enumerate((3, 9, 14))]
-
-    def run(fused):
-        engine = ServingEngine(
-            cfg, params, n_slots=2, cache_len=CACHE_LEN,
-            sampling=SamplingConfig(temperature=0.9, top_k=20),
-            prefill_chunk=8, kv_layout="paged", page_size=8,
-            fused_tail=fused,
-        )
-        handles = [
-            engine.submit(p, max_new_tokens=8, seed=i)
-            for i, p in enumerate(prompts)
-        ]
-        engine.run_until_idle()
-        assert all(h.status == "done" for h in handles)
-        return [h.tokens for h in handles], engine
-
-    fused, ef = run(True)
-    control, ec = run(False)
-    assert fused == control
-    assert ef.metrics_snapshot()["fused_tail"] == 1
-    snap = ec.metrics_snapshot()
-    assert snap["fused_tail"] == 0
-    assert snap["dispatch_sample_tail_signatures"] == 1
-    assert snap["dispatch_sample_tail_violations"] == 0
-    # the control rejects speculation: the verify step cannot be defused
-    with pytest.raises(ValueError):
-        ServingEngine(
-            cfg, params, n_slots=2, cache_len=CACHE_LEN,
-            prefill_chunk=8, kv_layout="paged", page_size=8,
-            fused_tail=False, draft_k=2,
-        )
 
 
 def test_flash_is_flash_or_raise_on_the_paged_decode_path(monkeypatch):
@@ -543,7 +494,7 @@ def test_flash_is_flash_or_raise_on_the_paged_decode_path(monkeypatch):
         engine = ServingEngine(
             cfg, params, n_slots=2, cache_len=CACHE_LEN,
             sampling=SamplingConfig(greedy=True), prefill_chunk=8,
-            kv_layout="paged", page_size=8,
+            page_size=8,
         )
         handle = engine.submit([1, 2, 3, 4, 5], max_new_tokens=4, seed=0)
         engine.run_until_idle()

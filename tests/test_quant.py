@@ -382,7 +382,7 @@ def test_quant_engine_parity_with_generate():
     ]
     engine = ServingEngine(
         qcfg, qparams, n_slots=2, cache_len=48, sampling=greedy,
-        prefill_chunk=8, kv_layout="paged", page_size=8,
+        prefill_chunk=8, page_size=8,
     )
     handles = [engine.submit(p, max_new_tokens=8, seed=i)
                for i, p in enumerate(prompts)]

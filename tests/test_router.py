@@ -920,7 +920,8 @@ def test_replica_healthz_carries_router_admission_inputs(cfg, params):
         assert health["itl_ewma_ms"] == 0.0  # no samples yet
         assert health["queue_depth"] == 0
         assert health["active_slots"] == 0
-        assert health["free_pages"] == 2  # slab layout: free slots
+        # free pool pages: 2 slots x 48 positions at 16 a page
+        assert health["free_pages"] == 2 * CACHE_LEN // 16
     finally:
         server.stop()
 

@@ -33,25 +33,18 @@ REQUIRED_KEYS = {
     "workload", "decode_tok_s", "prefill_chunk", "prefix_cache",
     "itl_ms_decode_only", "prefill_ms_hit_p50", "prefill_ms_miss_p50",
     "no_prefix_cache", "platform",
-    # paged KV + speculation evidence (ISSUE 6): layout, pool pressure, and
+    # paged KV + speculation evidence (ISSUE 6): pool pressure and
     # draft-and-verify acceptance economics with the spec-off control
-    "kv_layout", "page_size", "page_faults", "pages_reclaimed",
+    "page_size", "page_faults", "pages_reclaimed",
     "preemptions", "page_pool_util", "cow_copies",
     "draft_k", "acceptance_rate", "spec_ticks", "no_speculation",
-    # kernel-lane evidence (ISSUE 11): fused sampling tail + defused
-    # control, and whether the paged-attention kernel traced into the
-    # decode program on this run's backend
-    "fused_tail", "kernel_paged_attention", "no_fused_tail",
+    # kernel-lane evidence (ISSUE 11): whether the paged-attention kernel
+    # traced into the decode program on this run's backend
+    "kernel_paged_attention",
     # observability evidence (ISSUE 7): tracing-cost A/B (populated by
     # --obs-ab, None otherwise) and the Perfetto span artifact every run
     # writes beside the JSON
     "obs_overhead", "trace_file", "obs_spans",
-}
-
-CAPACITY_REQUIRED_KEYS = {
-    "metric", "value", "unit", "model", "kv_budget_tokens", "page_size",
-    "prefill_chunk", "max_new_tokens", "streams_offered", "slab", "paged",
-    "platform", "measured_at_utc",
 }
 
 ROUTER_REQUIRED_KEYS = {
@@ -141,13 +134,10 @@ def test_loadgen_artifact_schema_and_invariants(tmp_path):
     assert artifact["chaos"] is False and artifact["errors"] == 0
     assert artifact["final_state"] == "stopped"
     assert artifact["drain_latency_s"] >= 0
-    # paged KV is the loadgen default; speculation off in this run
-    assert artifact["kv_layout"] == "paged" and artifact["page_size"] > 0
+    # speculation off in this run
+    assert artifact["page_size"] > 0 and artifact["page_pool_util"] > 0
     assert artifact["preemptions"] == 0
     assert artifact["draft_k"] == 0 and artifact["no_speculation"] is None
-    # fused tail is the default; the defused control needs --fused-tail-ab
-    assert artifact["fused_tail"] is True
-    assert artifact["no_fused_tail"] is None
     assert artifact["kernel_paged_attention"] in (True, False)
     # every run writes a Perfetto-loadable span trace next to the artifact
     assert artifact["obs_overhead"] is None  # --obs-ab not requested here
@@ -180,31 +170,6 @@ def test_loadgen_speculative_run_verified_with_acceptance(tmp_path):
 
 
 @pytest.mark.slow
-def test_loadgen_fused_tail_ab(tmp_path):
-    """--fused-tail-ab: the defused-tail control engine (sampling as its
-    own dispatch) runs the same workload and embeds a no_fused_tail block;
-    every measured trajectory still verifies byte-identical against
-    generate() — the defused control changes dispatch count, never math.
-    Slow lane: the A/B is an extra full load run (+ its defused warmup);
-    tier-1 covers the schema keys (None without the flag) and the engine
-    fused/defused byte-parity in tests/test_paged_kernel.py, and make
-    serve-bench runs the real A/B into the committed BENCH_serve.json."""
-    loadgen = _load()
-    out = tmp_path / "BENCH_serve_ft.json"
-    artifact = loadgen.main([
-        "--requests", "6", "--slots", "2", "--concurrency", "6",
-        "--max-new-tokens", "8", "--cache-len", "48",
-        "--fused-tail-ab", "--out", str(out),
-    ])
-    assert artifact["fused_tail"] is True
-    nf = artifact["no_fused_tail"]
-    assert nf is not None
-    assert nf["decode_tok_s"] > 0
-    assert nf["itl_ms_decode_only_p99"] >= 0
-    assert artifact["verified"] is True and artifact["mismatches"] == 0
-
-
-@pytest.mark.slow
 def test_loadgen_obs_ab_measures_tracing_overhead(tmp_path):
     """--obs-ab: the tracing-on/off A/B runs both arms and embeds a sane
     obs_overhead block (fractions in [0, 1], both arms nonzero). Slow lane:
@@ -225,30 +190,6 @@ def test_loadgen_obs_ab_measures_tracing_overhead(tmp_path):
     assert ab["decode_tok_s_trace_on"] > 0
     assert 0.0 <= ab["overhead_frac"] <= 1.0
     assert ab["repeats"] == 1
-
-
-def test_loadgen_capacity_sweep_artifact(tmp_path):
-    """--capacity-sweep: slab vs paged concurrent streams at EQUAL KV
-    budget. The schema is pinned and the paged engine must beat the slab
-    by the ISSUE 6 bar (>=4x) with zero preemptions (reservation-backed
-    admission means capacity pressure -> waiting, not eviction)."""
-    loadgen = _load()
-    out = tmp_path / "BENCH_serve_capacity.json"
-    artifact = loadgen.main([
-        "--capacity-sweep", "--cache-len", "128", "--max-new-tokens", "8",
-        "--capacity-streams", "20", "--out", str(out),
-    ])
-    on_disk = json.loads(out.read_text())
-    assert on_disk == artifact
-    missing = CAPACITY_REQUIRED_KEYS - set(artifact)
-    assert not missing, f"capacity artifact missing keys: {sorted(missing)}"
-    assert artifact["metric"] == "serve_capacity_streams_ratio"
-    assert artifact["slab"]["completed"] == 20
-    assert artifact["paged"]["completed"] == 20
-    assert artifact["slab"]["capacity_streams"] == artifact["slab"]["slots"]
-    assert artifact["value"] >= 4.0, artifact
-    assert artifact["paged"]["preemptions"] == 0
-    assert 0 < artifact["paged"]["page_pool_util"] <= 1.0
 
 
 def test_loadgen_chaos_run_fails_retryably_and_drains(tmp_path):
@@ -687,7 +628,7 @@ def test_serve_bench_guard_logic():
     ok, _ = guard.compare(base, {**base, "decode_tok_s": 540.0,
                                  "itl_ms": {"p99": 2.2}})
     assert ok
-    # decode-only ITL tail (the fused-tail/kernel home metric) is graded
+    # decode-only ITL tail (the paged kernel's home metric) is graded
     # too, and absent blocks (older baselines) are skipped, not failed
     both = {**base, "itl_ms_decode_only": {"p99": 1.0}}
     ok, msgs = guard.compare(both, {**both, "itl_ms_decode_only": {"p99": 1.5}})
@@ -703,19 +644,10 @@ def test_serve_bench_guard_logic():
     # pre-platform-field baselines can only skip
     ok, msgs = guard.compare({"decode_tok_s": 600.0, "itl_ms": {"p99": 2.0}}, slow)
     assert ok and any("SKIP" in m for m in msgs)
-    # capacity artifacts compare on the paged/slab stream ratio
-    cap = {
-        "metric": "serve_capacity_streams_ratio", "value": 8.0,
-        "platform": {"backend": "cpu", "device": "x"},
-    }
-    ok, _ = guard.compare(cap, dict(cap))
-    assert ok
-    ok, msgs = guard.compare(cap, {**cap, "value": 4.0})
-    assert not ok and any("capacity" in m for m in msgs)
-    ok, _ = guard.compare(cap, {**cap, "value": 7.5})  # within tolerance
-    assert ok
-    # mismatched metrics (capacity vs throughput artifact) skip, not fail
-    ok, msgs = guard.compare(cap, base)
+    # mismatched metrics (another kind of artifact) skip, not fail
+    other = {"metric": "some_other_metric", "value": 8.0,
+             "platform": {"backend": "cpu", "device": "x"}}
+    ok, msgs = guard.compare(other, base)
     assert ok and any("SKIP" in m for m in msgs)
     # span-tracing overhead budget: >2% in the fresh artifact's own A/B
     # fails on matching hardware; <=2% passes; absent (no --obs-ab) passes

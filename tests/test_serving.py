@@ -7,14 +7,16 @@ cancellations, and deadline expiries in other slots must never perturb it.
 Everything runs the ``test`` zoo model on CPU; the fake-clock tests drive
 ``step()`` by hand so deadline semantics are deterministic.
 """
+import dataclasses
 import http.client
+import inspect
 import json
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from zero_transformer_tpu.config import model_config
+from zero_transformer_tpu.config import ServingConfig, model_config
 from zero_transformer_tpu.inference.generate import decode_model, generate
 from zero_transformer_tpu.inference.sampling import SamplingConfig
 from zero_transformer_tpu.models import Transformer
@@ -69,6 +71,57 @@ class FakeClock:
 
     def __call__(self):
         return self.t
+
+
+# ------------------------------------------- one engine, one set of defaults
+
+# ServingConfig's name for a constructor parameter, where the two differ
+_ENGINE_PARAM = {"slots": "n_slots"}
+_SHARED_FIELDS = [
+    f.name for f in dataclasses.fields(ServingConfig)
+    if _ENGINE_PARAM.get(f.name, f.name)
+    in inspect.signature(ServingEngine.__init__).parameters
+]
+
+
+@pytest.mark.parametrize("field", _SHARED_FIELDS)
+def test_engine_defaults_are_serving_configs(field):
+    """What ``ServingEngine(cfg, params)`` builds is what ``serve --server``
+    builds from ``ServingConfig()``: every parameter the constructor shares
+    with the config has the config's default, so a test that passes none
+    runs the engine users run."""
+    param = inspect.signature(ServingEngine.__init__).parameters[
+        _ENGINE_PARAM.get(field, field)
+    ]
+    assert param.default == getattr(ServingConfig(), field)
+    assert {"prefill_chunk", "prefix_cache_chunks", "page_size"} <= set(_SHARED_FIELDS)
+
+
+def _refused_by_the_cli(cfg, params):
+    from zero_transformer_tpu import serve
+
+    serve.main(["--model", "test", "--params", "unread.msgpack", "--server",
+                "--prefill-chunk", "0"])
+
+
+@pytest.mark.parametrize("build", [
+    lambda cfg, params: make_engine(cfg, params, kv_layout="slab"),
+    lambda cfg, params: make_engine(cfg, params, fused_tail=False),
+    lambda cfg, params: make_engine(cfg, params, prefill_chunk=0),
+    _refused_by_the_cli,
+], ids=["kv_layout=slab", "fused_tail=False", "prefill_chunk=0",
+        "serve --prefill-chunk 0"])
+def test_removed_paths_are_refused_by_name(cfg, params, build, capsys):
+    """The slab layout, the defused tail and one-shot prefill are gone: a
+    caller that still asks for one is told so, not served by another path.
+    (The keyword names stay accepted at their only remaining values because
+    the benchmark's traffic files pass them.)"""
+    with pytest.raises((ValueError, SystemExit)) as refusal:
+        build(cfg, params)
+    said = str(refusal.value) + capsys.readouterr().err
+    assert "was removed" in said
+    make_engine(cfg, params, kv_layout="paged", fused_tail=True,
+                max_prefill_buckets=8)
 
 
 # --------------------------------------------------------------- state machine

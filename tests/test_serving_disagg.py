@@ -94,7 +94,6 @@ def make_engine(cfg, params, **kw):
     kw.setdefault("cache_len", CACHE_LEN)
     kw.setdefault("sampling", SAMPLING)
     kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("page_size", 4)
     return ServingEngine(cfg, params, **kw)
 
@@ -444,8 +443,6 @@ def test_prefill_handoff_and_role_contracts(cfg, params, reference):
     torn = dst.import_stream({"kind": "decode", "leaves": {}})
     assert torn.status == "rejected" and torn.retryable
     assert "bad import payload" in torn.error
-    with pytest.raises(ValueError, match="paged"):
-        ServingEngine(cfg, params, role="decode", kv_layout="slab")
 
 
 def test_migration_failure_dumps_flight_and_fails_retryably(

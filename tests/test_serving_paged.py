@@ -3,10 +3,10 @@ refcount suite.
 
 Two load-bearing claims:
 
-- **Paged ≡ slab, bitwise.** Block-table paging only changes where K/V
-  bytes live, so a paged engine's token trajectories must be byte-identical
-  to the slab engine's AND to single-request ``generate()`` — across
-  position schemes (ALiBi / RoPE / learned), the int8 KV cache, prefix-
+- **Paged ≡ generate(), bitwise.** Block-table paging only changes where
+  K/V bytes live, so the engine's token trajectories must be byte-identical
+  to single-request ``generate()`` over the model's own contiguous cache —
+  across position schemes (ALiBi / RoPE / learned), the int8 KV cache, prefix-
   cache hits (which are page-refcount bumps, not span copies), and chunked
   prefill whose chunks cross page boundaries.
 - **Greedy speculation ≡ plain decode, token-for-token.** The batched
@@ -27,7 +27,8 @@ from zero_transformer_tpu.config import model_config
 from zero_transformer_tpu.inference.generate import decode_model, generate
 from zero_transformer_tpu.inference.sampling import SamplingConfig
 from zero_transformer_tpu.models import Transformer
-from zero_transformer_tpu.serving import PrefixCache, ServingEngine
+from zero_transformer_tpu.serving import PagedPrefixIndex, ServingEngine
+from zero_transformer_tpu.serving.slots import PagePool
 
 CACHE_LEN = 48
 SAMPLING = SamplingConfig(temperature=0.9, top_k=20)
@@ -66,7 +67,6 @@ def make_engine(cfg, params, **kw):
     kw.setdefault("cache_len", CACHE_LEN)
     kw.setdefault("sampling", SAMPLING)
     kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("kv_layout", "paged")
     kw.setdefault("page_size", 4)
     return ServingEngine(cfg, params, **kw)
 
@@ -80,23 +80,19 @@ def _prompt(length, offset=0):
 
 def test_paged_equals_slab_and_generate(cfg, params, reference):
     """5 mixed-length requests into 2 slots: the paged engine's every
-    trajectory is byte-identical to the slab engine's and to
-    single-request generate(). Lengths 9/17/31 make chunks cross page
+    trajectory is byte-identical to single-request generate() over the
+    model's contiguous cache. Lengths 9/17/31 make chunks cross page
     boundaries (chunk 8 = 2 pages of 4) and span multiple chunk ticks."""
     prompts = [_prompt(n, offset=i) for i, n in enumerate((2, 5, 9, 17, 31))]
-    results = {}
-    for layout in ("slab", "paged"):
-        engine = make_engine(cfg, params, kv_layout=layout)
-        handles = [
-            engine.submit(p, max_new_tokens=8, seed=i)
-            for i, p in enumerate(prompts)
-        ]
-        engine.run_until_idle()
-        assert all(h.status == "done" for h in handles), layout
-        results[layout] = [h.tokens for h in handles]
-    assert results["paged"] == results["slab"]
-    for i, p in enumerate(prompts):
-        assert results["paged"][i] == reference(p, i)
+    engine = make_engine(cfg, params)
+    handles = [
+        engine.submit(p, max_new_tokens=8, seed=i)
+        for i, p in enumerate(prompts)
+    ]
+    engine.run_until_idle()
+    assert all(h.status == "done" for h in handles)
+    for i, (p, h) in enumerate(zip(prompts, handles)):
+        assert h.tokens == reference(p, i)
 
 
 @pytest.mark.parametrize("position", ["rope", "learned"])
@@ -228,22 +224,26 @@ def test_index_eviction_is_refcount_aware(cfg, params):
 
 
 def test_prefix_lru_evicts_leaves_before_parents():
-    """The slab-era LRU bug: after a lookup touches chunks 1..k in order,
-    the LRU front is the SHALLOWEST chunk — evicting it orphans every
-    deeper entry. Eviction must take the least-recent LEAF instead."""
-    pc = PrefixCache(chunk_tokens=4, capacity=3)
+    """After a lookup touches chunks 1..k in order, the LRU front is the
+    SHALLOWEST chunk — evicting it orphans every deeper entry. Eviction
+    must take the least-recent LEAF instead, and return its page."""
+    pool = PagePool(6)
+    pc = PagedPrefixIndex(chunk_tokens=4, capacity=3, pool=pool)
     p1 = list(range(1, 14))  # chunks at 4, 8, 12
-    pc.store(p1, 1, "c1")
-    pc.store(p1, 2, "c2")
-    pc.store(p1, 3, "c3")
-    fill, spans = pc.lookup(p1)  # LRU order now: c1, c2, c3 (front = c1)
+    c1, c2, c3, x1 = ((pool.alloc(),) for _ in range(4))
+    pc.store_pages(p1, 1, c1)
+    pc.store_pages(p1, 2, c2)
+    pc.store_pages(p1, 3, c3)
+    fill, entries = pc.lookup(p1)  # LRU order now: c1, c2, c3 (front = c1)
     assert fill == 12
     other = [99] + p1[1:]
-    pc.store(other, 1, "x1")  # forces one eviction
+    pc.store_pages(other, 1, x1)  # forces one eviction
     assert pc.evictions == 1
-    # the chain c1 -> c2 survives intact: the LEAF c3 was evicted, not c1
-    fill, spans = pc.lookup(p1)
-    assert fill == 8 and spans == ["c1", "c2"]
+    # the chain c1 -> c2 survives intact: the LEAF c3 was evicted, not c1,
+    # and its page went back to the pool
+    fill, entries = pc.lookup(p1)
+    assert fill == 8 and entries == [c1, c2]
+    assert pool.refs[c3[0]] == 0 and pool.free_count == 2
 
 
 def test_paged_admission_waits_when_pool_exhausted(cfg, params):
@@ -269,16 +269,13 @@ def test_paged_admission_waits_when_pool_exhausted(cfg, params):
 # ------------------------------------------------------------- speculation
 
 
-@pytest.mark.parametrize("layout", ["slab", "paged"])
 @pytest.mark.parametrize("draft_k", [1, 4])
-def test_spec_greedy_matches_plain_decode(cfg, params, reference, layout, draft_k):
+def test_spec_greedy_matches_plain_decode(cfg, params, reference, draft_k):
     """Greedy speculative serving is token-for-token identical to plain
-    greedy decode (and therefore to generate()) on both KV layouts;
-    draft_k=1 is the degenerate single-draft case."""
+    greedy decode (and therefore to generate()); draft_k=1 is the
+    degenerate single-draft case."""
     prompts = [_prompt(n, offset=i) for i, n in enumerate((3, 7, 12))]
-    engine = make_engine(
-        cfg, params, kv_layout=layout, sampling=GREEDY, draft_k=draft_k
-    )
+    engine = make_engine(cfg, params, sampling=GREEDY, draft_k=draft_k)
     handles = [
         engine.submit(p, max_new_tokens=12, seed=i)
         for i, p in enumerate(prompts)
@@ -513,11 +510,6 @@ def test_kernel_page_counters_add_up(cfg, params):
     assert 3 * len(attrs) <= snap["kernel_pages_live"] <= (1 + 3 + 5) * len(attrs)
     text = engine.prometheus_text()
     assert "kernel_pages_live" in text and "kernel_pages_table" in text
-    # a slab engine has no table to walk
-    slab = make_engine(cfg, params, kv_layout="slab", sampling=GREEDY)
-    slab.submit(_prompt(5), max_new_tokens=3, seed=0)
-    slab.run_until_idle()
-    assert slab.metrics_snapshot()["kernel_pages_table"] == 0
 
 
 def test_page_pool_unit():

@@ -2,10 +2,11 @@
 and blast-radius suite.
 
 The load-bearing claim is EQUIVALENCE: chunked prefill (and a prefix-cache
-hit mid-prompt) must be bit-for-bit identical to the one-shot prefill path —
-the logits at ``true_len - 1`` AND the full generated sequence — across
-chunk sizes, prefill-bucket boundaries, position schemes (ALiBi, RoPE,
-learned), and the int8 KV cache. The resilience interactions are pinned
+hit mid-prompt) must agree with the whole prompt in one forward through the
+model's own contiguous cache — the logits at ``true_len - 1`` to a few ulp
+AND the full generated sequence byte for byte against ``generate()`` —
+across chunk sizes, position schemes (ALiBi, RoPE, learned), and the int8
+KV cache. The resilience interactions are pinned
 too: a fault during a prefill chunk retires ONLY the mid-prefill slots
 (decoding neighbors keep their exact trajectories), and a hot weight reload
 flushes the prefix cache so stale K/V can never serve under new weights.
@@ -20,11 +21,17 @@ import jax.numpy as jnp
 import pytest
 
 from zero_transformer_tpu.config import model_config
-from zero_transformer_tpu.inference.generate import decode_model, generate
+from zero_transformer_tpu.inference.generate import (
+    decode_model,
+    generate,
+    init_cache,
+    prefill,
+)
 from zero_transformer_tpu.inference.sampling import SamplingConfig
 from zero_transformer_tpu.models import Transformer
 from zero_transformer_tpu.serving import (
-    PrefixCache,
+    PagedPrefixIndex,
+    PagePool,
     ServeFault,
     ServingChaosMonkey,
     ServingEngine,
@@ -68,6 +75,7 @@ def reference(cfg, params):
 def make_engine(cfg, params, **kw):
     kw.setdefault("n_slots", 2)
     kw.setdefault("cache_len", CACHE_LEN)
+    kw.setdefault("page_size", 4)  # divides every chunk used here (4, 8, 16, 48)
     kw.setdefault("sampling", SAMPLING)
     return ServingEngine(cfg, params, **kw)
 
@@ -93,26 +101,25 @@ def _drive_prefill_only(engine):
 @pytest.mark.parametrize("chunk", [8, 64, CACHE_LEN])
 @pytest.mark.parametrize("length", [5, 9, 17, 31])
 def test_chunk_prefill_logits_match_oneshot(cfg, params, chunk, length):
-    """The logits at ``true_len - 1`` out of chunked prefill equal the
-    one-shot padded prefill's, for prompts crossing power-of-two bucket
-    boundaries and chunks from smaller-than-prompt up to (and past —
-    64 > cache clamps) the cache capacity.
+    """The logits at ``true_len - 1`` out of chunked prefill equal those of
+    the whole prompt in one ``[1, length]`` forward through the model's own
+    contiguous cache (``inference.generate.prefill``), for chunks from
+    smaller-than-prompt up to (and past — 64 > cache clamps) the cache
+    capacity.
 
-    Equality bar: a few ulp, for every chunk size. A chunked window and the
-    one-shot bucket are DIFFERENT XLA program shapes whenever their widths
-    differ ([S, chunk] windows vs a [1, 8..32] bucket), and XLA:CPU tiles
-    the attention reductions by shape: measured on jax 0.9, chunk 8 and 16
-    are bit-equal to one-shot for lengths 5 and 9 (bucket width = window
-    width) and exactly <= 1 ulp off for 17 and 31 (a 32-wide bucket against
-    8/16-wide windows), same argmax; the cache-wide chunks show the mirror
-    image. That is summation order, not an offset or padding fault (either
-    would be off by the size of a logit, not of an ulp) — so chunk 8, once
-    pinned bitwise on an older XLA that happened to tile both alike, is held
-    to the bar 48/64 always had. The split itself is still proven exact
-    where it matters: token-level decode outputs are asserted bit-identical
-    for EVERY chunk size in ``test_chunked_sequences_match_generate``."""
-    legacy = make_engine(cfg, params)  # prefill_chunk=0: one-shot path
-    oneshot_logits, _ = legacy._prefill(_prompt(length))
+    Equality bar: a few ulp, for every chunk size. A chunked ``[S, chunk]``
+    window and the one-shot ``[1, length]`` forward are DIFFERENT XLA
+    program shapes, and XLA:CPU tiles the attention reductions by shape:
+    that is summation order, not an offset or padding fault (either would
+    be off by the size of a logit, not of an ulp). The split itself is
+    still proven exact where it matters: token-level decode outputs are
+    asserted bit-identical for EVERY chunk size in
+    ``test_chunked_sequences_match_generate``."""
+    model = decode_model(cfg, CACHE_LEN)
+    oneshot_logits, _ = prefill(
+        model, params, jnp.asarray([_prompt(length)], jnp.int32),
+        init_cache(model, 1),
+    )
     oneshot = np.asarray(jax.device_get(oneshot_logits))[0]
 
     chunked = make_engine(cfg, params, prefill_chunk=chunk)
@@ -219,7 +226,7 @@ def test_learned_positions_chunked_parity():
     )[0].tolist()
     engine = ServingEngine(
         lcfg, lparams, n_slots=2, cache_len=lcfg.max_seq_len,
-        sampling=SAMPLING, prefill_chunk=4,
+        sampling=SAMPLING, prefill_chunk=4, page_size=4,
     )
     handle = engine.submit(prompt, max_new_tokens=6, seed=5)
     engine.run_until_idle()
@@ -373,57 +380,37 @@ def test_reload_flushes_prefix_cache(cfg, params, reference):
     assert after.tokens != reference(prefix + _prompt(3, offset=80), 4, max_new=6)
 
 
-# ------------------------------------------------------------ bucket cap
-
-
-def test_bucket_cap_bounds_compiled_prefill_programs(cfg, params, reference):
-    """Legacy one-shot path: past ``max_prefill_buckets`` distinct buckets,
-    new prompt lengths round UP to an existing bucket (exact — padded
-    prefill is causality-safe) instead of compiling another program, the
-    event is counted, and the gauge is exported."""
-    engine = make_engine(
-        cfg, params, n_slots=1, max_prefill_buckets=2
-    )
-    assert engine._bucket(3) == 8
-    assert engine._bucket(12) == 16
-    # budget spent: 24 would want bucket 32; it must round to an existing
-    # one — none fits, so the capacity bucket (always admissible) is used
-    assert engine._bucket(24) == CACHE_LEN
-    assert engine._bucket(5) == 8  # still served by the compiled 8-bucket
-    assert engine._bucket(13) == 16
-    assert engine._bucket(9) == 16  # 16 exists; no new 8->16 gap compile
-    assert engine.stats["prefill_bucket_capped"] >= 1
-    assert engine.metrics_snapshot()["prefill_buckets"] == 3  # 8, 16, cap
-    # and a request through the capped path still decodes exactly
-    handle = engine.submit(_prompt(24), max_new_tokens=4, seed=7)
-    engine.run_until_idle()
-    assert handle.tokens == reference(_prompt(24), 7, max_new=4)
-
-
 # ------------------------------------------------------------ prefix cache
 
 
 def test_prefix_cache_lru_unit():
     """Host-side LRU semantics: chunk-aligned keys, last-chunk exclusion,
-    eviction order, flush."""
-    pc = PrefixCache(chunk_tokens=4, capacity=2)
+    eviction order, flush — and the index's one reference per page."""
+    pool = PagePool(5)
+    pc = PagedPrefixIndex(chunk_tokens=4, capacity=2, pool=pool)
     p1 = list(range(1, 11))  # 10 tokens: chunks at 4 and 8
-    fill, spans = pc.lookup(p1)
-    assert fill == 0 and spans == [] and pc.misses == 2
-    pc.store(p1, 1, "span1")
-    pc.store(p1, 2, "span2")
-    fill, spans = pc.lookup(p1)
-    assert fill == 8 and spans == ["span1", "span2"] and pc.hits == 2
+    fill, entries = pc.lookup(p1)
+    assert fill == 0 and entries == [] and pc.misses == 2
+    page1, page2, page3 = ((pool.alloc(),) for _ in range(3))
+    pc.store_pages(p1, 1, page1)
+    pc.store_pages(p1, 2, page2)
+    fill, entries = pc.lookup(p1)
+    assert fill == 8 and entries == [page1, page2] and pc.hits == 2
     # a full-prompt-aligned lookup never consumes the final chunk: a
     # 8-token prompt sharing p1's first 8 tokens may only reuse chunk 1
-    fill, spans = pc.lookup(p1[:8])
-    assert fill == 4 and spans == ["span1"]
+    fill, entries = pc.lookup(p1[:8])
+    assert fill == 4 and entries == [page1]
     # divergent prefix: chunk 1 differs -> no hit, and a deeper stored
     # chunk alone is unreachable without its predecessors
     other = [99] + p1[1:]
-    fill, spans = pc.lookup(other)
-    assert fill == 0 and spans == []
-    # eviction: capacity 2, storing a third entry evicts the LRU one
-    pc.store(other, 1, "span3")
-    assert pc.evictions == 1 and len(pc) == 2
-    assert pc.flush() == 2 and len(pc) == 0
+    fill, entries = pc.lookup(other)
+    assert fill == 0 and entries == []
+    # a duplicate store hands the extra reference straight back
+    pool.incref(page1)
+    pc.store_pages(p1, 1, page1)
+    assert pool.refs[page1[0]] == 1 and pc.stores == 2
+    # eviction: capacity 2, storing a third entry evicts the LRU leaf and
+    # frees its page
+    pc.store_pages(other, 1, page3)
+    assert pc.evictions == 1 and len(pc) == 2 and pool.in_use == 2
+    assert pc.flush() == 2 and len(pc) == 0 and pool.in_use == 0

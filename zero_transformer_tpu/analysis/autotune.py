@@ -162,26 +162,20 @@ def train_space() -> KnobSpace:
 
 
 def serve_space() -> KnobSpace:
-    """The serving knob space (graded by BENCH_serve): KV layout/paging,
-    chunked prefill, speculation, fused sampling tail."""
+    """The serving knob space (graded by BENCH_serve): paging, chunked
+    prefill, speculation."""
     s = KnobSpace("serve")
-    s.register(Knob("kv_layout", ("paged", "slab"), "serving.kv_layout",
+    s.register(Knob("prefill_chunk", (8, 16), "serving.prefill_chunk",
                     "serve", "BENCH_serve",
-                    "block-table page pool vs fixed slab rows"))
-    s.register(Knob("prefill_chunk", (0, 8, 16), "serving.prefill_chunk",
-                    "serve", "BENCH_serve",
-                    "prompt tokens prefilled per tick (0 = one-shot)"))
+                    "prompt tokens prefilled per tick"))
     s.register(Knob("page_size", (4, 8, 16), "serving.page_size",
                     "serve", "BENCH_serve", "tokens per KV page"))
     s.register(Knob("page_pool_tokens", (0, 192),
                     "serving.page_pool_tokens", "serve", "BENCH_serve",
-                    "page-pool capacity (0 = slab-equivalent)"))
+                    "page-pool capacity (0 = slots x cache_len)"))
     s.register(Knob("draft_k", (0, 4), "serving.draft_k",
                     "serve", "BENCH_serve",
                     "speculative draft length per tick (0 = off)"))
-    s.register(Knob("fused_tail", (True, False), "serving.fused_tail",
-                    "serve", "BENCH_serve",
-                    "sampling inside the single jitted decode program"))
     return s
 
 
@@ -258,28 +252,12 @@ def train_memory_validator(
     return ("memory_budget", check)
 
 
-def serve_redundancy_validator() -> Validator:
-    def check(point: Dict[str, Any]) -> Optional[str]:
-        if point.get("kv_layout") == "slab":
-            if point.get("page_size", 4) != 4 or point.get("page_pool_tokens", 0):
-                return (
-                    "redundant: page_size/page_pool_tokens are inert with "
-                    "kv_layout='slab' (identical engine to the canonical "
-                    "page_size=4, page_pool_tokens=0 sibling)"
-                )
-        return None
-
-    return ("redundancy", check)
-
-
 def serve_feasibility_validator(cache_len: int) -> Validator:
     """Workload-level analytic rules config validation cannot see (it has
     no cache_len): page divisibility of the cache and minimum pool size to
     hold one worst-case stream (admission would wedge, not error)."""
 
     def check(point: Dict[str, Any]) -> Optional[str]:
-        if point.get("kv_layout") != "paged":
-            return None
         ps = point.get("page_size", 4)
         if cache_len % ps:
             return (

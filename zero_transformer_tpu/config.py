@@ -537,28 +537,21 @@ class ServingConfig:
     YAML deployment config and the flag surface can never drift):
 
     - **prefill_chunk**: prompts prefill ``prefill_chunk`` tokens per
-      scheduler tick, written DIRECTLY into the slot's rows of the shared
-      KV cache and interleaved with the fused decode step — one long prompt
-      can no longer stall every active stream for its full prefill
-      (Sarathi-style chunked prefill). 0 = legacy one-shot bucketed
-      prefill (the whole prompt in one padded [1, bucket] dispatch, then a
-      cache insert).
-    - **prefix_cache_chunks**: capacity (in chunk-sized K/V spans) of the
-      chunk-aligned token-prefix LRU; repeated system prompts skip straight
-      to the first novel chunk (vLLM-style block hashing). 0 disables.
-      Requires ``prefill_chunk > 0``. Flushed on hot weight reload — cached
-      K/V is only valid for the weights that produced it.
-    - **max_prefill_buckets**: cap on DISTINCT compiled one-shot prefill
-      buckets (legacy path): past it, new prompt lengths round up to an
-      already-compiled bucket instead of compiling another program, so
-      diverse prompt lengths cannot compile-storm a serving replica.
-    - **kv_layout / page_size / page_pool_tokens**: ``paged`` replaces the
-      fixed [slots, cache_len] KV slab with a block-table paged pool
-      (PagedAttention): HBM is ``page_pool_tokens`` positions regardless of
-      slot count, so concurrency scales with ACTUAL sequence lengths
-      instead of the worst case, and prefix-cache hits become page-refcount
-      bumps instead of span copies. ``page_pool_tokens = 0`` sizes the pool
-      to the exact slab equivalent (slots x cache_len).
+      scheduler tick, written through the slot's block table into the KV
+      page pool and interleaved with the fused decode step — one long
+      prompt cannot stall every active stream for its full prefill
+      (Sarathi-style chunked prefill). It is the only admission path.
+    - **prefix_cache_chunks**: capacity (in chunk entries) of the
+      chunk-aligned token-prefix index over page ids; repeated system
+      prompts skip straight to the first novel chunk (vLLM-style block
+      hashing), a hit being a refcount bump. 0 disables. Flushed on hot
+      weight reload — cached K/V is only valid for the weights that
+      produced it.
+    - **page_size / page_pool_tokens**: K/V lives in a block-table paged
+      pool (PagedAttention): HBM is ``page_pool_tokens`` positions
+      regardless of slot count, so concurrency scales with ACTUAL sequence
+      lengths instead of the worst case. ``page_pool_tokens = 0`` sizes the
+      pool to ``slots x cache_len``.
     - **draft_k**: per-tick self-speculative decoding — every decode tick
       proposes ``draft_k`` tokens per slot (prompt-lookup n-grams) and
       verifies them in ONE batched forward; greedy output is bit-identical
@@ -570,24 +563,15 @@ class ServingConfig:
     max_queue: int = 64
     prefill_chunk: int = 64
     prefix_cache_chunks: int = 256
-    max_prefill_buckets: int = 8
     drain_deadline_s: float = 30.0
-    kv_layout: str = "paged"
     page_size: int = 16
     page_pool_tokens: int = 0
     draft_k: int = 0
-    # fused decode tail (PR 11): sampling (temperature/top-k/veto/rejection)
-    # runs INSIDE the single jitted decode/spec-verify program. False is the
-    # A/B CONTROL — sampling as its own dispatch after the forward — kept
-    # only so the bench can price the fusion (BENCH_serve.json's
-    # no_fused_tail arm); byte-identical trajectories either way.
-    fused_tail: bool = True
     # disaggregated fleets (PR 12): a "prefill" replica runs only chunked
     # prefill at max batch and ships every finished stream's KV pages to
     # the decode replica the request names; a "decode" replica serves
     # imported streams (and plain requests, as the recompute fallback);
-    # "mixed" is the classic single-replica behavior. Non-mixed roles
-    # require the paged KV layout — pages are the unit that ships.
+    # "mixed" is the classic single-replica behavior.
     role: str = "mixed"
 
     def __post_init__(self):
@@ -595,40 +579,21 @@ class ServingConfig:
             raise ValueError("serving.slots must be >= 1")
         if self.max_queue < 1:
             raise ValueError("serving.max_queue must be >= 1")
-        if self.prefill_chunk < 0:
-            raise ValueError("serving.prefill_chunk must be >= 0 (0 disables)")
+        if self.prefill_chunk < 1:
+            raise ValueError(
+                "serving.prefill_chunk must be >= 1: one-shot prefill "
+                "(prefill_chunk=0) was removed, chunked prefill is the only "
+                "admission path"
+            )
         if self.prefix_cache_chunks < 0:
             raise ValueError(
                 "serving.prefix_cache_chunks must be >= 0 (0 disables)"
             )
-        if self.prefix_cache_chunks > 0 and self.prefill_chunk == 0:
-            raise ValueError(
-                "serving.prefix_cache_chunks requires prefill_chunk > 0: the "
-                "prefix cache is keyed on chunk-aligned token spans"
-            )
-        if self.max_prefill_buckets < 1:
-            raise ValueError("serving.max_prefill_buckets must be >= 1")
         if self.drain_deadline_s < 0:
             raise ValueError("serving.drain_deadline_s must be >= 0")
-        if self.kv_layout not in ("slab", "paged"):
-            raise ValueError(
-                f"serving.kv_layout must be 'slab' or 'paged', got "
-                f"{self.kv_layout!r}"
-            )
-        if self.kv_layout == "paged" and self.prefill_chunk == 0:
-            raise ValueError(
-                "serving.kv_layout='paged' requires prefill_chunk > 0 (the "
-                "legacy one-shot prefill has no block-table path); set "
-                "kv_layout='slab' to keep prefill_chunk=0 (serve --server "
-                "falls back to slab automatically for this combination)"
-            )
         if self.page_size < 1:
             raise ValueError("serving.page_size must be >= 1")
-        if (
-            self.kv_layout == "paged"
-            and self.prefill_chunk
-            and self.prefill_chunk % self.page_size
-        ):
+        if self.prefill_chunk % self.page_size:
             raise ValueError(
                 "serving.page_size must divide prefill_chunk (page-aligned "
                 "chunk sharing)"
@@ -639,20 +604,9 @@ class ServingConfig:
             )
         if self.draft_k < 0:
             raise ValueError("serving.draft_k must be >= 0 (0 disables)")
-        if not self.fused_tail and self.draft_k:
-            raise ValueError(
-                "serving.fused_tail=False (the A/B control) covers the "
-                "plain decode path only; speculative verify (draft_k > 0) "
-                "is inseparable from its in-program sampling"
-            )
         if self.role not in ("mixed", "prefill", "decode"):
             raise ValueError(
                 f"serving.role must be mixed|prefill|decode, got {self.role!r}"
-            )
-        if self.role != "mixed" and self.kv_layout != "paged":
-            raise ValueError(
-                f"serving.role={self.role!r} requires kv_layout='paged': "
-                "KV pages are the unit that ships between replicas"
             )
         if self.role == "prefill" and self.draft_k:
             raise ValueError(

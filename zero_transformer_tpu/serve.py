@@ -233,9 +233,7 @@ def _has_quantized_leaves(tree) -> bool:
 # the ServingConfig knobs the autotuner searches (scripts/autotune.py):
 # argparse leaves them at a None sentinel so explicit flags are
 # distinguishable from "use the default"
-_TUNED_KNOBS = (
-    "kv_layout", "prefill_chunk", "page_size", "page_pool_tokens", "draft_k",
-)
+_TUNED_KNOBS = ("prefill_chunk", "page_size", "page_pool_tokens", "draft_k")
 
 
 def _resolve_tuned_args(args):
@@ -289,8 +287,6 @@ def _resolve_tuned_args(args):
     for name in _TUNED_KNOBS:
         if getattr(args, name) is None:
             setattr(args, name, tuned.get(name, getattr(defaults, name)))
-    if args.no_fused_tail is None:
-        args.no_fused_tail = not tuned.get("fused_tail", defaults.fused_tail)
     return args
 
 
@@ -381,27 +377,12 @@ def _server(gen: TextGenerator, args) -> None:
         repetition_penalty=args.repetition_penalty, greedy=args.greedy,
         top_k_impl=gen.top_k_impl,
     )
-    kv_layout = args.kv_layout
-    if kv_layout == "paged" and args.prefill_chunk == 0:
-        print(
-            "serve: --prefill-chunk 0 (legacy one-shot prefill) has no "
-            "block-table path; falling back to --kv-layout slab",
-            flush=True,
-        )
-        kv_layout = "slab"
     draft_k = args.draft_k
     if draft_k and args.repetition_penalty != 1.0:
         print(
             "serve: --draft-k requires --repetition-penalty 1.0 (the batched "
             "verify step cannot emulate the in-block penalty); speculation "
             "DISABLED for this run",
-            flush=True,
-        )
-        draft_k = 0
-    if draft_k and args.no_fused_tail:
-        print(
-            "serve: --no-fused-tail (the fused-tail A/B control) covers the "
-            "plain decode path only; speculation DISABLED for this run",
             flush=True,
         )
         draft_k = 0
@@ -417,13 +398,10 @@ def _server(gen: TextGenerator, args) -> None:
         metrics=MetricsLogger(directory=args.metrics_dir),
         metrics_interval=args.metrics_interval,
         prefill_chunk=args.prefill_chunk,
-        prefix_cache_chunks=args.prefix_cache if args.prefill_chunk else 0,
-        max_prefill_buckets=args.max_prefill_buckets,
-        kv_layout=kv_layout,
+        prefix_cache_chunks=args.prefix_cache,
         page_size=args.page_size,
         page_pool_tokens=args.page_pool_tokens,
         draft_k=draft_k,
-        fused_tail=not args.no_fused_tail,
         role=args.role,
         obs_dir=args.obs_dir or args.metrics_dir,
         trace=not args.no_trace,
@@ -513,12 +491,6 @@ def main(argv=None) -> None:
                         "under ZT_PALLAS_INTERPRET=1), XLA elsewhere; 'xla' "
                         "forces the reference path; 'flash' is flash-or-"
                         "raise (never silently O(T^2))")
-    p.add_argument("--no-fused-tail", action="store_true", default=None,
-                   help="A/B CONTROL: run sampling as its own dispatch "
-                        "after the forward instead of inside the single "
-                        "jitted decode program (byte-identical output; "
-                        "exists so the bench can price the fused tail — "
-                        "disables --draft-k)")
     p.add_argument("--kv-cache-dtype", default="auto", choices=("auto", "int8"),
                    help="int8 halves KV-cache HBM traffic (doubles servable "
                         "context) at slight quantization cost")
@@ -573,34 +545,27 @@ def main(argv=None) -> None:
     p.add_argument("--prefill-chunk", type=int,
                    default=None,
                    help="prefill this many prompt tokens per scheduler tick, "
-                        "written directly into the slot KV cache and "
-                        "interleaved with decode — a long prompt no longer "
-                        "stalls every active stream for its full prefill "
-                        "(0 = legacy one-shot bucketed prefill; default "
+                        "written through the slot's block table into the KV "
+                        "page pool and interleaved with decode — a long "
+                        "prompt cannot stall every active stream for its "
+                        "full prefill (>= 1; default "
                         f"{serving_defaults.prefill_chunk})")
     p.add_argument("--prefix-cache", type=int,
                    default=serving_defaults.prefix_cache_chunks,
                    metavar="CHUNKS",
-                   help="capacity of the chunk-aligned token-prefix K/V "
-                        "LRU: repeated system prompts skip straight to "
-                        "their first novel chunk (0 = off; requires "
-                        "--prefill-chunk > 0; flushed on hot reload)")
-    p.add_argument("--kv-layout", default=None,
-                   choices=("slab", "paged"),
-                   help="KV cache layout: 'paged' (default) = block-table "
-                        "page pool (PagedAttention) — HBM scales with ACTUAL "
-                        "sequence lengths, not slots x cache_len, and prefix "
-                        "hits are page-refcount bumps; 'slab' = the classic "
-                        "fixed [slots, cache_len] rows")
+                   help="capacity of the chunk-aligned token-prefix index "
+                        "over KV pages: repeated system prompts skip "
+                        "straight to their first novel chunk, a hit being a "
+                        "page-refcount bump (0 = off; flushed on hot reload)")
     p.add_argument("--page-size", type=int,
                    default=None,
-                   help="tokens per KV page (paged layout); must divide "
+                   help="tokens per KV page; must divide "
                         "--prefill-chunk and the cache length (default "
                         f"{serving_defaults.page_size})")
     p.add_argument("--page-pool-tokens", type=int,
                    default=None,
                    help="total page-pool capacity in token positions "
-                        "(0 = the slab-equivalent slots x cache_len); at a "
+                        "(0 = slots x cache_len); at a "
                         "fixed budget, more concurrent streams fit whenever "
                         "real sequences run shorter than cache_len")
     p.add_argument("--draft-k", type=int, default=None,
@@ -616,14 +581,7 @@ def main(argv=None) -> None:
                         "decode replica each request names (prefill_to); "
                         "'decode' serves imported streams plus the "
                         "recompute fallback; 'mixed' (default) is the "
-                        "classic standalone replica. Non-mixed roles "
-                        "require --kv-layout paged")
-    p.add_argument("--max-prefill-buckets", type=int,
-                   default=serving_defaults.max_prefill_buckets,
-                   help="cap on distinct compiled one-shot prefill buckets "
-                        "(legacy --prefill-chunk 0 path): past it, new "
-                        "prompt lengths round up to an existing bucket "
-                        "instead of compiling another program")
+                        "classic standalone replica")
     p.add_argument("--metrics-dir", default=None,
                    help="JSONL sink for serving metrics (TTFT/ITL "
                         "percentiles, tokens/s, occupancy)")
@@ -653,6 +611,12 @@ def main(argv=None) -> None:
                         "finish, then are force-finished and the process "
                         "exits 0")
     args = _resolve_tuned_args(p.parse_args(argv))
+    if args.prefill_chunk < 1:
+        p.error(
+            "--prefill-chunk must be >= 1: one-shot prefill "
+            "(--prefill-chunk 0) was removed, chunked prefill is the only "
+            "admission path"
+        )
     from zero_transformer_tpu.utils import compile_cache
 
     compile_cache.configure()

@@ -1,6 +1,6 @@
 """Continuous-batching serving engine.
 
-The orchestration layer above the jitted decode path: a slot-based KV cache
+The orchestration layer above the jitted decode path: a paged KV cache
 (``slots``), a request scheduler with deadlines/cancellation/backpressure
 (``engine``), a streaming SSE front end (``server``), the shared
 incremental detokenizer (``detok``), and the serving resilience layer
@@ -27,10 +27,7 @@ from zero_transformer_tpu.serving.engine import (
     RequestHandle,
     ServingEngine,
 )
-from zero_transformer_tpu.serving.prefix_cache import (
-    PagedPrefixIndex,
-    PrefixCache,
-)
+from zero_transformer_tpu.serving.prefix_cache import PagedPrefixIndex
 from zero_transformer_tpu.serving.qos import (
     BROWNOUT_RUNGS,
     QOS_CLASSES,
@@ -69,7 +66,6 @@ from zero_transformer_tpu.serving.server import ServingServer, run_server
 from zero_transformer_tpu.serving.slots import (
     PagedKVCache,
     PagePool,
-    SlotKVCache,
     page_span_from_wire,
     page_span_to_wire,
     vectorize_index,
@@ -104,7 +100,6 @@ __all__ = [
     "PagedKVCache",
     "PagedPrefixIndex",
     "PagePool",
-    "PrefixCache",
     "ReloadError",
     "ServeFault",
     "ServingChaosMonkey",
@@ -123,7 +118,6 @@ __all__ = [
     "RequestHandle",
     "ServingEngine",
     "ServingServer",
-    "SlotKVCache",
     "StreamDecoder",
     "run_server",
     "vectorize_index",

@@ -5,33 +5,29 @@
 
 - requests queue behind a bounded admission queue (backpressure: a full
   queue REJECTS at submit time rather than stacking unbounded latency);
-- free slots admit queued requests. With ``prefill_chunk > 0`` (the serving
-  default) the prompt prefills CHUNKED: ``prefill_chunk`` tokens per tick,
-  written directly into the slot's rows of the shared ``SlotKVCache`` by one
-  fixed-shape ``[n_slots, chunk]`` program that advances EVERY mid-prefill
-  slot at once — so a long prompt never stalls active streams for its full
-  prefill (the Sarathi-Serve interleaving), multiple queued prompts prefill
-  as one batch (admission is inherently batched), and there is no
-  small-cache-then-insert copy or per-prompt-length compile. A chunk-aligned
-  token-prefix LRU (``serving/prefix_cache.py``) lets repeated system
-  prompts skip straight to the first novel chunk. ``prefill_chunk = 0``
-  keeps the legacy one-shot path: the prompt prefills into a fresh
-  single-row cache (padded to a power-of-two bucket, count-capped so
-  diverse lengths cannot compile-storm the replica), then
-  ``SlotKVCache.insert`` copies it into the slot;
-- every ``step()`` runs ONE fused decode step across all slots — padded and
-  masked so the compiled program is identical whatever the occupancy — then
-  retires slots that hit EOS, their token budget, a deadline, or a
-  cancellation. With ``draft_k > 0`` the step is the SPECULATIVE twin:
-  ``draft_k`` host-proposed prompt-lookup drafts per slot verified in the
-  same single forward, committing ``1 + n_acc`` tokens per tick (greedy ≡
-  plain decode bit-for-bit; sampling via the standard rejection rule);
-- with ``kv_layout="paged"`` (the serving default at the CLI) the K/V slab
-  is replaced by a block-table paged pool (``slots.PagedKVCache``): KV HBM
-  is ``page_pool_tokens`` positions regardless of slot count, admission
-  reserves each request's worst case so capacity pressure queues instead
-  of faulting, and prefix-cache hits map shared pages by refcount instead
-  of copying spans;
+- K/V lives in ONE place: the block-table page pool of
+  ``slots.PagedKVCache``. KV HBM is ``page_pool_tokens`` positions whatever
+  the slot count, admission reserves each request's worst case so capacity
+  pressure queues instead of faulting, and a prefix-cache hit maps shared
+  pages by refcount instead of copying bytes;
+- free slots admit queued requests, and the prompt prefills CHUNKED:
+  ``prefill_chunk`` tokens per tick, written through the slot's block table
+  into the pool by one fixed-shape ``[n_slots, chunk]`` program
+  (``_paged_chunk_prefill_impl``) that advances EVERY mid-prefill slot at
+  once — so a long prompt never stalls active streams for its full prefill
+  (the Sarathi-Serve interleaving), multiple queued prompts prefill as one
+  batch (admission is inherently batched), and there is no per-prompt-length
+  compile. A chunk-aligned token-prefix index over page ids
+  (``serving/prefix_cache.py``) lets repeated system prompts skip straight
+  to the first novel chunk;
+- every ``step()`` runs ONE fused decode step across all slots
+  (``_fused_step_impl``) — padded and masked so the compiled program is
+  identical whatever the occupancy — then retires slots that hit EOS, their
+  token budget, a deadline, or a cancellation. With ``draft_k > 0`` the step
+  is the SPECULATIVE twin (``_spec_step_impl``): ``draft_k`` host-proposed
+  prompt-lookup drafts per slot verified in the same single forward,
+  committing ``1 + n_acc`` tokens per tick (greedy ≡ plain decode
+  bit-for-bit; sampling via the standard rejection rule);
 - each request carries its OWN rng chain and repetition-penalty mask,
   threaded per-slot through the fused step, so its token trajectory is
   IDENTICAL to what single-request ``generate()`` produces with the same
@@ -85,7 +81,6 @@ Observability (``obs/`` owns the primitives — docs/OBSERVABILITY.md):
 from __future__ import annotations
 
 import dataclasses
-import functools
 import itertools
 import math
 import queue as queue_mod
@@ -115,11 +110,7 @@ from zero_transformer_tpu.obs import (
     hbm_device_stats,
 )
 
-from zero_transformer_tpu.inference.generate import (
-    _in_mesh,
-    decode_model,
-    init_cache,
-)
+from zero_transformer_tpu.inference.generate import _in_mesh, decode_model
 from zero_transformer_tpu.inference.sampling import (
     NEG_INF,
     SamplingConfig,
@@ -128,7 +119,7 @@ from zero_transformer_tpu.inference.sampling import (
 )
 from zero_transformer_tpu.inference.speculative import ngram_propose
 from zero_transformer_tpu.resilience.detect import nonfinite_rows
-from zero_transformer_tpu.serving.prefix_cache import PagedPrefixIndex, PrefixCache
+from zero_transformer_tpu.serving.prefix_cache import PagedPrefixIndex
 from zero_transformer_tpu.serving.qos import (
     BROWNOUT_RUNGS,
     ClassQueue,
@@ -153,7 +144,6 @@ from zero_transformer_tpu.serving.slots import (
     INDEX_LEAVES,
     TABLE_LEAF,
     PagedKVCache,
-    SlotKVCache,
     _leaf_name,
 )
 
@@ -429,7 +419,7 @@ class _ActiveSlot:
 
 @dataclasses.dataclass
 class _PrefillJob:
-    """A slot mid-chunked-prefill: acquired in the SlotKVCache but not yet
+    """A slot mid-chunked-prefill: acquired in the PagedKVCache but not yet
     decoding. ``fill`` counts prompt tokens whose K/V are in the slot's
     rows (prefix-cache hits included); prefill completes when it reaches
     the prompt length and the slot installs into the decode set."""
@@ -457,9 +447,7 @@ def _sample_tail_impl(sampling, last_logits, gen_mask, rngs):
     own rng chain. Each row reproduces the single-request loop
     bit-for-bit: the rng split order and the [1, V] sample shapes match
     ``generate()`` with B=1, so a slot's trajectory is independent of its
-    neighbors. Jitted STANDALONE only by the fused-tail A/B control
-    (``fused_tail=False``); the production path inlines it into the single
-    fused program below."""
+    neighbors."""
     split = jax.vmap(jax.random.split)(rngs)  # [S, 2, 2]
     rngs, subs = split[:, 0], split[:, 1]
 
@@ -484,10 +472,8 @@ def _forward_only_impl(model, params, token, cache):
 
 
 def _fused_step_impl(model, sampling, params, last_logits, cache, gen_mask, rngs):
-    """One decode tick as ONE program: the sampling tail + the fused
-    forward, COMPOSED from the exact halves the defused A/B control jits
-    separately — the fused/defused bit-identity is structural, not a
-    copy-discipline promise."""
+    """One decode tick as ONE program: the sampling tail, then the fused
+    forward over the tokens it drew."""
     token, gen_mask, rngs = _sample_tail_impl(sampling, last_logits, gen_mask, rngs)
     new_logits, cache, bad = _forward_only_impl(model, params, token, cache)
     return token, new_logits, cache, gen_mask, rngs, bad
@@ -503,145 +489,36 @@ def _jit_fused_step():
 _FUSED_SHARED = _jit_fused_step()
 
 
-def _jit_defused_pair():
-    return (
-        jax.jit(_sample_tail_impl, static_argnums=(0,), donate_argnums=(2, 3)),
-        jax.jit(_forward_only_impl, static_argnums=(0,), donate_argnums=(3,)),
-    )
-
-
-_DEFUSED_SHARED = _jit_defused_pair()
-
-
-def _slice_rows(leaf, ax, offsets, length):
-    """Per-row gather of ``length`` sequence positions at each row's own
-    offset: leaf [..., S@ax, L@ax+1, ...] -> [S, ..., length, ...] (slot
-    axis moved to the front so vmap can pair rows with offsets)."""
-    v = jnp.moveaxis(leaf, ax, 0)
-    # inside the vmapped row the slot axis is gone, so the sequence axis
-    # (originally ax + 1) sits at index ax
-    return jax.vmap(
-        lambda row, o: jax.lax.dynamic_slice_in_dim(row, o, length, axis=ax)
-    )(v, offsets)
-
-
-def _write_rows(leaf, regions, ax, offsets):
-    """Inverse of ``_slice_rows``: scatter per-row regions back at each
-    row's offset and restore the original axis order."""
-    v = jnp.moveaxis(leaf, ax, 0)
-    v = jax.vmap(
-        lambda row, r, o: jax.lax.dynamic_update_slice_in_dim(row, r, o, axis=ax)
-    )(v, regions, offsets)
-    return jnp.moveaxis(v, 0, ax)
-
-
-def _chunk_prefill_impl(model, axes_items, params, cache, tokens, starts, true_lens, active):
-    """One prefill chunk for EVERY mid-prefill slot, written directly into
-    the shared slot cache — the fixed-shape [S, C] program at the heart of
-    chunked prefill + batched admission.
+def _paged_chunk_prefill_impl(
+    model, params, cache, tokens, starts, true_lens, active, table, index_after
+):
+    """One prefill chunk for EVERY mid-prefill slot, written through each
+    slot's block table into the page pool — the fixed-shape [S, C] program
+    at the heart of chunked prefill + batched admission.
 
     Per row: ``tokens`` holds the prompt window at global positions
     ``[starts, starts + C)`` (zero-padded past the prompt; the host clamps
     ``starts`` to ``cache_len - C`` and re-sends earlier tokens in the
-    window, whose K/V recompute bit-identically, so the window never
-    clamps inside ``dynamic_update_slice``). The model's per-slot decode
-    path does the rest: vector cache index = per-row write offset, per-row
-    RoPE/ALiBi positions, causal masking against ``q_offset`` so real
-    query positions never attend to the window's padded tail.
+    window, whose K/V recompute bit-identically). The model's per-slot
+    decode path does the rest: vector cache index = per-row write offset,
+    per-row RoPE/ALiBi positions, causal masking against ``q_offset`` so
+    real query positions never attend to the window's padded tail.
 
     Rows NOT mid-prefill (parked or actively decoding) ride along because
-    the program's shape is fixed: their clobbered K/V window and index
-    cursor are stashed first and restored bit-exactly after the apply, so
-    the dispatch is invisible to them. The cache argument is deliberately
-    NOT donated: on a fault the engine keeps the pre-chunk cache and fails
-    only the prefilling slots (``_on_prefill_fault``) — decode slots
-    survive untouched, at the cost of the apply writing fresh buffers.
+    the program's shape is fixed: they are routed to the TRASH page for
+    the duration of the apply (their table rows swap to zeros), so the
+    dispatch cannot touch their K/V at all, and index leaves are
+    overwritten wholesale afterwards from ``index_after`` — the host knows
+    every row's true cursor (fill for prefilling rows, prompt + emitted
+    for decoding rows, 0 for parked). ``table`` is the authoritative host
+    mirror; the apply never mutates it. The cache is deliberately NOT
+    donated: on a fault the engine keeps the pre-chunk pool and fails only
+    the prefilling slots (``_on_prefill_fault``).
 
     Returns ``(cache, last_logits)`` where ``last_logits[s]`` is the f32
     logits row at the prompt's final position — meaningful only for rows
     whose prefill completes in this chunk (``true_lens`` falls inside the
-    window); the engine installs exactly those rows.
-    """
-    axes = dict(axes_items)
-    S, C = tokens.shape
-
-    saved_regions: Dict[str, jax.Array] = {}
-    saved_index: Dict[str, jax.Array] = {}
-
-    def collect(path, leaf):
-        key = jax.tree_util.keystr(path)
-        if _leaf_name(path) in INDEX_LEAVES:
-            saved_index[key] = leaf
-        elif key in axes:
-            saved_regions[key] = _slice_rows(leaf, axes[key], starts, C)
-
-    jax.tree_util.tree_map_with_path(collect, cache)
-
-    def set_index(path, leaf):
-        if _leaf_name(path) in INDEX_LEAVES:
-            return jnp.broadcast_to(starts.astype(leaf.dtype), leaf.shape)
-        return leaf
-
-    cache = jax.tree_util.tree_map_with_path(set_index, cache)
-    logits, vars_out = model.apply(
-        {"params": params, "cache": cache}, tokens, mutable=["cache"]
-    )
-    new_cache = vars_out["cache"]
-
-    # logits at the prompt's last position, per row (clip keeps the gather
-    # in-bounds for rows whose prompt does not end in this window — their
-    # value is garbage the engine never reads)
-    last = jax.vmap(
-        lambda row, i: jax.lax.dynamic_slice_in_dim(row, i, 1, axis=0)[0]
-    )(logits, jnp.clip(true_lens - 1 - starts, 0, C - 1)).astype(jnp.float32)
-
-    new_fill = jnp.minimum(starts + C, true_lens)
-
-    def fix(path, leaf):
-        key = jax.tree_util.keystr(path)
-        if _leaf_name(path) in INDEX_LEAVES:
-            # active rows: fill cursor = min(window end, prompt length) —
-            # the padded tail of a final chunk stays outside the validity
-            # mask exactly like the legacy padded prefill. Inactive rows:
-            # their pre-chunk cursor, bit-exact. (broadcast from the right:
-            # leaf is [..., S])
-            return jnp.where(active, new_fill.astype(leaf.dtype), saved_index[key])
-        ax = axes.get(key)
-        if ax is None:
-            return leaf
-        region = _slice_rows(leaf, ax, starts, C)
-        keep = active.reshape((S,) + (1,) * (region.ndim - 1))
-        return _write_rows(
-            leaf, jnp.where(keep, region, saved_regions[key]), ax, starts
-        )
-
-    return jax.tree_util.tree_map_with_path(fix, new_cache), last
-
-
-# shared like _FUSED_SHARED: the statics (model structure, cache axes map)
-# compare equal across engines, so warmup engines pre-pay this compile too.
-# ONE compiled program per (n_slots, chunk) whatever the prompt-length mix —
-# chunked prefill has no per-length bucket family to storm.
-_CHUNK_SHARED = jax.jit(_chunk_prefill_impl, static_argnums=(0, 1))
-
-
-def _paged_chunk_prefill_impl(
-    model, params, cache, tokens, starts, true_lens, active, table, index_after
-):
-    """The paged twin of ``_chunk_prefill_impl`` — one [S, C] chunk for
-    every mid-prefill slot, writing through each slot's block table into
-    the page pool.
-
-    Paging makes the slab version's stash-and-restore dance unnecessary:
-    rows NOT mid-prefill are routed to the TRASH page for the duration of
-    the apply (their table rows swap to zeros), so the dispatch cannot
-    touch their K/V at all, and index leaves are overwritten wholesale
-    afterwards from ``index_after`` — the host knows every row's true
-    cursor (fill for prefilling rows, prompt + emitted for decoding rows,
-    0 for parked). ``table`` is the authoritative host mirror; the apply
-    never mutates it. The cache is deliberately NOT donated (same fault
-    isolation as the slab chunk: a fault keeps the pre-chunk pool and
-    fails only the prefilling slots)."""
+    window); the engine installs exactly those rows."""
     S, C = tokens.shape
 
     def pre(path, leaf):
@@ -674,6 +551,9 @@ def _paged_chunk_prefill_impl(
     return jax.tree_util.tree_map_with_path(post, new_cache), last
 
 
+# shared like _FUSED_SHARED: the static (model structure) compares equal
+# across engines, so warmup engines pre-pay this compile too. ONE compiled
+# program per (n_slots, chunk) whatever the prompt-length mix.
 _PAGED_CHUNK_SHARED = jax.jit(_paged_chunk_prefill_impl, static_argnums=(0,))
 
 
@@ -864,10 +744,10 @@ class ServingEngine:
         shed_warmup: int = 8,
         itl_decay: float = 0.9,
         chaos=None,
-        prefill_chunk: int = 0,
-        prefix_cache_chunks: int = 0,
+        prefill_chunk: int = 64,
+        prefix_cache_chunks: int = 256,
         max_prefill_buckets: int = 8,
-        kv_layout: str = "slab",
+        kv_layout: str = "paged",
         page_size: int = 16,
         page_pool_tokens: int = 0,
         draft_k: int = 0,
@@ -883,26 +763,36 @@ class ServingEngine:
         emit_buffer_max: int = 1024,
         tenant_buckets_capacity: int = 4096,
     ):
+        # Retired arguments. benchmark/drivers/serve_open_loop.py passes its
+        # traffic file's "engine" block as keywords, and
+        # benchmark/traffic/alpaca_open_poisson{,_ouro}.json carry
+        # max_prefill_buckets, kv_layout and fused_tail; only a benchmark
+        # PR may edit those files. The names are accepted and select
+        # nothing; they go when such a PR drops the three keys.
+        del max_prefill_buckets
+        if kv_layout != "paged":
+            raise ValueError(
+                f"kv_layout={kv_layout!r} was removed: the KV page pool is "
+                "the only cache layout (drop the argument)"
+            )
+        if not fused_tail:
+            raise ValueError(
+                "fused_tail=False was removed: the fused step is the only "
+                "decode program (drop the argument)"
+            )
         self.cfg = cfg
         self.cache_len = cache_len or cfg.max_seq_len
-        if prefill_chunk < 0:
-            raise ValueError("prefill_chunk must be >= 0 (0 = one-shot prefill)")
+        if prefill_chunk < 1:
+            raise ValueError(
+                "prefill_chunk must be >= 1: one-shot prefill "
+                "(prefill_chunk=0) was removed, chunked prefill is the only "
+                "admission path"
+            )
         if prefix_cache_chunks < 0:
             raise ValueError("prefix_cache_chunks must be >= 0 (0 disables)")
-        if prefix_cache_chunks > 0 and prefill_chunk == 0:
-            raise ValueError(
-                "prefix caching requires chunked prefill (prefill_chunk > 0): "
-                "entries are keyed on chunk-aligned token spans"
-            )
-        if max_prefill_buckets < 1:
-            raise ValueError("max_prefill_buckets must be >= 1")
-        # a chunk larger than the cache degenerates to one-shot-sized
-        # windows; clamp so the window math never exceeds capacity
+        # a chunk larger than the cache clamps so the window math never
+        # exceeds capacity
         self.prefill_chunk = min(prefill_chunk, self.cache_len)
-        self.max_prefill_buckets = max_prefill_buckets
-        if kv_layout not in ("slab", "paged"):
-            raise ValueError(f"kv_layout must be 'slab' or 'paged', got {kv_layout!r}")
-        self.kv_layout = kv_layout
         if draft_k < 0:
             raise ValueError("draft_k must be >= 0 (0 disables speculation)")
         if draft_k and sampling.repetition_penalty != 1.0:
@@ -914,25 +804,8 @@ class ServingEngine:
             )
         self.draft_k = int(draft_k)
         self.draft_fn = draft_fn or ngram_propose
-        # fused_tail=False is the A/B CONTROL: sampling runs as its own
-        # dispatch after the forward (the pre-kernel-lane shape) instead of
-        # inside the single decode program. Byte-identical trajectories by
-        # construction (same ops, split across two dispatches) — the bench
-        # embeds it as the no_fused_tail arm. Production stays fused.
-        self.fused_tail = bool(fused_tail)
-        if not self.fused_tail and draft_k:
-            raise ValueError(
-                "fused_tail=False (the A/B control) covers the plain decode "
-                "path only; speculative verify (draft_k > 0) is inseparable "
-                "from its in-program sampling"
-            )
         if role not in ROLES:
             raise ValueError(f"role must be one of {ROLES}, got {role!r}")
-        if role != "mixed" and kv_layout != "paged":
-            raise ValueError(
-                f"role={role!r} requires kv_layout='paged': KV pages are "
-                "the unit that ships between disaggregated replicas"
-            )
         if role == "prefill" and draft_k:
             raise ValueError(
                 "role='prefill' replicas never decode; draft_k must be 0"
@@ -945,44 +818,32 @@ class ServingEngine:
         # migration retryably (the source stream falls back to recompute).
         self.page_shipper = page_shipper
         self.page_size = int(page_size)
-        if kv_layout == "paged":
-            if self.prefill_chunk == 0:
-                raise ValueError(
-                    "kv_layout='paged' requires chunked prefill "
-                    "(prefill_chunk > 0): the one-shot insert path has no "
-                    "block-table addressing"
-                )
-            if page_size < 1:
-                raise ValueError("page_size must be >= 1")
-            if self.cache_len % page_size:
-                raise ValueError(
-                    f"page_size ({page_size}) must divide cache_len "
-                    f"({self.cache_len})"
-                )
-            if self.prefill_chunk % page_size:
-                raise ValueError(
-                    f"page_size ({page_size}) must divide prefill_chunk "
-                    f"({self.prefill_chunk}): chunk-aligned prefix sharing "
-                    "must be page-aligned so divergence starts on a page "
-                    "boundary (no live page is ever written by two rows)"
-                )
-            if page_pool_tokens == 0:
-                # slab-equivalent budget: the paged pool defaults to exactly
-                # the HBM the slab would have reserved
-                page_pool_tokens = n_slots * self.cache_len
-            if page_pool_tokens % page_size:
-                raise ValueError(
-                    f"page_pool_tokens ({page_pool_tokens}) must be a "
-                    f"multiple of page_size ({page_size})"
-                )
-            self.page_pool_tokens = int(page_pool_tokens)
-            n_pages = page_pool_tokens // page_size + 1  # + trash page
-            self.model = decode_model(
-                cfg, self.cache_len, kv_pages=(n_pages, page_size)
+        if page_size < 1:
+            raise ValueError("page_size must be >= 1")
+        if self.cache_len % page_size:
+            raise ValueError(
+                f"page_size ({page_size}) must divide cache_len "
+                f"({self.cache_len})"
             )
-        else:
-            self.page_pool_tokens = 0
-            self.model = decode_model(cfg, self.cache_len)
+        if self.prefill_chunk % page_size:
+            raise ValueError(
+                f"page_size ({page_size}) must divide prefill_chunk "
+                f"({self.prefill_chunk}): chunk-aligned prefix sharing "
+                "must be page-aligned so divergence starts on a page "
+                "boundary (no live page is ever written by two rows)"
+            )
+        if page_pool_tokens == 0:
+            page_pool_tokens = n_slots * self.cache_len
+        if page_pool_tokens % page_size:
+            raise ValueError(
+                f"page_pool_tokens ({page_pool_tokens}) must be a "
+                f"multiple of page_size ({page_size})"
+            )
+        self.page_pool_tokens = int(page_pool_tokens)
+        n_pages = page_pool_tokens // page_size + 1  # + trash page
+        self.model = decode_model(
+            cfg, self.cache_len, kv_pages=(n_pages, page_size)
+        )
         self.params = params
         self.sampling = sampling
         self.eos_token_id = eos_token_id
@@ -992,7 +853,7 @@ class ServingEngine:
         self.metrics_interval = metrics_interval
 
         self.n_slots = n_slots
-        self.slots = self._make_slots()
+        self.slots = PagedKVCache(self.model, n_slots, mesh=mesh)
         V = cfg.vocab_size
         self._last_logits = jnp.zeros((n_slots, V), jnp.float32)
         self._gen_mask = jnp.zeros((n_slots, V), jnp.bool_)
@@ -1001,15 +862,13 @@ class ServingEngine:
         # last tick, masked out of this tick's sample (-1 = none)
         self._veto = jnp.full((n_slots,), -1, jnp.int32)
         self._active: List[Optional[_ActiveSlot]] = [None] * n_slots
-        # slot -> _PrefillJob for slots mid-chunked-prefill (acquired in the
-        # SlotKVCache, not yet decoding); only the tick thread touches it
+        # slot -> _PrefillJob for slots mid-chunked-prefill (acquired, not
+        # yet decoding); only the tick thread touches it
         self._prefilling: Dict[int, _PrefillJob] = {}
         self._prefix_cache_chunks = prefix_cache_chunks
-        self._prefix_cache: Optional[PrefixCache] = self._make_prefix_cache()
-        self._chunk_fused = _CHUNK_SHARED
+        self._prefix_cache: Optional[PagedPrefixIndex] = self._make_prefix_cache()
         self._paged_chunk = _PAGED_CHUNK_SHARED
         self._spec = _SPEC_SHARED
-        self._sample_tail, self._forward_only = _DEFUSED_SHARED
         # compile-family sanitizer (analysis/runtime.py): each labeled jit
         # dispatch site declares the number of distinct cache signatures it
         # may legitimately produce over this engine's lifetime. The fixed-
@@ -1021,19 +880,17 @@ class ServingEngine:
         self._ds_decode = bounded_dispatch("engine.decode_step", 1)
         self._ds_prefill = bounded_dispatch("engine.prefill_chunk", 1)
         self._ds_spec = bounded_dispatch("engine.spec_verify", 1)
-        # kernel-lane sites (PR 11): the defused control's standalone sample
-        # dispatch, and the paged-attention kernel's per-tick signature
-        # (table/pool/offset shapes — the kernel itself runs INSIDE the
-        # decode/spec program, so this site pins the host-visible inputs
-        # that select its compiled family)
-        self._ds_sample = bounded_dispatch("engine.sample_tail", 1)
+        # the paged-attention kernel's per-tick signature (table/pool/offset
+        # shapes — the kernel itself runs INSIDE the decode/spec program, so
+        # this site pins the host-visible inputs that select its compiled
+        # family)
         self._ds_paged = bounded_dispatch("engine.paged_attention", 1)
         # is the paged-attention kernel compiled into the decode program?
         # Same gate the model consults, so the exported gauge can never
         # disagree with what actually traced.
         from zero_transformer_tpu.ops.attention import paged_kernel_supported
 
-        self._paged_kernel = kv_layout == "paged" and paged_kernel_supported(
+        self._paged_kernel = paged_kernel_supported(
             cfg.attention_impl,
             T=1 + self.draft_k if self.draft_k else 1,
             H=cfg.n_heads,
@@ -1043,11 +900,8 @@ class ServingEngine:
             page_size=self.page_size,
             dtype=resolve_dtype(cfg.compute_dtype),
         )
-        # distinct one-shot prefill bucket lengths this engine has compiled
-        # (legacy path); bounded by max_prefill_buckets + the capacity bucket
-        self._buckets_seen: set = set()
-        # did THIS tick do prefill work (chunk, span copy, or one-shot
-        # admission)? classifies the tick's ITL samples for attribution
+        # did THIS tick run a prefill chunk? classifies the tick's ITL
+        # samples for attribution
         self._prefill_work = False
 
         # disaggregation / migration state (tick thread owns placement;
@@ -1096,13 +950,6 @@ class ServingEngine:
         self._drain_deadline: Optional[float] = None
         self._drain_started: Optional[float] = None
         self.drain_latency_s: Optional[float] = None
-        # one zeroed single-row cache for the LEGACY one-shot path, built
-        # lazily on first use: prefill's apply is functional (never mutates
-        # its input), so every admission reuses this template instead of
-        # paying an eval_shape retrace + a fresh device allocation per
-        # request; the chunked path writes straight into the slot cache and
-        # never needs it
-        self._prefill_cache = None
 
         # serving counters / latency samples (host side)
         self.stats: Dict[str, Any] = {
@@ -1127,11 +974,9 @@ class ServingEngine:
             "drain_forced": 0,
             "reloads": 0,
             "reloads_rejected": 0,
-            # prefill-path counters (chunked prefill / prefix cache /
-            # legacy bucket cap)
+            # prefill-path counters (chunked prefill / prefix cache)
             "prefill_chunks": 0,
             "prefill_faults": 0,
-            "prefill_bucket_capped": 0,
             "expired_prefilling": 0,
             # paged-KV counters: allocation pressure (a page fault = the
             # pool was empty and prefix-cache pages had to be reclaimed),
@@ -1251,13 +1096,6 @@ class ServingEngine:
         self._started = self.now()
 
     # ----------------------------------------------------- device-state build
-
-    def _make_slots(self):
-        """The KV manager for the configured layout (also the rebuild path:
-        a fresh instance means a fresh pool + allocator, nothing reused)."""
-        if self.kv_layout == "paged":
-            return PagedKVCache(self.model, self.n_slots, mesh=self.mesh)
-        return SlotKVCache(self.model, self.n_slots, mesh=self.mesh)
 
     def _make_queue(self) -> ClassQueue:
         """The admission queue: per-class DWRR priced in work tokens (the
@@ -1401,17 +1239,15 @@ class ServingEngine:
             emitted=victim_emitted,
         )
 
-    def _make_prefix_cache(self) -> Optional[PrefixCache]:
-        if not (self.prefill_chunk and self._prefix_cache_chunks):
+    def _make_prefix_cache(self) -> Optional[PagedPrefixIndex]:
+        if not self._prefix_cache_chunks:
             return None
-        if self.kv_layout == "paged":
-            # page-id entries refcounted against THIS pool instance — must
-            # be rebuilt whenever the pool is (reload keeps the pool and
-            # only flushes)
-            return PagedPrefixIndex(
-                self.prefill_chunk, self._prefix_cache_chunks, self.slots.pool
-            )
-        return PrefixCache(self.prefill_chunk, self._prefix_cache_chunks)
+        # page-id entries refcounted against THIS pool instance — must be
+        # rebuilt whenever the pool is (reload keeps the pool and only
+        # flushes)
+        return PagedPrefixIndex(
+            self.prefill_chunk, self._prefix_cache_chunks, self.slots.pool
+        )
 
     def _total_need_tokens(self, request: Request) -> int:
         """Worst-case cache positions the request can ever write: prompt +
@@ -1437,7 +1273,7 @@ class ServingEngine:
                 f"prompt ({T}) + max_new_tokens ({request.max_new_tokens}) "
                 f"exceeds cache_len ({self.cache_len})"
             )
-        if self.kv_layout == "paged" and self.slots.blocks_for(
+        if self.slots.blocks_for(
             self._total_need_tokens(request)
         ) > self.slots.n_pages - 1:
             # bigger than the ENTIRE pool: admission's capacity check could
@@ -1466,8 +1302,6 @@ class ServingEngine:
             and T + request.max_new_tokens > self.cfg.max_seq_len
         ):
             return "learned positions cannot extrapolate past max_seq_len"
-        if request.prefill_to is not None and self.kv_layout != "paged":
-            return "prefill_to requires kv_layout='paged' (pages ship)"
         if self.role == "prefill" and request.prefill_to is None:
             return (
                 "this is a prefill-role replica: requests must name a "
@@ -1643,74 +1477,10 @@ class ServingEngine:
 
     @property
     def free_pages(self) -> int:
-        """Spare KV capacity, in the unit the layout allocates: free pool
-        pages when paged, free decode slots when slab. A fleet router reads
-        this from /healthz as an admission input — "how much more can this
-        replica take" — without caring which layout backs it."""
-        if self.kv_layout == "paged":
-            return self.slots.pool.free_count
-        return max(0, self.n_slots - self.active_count - len(self._prefilling))
-
-    # --------------------------------------------------------------- prefill
-
-    def _bucket(self, length: int) -> int:
-        """Smallest power-of-two >= length (floor 8) that the cache admits —
-        one compiled prefill per bucket instead of one per prompt length.
-
-        The distinct-bucket count is CAPPED (``max_prefill_buckets``): each
-        compiled bucket is a whole XLA program held for the replica's
-        lifetime, so unbounded prompt-length diversity would otherwise
-        compile-storm a long-lived server. Past the cap, new lengths round
-        UP to the smallest already-compiled bucket that fits (worst case
-        the capacity bucket — always admissible) and the event is counted
-        (``prefill_bucket_capped``) so the storm is visible in /metrics
-        instead of silent."""
-        cap = self.cache_len
-        if self.cfg.position == "learned":
-            cap = min(cap, self.cfg.max_seq_len)
-        b = 8
-        while b < length:
-            b *= 2
-        b = min(b, cap)
-        if b not in self._buckets_seen:
-            if len(self._buckets_seen) >= self.max_prefill_buckets:
-                self.stats["prefill_bucket_capped"] += 1
-                fitting = [x for x in self._buckets_seen if x >= length]
-                b = min(fitting) if fitting else cap
-            self._buckets_seen.add(b)  # cap bucket may exceed the budget by 1
-        return b
-
-    @functools.partial(jax.jit, static_argnums=(0,))
-    def _prefill_padded(model, params, padded, cache, true_len):  # noqa: N805
-        """Right-padded prefill. Causality makes K/V at positions < true_len
-        and the logits at true_len-1 exact regardless of the padding. The
-        returned cache's index leaves are whatever the padded apply left
-        (the bucket length) — ``SlotKVCache.insert`` alone owns setting the
-        slot's index to ``true_len``, so decode OVERWRITES the padded
-        garbage K/V progressively and the validity mask hides the rest."""
-        logits, vars_out = model.apply(
-            {"params": params, "cache": cache}, padded, mutable=["cache"]
-        )
-        last = jax.lax.dynamic_slice_in_dim(logits, true_len - 1, 1, axis=1)
-        return last[:, 0].astype(jnp.float32), vars_out["cache"]
-
-    def _prefill(self, prompt: Sequence[int]):
-        if self._prefill_cache is None:
-            self._prefill_cache = init_cache(self.model, 1, mesh=self.mesh)
-        T = len(prompt)
-        bucket = self._bucket(T)
-        padded = jnp.asarray(
-            [list(prompt) + [0] * (bucket - T)], jnp.int32
-        )
-        return _in_mesh(
-            self.mesh,
-            ServingEngine._prefill_padded,
-            self.model,
-            self.params,
-            padded,
-            self._prefill_cache,
-            jnp.int32(T),
-        )
+        """Spare KV capacity: free pool pages. A fleet router reads this
+        from /healthz as an admission input — "how much more can this
+        replica take"."""
+        return self.slots.pool.free_count
 
     # -------------------------------------------------------------- schedule
 
@@ -1738,29 +1508,20 @@ class ServingEngine:
         return None
 
     def _admit(self) -> None:
-        if self.prefill_chunk:
-            self._admit_chunked()
-        else:
-            self._admit_oneshot()
-
-    def _admit_chunked(self) -> None:
         """Claim a slot per admissible queued request and start its chunked
-        prefill. Prefix-cache hits land here: the slab path copies the
-        cached chunk-aligned K/V spans into the slot's rows; the PAGED path
-        just maps the cached pages into the slot's block table (refcount
-        bumps — zero K/V bytes move). Either way the chunk loop starts at
-        the first NOVEL chunk, and the chunk forwards themselves happen in
-        ``_prefill_tick``, shared across every mid-prefill slot — admission
-        of N requests is one batch, not N prefills.
+        prefill. Prefix-cache hits land here: the cached pages are mapped
+        into the slot's block table (refcount bumps — zero K/V bytes move),
+        the chunk loop starts at the first NOVEL chunk, and the chunk
+        forwards themselves happen in ``_prefill_tick``, shared across
+        every mid-prefill slot — admission of N requests is one batch, not
+        N prefills.
 
-        Paged admission is CAPACITY-CHECKED: the request's worst case
+        Admission is CAPACITY-CHECKED: the request's worst case
         (prompt + budget + draft headroom, minus whatever the hit covers)
         is reserved in the page pool up front, so an admitted stream can
         never hit a mid-decode out-of-pages fault — when the pool can't
         cover it (even after reclaiming cold prefix-cache pages), the
-        request WAITS at the queue head instead. That waiting is the
-        capacity signal the loadgen sweep measures."""
-        paged = self.kv_layout == "paged"
+        request WAITS at the queue head instead (``page_waits``)."""
         self._maybe_preempt_for_class()
         while self.slots.free_count:
             in_use = self._class_slots_in_use()
@@ -1769,7 +1530,7 @@ class ServingEngine:
             )
             if handle is None:
                 return
-            if paged and not self._paged_admission_fits(handle):
+            if not self._paged_admission_fits(handle):
                 # back at the HEAD: admission stays FIFO, and the next
                 # retirement frees the pages this request is waiting for
                 self.stats["page_waits"] += 1
@@ -1781,19 +1542,13 @@ class ServingEngine:
             try:
                 if self._prefix_cache is not None:
                     fill, hits = self._prefix_cache.lookup(handle.request.prompt)
-                    if hits and paged:
+                    if hits:
                         self.slots.share(
                             slot, [p for entry in hits for p in entry]
                         )
-                    elif hits:
-                        # all hit chunks land in one dispatch — a deep hit
-                        # must not cost one dispatch per chunk it skipped
-                        self.slots.write_spans(hits, slot)
-                        self._prefill_work = True
-                if paged:
-                    self.slots.reserve(
-                        slot, self._total_need_tokens(handle.request)
-                    )
+                self.slots.reserve(
+                    slot, self._total_need_tokens(handle.request)
+                )
             except Exception as exc:
                 # the popped handle is in neither the queue nor any slot
                 # table yet, so _abort() cannot reach it — finish it HERE
@@ -1836,81 +1591,6 @@ class ServingEngine:
             self.stats["pages_reclaimed"] += freed
         return False
 
-    def _admit_oneshot(self) -> None:
-        """Legacy one-shot path (``prefill_chunk=0``): per-request bucketed
-        prefill + cache insert, with the install dispatches for EVERYTHING
-        admitted this pass coalesced into one ``_install_rows`` call."""
-        installs: List[tuple] = []
-        try:
-            self._maybe_preempt_for_class()
-            while self.slots.free_count:
-                in_use = self._class_slots_in_use()
-                handle = self._pop_queue(
-                    eligible=lambda c: self._slot_eligible(c, in_use)
-                )
-                if handle is None:
-                    return
-                handle.admitted_at = self.now()
-                self._h_queue_wait.observe(
-                    handle.admitted_at - handle.submitted_at
-                )
-                try:
-                    logits_row, small_cache = self._prefill(handle.request.prompt)
-                    slot = self.slots.acquire()
-                    self.slots.insert(
-                        small_cache, slot, len(handle.request.prompt)
-                    )
-                except Exception as exc:
-                    # the popped handle is in neither the queue nor _active,
-                    # so _abort() cannot reach it — finish it HERE or its
-                    # client hangs forever while everyone else gets a clean
-                    # failure
-                    handle._finish(
-                        FAILED, self.now(), error=f"admission failed: {exc!r}"
-                    )
-                    raise
-                handle.status = RUNNING
-                handle.prefill_done_at = self.now()
-                self._h_prefill.observe(
-                    handle.prefill_done_at - handle.admitted_at
-                )
-                self._active[slot] = _ActiveSlot(handle)
-                installs.append(
-                    (slot, logits_row[0], jax.random.PRNGKey(handle.request.seed))
-                )
-                self.stats["peak_occupancy"] = max(
-                    self.stats["peak_occupancy"], self.active_count
-                )
-        finally:
-            # the finally matters: admissions that succeeded BEFORE a failed
-            # one must still install, or their slots decode from stale row
-            # state next tick
-            if installs:
-                self._prefill_work = True
-                self._flush_installs(installs)
-
-    def _flush_installs(self, installs: List[tuple]) -> None:
-        """One ``_install_rows`` dispatch for [(slot, logits_row, key), ...]."""
-        mask = [False] * self.n_slots
-        zero_row = jnp.zeros((self.cfg.vocab_size,), jnp.float32)
-        zero_key = jnp.zeros((2,), jnp.uint32)
-        rows = [zero_row] * self.n_slots
-        keys = [zero_key] * self.n_slots
-        for slot, row, key in installs:
-            mask[slot], rows[slot], keys[slot] = True, row, key
-        self._last_logits, self._gen_mask, self._rngs = _in_mesh(
-            self.mesh,
-            _install_rows,
-            self._last_logits,
-            self._gen_mask,
-            self._rngs,
-            jnp.asarray(mask, jnp.bool_),
-            jnp.stack(rows),
-            jnp.stack(keys),
-        )
-        if self.draft_k:
-            self._veto = jnp.where(jnp.asarray(mask, jnp.bool_), -1, self._veto)
-
     # ------------------------------------------------------- chunked prefill
 
     # graftlint: hot-path
@@ -1918,8 +1598,8 @@ class ServingEngine:
     def _prefill_tick(self) -> bool:
         """Process ONE chunk for every mid-prefill slot in a single
         fixed-shape [n_slots, chunk] dispatch, then install the slots whose
-        prompt completed (their decode starts this same tick, exactly as
-        the legacy path's would). Supervised: a fault fails ONLY the
+        prompt completed (their decode starts this same tick).
+        Supervised: a fault fails ONLY the
         prefilling slots — the chunk program does not donate the cache, so
         decoding slots keep their buffers and the tick proceeds to a
         normal fused decode."""
@@ -1927,7 +1607,6 @@ class ServingEngine:
             return False
         self._prefill_work = True
         C, L, S = self.prefill_chunk, self.cache_len, self.n_slots
-        paged = self.kv_layout == "paged"
         tokens = [[0] * C for _ in range(S)]
         starts = [0] * S
         lens = [0] * S
@@ -1939,7 +1618,7 @@ class ServingEngine:
             # ending near the cap re-sends a few earlier tokens (their K/V
             # recompute bit-identically — the forward is deterministic)
             # instead of letting the device write clamp out of alignment.
-            # (Paged: the re-sent overlap may rewrite SHARED pages — with
+            # (The re-sent overlap may rewrite SHARED pages — with
             # bit-identical values, by the same determinism argument, so no
             # copy-on-write is spent on it.)
             w = min(job.fill, L - C)
@@ -1949,7 +1628,7 @@ class ServingEngine:
             # the slot's admission reservation — stealing from already-
             # admitted neighbors and breaking the no-mid-flight-fault
             # invariant
-            if paged and not self._ensure_pages_or_reclaim(
+            if not self._ensure_pages_or_reclaim(
                 slot, min(w + C, len(prompt))
             ):
                 faulted.append(slot)
@@ -1980,36 +1659,21 @@ class ServingEngine:
                                   slots=sum(active)):
                 if self._chaos is not None:
                     self._chaos.on_prefill_chunk(self._tick)
-                if paged:
-                    chunk_args = (
-                        self.model,
-                        self.params,
-                        self.slots.cache,
-                        jnp.asarray(tokens, jnp.int32),
-                        jnp.asarray(starts, jnp.int32),
-                        jnp.asarray(lens, jnp.int32),
-                        jnp.asarray(active, jnp.bool_),
-                        jnp.asarray(self.slots.table),
-                        jnp.asarray(self._index_after(starts, lens, active), jnp.int32),
-                    )
-                    # observe skips model+params (engine-lifetime constants):
-                    # the describe walk stays O(per-tick args), not O(params)
-                    self._ds_prefill.observe(*chunk_args[2:])
-                    cache, last = _in_mesh(self.mesh, self._paged_chunk, *chunk_args)
-                else:
-                    chunk_args = (
-                        self.model,
-                        self.slots.axes_items,
-                        self.params,
-                        self.slots.cache,
-                        jnp.asarray(tokens, jnp.int32),
-                        jnp.asarray(starts, jnp.int32),
-                        jnp.asarray(lens, jnp.int32),
-                        jnp.asarray(active, jnp.bool_),
-                    )
-                    # skip model (0) + params (2); axes_items are cache statics
-                    self._ds_prefill.observe(chunk_args[1], *chunk_args[3:])
-                    cache, last = _in_mesh(self.mesh, self._chunk_fused, *chunk_args)
+                chunk_args = (
+                    self.model,
+                    self.params,
+                    self.slots.cache,
+                    jnp.asarray(tokens, jnp.int32),
+                    jnp.asarray(starts, jnp.int32),
+                    jnp.asarray(lens, jnp.int32),
+                    jnp.asarray(active, jnp.bool_),
+                    jnp.asarray(self.slots.table),
+                    jnp.asarray(self._index_after(starts, lens, active), jnp.int32),
+                )
+                # observe skips model+params (engine-lifetime constants):
+                # the describe walk stays O(per-tick args), not O(params)
+                self._ds_prefill.observe(*chunk_args[2:])
+                cache, last = _in_mesh(self.mesh, self._paged_chunk, *chunk_args)
         except CompileFamilyExceeded:
             # strict-mode sanitizer trip: the whole point is the readable
             # signature listing — it must reach the test harness, not be
@@ -2034,10 +1698,10 @@ class ServingEngine:
         return True
 
     def _index_after(self, starts, lens, active) -> List[int]:
-        """Every row's true post-chunk cursor, host-derived (the paged
-        chunk program overwrites index leaves wholesale instead of the slab
-        path's stash-and-restore): mid-prefill rows advance their fill,
-        decoding rows sit at prompt + emitted, parked rows at zero."""
+        """Every row's true post-chunk cursor, host-derived (the chunk
+        program overwrites index leaves wholesale): mid-prefill rows
+        advance their fill, decoding rows sit at prompt + emitted, parked
+        rows at zero."""
         out = [0] * self.n_slots
         C = self.prefill_chunk
         for slot in range(self.n_slots):
@@ -2176,11 +1840,10 @@ class ServingEngine:
             self._bank_prefix(slot, job.handle)
 
     def _bank_prefix(self, slot: int, handle: RequestHandle) -> None:
-        """Bank a completed prefill's chunk-aligned prefix spans so the
+        """Bank a completed prefill's chunk-aligned prefix pages so the
         NEXT prompt sharing the prefix skips them. Store BEFORE the first
         decode write (and before a handoff detaches the slot): positions
-        [0, T) are all real prompt K/V right now. Slab: one extraction
-        dispatch covers every chunk-aligned span. Paged: banking is PURE
+        [0, T) are all real prompt K/V right now. Banking is PURE
         BOOKKEEPING — the slot's pages get one more reference and their
         ids land in the index; no bytes move (the reference survives the
         slot's release, which is what lets prefill-role replicas keep a
@@ -2195,17 +1858,12 @@ class ServingEngine:
             self._prefix_cache.contains(prompt, j)
             for j in range(1, n_chunks + 1)
         ):
-            if self.kv_layout == "paged":
-                bpc = C // self.page_size  # blocks per chunk
-                pages = self.slots.bank(slot, n_chunks * bpc)
-                for j in range(1, n_chunks + 1):
-                    self._prefix_cache.store_pages(
-                        prompt, j, pages[(j - 1) * bpc : j * bpc]
-                    )
-            else:
-                spans = self.slots.extract_spans(slot, C, n_chunks)
-                for j, span in enumerate(spans, start=1):
-                    self._prefix_cache.store(prompt, j, span)
+            bpc = C // self.page_size  # blocks per chunk
+            pages = self.slots.bank(slot, n_chunks * bpc)
+            for j in range(1, n_chunks + 1):
+                self._prefix_cache.store_pages(
+                    prompt, j, pages[(j - 1) * bpc : j * bpc]
+                )
 
     def _on_prefill_fault(self, exc: Exception) -> None:
         """A chunk-prefill dispatch failed: fail ONLY the slots mid-prefill
@@ -2339,10 +1997,10 @@ class ServingEngine:
                 # second — keep its schedule out of the ring too
                 sched_span.discard()
         ran_prefill = False
-        if self.prefill_chunk and self._prefilling:
+        if self._prefilling:
             with tr.span("prefill", "engine", tick=tick_idx):
                 ran_prefill = self._prefill_tick()
-        if self.kv_layout == "paged" and self.active_count:
+        if self.active_count:
             with tr.span("grow_pages", "engine", tick=tick_idx):
                 self._grow_decode_pages()
         # an idle DEGRADED engine still runs the fused step as a self-probe
@@ -2371,13 +2029,11 @@ class ServingEngine:
         # queued requests admit on the next tick)
         try:
             self.stats["loop_passes"] += self.cfg.n_loops
-            table_pages = 0
-            if self.kv_layout == "paged":
-                table_pages = self.n_slots * self.slots.n_blocks
-                self.stats["kernel_pages_table"] += table_pages
-                self.stats["kernel_pages_live"] += sum(
-                    max(1, n) for n in self.slots.alloc_blocks
-                )
+            table_pages = self.n_slots * self.slots.n_blocks
+            self.stats["kernel_pages_table"] += table_pages
+            self.stats["kernel_pages_live"] += sum(
+                max(1, n) for n in self.slots.alloc_blocks
+            )
             # decode_step is dispatch plus the host's wait for everything
             # the device still owes this tick: the decode program AND the
             # prefill program that prefill_chunk only dispatched. It is
@@ -2386,48 +2042,41 @@ class ServingEngine:
             with tr.span("decode_step", "engine", tick=tick_idx,
                          active=self.active_count, spec=bool(self.draft_k),
                          loops=self.cfg.n_loops,
-                         pages_in_use=(
-                             self.slots.pool.in_use
-                             if self.kv_layout == "paged" else 0
-                         ),
+                         pages_in_use=self.slots.pool.in_use,
                          table_pages=table_pages):
                 if self._chaos is not None:
                     self._chaos.on_tick(self._tick)
-                if self.kv_layout == "paged":
-                    # one batched push of every block-table change this tick
-                    # (admissions, growth, retirements) before the fused step
-                    # reads the device tables
-                    self.slots.flush_tables()
+                # one batched push of every block-table change this tick
+                # (admissions, growth, retirements) before the fused step
+                # reads the device tables
+                self.slots.flush_tables()
                 if self.draft_k and self._spec_enabled:
                     blocks, n_emits, bad_rows = self._dispatch_spec(tick_idx)
                 else:
                     with tr.span("dispatch", "engine", tick=tick_idx):
-                        if self.fused_tail:
-                            fused_args = (
-                                self.model,
-                                self.sampling,
-                                self.params,
-                                self._last_logits,
-                                self.slots.cache,
-                                self._gen_mask,
-                                self._rngs,
+                        fused_args = (
+                            self.model,
+                            self.sampling,
+                            self.params,
+                            self._last_logits,
+                            self.slots.cache,
+                            self._gen_mask,
+                            self._rngs,
+                        )
+                        # skip model (0) + params (2) — engine-lifetime
+                        # constants; sampling statics + cache/logits/mask/rng
+                        # shapes remain
+                        self._ds_decode.observe(fused_args[1], *fused_args[3:])
+                        if self._paged_kernel:
+                            # the paged kernel's compiled family is selected by
+                            # the table/pool shapes inside the cache tree plus
+                            # the decode window — pin them at bound 1
+                            self._ds_paged.observe(
+                                fused_args[4], 1 + self.draft_k
                             )
-                            # skip model (0) + params (2) — engine-lifetime
-                            # constants; sampling statics + cache/logits/mask/rng
-                            # shapes remain
-                            self._ds_decode.observe(fused_args[1], *fused_args[3:])
-                            if self._paged_kernel:
-                                # the paged kernel's compiled family is selected by
-                                # the table/pool shapes inside the cache tree plus
-                                # the decode window — pin them at bound 1
-                                self._ds_paged.observe(
-                                    fused_args[4], 1 + self.draft_k
-                                )
-                            token, self._last_logits, self.slots.cache, self._gen_mask, self._rngs, bad = _in_mesh(
-                                self.mesh, self._fused, *fused_args
-                            )
-                        else:
-                            token, bad = self._dispatch_defused()
+                        token, self._last_logits, self.slots.cache, self._gen_mask, self._rngs, bad = _in_mesh(
+                            self.mesh, self._fused, *fused_args
+                        )
                         if self._chaos is not None:
                             # injected NaNs land AFTER the step, so re-run the same
                             # predicate over the poisoned logits — injected and organic
@@ -2471,7 +2120,6 @@ class ServingEngine:
             ttft_new: List[tuple] = []  # (sample_s, qos_class)
             itl_new: List[tuple] = []
             tokens_before = self.stats["tokens_out"]
-            paged_ledger = self.kv_layout == "paged"
             for slot, act in enumerate(self._active):
                 if act is None:
                     continue
@@ -2479,12 +2127,11 @@ class ServingEngine:
                 toks = blocks[slot][: n_emits[slot]]
                 # cost ledger: one decode tick held, at this slot's current KV
                 # page footprint (pages x ticks is the capacity-time integral a
-                # tenant actually consumed; slab slots have no page unit — 0)
+                # tenant actually consumed)
                 act.handle.ledger["decode_ticks"] += 1
-                if paged_ledger:
-                    act.handle.ledger["pages_held_ticks"] += (
-                        self.slots.alloc_blocks[slot]
-                    )
+                act.handle.ledger["pages_held_ticks"] += (
+                    self.slots.alloc_blocks[slot]
+                )
                 if act.emitted == 0:
                     ttft_new.append((now - act.handle.submitted_at, qos_cls))
                 elif act.last_emit_at is not None:
@@ -2560,9 +2207,8 @@ class ServingEngine:
                 self._h_itl.observe(sample)
                 self._h_itl_class[cls].observe(sample)
                 if not self._prefill_work:
-                    # per-phase attribution: this tick ran no prefill work
-                    # (chunk, span copy, or one-shot admission), so these
-                    # samples are the pure-decode ITL floor
+                    # per-phase attribution: this tick ran no prefill
+                    # chunk, so these samples are the pure-decode ITL floor
                     self._h_itl_decode.observe(sample)
                 self._itl_ewma.update(sample)
             self._retire(finished)
@@ -2653,28 +2299,6 @@ class ServingEngine:
                 n_emits[slot] = 1 + acc
         return blocks, n_emits, bad_rows
 
-    # graftlint: hot-path
-    def _dispatch_defused(self):
-        """The fused-tail A/B CONTROL (``fused_tail=False``): the same tick
-        math as the fused step, split into a standalone sample dispatch and
-        a forward-only dispatch — what every token cost before sampling
-        moved into the decode program. Trajectories stay byte-identical to
-        the fused path (identical ops, identical rng split order); only the
-        dispatch count (and the [S] token round-trip between the two
-        programs) differs, which is exactly what the bench's
-        ``no_fused_tail`` arm prices."""
-        tail_args = (self.sampling, self._last_logits, self._gen_mask, self._rngs)
-        self._ds_sample.observe(*tail_args)
-        token, self._gen_mask, self._rngs = _in_mesh(
-            self.mesh, self._sample_tail, *tail_args
-        )
-        fwd_args = (self.model, self.params, token, self.slots.cache)
-        self._ds_decode.observe(fwd_args[2], fwd_args[3])
-        self._last_logits, self.slots.cache, bad = _in_mesh(
-            self.mesh, self._forward_only, *fwd_args
-        )
-        return token, bad
-
     # ------------------------------------- transferable streams (migration)
 
     @property
@@ -2685,12 +2309,8 @@ class ServingEngine:
     def request_migration(self, request_id: str, target: str) -> bool:
         """Ask the tick thread to migrate the live stream ``request_id`` to
         ``target`` (a replica base URL). Thread-safe; returns False when no
-        live stream carries that id (the caller maps it to 404) or when
-        this engine has nothing transferable (slab layout — a 202 here
-        would promise a migration that can never be serviced). The export
+        live stream carries that id (the caller maps it to 404). The export
         itself happens between ticks — device state stays tick-thread-owned."""
-        if self.kv_layout != "paged":
-            return False
         # snapshot under the GIL (list() of a dict/list is one C-level op)
         # — the tick thread mutates both containers concurrently, and bare
         # iteration from this HTTP thread could see "changed size"
@@ -2707,11 +2327,7 @@ class ServingEngine:
 
     def request_migrate_all(self, target: str) -> int:
         """Migrate EVERY live stream to ``target`` (scale-down / drain
-        upgrade). Returns how many streams were tagged (0 on a slab
-        engine: pages are the transfer unit, so there is nothing to ship
-        and the caller's classic drain covers it)."""
-        if self.kv_layout != "paged":
-            return 0
+        upgrade). Returns how many streams were tagged."""
         n = sum(1 for a in list(self._active) if a is not None) + len(
             self._prefilling
         )
@@ -2728,8 +2344,6 @@ class ServingEngine:
         ship acknowledges — success finishes it ``migrated`` (the router
         attaches at the target, zero tokens replayed), failure finishes it
         retryably (the router falls back to re-dispatch-and-recompute)."""
-        if self.kv_layout != "paged":
-            return
         with self._lock:
             reqs, self._migrate_requests = self._migrate_requests, {}
         if not reqs:
@@ -2988,11 +2602,6 @@ class ServingEngine:
             handle._finish(
                 REJECTED, now,
                 error="prefill-role replica cannot import streams",
-            )
-            return handle
-        if self.kv_layout != "paged":
-            handle._finish(
-                REJECTED, now, error="import requires kv_layout='paged'",
             )
             return handle
         if int(payload.get("draft_k", 0)) != self.draft_k:
@@ -3256,7 +2865,7 @@ class ServingEngine:
             # over self.slots.cache, whose buffers the faulted (donating)
             # call may have deleted, re-raising INSIDE the fault handler and
             # killing the scheduler; _rebuild_device_state below replaces
-            # the whole SlotKVCache (free list included) instead
+            # the whole PagedKVCache (free list included) instead
             self._active[slot] = None
         # mid-prefill slots die with the tick too: the rebuild below
         # replaces the cache tree their half-filled rows live in (the
@@ -3304,7 +2913,6 @@ class ServingEngine:
             # is the same executable family — swap it with its twin)
             self._fused = _jit_fused_step()
             self._spec = _jit_spec_step()
-            self._sample_tail, self._forward_only = _jit_defused_pair()
         # device buffers are suspect after EVERY fused-call fault, threshold
         # or not: the step donates logits/cache/masks/rngs, so an exception
         # after dispatch leaves them deleted or half-written — reusing them
@@ -3315,10 +2923,10 @@ class ServingEngine:
     def _rebuild_device_state(self) -> None:
         """Reallocate every device buffer the tick thread owns; nothing from
         a suspect tick is reused. Host state (queue, stats, lifecycle) and
-        params are untouched. Paged: a fresh ``PagedKVCache`` means a fresh
-        page pool AND a fresh allocator/refcount state — the pool
-        reinitializes wholesale, never patched."""
-        self.slots = self._make_slots()
+        params are untouched. A fresh ``PagedKVCache`` means a fresh page
+        pool AND a fresh allocator/refcount state — the pool reinitializes
+        wholesale, never patched."""
+        self.slots = PagedKVCache(self.model, self.n_slots, mesh=self.mesh)
         V = self.cfg.vocab_size
         self._last_logits = jnp.zeros((self.n_slots, V), jnp.float32)
         self._gen_mask = jnp.zeros((self.n_slots, V), jnp.bool_)
@@ -3326,17 +2934,13 @@ class ServingEngine:
         self._veto = jnp.full((self.n_slots,), -1, jnp.int32)
         self._active = [None] * self.n_slots
         self._prefilling.clear()
-        self._prefill_cache = None  # legacy template reallocates lazily
         if self._prefix_cache is not None:
             # conservative: cached entries trace to earlier, clean ticks,
             # but re-deriving which survived a faulted tick is not worth
             # wrong K/V if the reasoning ever rots — cold misses rebuild
-            # the cache. Paged: the old index refcounts into the DEAD pool;
+            # the cache. The old index refcounts into the DEAD pool;
             # rebuild it against the fresh one instead of flushing into it.
-            if self.kv_layout == "paged":
-                self._prefix_cache = self._make_prefix_cache()
-            else:
-                self._prefix_cache.flush()
+            self._prefix_cache = self._make_prefix_cache()
         self._event("engine_rebuilt")
 
     # ----------------------------------------------------------------- drain
@@ -3519,15 +3123,14 @@ class ServingEngine:
         for slot, job in self._prefilling.items():
             job.fill = 0
             job.handle.prefix_hit_tokens = 0
-            if self.kv_layout == "paged":
-                # the slot may map SHARED pages from its pre-reload prefix
-                # hit; re-prefilling under the new weights must not write
-                # into pages other slots still read — drop every page and
-                # refill fresh (the full worst case re-reserves)
-                self.slots.reset_slot_pages(slot)
-                self.slots.reserve(
-                    slot, self._total_need_tokens(job.handle.request)
-                )
+            # the slot may map SHARED pages from its pre-reload prefix
+            # hit; re-prefilling under the new weights must not write
+            # into pages other slots still read — drop every page and
+            # refill fresh (the full worst case re-reserves)
+            self.slots.reset_slot_pages(slot)
+            self.slots.reserve(
+                slot, self._total_need_tokens(job.handle.request)
+            )
         swap_event.set()
         self._event("reload_swapped", reloads=self.stats["reloads"])
 
@@ -3623,25 +3226,15 @@ class ServingEngine:
             "uptime_s": self.lifecycle.uptime_s,
             "breaker_open": self._breaker.open,
             "itl_ewma_ms": (self._itl_ewma.value or 0.0) * 1e3,
-            # prefill-path visibility: the chunk budget in force, how many
-            # slots are mid-prefill, and the compiled one-shot bucket count
-            # (the compile-storm gauge the bucket cap bounds)
+            # prefill-path visibility: the chunk budget in force and how
+            # many slots are mid-prefill
             "prefill_chunk": self.prefill_chunk,
             "prefilling": len(self._prefilling),
-            "prefill_buckets": len(self._buckets_seen),
-            # paged-KV + speculation gauges (zeros when the feature is off,
-            # so dashboards and the bench schema stay layout-agnostic)
-            "kv_layout": self.kv_layout,
+            # page-pool + speculation gauges
             "draft_k": self.draft_k,
-            "page_pool_util": (
-                self.slots.page_pool_util if self.kv_layout == "paged" else 0.0
-            ),
-            "page_pool_peak": (
-                self.slots.pool.peak_in_use if self.kv_layout == "paged" else 0
-            ),
-            "cow_copies": (
-                self.slots.cow_copies if self.kv_layout == "paged" else 0
-            ),
+            "page_pool_util": self.slots.page_pool_util,
+            "page_pool_peak": self.slots.pool.peak_in_use,
+            "cow_copies": self.slots.cow_copies,
             # what ONE cached position costs in every K/V entry (a looped
             # stack keeps n_loops a layer): the number to size
             # page_pool_tokens with
@@ -3651,12 +3244,9 @@ class ServingEngine:
                 if self.stats["draft_tokens"]
                 else 0.0
             ),
-            # kernel-lane gauges (PR 11): is the paged-attention kernel
-            # compiled into the decode program (vs the gather-to-slab
-            # fallback), and is the sampling tail fused (vs the A/B
-            # control's split dispatches)?
+            # is the paged-attention kernel compiled into the decode
+            # program (vs the gather fallback)?
             "kernel_paged_attention": int(self._paged_kernel),
-            "fused_tail": int(self.fused_tail),
             # disaggregation / migration gauges
             "role": self.role,
             "free_pages": self.free_pages,
@@ -3667,7 +3257,7 @@ class ServingEngine:
         # labeled dispatch site vs its declared bound; a nonzero violation
         # count is the "serving got slow" compile-storm smoking gun
         for site in (self._ds_decode, self._ds_prefill, self._ds_spec,
-                     self._ds_sample, self._ds_paged):
+                     self._ds_paged):
             short = site.name.rsplit(".", 1)[-1]
             snap[f"dispatch_{short}_signatures"] = site.distinct
             snap[f"dispatch_{short}_violations"] = site.violations
@@ -3695,8 +3285,7 @@ class ServingEngine:
             "peak_occupancy", "peak_queue_depth",
             "tick_faults", "poisoned_slots", "breaker_trips", "shed_infeasible",
             "rejected_draining", "drain_forced", "reloads", "reloads_rejected",
-            "prefill_chunks", "prefill_faults", "prefill_bucket_capped",
-            "expired_prefilling",
+            "prefill_chunks", "prefill_faults", "expired_prefilling",
             "page_faults", "pages_reclaimed", "preemptions",
             "page_waits", "loop_passes",
             "kernel_pages_live", "kernel_pages_table",
@@ -3742,7 +3331,6 @@ class ServingEngine:
             ("reloads_rejected", "Hot weight reloads rejected"),
             ("prefill_chunks", "Chunk-prefill row dispatches"),
             ("prefill_faults", "Supervised chunk-prefill faults"),
-            ("prefill_bucket_capped", "One-shot prefill bucket-cap events"),
             ("page_faults", "Page-pool exhaustions that reclaimed prefix pages"),
             ("pages_reclaimed", "Prefix-cache pages reclaimed under pressure"),
             ("preemptions", "Requests preempted for KV pages (last resort)"),
@@ -3810,28 +3398,20 @@ class ServingEngine:
             lambda: self._itl_ewma.value or 0.0,
         )
         reg.gauge_func(
-            "serve_prefill_buckets", "Compiled one-shot prefill buckets",
-            lambda: len(self._buckets_seen),
-        )
-        reg.gauge_func(
-            "serve_page_pool_util", "Paged-KV pool utilization (0 when slab)",
-            lambda: (
-                self.slots.page_pool_util if self.kv_layout == "paged" else 0.0
-            ),
+            "serve_page_pool_util", "KV page pool utilization",
+            lambda: self.slots.page_pool_util,
         )
         # page-pool pressure as first-class scrape families (pre-PR12 a
         # router could only see free_pages by polling /healthz)
         reg.gauge_func(
             "serve_free_pages",
-            "Spare KV capacity (free pool pages, or free slots when slab)",
+            "Spare KV capacity (free pool pages)",
             lambda: self.free_pages,
         )
         reg.counter_func(
             "serve_cow_copies",
             "Copy-on-write page copies (shared page written post-import/share)",
-            lambda: (
-                self.slots.cow_copies if self.kv_layout == "paged" else 0
-            ),
+            lambda: self.slots.cow_copies,
         )
         reg.gauge_func(
             "serve_migrations_in_flight",

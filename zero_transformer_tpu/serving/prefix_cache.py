@@ -1,4 +1,5 @@
-"""Chunk-aligned token-prefix K/V cache (vLLM-style block hashing).
+"""Chunk-aligned token-prefix index over KV page ids (vLLM-style block
+hashing).
 
 Shared-prefix traffic — N personas behind one system prompt, retried
 requests, agent loops replaying a conversation head — re-pays prefill for
@@ -12,46 +13,56 @@ prompt skip straight to its first novel chunk:
   *contents* but never chunk *K/V* unless the whole prefix matches. This is
   exactly vLLM's prefix/block hash. Exact tuple keys (not a digest) mean a
   hash collision can never serve wrong K/V.
-- **Value**: the per-layer K/V span for that chunk's positions
-  (``SlotKVCache.extract_span`` — int8 scale leaves included), copied OUT
-  of a slot row when a prefill completes and back IN on a later hit.
-  Deterministic forward ⇒ reused spans are bit-identical to recomputation,
-  so prefix hits preserve the engine's byte-identical parity contract.
+- **Value**: the tuple of pool pages holding that chunk's K/V (int8 scale
+  leaves ride the same pages), not a copy of the bytes. ``store_pages``
+  records pages already refcount-bumped by ``PagedKVCache.bank`` — no
+  device work; a hit hands them to ``PagedKVCache.share``, which maps them
+  into the new slot's block table and bumps refcounts. Deterministic
+  forward ⇒ reused pages are bit-identical to recomputation, so prefix
+  hits preserve the engine's byte-identical parity contract.
 - **Hit walk**: ``lookup`` extends the match one chunk at a time and stops
   strictly BEFORE the prompt's final token (``j * chunk < len(prompt)``):
   the last chunk is always recomputed, because the admission needs the
-  logits at ``true_len - 1`` and spans store K/V only.
+  logits at ``true_len - 1`` and pages store K/V only.
+- **Eviction / flush** drop the index's reference through the pool: a page
+  still mapped by a live slot survives until its last reference.
+  ``reclaim(n)`` frees at least ``n`` pages for an allocation that found
+  the pool exhausted — the page-fault path the engine counts.
 - **Invalidation**: ``flush()`` on hot weight reload (new weights make
-  every cached span stale) and on device-state rebuild after a tick fault
-  (the buffers are suspect). The engine owns calling it.
+  every cached page stale); after a tick fault the engine rebuilds the
+  index against its fresh pool.
 
-Host-side bookkeeping only; the device copies happen in the engine's jitted
-span ops. Not thread-safe by itself — only the scheduler tick thread touches
-it (admission and completion both run inside ``step()``).
+Host-side bookkeeping only. Not thread-safe by itself — only the scheduler
+tick thread touches it (admission and completion both run inside
+``step()``).
 """
 from __future__ import annotations
 
 from collections import OrderedDict
-from typing import Any, Dict, List, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
+
+Pages = Tuple[int, ...]
 
 
-class PrefixCache:
-    """LRU of chunk-aligned prefix K/V spans.
+class PagedPrefixIndex:
+    """LRU of chunk-aligned prefixes, each entry the pages of one chunk.
 
     ``capacity`` counts CHUNK ENTRIES (each worth ``chunk_tokens`` cache
-    positions of K/V per layer), so the device memory the cache pins is
-    bounded at ``capacity * chunk_tokens`` positions regardless of how many
-    distinct prompts pass through.
+    positions of K/V), so the pool pages the index pins are bounded at
+    ``capacity * chunk_tokens`` positions regardless of how many distinct
+    prompts pass through. ``pool`` is the ``PagePool`` whose refcounts the
+    entries hold.
     """
 
-    def __init__(self, chunk_tokens: int, capacity: int):
+    def __init__(self, chunk_tokens: int, capacity: int, pool):
         if chunk_tokens < 1:
             raise ValueError("chunk_tokens must be >= 1")
         if capacity < 1:
             raise ValueError("capacity must be >= 1 (0 disables at the engine)")
         self.chunk_tokens = chunk_tokens
         self.capacity = capacity
-        self._entries: "OrderedDict[Tuple[int, ...], Any]" = OrderedDict()
+        self._pool = pool
+        self._entries: "OrderedDict[Tuple[int, ...], Pages]" = OrderedDict()
         # cached DEEPER chunks per entry: an entry with live children is
         # never evicted (its children would become unreachable dead weight —
         # the hit walk stops at the first absent chunk), so eviction takes
@@ -89,49 +100,50 @@ class PrefixCache:
             else:
                 self._children.pop(parent, None)
 
-    def _evict_one(self):
-        """Pop the least-recently-used LEAF entry (no cached deeper chunk
-        depends on it). Evicting a mid-chain entry would orphan its
-        descendants: still resident, never again reachable by the hit walk —
-        the whole-prefix-eviction bug this ordering exists to fix."""
+    def _evict_one(self) -> None:
+        """Drop the least-recently-used LEAF entry (no cached deeper chunk
+        depends on it) and its page references. Evicting a mid-chain entry
+        would orphan its descendants: still resident, never again reachable
+        by the hit walk — the whole-prefix-eviction bug this ordering
+        exists to fix."""
         victim = next(
             (k for k in self._entries if not self._children.get(k)),
             next(iter(self._entries)),  # cycle-free tree: always has a leaf
         )
-        return self._pop_entry(victim)
+        self._pool.decref(self._pop_entry(victim))
 
-    def _pop_entry(self, victim: Tuple[int, ...]):
-        value = self._entries.pop(victim)
+    def _pop_entry(self, victim: Tuple[int, ...]) -> Pages:
+        pages = self._entries.pop(victim)
         self._unlink(victim)
         self.evictions += 1
-        return victim, value
+        return pages
 
-    def lookup(self, prompt: Sequence[int]) -> Tuple[int, List[Any]]:
+    def lookup(self, prompt: Sequence[int]) -> Tuple[int, List[Pages]]:
         """Longest chunk-aligned cached prefix of ``prompt``.
 
-        Returns ``(tokens_covered, spans)`` where ``spans[i]`` is chunk
-        ``i+1``'s K/V span; every covered chunk counts a hit and every
+        Returns ``(tokens_covered, entries)`` where ``entries[i]`` is chunk
+        ``i+1``'s pages; every covered chunk counts a hit and every
         remaining chunk-aligned chunk (still ending before the final token)
         counts a miss. The walk stops at the first absent chunk — a cached
         DEEPER chunk is unusable without its predecessors' K/V in the row.
         """
         C = self.chunk_tokens
-        fill, spans = self.walk(prompt)
-        for j in range(1, len(spans) + 1):
+        fill, entries = self.walk(prompt)
+        for j in range(1, len(entries) + 1):
             self._entries.move_to_end(self._key(prompt, j))
-        self.hits += len(spans)
-        j = len(spans) + 1
+        self.hits += len(entries)
+        j = len(entries) + 1
         while j * C < len(prompt):
             self.misses += 1
             j += 1
-        return fill, spans
+        return fill, entries
 
-    def walk(self, prompt: Sequence[int]) -> Tuple[int, List[Any]]:
+    def walk(self, prompt: Sequence[int]) -> Tuple[int, List[Pages]]:
         """The hit walk WITHOUT stats or recency side effects — capacity
-        planning (the paged admission sizes its page reservation before
-        committing to the hit, and must not count the same hit twice)."""
+        planning (admission sizes its page reservation before committing
+        to the hit, and must not count the same hit twice)."""
         C = self.chunk_tokens
-        vals: List[Any] = []
+        vals: List[Pages] = []
         j = 1
         while j * C < len(prompt):
             v = self._entries.get(self._key(prompt, j))
@@ -144,79 +156,22 @@ class PrefixCache:
     def contains(self, prompt: Sequence[int], j: int) -> bool:
         return self._key(prompt, j) in self._entries
 
-    def store(self, prompt: Sequence[int], j: int, span: Any) -> None:
-        """Insert chunk ``j`` (1-based) of ``prompt``'s prefix; evicts LRU
-        entries past capacity. Re-storing an existing key just refreshes
-        its recency (the spans are bit-identical by construction)."""
-        key = self._key(prompt, j)
-        if key in self._entries:
-            self._entries.move_to_end(key)
-            return
-        self._entries[key] = span
-        self._link(key)
-        self.stores += 1
-        while len(self._entries) > self.capacity:
-            self._evict_one()
-
-    def flush(self) -> int:
-        """Drop every entry (hot reload / device rebuild); returns how many."""
-        n = len(self._entries)
-        self._entries.clear()
-        self._children.clear()
-        return n
-
-    def stats(self) -> Dict[str, float]:
-        total = self.hits + self.misses
-        return {
-            "prefix_hits": self.hits,
-            "prefix_misses": self.misses,
-            "prefix_stores": self.stores,
-            "prefix_evictions": self.evictions,
-            "prefix_entries": len(self._entries),
-            "prefix_hit_rate": (self.hits / total) if total else 0.0,
-        }
-
-
-class PagedPrefixIndex(PrefixCache):
-    """Prefix cache over PAGE IDS (the paged-KV unification): an entry's
-    value is the tuple of pool pages holding that chunk's K/V, not a copy
-    of the bytes.
-
-    - **store** records the pages (already refcount-bumped by
-      ``PagedKVCache.bank``) — no extraction dispatch, no device copy;
-    - **a hit** hands the pages to ``PagedKVCache.share``, which maps them
-      into the new slot's block table and bumps refcounts — reuse without
-      moving a byte;
-    - **eviction / flush** drop the index's reference through the pool:
-      a page still mapped by a live slot (or, impossible by key-scheme but
-      guarded anyway, another entry) survives until its last reference —
-      the refcount-aware eviction the slab-era LRU lacked;
-    - **reclaim(n)** frees at least ``n`` pages for an allocation that
-      found the pool exhausted, evicting least-recent leaf entries first —
-      the page-fault path the engine counts.
-
-    Same key scheme, hit walk, children-aware LRU order, and stats surface
-    as ``PrefixCache``.
-    """
-
-    def __init__(self, chunk_tokens: int, capacity: int, pool):
-        super().__init__(chunk_tokens, capacity)
-        self._pool = pool
-
-    def _evict_one(self):
-        key, pages = super()._evict_one()
-        self._pool.decref(pages)
-        return key, pages
-
     def store_pages(self, prompt: Sequence[int], j: int, pages) -> None:
-        """Insert chunk ``j``'s pages; a duplicate store returns the extra
-        references immediately (one index hold per page, ever)."""
+        """Insert chunk ``j`` (1-based) of ``prompt``'s prefix as ``pages``
+        (already refcount-bumped by ``bank``); evicts LRU entries past
+        capacity. A duplicate store refreshes the entry's recency and
+        returns the extra references immediately (one index hold per page,
+        ever)."""
         key = self._key(prompt, j)
         if key in self._entries:
             self._entries.move_to_end(key)
             self._pool.decref(pages)  # bank() bumped; the entry already holds
             return
-        self.store(prompt, j, tuple(pages))
+        self._entries[key] = tuple(pages)
+        self._link(key)
+        self.stores += 1
+        while len(self._entries) > self.capacity:
+            self._evict_one()
 
     def reclaim(self, n_pages: int) -> int:
         """Evict entries until >= ``n_pages`` pages came FREE (refcount
@@ -239,11 +194,26 @@ class PagedPrefixIndex(PrefixCache):
             )
             if victim is None:
                 break
-            _, pages = self._pop_entry(victim)
-            freed += self._pool.decref(pages)
+            freed += self._pool.decref(self._pop_entry(victim))
         return freed
 
     def flush(self) -> int:
+        """Drop every entry and its page references (hot reload); returns
+        how many entries went."""
         for pages in self._entries.values():
             self._pool.decref(pages)
-        return super().flush()
+        n = len(self._entries)
+        self._entries.clear()
+        self._children.clear()
+        return n
+
+    def stats(self) -> Dict[str, float]:
+        total = self.hits + self.misses
+        return {
+            "prefix_hits": self.hits,
+            "prefix_misses": self.misses,
+            "prefix_stores": self.stores,
+            "prefix_evictions": self.evictions,
+            "prefix_entries": len(self._entries),
+            "prefix_hit_rate": (self.hits / total) if total else 0.0,
+        }

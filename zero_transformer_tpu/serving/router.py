@@ -154,9 +154,9 @@ class Replica:
     migrations_in_flight: int = 0
     page_faults: int = 0
     cow_copies: int = 0
-    # importability: pages can only ship to a paged-layout engine; "" until
-    # the first successful probe (treated as NOT importable — never ship
-    # into the unknown). ``draft_k`` rides along because an import's
+    # importability: /healthz of an engine says "paged"; "" until the
+    # first successful probe (treated as NOT importable — never ship into
+    # the unknown). ``draft_k`` rides along because an import's
     # veto/rewind carry is draft_k-shaped — a mismatched target rejects
     # every ship, so placement filters on it up front.
     kv_layout: str = ""
@@ -1459,11 +1459,11 @@ class RouterServer:
         most free_pages, then lowest ITL EWMA (both scraped on /healthz)."""
         reps = self.registry.routable()
         prefills = [r for r in reps if r.role == "prefill"]
-        # pages can only land on a paged-layout engine with a MATCHING
-        # draft_k (prefill replicas never speculate, so their handoffs
-        # carry draft_k 0): a slab or speculative replica in the fleet
-        # must not silently turn every handoff into a failed ship +
-        # recompute fallback
+        # pages can only land on an engine whose probe has answered, with a
+        # MATCHING draft_k (prefill replicas never speculate, so their
+        # handoffs carry draft_k 0): an unprobed or speculative replica in
+        # the fleet must not silently turn every handoff into a failed
+        # ship + recompute fallback
         decodes = [
             r for r in reps
             if r.role != "prefill" and r.importable and r.draft_k == 0

@@ -21,8 +21,8 @@ CUDA+gradio app (reference ``app.py``). Endpoints:
 - ``GET /metrics``: content-negotiated. The default stays the JSON snapshot
   (TTFT/ITL percentiles — with a pure-decode ``itl_decode_ms_*`` split
   isolating chunked-prefill interference — tokens/s, rejects, prefix-cache
-  hit/miss/entry counters, compiled prefill-bucket gauge, resilience
-  counters); an ``Accept`` header naming ``text/plain`` or ``openmetrics``
+  hit/miss/entry counters, resilience counters); an ``Accept`` header
+  naming ``text/plain`` or ``openmetrics``
   (what a Prometheus scraper sends), or ``?format=prometheus``, gets the
   text exposition format backed by the engine's fixed-bucket histograms —
   O(buckets) per scrape, never the tick lock (docs/OBSERVABILITY.md).
@@ -356,14 +356,13 @@ class ServingServer:
             # page-pool pressure stats ride along so the router can mirror
             # them as per-replica gauges without a /metrics scrape
             "role": self.engine.role,
-            "kv_layout": self.engine.kv_layout,
+            # a constant: the router ships pages only to a replica whose
+            # probe says so (``Replica.importable``), never into the unknown
+            "kv_layout": "paged",
             "draft_k": self.engine.draft_k,
             "migrations_in_flight": self.engine.migrations_in_flight,
             "page_faults": self.engine.stats["page_faults"],
-            "cow_copies": (
-                self.engine.slots.cow_copies
-                if self.engine.kv_layout == "paged" else 0
-            ),
+            "cow_copies": self.engine.slots.cow_copies,
             # overload-isolation inputs (ISSUE 18): the fleet brownout
             # controller reads the rung it last pushed back off the same
             # poll (convergence check), and per-class queue depths let the

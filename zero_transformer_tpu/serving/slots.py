@@ -1,11 +1,11 @@
-"""Slot-based KV-cache manager for continuous batching.
+"""Paged KV-cache manager for continuous batching.
 
-One fixed ``[n_slots, cache_len]`` decode cache (allocated through
-``inference.init_cache`` — int8-KV aware, optionally tensor-sharded) whose
-rows are SLOTS: a request is prefetched into a fresh single-row cache, then
-copied into a free slot with ``lax.dynamic_update_slice``; from then on every
-scheduler tick runs ONE fused decode step over all slots. The piece that
-makes rows independent is the cache index: ``init_cache`` gives the scalar
+K/V lives in ONE global page pool (allocated through
+``inference.init_cache`` on a ``kv_pages`` decode model — int8-KV aware,
+optionally tensor-sharded); a SLOT is a row of the per-slot block table
+that maps its logical positions to pool pages, and every scheduler tick
+runs ONE fused decode step over all slots. The piece that makes rows
+independent is the cache index: ``init_cache`` gives the scalar
 ``cache_index``/``decode_pos`` the single-request paths use, and
 ``vectorize_index`` widens it to a per-slot ``[n_slots]`` vector — the
 model's decode path (``models.gpt.Attention``) sees a vector index and
@@ -13,8 +13,9 @@ switches every position-dependent computation (writes, validity mask, RoPE /
 ALiBi / causal biases) to per-row form.
 
 Jit-signature stability invariant: every device function here is traced for
-ONE shape — the full ``[n_slots, ...]`` cache with dynamic slot/length
-scalars — so admissions, retirements, and occupancy changes never recompile.
+ONE shape — the full pool with dynamic slot/length scalars (page spans:
+one per power-of-two page count) — so admissions, retirements, and occupancy
+changes never recompile.
 """
 from __future__ import annotations
 
@@ -79,74 +80,6 @@ def vectorize_index(cache: Any, n_slots: int) -> Any:
     return jax.tree_util.tree_map_with_path(widen, cache)
 
 
-# ---- token-span ops (chunked prefill + prefix cache) -----------------------
-#
-# Every K/V leaf (and int8 scale leaf) is laid out [..., n_slots, cache_len,
-# ...]: the sequence axis sits immediately after the slot axis in every
-# layout this repo produces (per-layer [B, L, KVH, D], scanned
-# [n_layers, B, L, KVH, D], a looped model's pass axis in front of the slot
-# axis, scales [..., KVH, 1]) — asserted at SlotKVCache
-# construction so a future layout change fails loudly instead of silently
-# copying the wrong axis. ``axes_items`` (the per-leaf slot-axis map as a
-# sorted tuple) is a STATIC argument: one compiled program per cache
-# structure, shared across engines, with slot/start as dynamic scalars.
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _extract_spans_impl(axes_items, length, count, cache, slot):
-    """Copy ``count`` consecutive ``length``-position spans of one slot's
-    K/V rows out of the cache in ONE dispatch: a list of
-    {leaf path -> [..., 1, length, ...]} trees, span ``j`` covering
-    positions ``[j*length, (j+1)*length)``. Batching the spans matters:
-    per-span dispatches put the prefix-cache STORE cost (paid by every
-    cold shared-prefix request at completion) on the tick thread's
-    critical path once per chunk instead of once per request."""
-    axes = dict(axes_items)
-    spans: list = [{} for _ in range(count)]
-
-    def grab(path, leaf):
-        key = jax.tree_util.keystr(path)
-        ax = axes.get(key)
-        if ax is None or _leaf_name(path) in INDEX_LEAVES:
-            return
-        for j in range(count):
-            starts = [0] * leaf.ndim
-            starts[ax], starts[ax + 1] = slot, j * length
-            sizes = list(leaf.shape)
-            sizes[ax], sizes[ax + 1] = 1, length
-            spans[j][key] = jax.lax.dynamic_slice(
-                leaf, tuple(starts), tuple(sizes)
-            )
-
-    jax.tree_util.tree_map_with_path(grab, cache)
-    return spans
-
-
-@functools.partial(jax.jit, static_argnums=(0,))
-def _write_spans_impl(axes_items, cache, spans, slot):
-    """Write extracted spans back into one slot's rows, span ``j`` at its
-    chunk-aligned position, all in ONE dispatch (the prefix-cache HIT
-    path). Index leaves are untouched — the prefill scheduler owns the
-    fill cursor; a span copy only moves K/V bytes."""
-    axes = dict(axes_items)
-
-    def put(path, leaf):
-        key = jax.tree_util.keystr(path)
-        if not spans or key not in spans[0]:
-            return leaf
-        ax = axes[key]
-        length = spans[0][key].shape[ax + 1]
-        for j, span in enumerate(spans):
-            starts = [0] * leaf.ndim
-            starts[ax], starts[ax + 1] = slot, j * length
-            leaf = jax.lax.dynamic_update_slice(
-                leaf, span[key].astype(leaf.dtype), tuple(starts)
-            )
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(put, cache)
-
-
 def _update_index(cache: Any, update, *args) -> Any:
     """Run the jitted ``update(index_leaves, *args)`` over the cache's
     ``INDEX_LEAVES`` and graft the result back into the tree. Every other
@@ -165,184 +98,21 @@ def _update_index(cache: Any, update, *args) -> Any:
 def _reset_index(index_leaves: List[jax.Array], keep: jax.Array) -> List[jax.Array]:
     """Zero the positions of retired slots (``keep`` [n_slots] bool). K/V
     rows are left in place — the validity mask (positions < index) already
-    excludes them, and the next insert overwrites the row."""
+    excludes them."""
     # keep broadcasts from the right
     return [jnp.where(keep, leaf, 0) for leaf in index_leaves]
 
 
-class SlotKVCache:
-    """Owns the engine's fixed-shape cache + host-side slot bookkeeping.
-
-    Device state: ``self.cache`` (the [n_slots, cache_len] tree, vector
-    index). Host state: which slots are free. The manager is not thread-safe
-    by itself — the engine serializes access from its scheduler loop.
-    """
-
-    def __init__(self, model, n_slots: int, mesh=None):
-        if n_slots < 1:
-            raise ValueError("n_slots must be >= 1")
-        self.model = model
-        self.n_slots = n_slots
-        self.mesh = mesh
-        self.cache = vectorize_index(
-            init_cache(model, n_slots, mesh=mesh), n_slots
-        )
-        self._free: List[int] = list(range(n_slots))
-        self._axes = self._find_batch_axes(model)
-        self._insert = self._build_insert()
-        # span ops assume [slot, seq] adjacency on every per-position leaf
-        # (see _extract_span_impl); verify against the real cache once here
-        cap = model.cache_len or model.cfg.max_seq_len
-        self.seq_capacity = cap
-        for path, leaf in jax.tree_util.tree_leaves_with_path(self.cache):
-            ax = self._axes.get(jax.tree_util.keystr(path))
-            if ax is not None and (
-                leaf.shape[ax] != n_slots or leaf.shape[ax + 1] != cap
-            ):
-                raise AssertionError(
-                    f"cache leaf {jax.tree_util.keystr(path)} breaks the "
-                    f"[slots, cache_len] adjacency span ops rely on: shape "
-                    f"{leaf.shape}, slot axis {ax}"
-                )
-
-    @property
-    def axes_items(self) -> Tuple:
-        """Per-leaf slot-axis map as a hashable (static-arg) tuple."""
-        return tuple(sorted(self._axes.items()))
-
-    # ---- token-span ops --------------------------------------------------
-
-    def _quantized_count(self, length: int, count: int) -> int:
-        """Span counts are STATIC in the compiled span ops, so every
-        distinct count is a whole compiled program traversing the cache
-        tree — an unbounded family under diverse prompt lengths (the same
-        storm the engine's prefill-bucket cap exists for). Quantize to the
-        next power of two (capped at capacity), bounding the family at
-        ~log2(capacity / chunk) programs per direction."""
-        cap = max(1, self.seq_capacity // length)
-        b = 1
-        while b < count:
-            b *= 2
-        return min(b, cap)
-
-    def extract_spans(self, slot: int, length: int, count: int) -> List[Any]:
-        """Copy the first ``count`` consecutive ``length``-position spans of
-        ``slot`` in one dispatch (prefix-cache store). Extraction is padded
-        to the quantized count; the extra spans are sliced off host-side."""
-        padded = self._quantized_count(length, count)
-        spans = _extract_spans_impl(
-            self.axes_items, length, padded, self.cache, jnp.int32(slot)
-        )
-        return spans[:count]
-
-    def write_spans(self, spans: List[Any], slot: int) -> None:
-        """Write extracted spans into ``slot`` at their chunk-aligned
-        positions, one dispatch (prefix-cache hit). The fill cursor stays
-        with the caller. Padding spans (the quantized tail, repeats of the
-        first span) land at positions >= the caller's fill cursor: the
-        validity mask hides everything at or past the cursor, and the
-        chunk prefill / decode writes overwrite those positions with real
-        K/V before the cursor ever reaches them."""
-        if not spans:
-            return
-        key, leaf = next(iter(spans[0].items()))
-        length = leaf.shape[self._axes[key] + 1]
-        padded = self._quantized_count(length, len(spans))
-        full = list(spans) + [spans[0]] * (padded - len(spans))
-        self.cache = _write_spans_impl(
-            self.axes_items, self.cache, full, jnp.int32(slot)
-        )
-
-    @staticmethod
-    def _find_batch_axes(model) -> Dict[str, int]:
-        """Per-leaf batch-axis index, found by diffing the cache structure
-        for batch=1 vs batch=2 — shape-sniffing a single structure would
-        misread layouts where the slot count collides with another dim
-        (n_layers == n_slots under the scanned stack). Index leaves don't
-        scale with batch (scalar per layer) and get no entry — insert
-        handles them by name."""
-        one = jax.tree_util.tree_leaves_with_path(_cache_struct(model, 1))
-        two = jax.tree_util.tree_leaves_with_path(_cache_struct(model, 2))
-        axes: Dict[str, int] = {}
-        for (path, a), (path2, b) in zip(one, two):
-            assert path == path2, "cache structure must not depend on batch"
-            diff = [i for i, (x, y) in enumerate(zip(a.shape, b.shape)) if x != y]
-            if diff:
-                axes[jax.tree_util.keystr(path)] = diff[0]
-        return axes
-
-    def _build_insert(self):
-        axes = self._axes
-
-        @jax.jit
-        def insert(big, small, slot, true_len):
-            def upd(path, b, s):
-                if _leaf_name(path) in INDEX_LEAVES:
-                    # set [..., slot] = true_len
-                    block = jnp.full(b.shape[:-1] + (1,), true_len, b.dtype)
-                    starts = (0,) * (b.ndim - 1) + (slot,)
-                    return jax.lax.dynamic_update_slice(b, block, starts)
-                ax = axes.get(jax.tree_util.keystr(path))
-                if ax is None:
-                    # leaf does not scale with batch and is not an index —
-                    # shared state; keep the engine's copy
-                    return b
-                starts = [0] * b.ndim
-                starts[ax] = slot
-                return jax.lax.dynamic_update_slice(
-                    b, s.astype(b.dtype), tuple(starts)
-                )
-
-            return jax.tree_util.tree_map_with_path(upd, big, small)
-
-        return insert
-
-    # ---- slot bookkeeping ------------------------------------------------
-
-    @property
-    def free_count(self) -> int:
-        return len(self._free)
-
-    @property
-    def active_count(self) -> int:
-        return self.n_slots - len(self._free)
-
-    def acquire(self) -> Optional[int]:
-        """Claim a free slot index, or None when fully occupied."""
-        return self._free.pop(0) if self._free else None
-
-    def insert(self, small_cache: Any, slot: int, true_len: int) -> None:
-        """Copy a prefilled single-row cache into ``slot`` and set its
-        position to ``true_len`` (the PROMPT length, not the padded prefill
-        length — decode overwrites any padded tail progressively)."""
-        self.cache = self._insert(
-            self.cache, small_cache, jnp.int32(slot), jnp.int32(true_len)
-        )
-
-    def release(self, slots: List[int]) -> None:
-        """Retire slots: free them and zero their positions so a parked row
-        never walks its index toward the capacity poison guard."""
-        if not slots:
-            return
-        for s in slots:
-            if s in self._free:
-                raise ValueError(f"slot {s} double-released")
-            self._free.append(s)
-        keep = jnp.asarray(
-            [s not in self._free for s in range(self.n_slots)], jnp.bool_
-        )
-        self.cache = _update_index(self.cache, _reset_index, keep)
-
-
 # ---- paged KV cache (block tables over a global page pool) -----------------
 #
-# The slab above reserves n_slots * cache_len positions of K/V whatever the
-# actual sequence lengths; the paged layout below reserves only the pages a
-# sequence really fills (PagedAttention, Kwon et al. 2309.06180). Pages are
-# REFCOUNTED: a slot mapping a page holds one reference and the paged prefix
-# index holds another per cached chunk, so a prefix hit is a refcount bump
-# into the new slot's block table — zero K/V bytes move — and nothing frees
-# a page while any live slot or cached prefix still maps it.
+# A fixed [n_slots, cache_len] cache would reserve n_slots * cache_len
+# positions of K/V whatever the actual sequence lengths; the page pool
+# reserves only the pages a sequence really fills (PagedAttention, Kwon et
+# al. 2309.06180). Pages are REFCOUNTED: a slot mapping a page holds one
+# reference and the prefix index holds another per cached chunk, so a prefix
+# hit is a refcount bump into the new slot's block table — zero K/V bytes
+# move — and nothing frees a page while any live slot or cached prefix still
+# maps it.
 
 
 # ---- transferable page spans (disaggregated prefill/decode + migration) ----
@@ -353,7 +123,7 @@ class SlotKVCache:
 # replicas — a prefill replica ships finished spans to a decode replica,
 # and live migration ships a mid-stream slot's span to its new home. The
 # gather/scatter programs are compiled per QUANTIZED page count (power of
-# two, same discipline as the span ops above) so diverse sequence lengths
+# two) so diverse sequence lengths
 # cannot compile-storm a long-lived replica; padding routes through the
 # trash page (gather pads are sliced off host-side, scatter pads write
 # garbage into page 0, which nothing ever reads).
@@ -592,9 +362,10 @@ class PagePool:
 
 
 class PagedKVCache:
-    """Paged drop-in for ``SlotKVCache``: same slot bookkeeping surface
-    (acquire / release / free_count / insert-less chunked fill), but K/V
-    lives in the model's page pool and each slot's rows are a block table.
+    """Owns the engine's page pool + host-side slot and page bookkeeping:
+    K/V lives in the model's page pool and each slot's rows are a block
+    table (acquire / release / free_count for slots; reserve / ensure /
+    share / bank / cow for pages).
 
     Device state: ``self.cache`` (pool leaves + ``block_table`` + vector
     index leaves). Host state: the authoritative block-table mirror
@@ -887,7 +658,7 @@ class PagedKVCache:
         self.alloc_blocks[slot] = 0
         self.tables_dirty = True
 
-    # ---- slot bookkeeping (SlotKVCache-compatible surface) ---------------
+    # ---- slot bookkeeping -------------------------------------------------
 
     @property
     def free_count(self) -> int:
