@@ -214,12 +214,15 @@ def _cfg_580m_cut(int8: bool, scan: bool):
     )
 
 
-def _serving_programs(one_chip, monkeypatch, cfg, cache_len=CACHE_LEN, n_pages=N_PAGES):
+def _serving_programs(one_chip, monkeypatch, cfg, cache_len=CACHE_LEN, n_pages=N_PAGES,
+                      held=False):
     """``cfg`` at a benchmark cell's engine shapes — 16 slots, page 16,
     chunk 64, the cell's cache length and pool — as the engine's own jitted
-    decode step and paged chunk prefill, compiled for the described chip.
-    Returns their optimised HLO and the number of pool leaves."""
-    from zero_transformer_tpu.inference.generate import decode_model
+    decode step and paged chunk prefill, compiled for the described chip,
+    from the tree a checkpoint gives or (``held``) from the serving form
+    the engine holds of it. Returns their optimised HLO and the number of
+    pool leaves."""
+    from zero_transformer_tpu.inference.generate import decode_model, serving_params
     from zero_transformer_tpu.inference.sampling import SamplingConfig
     from zero_transformer_tpu.serving import engine as eng
     from zero_transformer_tpu.serving.slots import (
@@ -242,7 +245,10 @@ def _serving_programs(one_chip, monkeypatch, cfg, cache_len=CACHE_LEN, n_pages=N
     )
     from zero_transformer_tpu.parallel.sharding import unbox
 
-    params = on_chip(unbox(shapes["params"]))
+    params = unbox(shapes["params"])
+    if held:
+        params = jax.eval_shape(lambda p: serving_params(model, p), params)
+    params = on_chip(params)
     cache = on_chip(jax.eval_shape(
         lambda: vectorize_index(
             jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
@@ -307,6 +313,45 @@ def test_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch, int8, 
     on_kv = [op for op, _, kv, _ in _pool_ops(prefill) if kv and op not in PREFETCH]
     assert set(on_kv) <= {"scatter", "copy", "copy-start"}, on_kv
     assert len(on_kv) - on_kv.count("scatter") == n_kv, on_kv
+
+
+def _weight_reads(hlo: str, cfg):
+    """(float32 entry parameters of a weight's shape — a scanned stack's
+    norm scales [L, d] are rank 2 and are not — and the shapes of bf16
+    values of a weight's shape — a whole stack, a layer's slice or
+    the embedding table — that a conversion makes, anywhere in the
+    program). The compiled text gives no operand types, so a conversion is
+    known by its name (``convert.N``, ``convert_element_type.N``) and a
+    weight by its shape, which no activation shares."""
+    import re
+
+    d, f = str(cfg.d_model), str(cfg.ff_dim)
+    pairs = {(d, d), (d, f), (f, d), (str(cfg.vocab_size), d)}
+    entry = hlo.split("\nENTRY ", 1)[1]
+    params = re.findall(r"%(params\w*)\S* = f32\[(\d+(?:,\d+)+)\]\S* parameter\(", entry)
+    made = re.findall(r"%convert[\w.\-]* = bf16\[(\d+(?:,\d+)+)\]", hlo)
+    weight = lambda dims: tuple(dims.split(",")[-2:]) in pairs  # noqa: E731
+    return [p for p in params if weight(p[1])], [dims for dims in made if weight(dims)]
+
+
+@pytest.mark.parametrize("scan", [True, False], ids=["scan", "unrolled"])
+def test_serving_programs_multiply_the_weights_as_held(one_chip, monkeypatch, scan):
+    """The counter of "the engine holds the weights in the dtype the model
+    multiplies in": from the serving form, the 580M cell's fused step and
+    chunk-prefill program take no float32 weight matrix as an argument and
+    convert none to bf16 (the float32 parameters left are the norm
+    scales). From the checkpoint's own float32 tree — what the engine
+    dispatched until PR 29 — every program of every tick holds one
+    conversion per weight, which is what the same search finds."""
+    cfg = _cfg_580m_cut(False, scan)
+    decode, prefill, _ = _serving_programs(one_chip, monkeypatch, cfg, held=True)
+    for hlo in (decode, prefill):
+        assert _weight_reads(hlo, cfg) == ([], [])
+    decode, prefill, _ = _serving_programs(one_chip, monkeypatch, cfg)
+    for hlo in (decode, prefill):
+        params, converted = _weight_reads(hlo, cfg)
+        assert len(params) >= 7, params  # wte + q, k, v, out, wi, wo
+        assert len(converted) >= 7, converted
 
 
 LOOP_CACHE_LEN, LOOP_POOL_TOKENS = 512, 2560

@@ -213,7 +213,8 @@ def test_empty_spins_and_idle_stay_out_of_the_ring(cfg, params, tmp_path):
         stop.set()
         thread.join(timeout=30)
         jax.profiler.stop_trace()
-    assert [s for s in engine.tracer.by_track("engine")] == []
+    # (what the ring holds is the constructor's one span, made before run())
+    assert [s[NAME] for s in engine.tracer.by_track("engine")] == ["prepare_weights"]
     names = {n for n, *_ in host_events(tmp_path)}
     assert {"engine/tick", "engine/schedule", "engine/idle"} <= names
 

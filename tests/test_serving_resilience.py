@@ -36,6 +36,7 @@ from zero_transformer_tpu.config import model_config
 from zero_transformer_tpu.inference.generate import decode_model, generate
 from zero_transformer_tpu.inference.sampling import SamplingConfig
 from zero_transformer_tpu.models import Transformer
+from zero_transformer_tpu.obs.spans import NAME
 from zero_transformer_tpu.serving import (
     DEGRADED,
     DRAINING,
@@ -395,6 +396,96 @@ def test_reload_rejects_mismatched_and_corrupt(cfg, params, reference):
     finally:
         stop.set()
         thread.join(timeout=30)
+
+
+@pytest.fixture(scope="module")
+def cfg_bf16():
+    """float32 weights multiplied in bfloat16, as ``serve`` loads a
+    training checkpoint: the engine holds the matmul leaves converted."""
+    return model_config("test", dropout=0.0, compute_dtype="bfloat16")
+
+
+def _bytes(tree, dtype=None):
+    return sum(
+        x.nbytes for x in jax.tree.leaves(tree) if dtype is None or x.dtype == dtype
+    )
+
+
+def test_float32_checkpoint_reloads_into_an_engine_that_holds_bfloat16(
+    cfg_bf16, params, params2
+):
+    """The reload is validated against the SOURCE form the engine was built
+    from, not against what it holds: a float32 artifact swaps in, is held
+    converted like the tree it replaces, the in-flight stream survives, and
+    a fresh stream equals ``generate()`` under the new float32 weights."""
+    engine = make_engine(cfg_bf16, params, n_slots=1)
+    held = jax.tree.leaves(engine.params)
+    assert {x.dtype for x in held} == {jnp.dtype("bfloat16"), jnp.dtype("float32")}
+    mid = engine.submit([1, 2], max_new_tokens=10, seed=0)
+    for _ in range(3):
+        engine.step()
+    assert mid.status == "running"
+    engine.reload_params(params2)
+    engine.run_until_idle()
+    assert mid.status == "done" and len(mid.tokens) == 10
+    assert engine.stats["reloads"] == 1 and engine.stats["reloads_rejected"] == 0
+    assert [x.dtype for x in jax.tree.leaves(engine.params)] == [x.dtype for x in held]
+    fresh = engine.submit([5, 6, 7], max_new_tokens=8, seed=3)
+    engine.run_until_idle()
+    model = decode_model(cfg_bf16, CACHE_LEN)
+
+    def want(p):
+        toks = generate(
+            model, p, jnp.asarray([[5, 6, 7]], jnp.int32), 8,
+            jax.random.PRNGKey(3), SAMPLING,
+        )
+        return jax.device_get(toks)[0].tolist()
+
+    assert fresh.tokens == want(params2)
+    assert fresh.tokens != want(params)  # weights really swapped
+
+
+def test_reload_refuses_a_candidate_of_another_source_dtype(cfg_bf16, params):
+    """What the engine HOLDS is no valid artifact: a tree already rounded to
+    bfloat16 differs from the float32 source form and is refused, the first
+    differing leaf named."""
+    engine = make_engine(cfg_bf16, params, n_slots=1)
+    rounded = jax.tree.map(lambda x: x.astype(jnp.bfloat16), params)
+    with pytest.raises(ReloadError) as err:
+        engine.reload_params(rounded)
+    assert "['blocks']['attn']['key']['kernel']" in str(err.value)
+    assert "float32" in str(err.value) and "bfloat16" in str(err.value)
+    with pytest.raises(ReloadError, match="mismatch"):
+        engine.reload_params(engine.params)
+    assert engine.stats["reloads_rejected"] == 2 and engine.stats["reloads"] == 0
+
+
+def test_prepare_weights_span_and_byte_gauges(cfg, cfg_bf16, params, params2):
+    """``engine/prepare_weights`` is in the ring at start and at each
+    reload; ``metrics_snapshot()`` says what is held and how much of it a
+    conversion made (nothing, for a model served in its compute dtype:
+    the engine then holds the very arrays it was given)."""
+    engine = make_engine(cfg_bf16, params, n_slots=1)
+
+    def spans():
+        return [s for s in engine.tracer.by_track("engine") if s[NAME] == "prepare_weights"]
+
+    assert len(spans()) == 1
+    snap = engine.metrics_snapshot()
+    assert snap["weights_bytes_held"] == _bytes(engine.params)
+    assert snap["weights_bytes_converted_at_load"] == _bytes(engine.params, jnp.bfloat16)
+    assert 0 < snap["weights_bytes_converted_at_load"] < snap["weights_bytes_held"]
+    assert snap["weights_bytes_held"] < _bytes(params)
+    engine.reload_params(params2)
+    assert len(spans()) == 2  # made on the reload's thread, before the swap
+    engine.step()
+    assert engine.metrics_snapshot()["weights_bytes_held"] == snap["weights_bytes_held"]
+
+    same = make_engine(cfg, params, n_slots=1)  # float32 compute: nothing to convert
+    assert same.metrics_snapshot()["weights_bytes_converted_at_load"] == 0
+    assert same.metrics_snapshot()["weights_bytes_held"] == _bytes(params)
+    for a, b in zip(jax.tree.leaves(same.params), jax.tree.leaves(params)):
+        assert a is b
 
 
 def test_chaos_corrupt_reload_artifact_rejected(cfg, params, params2):

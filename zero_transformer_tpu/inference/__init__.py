@@ -12,6 +12,7 @@ from zero_transformer_tpu.inference.generate import (
     init_cache,
     prefill,
     serve_mesh,
+    serving_params,
     shard_for_inference,
     stream_tokens,
 )
@@ -37,6 +38,7 @@ __all__ = [
     "process_logits",
     "sample_token",
     "serve_mesh",
+    "serving_params",
     "shard_for_inference",
     "stream_tokens",
     "top_k_filter",

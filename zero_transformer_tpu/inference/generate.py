@@ -21,8 +21,9 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
 
-from zero_transformer_tpu.config import ModelConfig
+from zero_transformer_tpu.config import ModelConfig, resolve_dtype
 from zero_transformer_tpu.inference.sampling import SamplingConfig, sample_token
 from zero_transformer_tpu.models.gpt import Transformer
 
@@ -47,6 +48,94 @@ def decode_model(cfg: ModelConfig, cache_len: int, kv_pages=None) -> Transformer
             )
         kv_pages = (int(n_pages), int(page))
     return Transformer(cfg, decode=True, cache_len=cache_len, kv_pages=kv_pages)
+
+
+def _call_body(eqn):
+    """The jaxpr that a call-like equation (jit, scan, remat, custom_jvp)
+    runs on its operands one to one; None for every other equation."""
+    bodies = [
+        v for v in eqn.params.values()
+        if isinstance(v, (jex_core.Jaxpr, jex_core.ClosedJaxpr))
+    ]
+    if len(bodies) != 1:
+        return None
+    body = getattr(bodies[0], "jaxpr", bodies[0])
+    return body if len(body.invars) == len(eqn.invars) else None
+
+
+def _read_only_through_cast(jaxpr, var, dtype) -> bool:
+    """Is every read of ``var`` in ``jaxpr`` a ``convert_element_type`` to
+    ``dtype``? Call-like equations are followed into their bodies; any
+    other reader, and a value that leaves the jaxpr, says no."""
+    if any(v is var for v in jaxpr.outvars):
+        return False
+    read = False
+    for eqn in jaxpr.eqns:
+        for i, v in enumerate(eqn.invars):
+            if v is not var:
+                continue
+            read = True
+            if (
+                eqn.primitive.name == "convert_element_type"
+                and eqn.params["new_dtype"] == dtype
+            ):
+                continue
+            body = _call_body(eqn)
+            if body is None or not _read_only_through_cast(
+                body, body.invars[i], dtype
+            ):
+                return False
+    return read
+
+
+@functools.partial(jax.jit, static_argnames="dtype")
+def _cast_leaves(leaves, dtype):
+    return [x.astype(dtype) for x in leaves]
+
+
+def serving_params(model: Transformer, params: Any) -> Any:
+    """``params`` in the form a server holds them: every leaf that the
+    forward only ever reads through a cast to ``cfg.compute_dtype`` (matmul
+    kernels, embedding tables, the untied head, MoE expert weights, the
+    int8 kernels' scales) is that cast's result, made here once in place of
+    once a program. The programs then multiply the very same roundings of
+    the very same weights: logits are bit-equal.
+
+    Which leaves those are is read off the model's own forward (its jaxpr),
+    not off their names. A leaf the model reads in its stored dtype stays
+    THE SAME ARRAY: norm scales (multiplied in float32), the exit gate (a
+    float32 Dense), the MoE router (float32 logits) — rounding those would
+    be a lower precision than the configuration states — and so does one
+    the cast would widen (int8 payloads: the narrow form is the point).
+    A tree already in the compute dtype is returned as it is: no trace, no
+    program, no copy. The conversion is one jitted elementwise call over
+    the converted leaves: shardings and flax ``Partitioned`` boxes pass
+    through, and a warm start reads it from the compile cache."""
+    dtype = jnp.dtype(resolve_dtype(model.cfg.compute_dtype))
+    flat, treedef = jax.tree_util.tree_flatten(params)
+    candidates = [
+        i for i, x in enumerate(flat)
+        if x.dtype != dtype and dtype.itemsize <= x.dtype.itemsize
+    ]
+    if not candidates:
+        return params
+    from zero_transformer_tpu.utils.jax_compat import clear_abstract_mesh
+
+    # the ambient mesh is cleared as for init_cache: flax's boxes would
+    # read the params' logical axis names as mesh axes
+    with clear_abstract_mesh():
+        forward = jax.make_jaxpr(
+            lambda p: model.apply(
+                {"params": p}, jnp.zeros((1, 1), jnp.int32), mutable=["cache"]
+            )
+        )(params).jaxpr
+    cast = [
+        i for i in candidates
+        if _read_only_through_cast(forward, forward.invars[i], dtype)
+    ]
+    for i, x in zip(cast, _cast_leaves([flat[i] for i in cast], dtype=dtype)):
+        flat[i] = x
+    return jax.tree_util.tree_unflatten(treedef, flat)
 
 
 def serve_mesh(tensor: int):

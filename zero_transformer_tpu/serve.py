@@ -406,6 +406,9 @@ def _server(gen: TextGenerator, args) -> None:
         obs_dir=args.obs_dir or args.metrics_dir,
         trace=not args.no_trace,
     )
+    # the engine holds the weights in their serving form; the loaded tree
+    # (a float32 checkpoint: twice those bytes) has no other reader
+    gen.params = None
     run_server(
         engine, gen.tokenizer, host=args.host, port=args.port,
         reload_source=_reload_loader(gen, args),

@@ -90,7 +90,7 @@ import time
 import uuid
 from collections import deque
 from pathlib import Path
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -110,7 +110,11 @@ from zero_transformer_tpu.obs import (
     hbm_device_stats,
 )
 
-from zero_transformer_tpu.inference.generate import _in_mesh, decode_model
+from zero_transformer_tpu.inference.generate import (
+    _in_mesh,
+    decode_model,
+    serving_params,
+)
 from zero_transformer_tpu.inference.sampling import (
     NEG_INF,
     SamplingConfig,
@@ -844,7 +848,6 @@ class ServingEngine:
         self.model = decode_model(
             cfg, self.cache_len, kv_pages=(n_pages, page_size)
         )
-        self.params = params
         self.sampling = sampling
         self.eos_token_id = eos_token_id
         self.mesh = mesh
@@ -944,7 +947,8 @@ class ServingEngine:
         self._itl_ewma = ItlEwma(decay=itl_decay, warmup=shed_warmup)
         self._chaos = chaos
         self._fused = _FUSED_SHARED  # swapped for a private jit on rebuild
-        # staged by reload_params as (tree, swap-event); swapped at tick
+        # staged by reload_params as (serving tree, its byte gauges,
+        # swap-event); swapped at tick
         self._pending_params = None
         self._last_reload_event: Optional[threading.Event] = None
         self._drain_deadline: Optional[float] = None
@@ -1042,6 +1046,14 @@ class ServingEngine:
             tracer=self.tracer, clock=clock,
         )
         self._profiler = ProfileWindow(self.obs_dir, prefix="serve")
+        # The engine holds the SERVING form of the weights
+        # (inference.serving_params), never the tree it was given: what a
+        # reload is validated against is the source form's shapes and
+        # dtypes, no bytes.
+        self._source_spec = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params
+        )
+        self.params, self._weights_bytes = self._prepare_weights(params)
         self._h_ttft = self.registry.histogram(
             "serve_ttft_seconds",
             "Submit-to-first-token latency (queue wait included)",
@@ -3040,6 +3052,21 @@ class ServingEngine:
 
     # ------------------------------------------------------------ hot reload
 
+    def _prepare_weights(self, tree) -> Tuple[Any, Dict[str, int]]:
+        """``tree`` in its serving form, and the two gauges that say what
+        that took: the bytes now held, and how many of them are the result
+        of a conversion (0 for a model stored in its compute dtype). Runs
+        at start and on the reload thread, never on the tick thread."""
+        with self.tracer.span("prepare_weights", "engine"):
+            held = jax.block_until_ready(serving_params(self.model, tree))
+        src, out = jax.tree.leaves(tree), jax.tree.leaves(held)
+        return held, {
+            "weights_bytes_held": sum(x.nbytes for x in out),
+            "weights_bytes_converted_at_load": sum(
+                x.nbytes for x, was in zip(out, src) if x is not was
+            ),
+        }
+
     def reload_params(self, source) -> Dict[str, Any]:
         """Stage a standby param tree and swap it in between ticks — no slot
         is retired; in-flight generations continue on the new weights from
@@ -3047,15 +3074,18 @@ class ServingEngine:
 
         ``source`` is a param tree or a zero-arg callable returning one
         (e.g. a lambda over ``checkpoint.import_params_msgpack``). Called
-        OFF the tick thread (HTTP handler, SIGHUP thread): the load and the
-        eval_shape validation happen here; the tick thread only flips a
-        reference. A corrupt or mismatched artifact raises ``ReloadError``
-        and the engine keeps serving the old weights, READY throughout."""
+        OFF the tick thread (HTTP handler, SIGHUP thread): the load, the
+        eval_shape validation against the SOURCE form the engine was built
+        from (so a float32 checkpoint reloads into an engine that holds
+        bfloat16) and the conversion to the serving form happen here; the
+        tick thread only flips a reference. A corrupt or mismatched
+        artifact raises ``ReloadError`` and the engine keeps serving the
+        old weights, READY throughout."""
         try:
             tree = source() if callable(source) else source
             if self._chaos is not None:
                 tree = self._chaos.corrupt_reload(tree)
-            validate_reload(self.params, tree)
+            validate_reload(self._source_spec, tree)
             tree = jax.tree.map(jnp.asarray, tree)
             # runtime-owned buffers before the swap: msgpack/orbax restores
             # and device_put can hand back zero-copy host views, and a
@@ -3065,7 +3095,7 @@ class ServingEngine:
             # exactly as serve.py does at startup.
             from zero_transformer_tpu.utils.jax_compat import ensure_donatable
 
-            tree = ensure_donatable(tree)
+            staged = self._prepare_weights(ensure_donatable(tree))
         except ReloadError as exc:
             self.stats["reloads_rejected"] += 1
             self._event("reload_rejected", error=str(exc))
@@ -3085,7 +3115,7 @@ class ServingEngine:
             # a superseded (staged-but-unswapped) predecessor never serves:
             # its event stays unset and its caller truthfully gets "staged,
             # not swapped" rather than credit for a swap that was B's
-            self._pending_params = (tree, swap_event)
+            self._pending_params = staged + (swap_event,)
             self._last_reload_event = swap_event
         return {
             "staged": True,
@@ -3101,7 +3131,7 @@ class ServingEngine:
             pending, self._pending_params = self._pending_params, None
         if pending is None:
             return
-        self.params, swap_event = pending
+        self.params, self._weights_bytes, swap_event = pending
         self.stats["reloads"] += 1
         if self._prefix_cache is not None:
             # invalidation-on-reload: cached K/V spans embody the OLD
@@ -3239,6 +3269,9 @@ class ServingEngine:
             # stack keeps n_loops a layer): the number to size
             # page_pool_tokens with
             "kv_bytes_per_token": kv_bytes_per_token(self.cfg),
+            # the weights as held (the serving form) and how many of those
+            # bytes a conversion at load made
+            **self._weights_bytes,
             "acceptance_rate": (
                 self.stats["accepted_tokens"] / self.stats["draft_tokens"]
                 if self.stats["draft_tokens"]

@@ -211,7 +211,8 @@ class ReloadError(RuntimeError):
 
 def validate_reload(current: Any, candidate: Any) -> None:
     """Reject a candidate param tree whose structure, shapes, or dtypes
-    differ from the serving tree (``jax.eval_shape``-level check: metadata
+    differ from ``current``: the tree the engine was built from, as arrays
+    or as the ``ShapeDtypeStruct`` tree the engine keeps of it (metadata
     only, nothing materializes). Raises ``ReloadError`` naming the first
     mismatch.
 
