@@ -12,7 +12,6 @@ tokens.
 """
 from __future__ import annotations
 
-import dataclasses
 import gc
 import shutil
 import sys
@@ -21,7 +20,7 @@ import time
 
 import numpy as np
 
-from benchmark import arith, harness, traffic, weights
+from benchmark import arith, harness, host_trace, traffic, weights
 from benchmark import trace as trace_mod
 
 
@@ -97,15 +96,11 @@ class Client:
 
 
 def build_engine(cell, params, out_dir):
-    from zero_transformer_tpu.config import ModelConfig
     from zero_transformer_tpu.inference import SamplingConfig
     from zero_transformer_tpu.serving import ServingEngine
 
     mix = cell["traffic"]
-    m = dict(cell["config"]["model"], **mix.get("model_overrides", {}))
-    fields = {f.name for f in dataclasses.fields(ModelConfig)}
-    cfg = ModelConfig(name=cell["config"]["name"],
-                      **{k: v for k, v in m.items() if k in fields})
+    cfg = harness.model_config(cell["config"], mix.get("model_overrides"))
     return ServingEngine(
         cfg, params, sampling=SamplingConfig(**mix["sampling"]), eos_token_id=None,
         obs_dir=str(out_dir), **mix["engine"],
@@ -126,20 +121,40 @@ def serve_and_collect(engine, requests, window_s, drain_s, during=None):
     return t0, client.records
 
 
-def reference_gaps(cell, seed, records, sample_ids, mode="f32", tokens_from=None):
-    """For each sampled request, the widest gap by which a served token's
-    logit lies below the reference's best. With ``tokens_from`` (a mode),
-    the tokens judged are the ones that precision puts first at each
-    position of the same prompts and served tokens: the control."""
+GAP_STATISTICS = ("max", "p90")  # what a mix's ``limits`` may name, as served_logit_gap_<s>
+
+
+def reference_gaps(cell, seed, records, sample_ids, tokens_from=None, whole_tree=False):
+    """The gap of EVERY judged token of the sampled requests: by how much a
+    served token's logit lies below the reference's best at its position.
+    With ``tokens_from`` (a mode), the tokens judged are the ones that
+    precision puts first at each position of the same prompts and served
+    tokens: the control.
+
+    The reference runs in blocks wherever the family offers
+    ``logits_by_blocks``: it is handed a way to make any leaf of its table
+    from the seed, bit for bit what ``weights.build`` gives, and never holds
+    the tree. A family without one (or ``whole_tree``, for the comparison of
+    the two paths) gets the whole float32 tree."""
     import jax
     import jax.numpy as jnp
 
     model, mix = cell["config"]["model"], cell["traffic"]
     ref = harness.load_reference(cell["config"])
-    key = weights.seed_key(seed, "weights")
-    params = weights.build(ref.leaf_table(model), key)
+    table, key = ref.leaf_table(model), weights.seed_key(seed, "weights")
+    if hasattr(ref, "logits_by_blocks") and not whole_tree:
+        make = weights.leaf_maker(table, key)
+
+        def forward(toks, mode):
+            return ref.logits_by_blocks(make, toks, model, mode)
+    else:
+        params = weights.build(table, key)
+
+        def forward(toks, mode):
+            return ref.logits(params, toks, model, mode)
+
     pad_to = mix["reference"]["pad_to"]
-    worst, n_tokens = 0.0, 0
+    gaps: list = []
     for rid in sample_ids:
         prompt, served = records[rid]["prompt"], records[rid]["tokens"]
         if not served:
@@ -149,17 +164,25 @@ def reference_gaps(cell, seed, records, sample_ids, mode="f32", tokens_from=None
         padded = seq + [0] * ((-T) % pad_to)
         toks = jnp.asarray([padded], jnp.int32)
         with jax.default_matmul_precision("highest"):
-            lg = ref.logits(params, toks, model, "f32")[0]
-            rows = lg[len(prompt) - 1: T - 1]
+            rows = forward(toks, "f32")[0][len(prompt) - 1: T - 1]
             if tokens_from is None:
                 judged = jnp.asarray(served, jnp.int32)
             else:
-                low = ref.logits(params, toks, model, tokens_from)[0]
+                low = forward(toks, tokens_from)[0]
                 judged = jnp.argmax(low[len(prompt) - 1: T - 1], axis=-1)
         gap = jnp.max(rows, axis=-1) - jnp.take_along_axis(rows, judged[:, None], axis=-1)[:, 0]
-        worst = max(worst, float(jnp.max(gap)))
-        n_tokens += len(served)
-    return worst, n_tokens
+        gaps.extend(np.asarray(gap).tolist())
+    return gaps
+
+
+def gap_statistics(gaps: list) -> dict:
+    """Over all judged tokens: the widest gap, which reads the rarest flip,
+    and beside it the 90th percentile, the mean and the share of tokens that
+    are not the reference's first at all, which read the program."""
+    if not gaps:
+        return {"tokens": 0, "max": None, "p90": None, "mean": None, "not_first": None}
+    return {"tokens": len(gaps), "max": max(gaps), "p90": arith.percentile(gaps, 90),
+            "mean": sum(gaps) / len(gaps), "not_first": sum(g > 0 for g in gaps) / len(gaps)}
 
 
 def pick_sample(records, seed, k):
@@ -254,9 +277,9 @@ def run(cell: dict, devices, seed: int, seconds: float, trace: bool,
     half = [(r["token_times"][0] - r["due"]) * 1e3 for r in records if r["token_times"]]
     mid = len(half) // 2
     served = [t for r in records for t in r["tokens"]]
+    ttft_halves = [arith.percentile(half[:mid] or [0], 50), arith.percentile(half[mid:] or [0], 50)]
     print(f"backlog at close {backlog}; ttft p50 first half "
-          f"{arith.percentile(half[:mid] or [0], 50):.1f} ms, second half "
-          f"{arith.percentile(half[mid:] or [0], 50):.1f} ms; drained "
+          f"{ttft_halves[0]:.1f} ms, second half {ttft_halves[1]:.1f} ms; drained "
           f"{t_end - close:.2f} s after the close; distinct served tokens "
           f"{len(set(served))} of {len(served)}", file=sys.stderr)
     ticks = [(e - s, s - t0) for _, track, name, s, e, _ in spans
@@ -278,17 +301,29 @@ def run(cell: dict, devices, seed: int, seconds: float, trace: bool,
     gc.collect()
 
     sample = pick_sample(records, seed, mix["reference"]["sample"])
-    t_ref = time.monotonic()
-    gap, n_judged = reference_gaps(cell, seed, records, sample)
+    t_ref, compiled_before = time.monotonic(), compiles.count
+    judged = gap_statistics(reference_gaps(cell, seed, records, sample))
     reference_s = time.monotonic() - t_ref
-    controls = {f"control_{m}": reference_gaps(cell, seed, records, sample, tokens_from=m)[0]
-                for m in control_modes}
+    print(f"reference: {len(sample)} requests, {judged['tokens']} tokens judged in "
+          f"{reference_s:.1f} s, {compiles.count - compiled_before} programs compiled",
+          file=sys.stderr)
+    # a control is a mode's tokens on the same prompts and served tokens;
+    # "whole_tree" is the served tokens again, through the whole-tree path
+    controls = {
+        f"control_{m}": gap_statistics(reference_gaps(
+            cell, seed, records, sample,
+            **({"whole_tree": True} if m == "whole_tree" else {"tokens_from": m})))
+        for m in control_modes}
     wrong_count = sum(1 for r in records
                       if r["status"] == "done" and len(r["tokens"]) != r["max_new_tokens"])
+    # the mix's ``limits`` say which statistics of the gap decide ``correct``
+    named = [s for s in GAP_STATISTICS if f"served_logit_gap_{s}" in mix["limits"]]
+    if not named:
+        raise SystemExit(f"the mix's limits name no served_logit_gap_<{'|'.join(GAP_STATISTICS)}>")
     compared = {
-        "served_logit_gap_max": {"value": gap if sample else None,
-                                 "limit": mix["limits"]["served_logit_gap_max"],
-                                 "tokens": n_judged},
+        **{f"served_logit_gap_{s}": {"value": judged[s],
+                                     "limit": mix["limits"][f"served_logit_gap_{s}"],
+                                     "tokens": judged["tokens"]} for s in named},
         "served_count_mismatch": {"value": float(wrong_count), "limit": 0.0},
         "compiles_in_window": {"value": float(compiled_in_window), "limit": 0.0},
     }
@@ -299,9 +334,11 @@ def run(cell: dict, devices, seed: int, seconds: float, trace: bool,
         "metrics": {},
         "device": device,
         "reference_s": reference_s,
+        "served_logit_gap": judged,  # every statistic of the judged tokens, compared or not
         "lateness_ms_max": max(lateness),
         "ttft_p95_ms": arith.percentile(ttft, 95),  # a per-layer metric; here for the sweep
         "backlog_at_close": backlog,
+        "ttft_p50_halves_ms": ttft_halves,  # a queue that grows shows in the second
         "drain_s": t_end - close,
         **controls,
         "compared": compared,
@@ -328,21 +365,33 @@ def run(cell: dict, devices, seed: int, seconds: float, trace: bool,
     result["device"]["busy_s"] = reduced["busy_s"]
     result["device"]["window_s"] = reduced["window_s"]
     result["breakdown"] = reduced["breakdown"]
+    loaded = host_trace.load()
+    result["capture_programs"] = host_trace.programs(loaded) if loaded else {}
+    print(f"programs in the {reduced['window_s']:.2f} s capture: " + ", ".join(
+        f"{name} {n} ({sec:.3f} s)" for name, (n, sec) in result["capture_programs"].items()),
+        file=sys.stderr)
     return result
 
 
 def calibrate(cell, devices, seeds, controls, seconds):
     """Yields, per seed, the numbers the limit is set from: a short window at
-    the cell's own load, the program's gap and, for the first ``controls``
-    seeds, the gap of the tokens the reference puts first in fp8 and in
-    bfloat16."""
+    the cell's own load, every statistic of the program's gap and, for the
+    first ``controls`` seeds, of the gap of the tokens the reference puts
+    first in fp8 and in bfloat16; where the family's reference runs in
+    blocks, also the program's gap by the whole-tree path on the same served
+    tokens."""
+    modes = ("fp8", "bf16")
+    if hasattr(harness.load_reference(cell["config"]), "logits_by_blocks"):
+        modes += ("whole_tree",)
     for i, seed in enumerate(seeds):
         res = run(cell, devices, seed=seed, seconds=seconds, trace=False,
-                  control_modes=("fp8", "bf16") if i < controls else ())
+                  control_modes=modes if i < controls else ())
         yield {
             "seed": seed,
             "program": {k: v["value"] for k, v in res["compared"].items()},
+            "served_logit_gap": res["served_logit_gap"],
             **{k: v for k, v in res.items() if k.startswith("control_")},
             "failed": res["failed"], "attempted": res["attempted"],
+            "reference_s": res["reference_s"], "device": res["device"],
             "metrics": res["metrics"],
         }

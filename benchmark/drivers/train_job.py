@@ -82,15 +82,9 @@ class NoCheckpoint:
 
 
 def build_config(cell: dict, out_dir: Path):
-    from zero_transformer_tpu.config import (
-        Config, ModelConfig, apply_dotted_overrides,
-    )
+    from zero_transformer_tpu.config import Config, apply_dotted_overrides
 
-    m = cell["config"]["model"]
-    fields = {f.name for f in dataclasses.fields(ModelConfig)}
-    model = ModelConfig(name=cell["config"]["name"],
-                        **{k: v for k, v in m.items() if k in fields})
-    cfg = dataclasses.replace(Config(), model=model)
+    cfg = dataclasses.replace(Config(), model=harness.model_config(cell["config"]))
     over = dict(cell["traffic"]["overrides"])
     over["mesh.data"] = cell["chips"]
     over["data.source"] = "synthetic"

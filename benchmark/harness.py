@@ -4,6 +4,7 @@ per-layer metric readers, the decision on ``correct`` and the result line.
 No cell, configuration, traffic mix or metric is named in this file."""
 from __future__ import annotations
 
+import dataclasses
 import importlib.util
 import json
 import os
@@ -30,6 +31,9 @@ def _process_start() -> float:
 
 
 T_START = _process_start()
+# the seconds ``jax.devices()`` took to reach the chip: the machine's, not the
+# program's, and inside ``setup_s``; every result line carries it beside it
+CHIP_REACH_S = None
 
 
 def load_cell(workload: str) -> dict:
@@ -74,6 +78,35 @@ def load_reference(config: dict):
     return _load(path, "benchmark_reference_" + path.stem)
 
 
+def model_config(config: dict, overrides=None):
+    """The program's ``ModelConfig`` from the keys of the configuration's
+    ``model`` group (and a mix's ``model_overrides``) that are its fields."""
+    from zero_transformer_tpu.config import ModelConfig
+
+    m = dict(config["model"], **(overrides or {}))
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    return ModelConfig(name=config["name"], **{k: v for k, v in m.items() if k in fields})
+
+
+def check_configuration(config: dict) -> None:
+    """What a run relies on, without a byte of memory: the configuration
+    builds the program's ``ModelConfig``, and the abstract parameter tree of
+    that model has exactly the paths and shapes of the family's
+    ``leaf_table``. Raises ``SystemExit`` where they differ."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import weights
+    from zero_transformer_tpu.models import Transformer
+    from zero_transformer_tpu.parallel.sharding import unbox
+
+    cfg = model_config(config)
+    abstract = jax.eval_shape(
+        lambda: Transformer(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    )["params"]
+    weights.check_tree(load_reference(config).leaf_table(config["model"]), unbox(abstract))
+
+
 def load_optimizer(name: str):
     """A training job's ``optimizer.optimizer`` names the plain optimizer the
     reference follows: ``optimizers/<name>.py``."""
@@ -99,7 +132,10 @@ def require_chips(chips: int):
     """The devices, or exit non-zero with no result: never a fallback."""
     import jax
 
+    global CHIP_REACH_S
+    asked = time.monotonic()
     devices = jax.devices()
+    CHIP_REACH_S = time.monotonic() - asked
     if devices[0].platform != "tpu":
         raise SystemExit(
             f"the benchmark measures a TPU; JAX found {devices[0].platform!r}"
@@ -183,5 +219,7 @@ def emit(result: dict) -> None:
     for k in result:
         if k not in ordered and k != "compared":
             ordered[k] = result[k]
+    if CHIP_REACH_S is not None:
+        ordered["chip_reach_s"] = CHIP_REACH_S
     ordered["compared"] = result.get("compared", {})
     print(json.dumps(ordered), flush=True)

@@ -119,6 +119,18 @@ def program_durations(loaded: dict, pattern: str) -> list:
             for name, s, e, _ in evs if rx.search(name)]
 
 
+def programs(loaded: dict) -> dict:
+    """{jitted function: [launches, seconds on the device]} of a capture's
+    ``XLA Modules`` line, so that a traced run says how many ticks it holds."""
+    out: dict = {}
+    for evs in loaded["modules"].values():
+        for name, s, e, _ in evs:
+            entry = out.setdefault(name.split("(")[0], [0, 0.0])
+            entry[0] += 1
+            entry[1] += e - s
+    return out
+
+
 def program_ms_p50(ctx: dict, pattern: str) -> float | None:
     """Median milliseconds of the matching programs in a traced run's
     capture: what the ``*_program_ms_p50`` readers return. None in an

@@ -14,9 +14,10 @@ against.
 
 What the harness asks of a family's reference module, which it finds by
 the configuration file's ``reference`` key: ``leaf_table``,
-``active_params``, ``attention_flops_per_position``, ``decays``, ``logits``,
-``step_loss_and_grads`` and ``step_loss``, each taking the configuration's
-``model`` group. The drivers name no family.
+``active_params``, ``attention_flops_per_position``, ``decays``, ``logits``
+(and, where the tree should never be held whole, its block-wise twin
+``logits_by_blocks``), ``step_loss_and_grads`` and ``step_loss``, each
+taking the configuration's ``model`` group. The drivers name no family.
 
 Training is followed micro-batch by micro-batch with a hand-written
 layer-by-layer backward pass (``jax.vjp`` of one layer at a time, the
@@ -33,6 +34,12 @@ import jax.numpy as jnp
 
 LN_EPS = 1e-6
 _ROUND = {"f32": None, "bf16": jnp.bfloat16, "fp8": jnp.float8_e4m3fn}
+# XLA may keep MORE precision than a program asks for (its default): on the
+# chip it then drops ``_q``'s roundings, all of bfloat16's and, in a program
+# that takes a layer's weights as arguments, most of fp8's (my chip run, PR
+# 30: the "bf16" logits equalled float32's to 2.5e-6). The programs a control
+# is read through are compiled to round where they say.
+STRICT = {"xla_allow_excess_precision": False}
 
 
 def alibi_slopes(n_heads: int) -> jnp.ndarray:
@@ -152,13 +159,51 @@ def logits(params: dict, tokens, model: dict, mode: str = "f32"):
     return _logits(params, tokens, n_heads=model["n_heads"], mode=mode)
 
 
-@partial(jax.jit, static_argnames=("n_heads", "mode"))
+@partial(jax.jit, static_argnames=("n_heads", "mode"), compiler_options=STRICT)
 def _logits(params: dict, tokens, n_heads: int, mode: str = "f32"):
     h = jnp.take(params["wte"]["embedding"], tokens, axis=0)
     h, _ = jax.lax.scan(
         lambda h, pl: (layer(pl, h, n_heads, mode), None), h, params["blocks"]
     )
     return _head_logits(h, params["ln_f"]["scale"], params["wte"]["embedding"], mode)
+
+
+def logits_by_blocks(make, tokens, model: dict, mode: str = "f32"):
+    """``logits`` without the tree: the weights are asked for a block at a
+    time. ``make(paths)`` gives {path: leaf} for unstacked leaves of
+    ``leaf_table``, ``make(paths, layer)`` one layer's slice of stacked
+    ones. A block is the table, one layer, or the final norm and the (tied)
+    table again, so what the device holds is the largest of them and one
+    request's activations, whatever the depth. The same ``layer`` and head
+    as ``logits``; only the loop over the layers is on the host."""
+    h = _embed(make(("wte/embedding",))["wte/embedding"], tokens)
+    for l in range(model["n_layers"]):
+        h = _layer(_nest(make(LAYER_LEAVES, l)), h, n_heads=model["n_heads"], mode=mode)
+    last = make(("ln_f/scale", "wte/embedding"))
+    return _head(h, last["ln_f/scale"], last["wte/embedding"], mode=mode)
+
+
+LAYER_LEAVES = tuple(
+    f"blocks/{name}" for name in (
+        "ln_attn/scale", "attn/query/kernel", "attn/key/kernel", "attn/value/kernel",
+        "attn/out/kernel", "ln_mlp/scale", "mlp/wi/kernel", "mlp/wo/kernel"))
+
+
+def _nest(layer_leaves: dict) -> dict:
+    """{"blocks/attn/query/kernel": x, ...} -> {"attn": {"query": {"kernel": x}}, ...}"""
+    out: dict = {}
+    for path, x in layer_leaves.items():
+        node = out
+        *parents, last = path.split("/")[1:]
+        for p in parents:
+            node = node.setdefault(p, {})
+        node[last] = x
+    return out
+
+
+_embed = jax.jit(lambda table, tokens: jnp.take(table, tokens, axis=0))
+_layer = jax.jit(layer, static_argnames=("n_heads", "mode"), compiler_options=STRICT)
+_head = jax.jit(_head_logits, static_argnames=("mode",), compiler_options=STRICT)
 
 
 @partial(jax.jit, static_argnames=("n_heads", "mode"), donate_argnums=(2,))

@@ -41,7 +41,6 @@ What the harness asks of a family's reference module: ``leaf_table``,
 from __future__ import annotations
 
 import math
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -220,10 +219,15 @@ def exit_state(states: list, gates: list, threshold: float):
     return out
 
 
-@partial(jax.jit, static_argnames=("n_heads", "n_kv_heads", "n_loops", "theta",
-                                   "threshold", "param_dtype", "mode"))
-def _logits(params, tokens, n_heads, n_kv_heads, n_loops, theta, threshold,
-            param_dtype, mode):
+# XLA may keep MORE precision than a program asks for (its default) and then
+# drops ``_q``'s roundings: on the chip the "bf16" logits equalled float32's
+# (my chip runs, PR 30; PR 26 read its bfloat16 control as 0.0 for that
+# reason). Compiled to round where it says.
+STRICT = {"xla_allow_excess_precision": False}
+
+
+def _forward(params, tokens, n_heads, n_kv_heads, n_loops, theta, threshold,
+             param_dtype, mode):
     def held(tree):
         return jax.tree.map(lambda x: _held(x, param_dtype), tree)
 
@@ -245,21 +249,31 @@ def _logits(params, tokens, n_heads, n_kv_heads, n_loops, theta, threshold,
     return _mm("btd,dv->btv", s, held(params["lm_head"]["kernel"]), mode)
 
 
-def logits(params: dict, tokens, model: dict, mode: str = "f32"):
-    """[B, T] tokens -> [B, T, V] float32 logits: the whole forward pass."""
-    return _logits(
-        params, tokens, n_heads=model["n_heads"],
-        n_kv_heads=model.get("n_kv_heads") or model["n_heads"],
+_SIZES = ("n_heads", "n_kv_heads", "n_loops", "theta", "threshold", "param_dtype", "mode")
+_logits = jax.jit(_forward, static_argnames=_SIZES, compiler_options=STRICT)
+# ``compiler_options`` are a top-level jit's alone: what a caller
+# differentiates or jits itself (``loss``) goes through this one
+_logits_nested = jax.jit(_forward, static_argnames=_SIZES)
+
+
+def _statics(model: dict, mode: str) -> dict:
+    return dict(
+        n_heads=model["n_heads"], n_kv_heads=model.get("n_kv_heads") or model["n_heads"],
         n_loops=model["n_loops"], theta=float(model["rope_theta"]),
         threshold=float(model["exit_threshold"]),
         param_dtype=model.get("param_dtype", "float32"), mode=mode,
     )
 
 
+def logits(params: dict, tokens, model: dict, mode: str = "f32"):
+    """[B, T] tokens -> [B, T, V] float32 logits: the whole forward pass."""
+    return _logits(params, tokens, **_statics(model, mode))
+
+
 def loss(params: dict, tokens, model: dict, mode: str = "f32"):
     """Next-token cross entropy of the exit state's logits, averaged over
     the ``T - 1`` predicted positions of every row."""
-    lg = logits(params, tokens, model, mode)[:, :-1]
+    lg = _logits_nested(params, tokens, **_statics(model, mode))[:, :-1]
     lse = jax.scipy.special.logsumexp(lg, axis=-1)
     lab = jnp.take_along_axis(lg, tokens[:, 1:, None], axis=-1)[..., 0]
     return jnp.mean(lse - lab)

@@ -6,7 +6,12 @@ Each rate is one run of the cell's own driver in this process, with only
 ``rate_per_s`` changed. The knee is the highest swept rate whose queue does
 not grow through the window: few requests still waiting for a first token at
 the close, and the second half's time to first token no worse than the
-first's. The cell's traffic file then fixes 0.8 of it. One JSON line a rate.
+first's. The cell's traffic file then fixes 0.8 of it. The builder may
+depart from 0.8, downwards only, where six seeds at 0.8 show an end-to-end
+metric spreading by more than half its bound and a lower rate does not (a
+window that closes on the trace's longest request, a percentile that sits
+between two kinds of tick), and must then say so, with both readings, in the
+traffic file's ``rate_note``, as both serving mixes do. One JSON line a rate.
 """
 from __future__ import annotations
 
@@ -38,6 +43,7 @@ def main(argv=None) -> int:
         print(json.dumps({
             "rate_per_s": rate, "seed": seed, "compared": res["compared"], "attempted": res["attempted"], "failed": res["failed"],
             "backlog_at_close": res["backlog_at_close"], "drain_s": res["drain_s"],
+            "ttft_p50_halves_ms": res["ttft_p50_halves_ms"],
             "lateness_ms_max": res["lateness_ms_max"], "correct": res["correct"],
             "ttft_p95_ms": res["ttft_p95_ms"],
             **{k: v["value"] for k, v in res["metrics"].items()},

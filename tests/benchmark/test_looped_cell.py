@@ -4,19 +4,17 @@ program's tree at full size (abstract: nothing is allocated), the byte count
 ``decode_bandwidth_share`` divides by is right by a hand count, the cell's
 traffic is the chat cell's one trace, and the two new readers read what the
 program writes and nothing where it writes nothing."""
-import dataclasses
 import json
+import math
 import sys
 from pathlib import Path
 
-import jax
-import jax.numpy as jnp
 import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from benchmark import harness, host_trace, traffic, weights  # noqa: E402
+from benchmark import harness, host_trace, traffic  # noqa: E402
 
 CELL = "serve_ouro_2_6b_chat"
 TINY = {"d_model": 64, "n_layers": 3, "n_loops": 3, "n_heads": 4, "n_kv_heads": 4,
@@ -51,22 +49,14 @@ def test_configuration_states_the_published_sizes_and_cuts_nothing(cell):
 
 
 def test_leaf_table_is_the_programs_tree_at_full_size(cell):
-    from zero_transformer_tpu.config import ModelConfig
-    from zero_transformer_tpu.models import Transformer
-    from zero_transformer_tpu.parallel.sharding import unbox
-
     config, model = cell["config"], cell["config"]["model"]
-    fields = {f.name for f in dataclasses.fields(ModelConfig)}
-    cfg = ModelConfig(name=config["name"], **{k: v for k, v in model.items() if k in fields})
-    abstract = jax.eval_shape(
-        lambda: Transformer(cfg).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
-    )["params"]
+    harness.check_configuration(config)  # the abstract tree: nothing is allocated
     ref = harness.load_reference(config)
-    table = ref.leaf_table(model)
-    weights.check_tree(table, unbox(abstract))
-    held = sum(int(jnp.prod(jnp.asarray(s))) for s, _ in table.values())
-    assert held == cfg.num_params == config["parameters_held"] == 2_667_974_657
-    assert ref.active_params(model) == config["parameters"] == \
+    held = sum(math.prod(s) for s, _ in ref.leaf_table(model).values())
+    # what memory holds, and what a token is multiplied by: 3.7 times as much
+    assert held == harness.model_config(config).num_params == config["parameters"] \
+        == 2_667_974_657
+    assert ref.active_params(model) == config["active_parameters"] == \
         4 * 48 * 51_380_224 + 2048 * 49152
     assert ref.attention_flops_per_position(model) == 4 * 192 * 2048
     # a cached position costs one K and one V row in each of 192 entries
