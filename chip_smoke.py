@@ -304,22 +304,29 @@ def extract_phase(out: Path, require_tpu: bool = True) -> dict:
 # (slots, heads, cache_len) of the benchmark's serving cells: 580M and the
 # looped 2.6B, heads of 128 as SERVE_MODEL's
 CELL_SHAPES = ((16, 12, 2048), (16, 16, 512))
+# (slots, heads, cached row's lanes, value lanes, cache_len) of the latent
+# serving cell: GLM-4.7-Flash's 20 heads over rows of 512 + 64 (+ 64) lanes
+LATENT_SHAPES = ((16, 20, 640, 512, 5120),)
 
 
 def kernels_phase(model: str = SERVE_MODEL, slots: int = 4,
                   cache_len: int = 1024, ragged_shapes: tuple = CELL_SHAPES,
+                  latent_shapes: tuple = LATENT_SHAPES,
                   require_tpu: bool = True) -> dict:
     """The paged decode kernel as the chip's compiler built it, against the
     gather path it replaces, at the shapes the servers below decode with —
     greedy tokens alone cannot tell a wrong mask from a right one on a
     random-weight model that emits one token — and, the kernel's walk being
     bounded by each row's own length, at the benchmark cells' shapes with
-    most rows a few pages long."""
+    most rows a few pages long; and the latent decode kernel against ITS
+    gather path at the latent cell's shapes, to the same bar."""
     device = device_or_exit(require_tpu)
     import jax.numpy as jnp
 
     from zero_transformer_tpu.config import ServingConfig, model_config
-    from zero_transformer_tpu.ops.pallas.parity import paged_vs_gather
+    from zero_transformer_tpu.ops.pallas.parity import (
+        latent_vs_gather, paged_vs_gather,
+    )
 
     cfg = model_config(model)
     page = ServingConfig().page_size
@@ -336,14 +343,22 @@ def kernels_phase(model: str = SERVE_MODEL, slots: int = 4,
         for B, H, KVH, S, ragged in shapes
         for T in (1, 1 + DRAFT_K) for int8 in (False, True)
     ]
-    for case in cases:
+    latent = [
+        latent_vs_gather(
+            B=B, T=T, H=H, R=R, value_width=V, page=page, n_blocks=S // page,
+            dtype=jnp.bfloat16, seed=SEED, interpret=device["platform"] != "tpu",
+        )
+        for B, H, R, V, S in latent_shapes for T in (1, 1 + DRAFT_K)
+    ]
+    for case in cases + latent:
         if not (case["finite"] and case["ulps"] <= PAGED_ULPS < case["control_ulps"]):
             raise RuntimeError(
                 f"paged kernel outside {PAGED_ULPS} bf16 ulps of the gather "
                 f"path (or the control inside them): {case}"
             )
     return {"phase": "kernels", "ok": True, "device": device, "model": model,
-            "paged_ulps_bar": PAGED_ULPS, "paged_vs_gather": cases}
+            "paged_ulps_bar": PAGED_ULPS, "paged_vs_gather": cases,
+            "latent_vs_gather": latent}
 
 
 def serve_child(model: str, params: Path, port: int, extra: list,

@@ -409,6 +409,72 @@ def test_looped_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch,
     assert len(on_kv) - on_kv.count("scatter") == n_pools, on_kv
 
 
+GLM_CACHE_LEN, GLM_N_PAGES, GLM_ROW = 5120, 81920 // PAGE + 1, 640  # 5121 = 9 x 569
+
+
+@pytest.mark.parametrize("T", [1, 5])
+def test_latent_decode_kernel_compiles(one_chip, T):
+    """The latent decode kernel at the GLM cell's shapes: 16 slots, 20 heads
+    over ONE cached row of 640 lanes (512 latent + 64 key + 64 of padding to
+    whole tiles: the unpadded 576 is 4.5 tiles, which no DMA addresses), page
+    16, a 5,120 cache, the stacked pool handed over in ``pl.ANY`` with a
+    traced layer index: one Mosaic call, nothing pool-sized made on the way."""
+    from zero_transformer_tpu.ops.pallas import latent_attention as la
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(q, pool, table, offsets, layer):
+        return la.latent_paged_attention(
+            q, pool, table, offsets, value_width=512, causal=T > 1,
+            softmax_scale=1 / 16.0, layer=layer,
+        )
+
+    text = jax.jit(step).lower(
+        sds((N_SLOTS, T, 20, GLM_ROW), jnp.bfloat16),
+        sds((3, GLM_N_PAGES, PAGE, GLM_ROW), jnp.bfloat16),
+        sds((N_SLOTS, GLM_CACHE_LEN // PAGE), jnp.int32), sds((N_SLOTS,), jnp.int32),
+        sds((), jnp.int32),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not _pool_ops(text, GLM_N_PAGES, PAGE, GLM_ROW)
+
+
+def test_latent_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch):
+    """GLM-4.7-Flash's structure at published widths (latent attention, one
+    dense layer, then 64 sigmoid-routed experts and a shared one; depth cut
+    to the dense layer + two routed ones) at its cell's engine shapes: 16
+    slots x 5,120 over an 81,920-token pool of latent rows. What the K/V
+    cases allow and no more: the stack is unrolled, so each block keeps its
+    own pool [5121, 16, 640], the latent kernel and XLA's grouped matmuls are
+    on the decode path, decode aliases the pool and holds nothing else
+    pool-sized but its in-place scatters, prefill the one copy that not
+    donating forces."""
+    from zero_transformer_tpu.config import model_config
+
+    cfg = model_config("glm_4_7_flash_7l", n_layers=3, attention_impl="auto")
+    decode, prefill, n_pools = _serving_programs(
+        one_chip, monkeypatch, cfg, GLM_CACHE_LEN, GLM_N_PAGES)
+    assert n_pools == 3
+    assert "latent_paged_attention" in decode and "latent_paged_attention" not in prefill
+    assert "ragged-dot" in decode and "ragged-dot" in prefill
+    aliased = decode.split("input_output_alias={", 1)[1].split("}, entry", 1)[0]
+    assert aliased.count("-alias") >= n_pools
+
+    def ops(hlo):
+        return _pool_ops(hlo, GLM_N_PAGES, PAGE, GLM_ROW)
+
+    for found in (ops(decode), ops(prefill)):
+        sliced = {"dynamic-slice", "dynamic-update-slice", "gather", "AllocateBuffer"}
+        assert not [o for o in found if o[0] in sliced], found
+        assert {op for op, _, kv, in_loop in found if kv and in_loop} <= {"scatter"}, found
+    on_kv = [op for op, _, kv, _ in ops(decode) if kv]
+    assert set(on_kv) == {"scatter"}, on_kv
+    on_kv = [op for op, _, kv, _ in ops(prefill) if kv and op not in PREFETCH]
+    assert set(on_kv) <= {"scatter", "copy", "copy-start"}, on_kv
+    assert len(on_kv) - on_kv.count("scatter") == n_pools, on_kv
+
+
 def _flash_grads(docs: bool, entry=flash.flash_attention):
     def loss(q, k, v, ids):
         out = entry(

@@ -89,9 +89,14 @@ def test_kernels_phase_holds_the_paged_kernel_to_its_bar(monkeypatch):
     ``test`` model's head shapes: every case inside the bar, its off-by-one
     control far outside — and a bar the control would pass is refused."""
     # ragged at a shape with three buckets and two staged groups (page 16)
-    kw = dict(slots=2, cache_len=64, ragged_shapes=((4, 4, 384),), require_tpu=False)
+    # and the latent kernel at a small row (96 latent + 16 key lanes of 128)
+    kw = dict(slots=2, cache_len=64, ragged_shapes=((4, 4, 384),),
+              latent_shapes=((4, 5, 128, 96, 384),), require_tpu=False)
     got = chip_smoke.kernels_phase("test", **kw)
     assert got["ok"] and len(got["paged_vs_gather"]) == 8
+    assert [c["shape"]["T"] for c in got["latent_vs_gather"]] == [1, 5]
+    for case in got["latent_vs_gather"]:
+        assert case["ulps"] <= chip_smoke.PAGED_ULPS < case["control_ulps"]
     assert {(c["shape"]["T"], c["int8_pages"], c["ragged"])
             for c in got["paged_vs_gather"]} == {
         (T, int8, ragged)

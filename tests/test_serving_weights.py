@@ -94,7 +94,7 @@ def _serve(model, params, steps=3):
     rng = np.random.default_rng(3)
     for s, n in enumerate(lens):
         tokens[s, :n] = rng.integers(1, model.cfg.vocab_size, n)
-    cache, last = jax.jit(eng._paged_chunk_prefill_impl, static_argnums=(0,))(
+    cache, last, _ = jax.jit(eng._paged_chunk_prefill_impl, static_argnums=(0,))(
         model, params, cache, jnp.asarray(tokens), jnp.zeros(N_SLOTS, jnp.int32),
         jnp.asarray(lens), jnp.ones(N_SLOTS, jnp.bool_), jnp.asarray(table),
         jnp.asarray(lens),
@@ -106,7 +106,7 @@ def _serve(model, params, steps=3):
     step = jax.jit(eng._fused_step_impl, static_argnums=(0, 1))
     sampling = SamplingConfig(greedy=True, repetition_penalty=1.0)
     for _ in range(steps):
-        _, last, cache, gen_mask, rngs, bad = step(
+        _, last, cache, gen_mask, rngs, bad, _ = step(
             model, sampling, params, last, cache, gen_mask, rngs
         )
         assert not np.asarray(bad).any()

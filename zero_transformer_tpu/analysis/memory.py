@@ -56,7 +56,11 @@ def kv_bytes_per_token(m) -> int:
     looped stack pays ``n_loops`` times a plain one — at the compute dtype,
     or int8 values plus one float32 scale per (position, head). What a
     serving pool is sized from: ``page_pool_tokens`` x this, beside the
-    weights."""
+    weights. Latent attention keeps ONE row an entry, the latent and the
+    rotated key padded to whole lane tiles (``latent_row``): the bytes the
+    pool really holds."""
+    if m.latent_attention:
+        return m.kv_entries * m.latent_row * _dtype_bytes(m.compute_dtype)
     per_head = (
         m.head_width + 4 if m.kv_cache_dtype == "int8"
         else m.head_width * _dtype_bytes(m.compute_dtype)

@@ -128,6 +128,40 @@ class ModelConfig:
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01  # load-balance aux loss weight
     router_z_coef: float = 1e-3  # router z-loss weight
+    # How a routed layer moves tokens (models/moe.py). "capacity": softmax
+    # top-1/2 over fixed per-expert buffers, overflow dropped (the training
+    # recipes' MoEMLP). "dropless": sigmoid scores, the top ``moe_top_k`` of
+    # score + a selection bias, weights from the scores alone (normalised,
+    # times ``moe_routed_scale``), rows sorted by expert into a grouped
+    # matmul: no token is dropped and a row's result depends on that row
+    # alone, which chunked prefill + decode against a full forward needs.
+    # Its grouped matmuls are custom calls, which take whole buffers: a layer
+    # sliced out of a SCANNED stack of expert weights is first copied (three
+    # copies of 201 MB a layer a program at GLM-4.7-Flash's widths, 22 of a
+    # 30 ms decode program; my chip run, PR 31), so a dropless stack is
+    # unrolled (``scan_layers`` false): each block's weights are buffers.
+    moe_dispatch: str = "capacity"  # "capacity" | "dropless"
+    moe_d_ff: Optional[int] = None  # width of ONE routed / shared expert; None -> ff_dim
+    moe_shared_experts: int = 0  # always-on experts beside the routed ones (dropless)
+    moe_routed_scale: float = 1.0
+    # leading layers that keep the dense MLP (width ``d_ff``) in a routed
+    # model (dropless, so unrolled: block i is dense where i < this)
+    moe_dense_layers: int = 0
+    # Latent attention (MLA, DeepSeek-V2/V3), set by ``kv_lora_rank`` with all
+    # five widths: queries through a rank ``q_lora_rank`` bottleneck; keys and values up-projected from ONE normed
+    # latent row of ``kv_lora_rank`` values a position, plus one rotated key of
+    # ``qk_rope_head_dim`` shared by all heads. A head's query and key are
+    # ``qk_nope_head_dim + qk_rope_head_dim`` wide (= ``head_dim``), its value
+    # ``v_head_dim``. The cache holds the latent row and the rotated key only
+    # (models/mla.py). None = full multi-head / grouped attention.
+    kv_lora_rank: Optional[int] = None
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    # RoPE pairs lanes (2i, 2i+1) instead of (i, i + D/2)
+    rope_interleaved: bool = False
+    norm_eps: float = 1e-6
 
     @property
     def kv_heads(self) -> int:
@@ -147,33 +181,89 @@ class ModelConfig:
         return 4 * self.d_model
 
     @property
-    def layer_params(self) -> int:
-        """Parameters of ONE block (matrices and norm scales)."""
-        d, f = self.d_model, self.ff_dim
-        h, kv, hd = self.n_heads, self.kv_heads, self.head_width
-        attn = d * h * hd + 2 * d * kv * hd + h * hd * d
-        mlp = (3 if self.activation == "swiglu" else 2) * d * f
-        if self.n_experts > 0:
-            mlp = self.n_experts * mlp + d * self.n_experts  # experts + router
-        norms = (4 if self.post_norm else 2) * d
-        return attn + mlp + norms
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank is not None
 
     @property
-    def num_params(self) -> int:
-        """Approximate parameter count (embedding included once when tied; a
-        looped stack's shared layers once)."""
+    def latent_row(self) -> int:
+        """Lanes of one cached latent row: the ``kv_lora_rank`` latent
+        values and the ``qk_rope_head_dim`` rotated key, padded to whole
+        128-lane tiles (a page DMA moves whole tiles of a pool row)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def moe_ff_dim(self) -> int:
+        return self.moe_d_ff if self.moe_d_ff is not None else self.ff_dim
+
+    def layer_kind(self, i: int) -> str:
+        """What block ``i`` of the stack is: "moe" (the routed layer of
+        ``moe_dispatch``) or "dense" (the MLP; the ``moe_dense_layers``
+        leading blocks of a routed stack, every block of a dense one)."""
+        return "moe" if self.n_experts > 0 and i >= self.moe_dense_layers else "dense"
+
+    @property
+    def _attention_params(self) -> int:
+        d, h = self.d_model, self.n_heads
+        if self.latent_attention:
+            r, rq = self.kv_lora_rank, self.q_lora_rank
+            qk, rope = self.head_width, self.qk_rope_head_dim
+            q = d * rq + rq + rq * h * qk
+            kv = d * (r + rope) + r + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+            return q + kv + h * self.v_head_dim * d
+        kv, hd = self.kv_heads, self.head_width
+        return d * h * hd + 2 * d * kv * hd + h * hd * d
+
+    def _layer_params(self, kind: str, active: bool = False) -> int:
+        """Parameters of ONE block of a kind (matrices, norm scales, router);
+        with ``active``, of a routed block only what one token is multiplied
+        by: ``moe_top_k`` of its experts."""
+        d = self.d_model
+        per = 3 if self.activation == "swiglu" else 2
+        if kind == "moe":
+            e = self.moe_top_k if active else self.n_experts
+            mlp = per * d * self.moe_ff_dim * (e + self.moe_shared_experts) + d * self.n_experts
+            if self.moe_dispatch == "dropless":
+                mlp += self.n_experts  # the selection bias
+        else:
+            mlp = per * d * self.ff_dim
+        norms = (4 if self.post_norm else 2) * d
+        return self._attention_params + mlp + norms
+
+    @property
+    def layer_params(self) -> int:
+        """Parameters of ONE block (matrices and norm scales); of a stack
+        with leading dense layers, of a routed block."""
+        return self._layer_params(self.layer_kind(self.n_layers - 1))
+
+    def _stack_params(self, active: bool) -> int:
         d, v = self.d_model, self.vocab_size
         embed = v * d * (1 if self.tie_embeddings else 2)
         gate = d + 1 if self.exit_gate else 0
-        return self.n_layers * self.layer_params + embed + d + gate
+        layers = sum(
+            self._layer_params(self.layer_kind(i), active) for i in range(self.n_layers)
+        )
+        return layers + embed + d + gate
+
+    @property
+    def num_params(self) -> int:
+        """Approximate parameter count, what memory holds (embedding
+        included once when tied; a looped stack's shared layers once; every
+        expert of a routed layer)."""
+        return self._stack_params(active=False)
 
     @property
     def params_per_token(self) -> int:
         """Parameters a token is multiplied through: ``num_params`` with a
-        looped stack's shared layers counted once a PASS. What FLOP
-        arithmetic wants where ``num_params`` is what memory holds; the
-        same number for a plain stack."""
-        return self.num_params + (self.n_loops - 1) * self.n_layers * self.layer_params
+        looped stack's shared layers counted once a PASS, a routed layer's
+        experts as the ``moe_top_k`` a token is sent to, and an untied
+        embedding table as the lookup it is. What FLOP arithmetic wants
+        where ``num_params`` is what memory holds; the same number for a
+        plain dense stack with tied embeddings."""
+        n = self._stack_params(active=self.moe_dispatch == "dropless")
+        n += (self.n_loops - 1) * self.n_layers * self.layer_params
+        if self.moe_dispatch == "dropless" and not self.tie_embeddings:
+            n -= self.vocab_size * self.d_model
+        return n
 
     @property
     def kv_entries(self) -> int:
@@ -217,8 +307,54 @@ class ModelConfig:
             raise ValueError("loss_chunk must be a positive chunk size or None")
         if self.n_experts < 0:
             raise ValueError("n_experts must be >= 0")
-        if self.n_experts > 0 and self.moe_top_k not in (1, 2):
-            raise ValueError("moe_top_k must be 1 or 2")
+        if self.moe_dispatch not in ("capacity", "dropless"):
+            raise ValueError(f"invalid moe_dispatch {self.moe_dispatch!r}")
+        dropless = self.moe_dispatch == "dropless"
+        if self.n_experts > 0 and not dropless and self.moe_top_k not in (1, 2):
+            raise ValueError("moe_top_k must be 1 or 2 (capacity dispatch)")
+        if self.n_experts > 0 and self.moe_top_k < 1:
+            raise ValueError("moe_top_k must be >= 1")
+        if dropless and self.n_experts == 0:
+            raise ValueError("moe_dispatch='dropless' needs n_experts > 0")
+        if dropless and (self.activation != "swiglu" or self.param_quant != "none"):
+            raise ValueError(
+                "moe_dispatch='dropless' experts are SwiGLU, with no int8 weights"
+            )
+        if dropless and (self.scan_layers or self.n_loops > 1):
+            raise ValueError(
+                "moe_dispatch='dropless' needs scan_layers=False and one pass: a "
+                "grouped matmul copies its expert weights out of a scanned stack"
+            )
+        if not dropless and (self.moe_shared_experts or self.moe_dense_layers):
+            raise ValueError(
+                "moe_shared_experts / moe_dense_layers belong to "
+                "moe_dispatch='dropless'"
+            )
+        if not 0 <= self.moe_dense_layers < self.n_layers:
+            raise ValueError("moe_dense_layers must lie in [0, n_layers)")
+        if self.latent_attention:
+            widths = (self.q_lora_rank, self.qk_nope_head_dim,
+                      self.qk_rope_head_dim, self.v_head_dim)
+            if None in widths or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "latent attention needs q_lora_rank, qk_nope_head_dim, an "
+                    "even qk_rope_head_dim and v_head_dim"
+                )
+            if self.head_width != self.qk_nope_head_dim + self.qk_rope_head_dim:
+                raise ValueError(
+                    "head_dim must be qk_nope_head_dim + qk_rope_head_dim "
+                    "(the width of a query and key head)"
+                )
+            if self.position != "rope" or self.n_kv_heads is not None:
+                raise ValueError(
+                    "latent attention is RoPE on its own shared key; it has "
+                    "no kv heads to group"
+                )
+            if self.kv_cache_dtype != "auto" or self.param_quant != "none" or self.n_loops > 1:
+                raise ValueError(
+                    "latent attention has no int8 pages, int8 weights or "
+                    "looped stack"
+                )
         if self.n_experts > 0 and self.moe_top_k > self.n_experts:
             raise ValueError("moe_top_k cannot exceed n_experts")
         if self.attention_impl not in ("auto", "xla", "flash"):

@@ -1,8 +1,10 @@
 """Decoder-only transformer LM, TPU-first.
 
 Covers the reference's GPT-2+ALiBi family (reference ``src/models/GPT.py``,
-``src/models/layers.py``) and the Llama family (RoPE/RMSNorm/SwiGLU/GQA) from
-one module tree, with:
+``src/models/layers.py``), the Llama family (RoPE/RMSNorm/SwiGLU/GQA), looped
+stacks and, with latent attention (``models/mla.py``) and a dropless routed
+layer (``models/moe.py``) after leading dense ones, the DeepSeek-V3 block,
+from one module tree, with:
 
 - logical-axis sharding metadata on every parameter (``nn.with_partitioning``),
   which the reference only gestured at (reference ``layers.py:13-14``, unused);
@@ -29,7 +31,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.nn import initializers
 
 from zero_transformer_tpu.config import ModelConfig, resolve_dtype
-from zero_transformer_tpu.models.moe import MoEMLP
+from zero_transformer_tpu.models.mla import LatentAttention, latent_pool_leaves
+from zero_transformer_tpu.models.moe import DroplessMoE, MoEMLP
 from zero_transformer_tpu.parallel.sharding import (
     constrain_activation,
     replicate_activation,
@@ -108,7 +111,11 @@ def kv_pool_leaves(cfg: ModelConfig, kv_pages: Tuple[int, int], dtype) -> dict:
     another physical layout for the pool than the Mosaic call and the
     scatter want, and converts between them with pool-sized copies in
     every layer of every tick. One layout from allocation to kernel, so
-    no program re-lays-out a pool-sized value."""
+    no program re-lays-out a pool-sized value.
+    The layer's attention declares what it keeps: latent attention one
+    latent row a position (``models.mla.latent_pool_leaves``)."""
+    if cfg.latent_attention:
+        return latent_pool_leaves(cfg, kv_pages, dtype)
     n_pages, page = kv_pages
     KVH, D = cfg.kv_heads, cfg.head_width
     int8 = cfg.kv_cache_dtype == "int8"
@@ -118,6 +125,12 @@ def kv_pool_leaves(cfg: ModelConfig, kv_pages: Tuple[int, int], dtype) -> dict:
         scale = ((n_pages, page, KVH), jnp.float32)
         leaves.update(key_scale=scale, value_scale=scale)
     return leaves
+
+
+def kv_pool_wire_heads(cfg: ModelConfig) -> int:
+    """Heads a pool row's lanes split into in a page span on the wire
+    (``serving.slots``): the kv heads, or one for a latent row."""
+    return 1 if cfg.latent_attention else cfg.kv_heads
 
 
 def resolve_remat_policy(cfg: ModelConfig):
@@ -147,6 +160,7 @@ def resolve_remat_policy(cfg: ModelConfig):
 
 def _norm(cfg: ModelConfig, dtype, name: str):
     kwargs = dict(
+        epsilon=cfg.norm_eps,
         dtype=dtype,
         param_dtype=resolve_dtype(cfg.param_dtype),
         scale_init=nn.with_partitioning(initializers.ones, ("embed",)),
@@ -591,7 +605,17 @@ class Block(nn.Module):
     decode stack), the carry's third element is the stacked K/V pool, which
     ``Attention`` updates in place at that layer. ``step`` is the pass of a
     looped stack (see ``Attention``). With ``cfg.post_norm`` each sublayer's
-    output is normed once more before it joins the residual stream."""
+    output is normed once more before it joins the residual stream.
+
+    ``kind`` is ``cfg.layer_kind`` of the block's place in an unrolled
+    stack: "dense" (the MLP) or "moe" (the routed layer of
+    ``cfg.moe_dispatch``); None, in a scanned stack, is the one kind all its
+    layers have. The attention is ``cfg``'s (full heads or latent), and
+    declares the cache state it keeps. A dropless routed block sows
+    ``expert_counts`` ``[B, n_experts]`` into the ``routing`` collection
+    where the caller makes it mutable: how many of each batch row's
+    positions it sent to each expert (the engine's decode step sums them
+    over the rows that decode)."""
 
     cfg: ModelConfig
     deterministic: bool = True
@@ -599,6 +623,7 @@ class Block(nn.Module):
     cache_len: Optional[int] = None
     mesh: Optional[Any] = None
     kv_pages: Optional[Tuple[int, int]] = None
+    kind: Optional[str] = None
 
     @nn.compact
     def __call__(self, carry, layer=None, step=None):
@@ -614,7 +639,8 @@ class Block(nn.Module):
             x, aux, pools = carry
         else:
             x, aux = carry
-        attn = Attention(
+        kind = self.kind or cfg.layer_kind(0)
+        attn = (LatentAttention if cfg.latent_attention else Attention)(
             cfg, self.deterministic, self.decode, self.cache_len, self.mesh,
             self.kv_pages, name="attn"
         )(
@@ -628,7 +654,13 @@ class Block(nn.Module):
         # pin the residual stream: batch/seq sharded, replicated over tensor
         # (Megatron layout) — GSPMD must not invent another layout for it
         x = constrain_activation(x, "batch", "seq", "embed")
-        if cfg.n_experts > 0:
+        if kind == "moe" and cfg.moe_dispatch == "dropless":
+            layer_aux = None  # no auxiliary loss: the bias balances the load
+            mo, counts = DroplessMoE(cfg, name="moe")(
+                _norm(cfg, x.dtype, "ln_mlp")(x)
+            )
+            self.sow("routing", "expert_counts", counts)
+        elif kind == "moe":
             mo, layer_aux = MoEMLP(cfg, self.deterministic, name="moe")(
                 _norm(cfg, x.dtype, "ln_mlp")(x)
             )
@@ -804,10 +836,12 @@ class Transformer(nn.Module):
             )(cfg, not train, self.decode, self.cache_len, self.mesh,
               self.kv_pages, name="blocks")
         else:
+            # unrolled, each block is its own kind (``cfg.layer_kind``): the
+            # only stack whose layers may differ
             blocks = [
                 block_cls(
                     cfg, not train, self.decode, self.cache_len, self.mesh,
-                    self.kv_pages, name=f"block_{i}",
+                    self.kv_pages, cfg.layer_kind(i), name=f"block_{i}",
                 )
                 for i in range(cfg.n_layers)
             ]

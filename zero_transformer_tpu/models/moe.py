@@ -20,10 +20,19 @@ none). TPU-first design choices:
 - expert weights are stacked ``[E, d, f]`` with the ``expert`` logical axis
   → sharded over the mesh's ``expert`` axis (EP) and composable with
   Megatron TP on the ``mlp`` axis within each expert.
+
+``DroplessMoE`` (``cfg.moe_dispatch == "dropless"``) is the SERVED routed
+layer, the DeepSeek-V3 one: sigmoid scores, the top k of score + a selection
+bias, weights from the scores alone, a shared expert beside the routed ones,
+rows sorted by expert into a grouped matmul (``jax.lax.ragged_dot``). No
+token is dropped and a row's result depends on that row alone: what the
+serving engine needs (a dropped token makes chunked prefill + decode
+disagree with a full forward; dead slots and a chunk's padding rows share
+every batch). ``MoEMLP``'s capacity path stays for the training recipes.
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -187,3 +196,134 @@ class MoEMLP(nn.Module):
         out = jnp.einsum("btec,ebcd->btd", combine.astype(dtype), out_e)
         out = nn.Dropout(cfg.dropout, deterministic=self.deterministic)(out)
         return out, aux
+
+
+def route_sigmoid(
+    x: jax.Array, router: jax.Array, bias: jax.Array, top_k: int, scale: float,
+) -> Tuple[jax.Array, jax.Array]:
+    """``[N, d]`` rows -> (chosen experts ``[N, k]`` int32, weights ``[N, k]``
+    float32). Scores ``s = sigmoid(x W_r)`` in float32; the top ``k`` of
+    ``s + bias`` are chosen; the weights are ``s[chosen]`` (the bias moves
+    the CHOICE, never the weight), normalised over the chosen and times
+    ``scale``. Every row on its own."""
+    s = jax.nn.sigmoid(jnp.einsum(
+        "nd,de->ne", x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ))
+    _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, chosen, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), w * jnp.float32(scale)
+
+
+class _SwiGLU(nn.Module):
+    """``wo(silu(gate x) * wi x)`` at width ``f``: the shared expert."""
+
+    cfg: ModelConfig
+    f: int
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        param_dtype = resolve_dtype(cfg.param_dtype)
+
+        def dense(features, axes, std, name):
+            return nn.Dense(
+                features, use_bias=False, dtype=x.dtype, param_dtype=param_dtype,
+                kernel_init=nn.with_partitioning(initializers.normal(stddev=std), axes),
+                name=name,
+            )
+
+        h = nn.silu(dense(self.f, ("embed", "mlp"), 0.02, "gate")(x)) * dense(
+            self.f, ("embed", "mlp"), 0.02, "wi")(x)
+        return dense(
+            cfg.d_model, ("mlp", "embed"), 0.02 / (2 * cfg.n_layers) ** 0.5, "wo"
+        )(h)
+
+
+class DroplessMoE(nn.Module):
+    """The routed layer of ``cfg.moe_dispatch == "dropless"``: returns
+    ``(output, expert_counts)``.
+
+    ``experts = (lo, hi)`` says which experts THIS module holds (None: all
+    ``cfg.n_experts``). It routes every row over all of them, computes the
+    part of its own — expert weights ``[hi - lo, ...]`` — and, with
+    ``shared``, the shared expert's: the parts of modules that split the
+    experts between them (one of them holding the shared expert) add up to
+    the whole layer, which is the shape an expert-parallel cut has. No code
+    here stands in for absent chips.
+
+    ``expert_counts`` ``[B, n_experts]`` int32: how many of a batch row's
+    positions were sent to each expert (the caller knows which batch rows
+    are live)."""
+
+    cfg: ModelConfig
+    experts: Optional[Tuple[int, int]] = None
+    shared: bool = True
+
+    @nn.compact
+    def __call__(self, x: jax.Array):
+        cfg = self.cfg
+        dtype = x.dtype
+        param_dtype = resolve_dtype(cfg.param_dtype)
+        B, T, d = x.shape
+        E, k, f = cfg.n_experts, cfg.moe_top_k, cfg.moe_ff_dim
+        lo, hi = self.experts or (0, E)
+        held = hi - lo
+        N = B * T
+        rows = x.reshape(N, d)
+
+        router = self.param(
+            "router",
+            nn.with_partitioning(initializers.normal(stddev=0.02), ("embed", None)),
+            (d, E), param_dtype,
+        )
+        bias = self.param(
+            "router_bias", nn.with_partitioning(initializers.zeros, (None,)),
+            (E,), param_dtype,
+        )
+        with jax.named_scope("moe_route"):
+            chosen, weight = route_sigmoid(rows, router, bias, k, cfg.moe_routed_scale)
+            counts = jnp.sum(
+                jax.nn.one_hot(chosen, E, dtype=jnp.int32).reshape(B, T * k, E), axis=1
+            )
+            # every (row, choice) pair, sorted by the expert that takes it;
+            # a pair whose expert another module holds sorts past the last
+            # group, where the grouped matmul computes nothing
+            pair_expert = chosen.reshape(N * k) - lo
+            mine = (pair_expert >= 0) & (pair_expert < held)
+            group = jnp.where(mine, pair_expert, held)
+            order = jnp.argsort(group, stable=True)
+            sizes = jnp.sum(
+                jax.nn.one_hot(group, held, dtype=jnp.int32), axis=0
+            )
+            back = jnp.argsort(order)
+
+        def experts_weight(name, shape, axes, std):
+            return self.param(
+                name, nn.with_partitioning(initializers.normal(stddev=std), axes),
+                shape, param_dtype,
+            ).astype(dtype)
+
+        wi = experts_weight("wi", (held, d, f), ("expert", "embed", "mlp"), 0.02)
+        wg = experts_weight("gate", (held, d, f), ("expert", "embed", "mlp"), 0.02)
+        wo = experts_weight(
+            "wo", (held, f, d), ("expert", "mlp", "embed"),
+            0.02 / (2 * cfg.n_layers) ** 0.5,
+        )
+        with jax.named_scope("moe_experts"):
+            xs = rows[order // k]  # [N * k, d], grouped by expert
+            h = nn.silu(jax.lax.ragged_dot(xs, wg, sizes)) * jax.lax.ragged_dot(xs, wi, sizes)
+            ys = jax.lax.ragged_dot(h, wo, sizes)
+            # back to (row, choice) order; another module's pairs add nothing
+            # (whatever the grouped matmul left in the rows past its groups)
+            y = ys[back].reshape(N, k, d).astype(jnp.float32) * weight[..., None]
+            out = jnp.sum(
+                jnp.where(mine.reshape(N, k, 1), y, 0.0), axis=1
+            ).astype(dtype)
+        if self.shared and cfg.moe_shared_experts:
+            with jax.named_scope("moe_shared"):
+                out = out + _SwiGLU(
+                    cfg, cfg.moe_shared_experts * f, name="shared"
+                )(rows)
+        return out.reshape(B, T, d), counts

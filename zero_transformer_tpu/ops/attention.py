@@ -212,3 +212,49 @@ def paged_decode_attention(
         (q_names,),
     )(q, block_table, offs, slopes.reshape(H), lyr, *pools)
     return out
+
+
+def latent_kernel_supported(impl: str, *, H: int, **shape) -> bool:
+    """``paged_kernel_supported`` for the latent decode kernel
+    (``ops.pallas.latent_attention``): the heads a device holds, one cached
+    row serving them all."""
+    from zero_transformer_tpu.ops.pallas import latent_attention as la
+    from zero_transformer_tpu.parallel.sharding import (
+        kernel_local_size, kernel_shardable,
+    )
+
+    return kernel_shardable(heads=H) and la.supported(
+        impl, H=kernel_local_size("heads", H), **shape
+    )
+
+
+# graftlint: hot-path
+def latent_decode_attention(
+    q, pool, block_table, q_offset, *, value_width: int, causal: bool,
+    softmax_scale: float, layer=None,
+) -> jax.Array:
+    """The latent decode kernel at its dispatch site: on a mesh each device
+    attends for ITS heads over the (replicated) latent pool, the absorbed
+    form having made the heads independent of one another."""
+    from zero_transformer_tpu.ops.pallas import latent_attention as la
+    from zero_transformer_tpu.parallel.sharding import shard_kernel
+
+    B = q.shape[0]
+    offs = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32).reshape(-1), (B,))
+    if layer is None:  # an unstacked pool is a stack of one: moves no byte
+        layer, pool = 0, pool[None]
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def local(q, block_table, offs, lyr, pool):
+        return (la.latent_paged_attention(
+            q, pool, block_table, offs, value_width=value_width, causal=causal,
+            softmax_scale=softmax_scale, layer=lyr[0],
+        ),)
+
+    names = ("batch", None, "heads", None)
+    (out,) = shard_kernel(
+        local,
+        (names, ("batch", None), ("batch",), (None,), (None, None, None, None)),
+        (names,),
+    )(q, block_table, offs, lyr, pool)
+    return out

@@ -15,6 +15,9 @@ Cases:
   gather-to-slab path it replaces. Both sides run under jit with the
   gather INSIDE the reference program, as the engine's fused step computes
   take + attention in one compiled program.
+
+``latent_vs_gather`` is ``paged_vs_gather``'s twin for the latent decode
+kernel (``chip_smoke.py``'s ``kernels`` phase, ``tests``).
 """
 from __future__ import annotations
 
@@ -190,6 +193,54 @@ def paged_vs_gather(
         "shape": {"B": B, "T": T, "H": H, "KVH": KVH, "D": D, "page": page,
                   "cache_len": S},
         "dtype": jnp.dtype(dtype).name, "int8_pages": int8, "ragged": ragged,
+        "finite": bool(jnp.all(jnp.isfinite(out))),
+        "max_abs_diff": diff, "ulps": diff / ulp,
+        "control_ulps": float(jnp.max(jnp.abs(control - ref))) / ulp,
+    }
+
+
+def latent_vs_gather(
+    *, B: int, T: int, H: int, R: int, value_width: int, page: int,
+    n_blocks: int, dtype, seed: int = 0, interpret: bool = False,
+    layers: int = 2,
+) -> dict:
+    """``paged_vs_gather`` for the latent decode kernel
+    (``ops.pallas.latent_attention``) against its own gather path, on a
+    stacked pool with a traced layer index, the ragged batch a server hands
+    it: one row full, one at offset 0, one ending on a page's last position,
+    the others a few pages long, every table entry past a row's live pages
+    the trash page 0. Same units, same control."""
+    from zero_transformer_tpu.ops.pallas import latent_attention as la
+
+    S = page * n_blocks
+    n_pages = B * n_blocks + 1
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (B, T, H, R), dtype)
+    pool = jax.random.normal(ks[1], (layers, n_pages, page, R), dtype)
+    table = (1 + jax.random.permutation(ks[2], n_pages - 1)).reshape(B, n_blocks)
+    offsets = jax.random.randint(ks[3], (B,), 1, min(S, 6 * page) - T, jnp.int32)
+    edges = jnp.asarray([S - T, 0, 2 * page - T], jnp.int32)
+    offsets = offsets.at[: min(B, 3)].set(edges[: min(B, 3)])
+    live_pages = (offsets + T + page - 1) // page
+    table = jnp.where(
+        jnp.arange(n_blocks)[None, :] < live_pages[:, None], table, 0
+    ).astype(jnp.int32)
+    kw = dict(value_width=value_width, causal=T > 1, softmax_scale=R ** -0.5)
+    layer = jnp.int32(layers - 1)
+    reference = jax.jit(lambda q, pool, tbl, off, l: la.gather_attention(
+        q, pool, tbl, off, layer=l, **kw))
+    kernel = jax.jit(lambda q, pool, tbl, off, l: la.latent_paged_attention(
+        q, pool, tbl, off, layer=l, interpret=interpret, **kw))
+    ref = reference(q, pool, table, offsets, layer).astype(jnp.float32)
+    out = kernel(q, pool, table, offsets, layer).astype(jnp.float32)
+    off_by_one = jnp.where(offsets > 0, offsets - 1, offsets + 1)
+    control = reference(q, pool, table, off_by_one, layer).astype(jnp.float32)
+    ulp = float(jnp.finfo(dtype).eps) * float(jnp.max(jnp.abs(ref)))
+    diff = float(jnp.max(jnp.abs(out - ref)))
+    return {
+        "shape": {"B": B, "T": T, "H": H, "row": R, "value_width": value_width,
+                  "page": page, "cache_len": S},
+        "dtype": jnp.dtype(dtype).name,
         "finite": bool(jnp.all(jnp.isfinite(out))),
         "max_abs_diff": diff, "ulps": diff / ulp,
         "control_ulps": float(jnp.max(jnp.abs(control - ref))) / ulp,

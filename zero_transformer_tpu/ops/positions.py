@@ -75,16 +75,26 @@ def rope_frequencies(head_dim: int, theta: float = 10000.0) -> jax.Array:
 
 
 def apply_rope(
-    x: jax.Array, positions: jax.Array, theta: float = 10000.0
+    x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+    interleaved: bool = False,
 ) -> jax.Array:
     """Rotate [..., T, n_heads, head_dim] by position. ``positions`` is [T] or
-    broadcastable to x's batch+time dims; rotation math runs in float32."""
+    broadcastable to x's batch+time dims; rotation math runs in float32.
+    A pair is lanes ``(i, i + head_dim/2)``, or with ``interleaved`` lanes
+    ``(2i, 2i + 1)``."""
     head_dim = x.shape[-1]
     freqs = rope_frequencies(head_dim, theta)  # [hd/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., T, hd/2]
     # insert head axis
     angles = angles[..., None, :]
     sin, cos = jnp.sin(angles), jnp.cos(angles)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+        rotated = jnp.stack(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        ).reshape(x.shape)
+        return rotated.astype(x.dtype)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
     rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return rotated.astype(x.dtype)

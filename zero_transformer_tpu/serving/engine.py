@@ -463,24 +463,46 @@ def _sample_tail_impl(sampling, last_logits, gen_mask, rngs):
     return token, gen_mask | newly, rngs
 
 
-def _forward_only_impl(model, params, token, cache):
+def _forward_only_impl(model, params, token, cache, decoding=None):
     """The forward half of the decode tick: one fused model apply + the
     per-slot non-finite guard (the training anomaly predicate inlines
     here) so the healthy path pays one dispatch per tick, not two, and the
-    [S] mask rides the same device_get as the tokens."""
+    [S] mask rides the same device_get as the tokens.
+
+    With ``decoding`` (``[S]`` bool: the rows whose token someone is
+    waiting for; a dropless routed model's engine passes it) also the
+    routed layers' ``expert_counts`` of this apply, summed over those rows
+    and reduced here to three int32: (row, expert) pairs routed, the
+    busiest expert's rows summed over the layers, and the (layer, expert)
+    pairs that took any row."""
     logits, vars_out = model.apply(
-        {"params": params, "cache": cache}, token[:, None], mutable=["cache"]
+        {"params": params, "cache": cache}, token[:, None],
+        mutable=["cache"] if decoding is None else ["cache", "routing"],
     )
     new_logits = logits[:, -1, :].astype(jnp.float32)
-    return new_logits, vars_out["cache"], nonfinite_rows(new_logits)
+    routing = None
+    if decoding is not None:
+        load = jnp.stack([
+            jnp.sum(jnp.where(decoding[:, None], counts, 0), axis=0)
+            for counts in jax.tree.leaves(vars_out["routing"])
+        ])  # [routed layers, n_experts]
+        routing = jnp.stack([
+            jnp.sum(load), jnp.sum(jnp.max(load, axis=1)), jnp.sum(load > 0)
+        ]).astype(jnp.int32)
+    return new_logits, vars_out["cache"], nonfinite_rows(new_logits), routing
 
 
-def _fused_step_impl(model, sampling, params, last_logits, cache, gen_mask, rngs):
+def _fused_step_impl(
+    model, sampling, params, last_logits, cache, gen_mask, rngs, decoding=None
+):
     """One decode tick as ONE program: the sampling tail, then the fused
-    forward over the tokens it drew."""
+    forward over the tokens it drew. The last output is
+    ``_forward_only_impl``'s routing counts, None without ``decoding``."""
     token, gen_mask, rngs = _sample_tail_impl(sampling, last_logits, gen_mask, rngs)
-    new_logits, cache, bad = _forward_only_impl(model, params, token, cache)
-    return token, new_logits, cache, gen_mask, rngs, bad
+    new_logits, cache, bad, routing = _forward_only_impl(
+        model, params, token, cache, decoding
+    )
+    return token, new_logits, cache, gen_mask, rngs, bad, routing
 
 
 def _jit_fused_step():
@@ -519,11 +541,15 @@ def _paged_chunk_prefill_impl(
     donated: on a fault the engine keeps the pre-chunk pool and fails only
     the prefilling slots (``_on_prefill_fault``).
 
-    Returns ``(cache, last_logits)`` where ``last_logits[s]`` is the f32
-    logits row at the prompt's final position — meaningful only for rows
-    whose prefill completes in this chunk (``true_lens`` falls inside the
-    window); the engine installs exactly those rows."""
+    Returns ``(cache, last_logits, touched)`` where ``last_logits[s]`` is
+    the f32 logits row at the prompt's final position — meaningful only for
+    rows whose prefill completes in this chunk (``true_lens`` falls inside
+    the window); the engine installs exactly those rows. ``touched`` (None
+    but for a dropless routed model) counts the (layer, expert) pairs that
+    took any of the ``S * C`` rows the program computes whoever prefills:
+    what its grouped matmuls were handed."""
     S, C = tokens.shape
+    counts = model.cfg.moe_dispatch == "dropless"
 
     def pre(path, leaf):
         name = _leaf_name(path)
@@ -536,9 +562,15 @@ def _paged_chunk_prefill_impl(
 
     staged = jax.tree_util.tree_map_with_path(pre, cache)
     logits, vars_out = model.apply(
-        {"params": params, "cache": staged}, tokens, mutable=["cache"]
+        {"params": params, "cache": staged}, tokens,
+        mutable=["cache", "routing"] if counts else ["cache"],
     )
     new_cache = vars_out["cache"]
+    touched = None
+    if counts:
+        touched = sum(
+            jnp.sum(jnp.sum(c, axis=0) > 0) for c in jax.tree.leaves(vars_out["routing"])
+        ).astype(jnp.int32)
 
     last = jax.vmap(
         lambda row, i: jax.lax.dynamic_slice_in_dim(row, i, 1, axis=0)[0]
@@ -552,7 +584,7 @@ def _paged_chunk_prefill_impl(
             return jnp.broadcast_to(index_after, leaf.shape).astype(leaf.dtype)
         return leaf
 
-    return jax.tree_util.tree_map_with_path(post, new_cache), last
+    return jax.tree_util.tree_map_with_path(post, new_cache), last, touched
 
 
 # shared like _FUSED_SHARED: the static (model structure) compares equal
@@ -891,18 +923,30 @@ class ServingEngine:
         # is the paged-attention kernel compiled into the decode program?
         # Same gate the model consults, so the exported gauge can never
         # disagree with what actually traced.
-        from zero_transformer_tpu.ops.attention import paged_kernel_supported
+        from zero_transformer_tpu.ops.attention import (
+            latent_kernel_supported, paged_kernel_supported,
+        )
 
-        self._paged_kernel = paged_kernel_supported(
-            cfg.attention_impl,
-            T=1 + self.draft_k if self.draft_k else 1,
-            H=cfg.n_heads,
-            KVH=cfg.kv_heads,
-            D=cfg.head_width,
-            S=self.cache_len,
-            page_size=self.page_size,
+        window = dict(
+            T=1 + self.draft_k if self.draft_k else 1, H=cfg.n_heads,
+            S=self.cache_len, page_size=self.page_size,
             dtype=resolve_dtype(cfg.compute_dtype),
         )
+        if cfg.latent_attention:  # the latent kernel reads the latent pages
+            self._paged_kernel = latent_kernel_supported(
+                cfg.attention_impl, R=cfg.latent_row, **window
+            )
+        else:
+            self._paged_kernel = paged_kernel_supported(
+                cfg.attention_impl, KVH=cfg.kv_heads, D=cfg.head_width, **window
+            )
+        # a dropless routed model's decode step also counts what its
+        # routed layers sent where (``_forward_only_impl``)
+        self._counts_routing = cfg.moe_dispatch == "dropless"
+        self._decoding: Tuple[Optional[tuple], Any] = (None, None)
+        # ... and so does its chunk-prefill program: the last chunk's count,
+        # on the device until a decode tick's device_get takes it along
+        self._prefill_touched = None
         # did THIS tick run a prefill chunk? classifies the tick's ITL
         # samples for attribution
         self._prefill_work = False
@@ -1000,6 +1044,12 @@ class ServingEngine:
             # kernel), and the whole table it is handed
             "kernel_pages_live": 0,
             "kernel_pages_table": 0,
+            # a dropless routed model's decode ticks, summed over ticks and
+            # routed layers: (row, expert) pairs routed, the busiest
+            # expert's rows, the mean rows an expert (pairs / n_experts)
+            "moe_tokens_routed": 0,
+            "moe_expert_load_max": 0,
+            "moe_expert_load_mean": 0.0,
             # speculation counters: acceptance_rate = accepted / drafted
             "spec_ticks": 0,
             "draft_tokens": 0,
@@ -1685,7 +1735,9 @@ class ServingEngine:
                 # observe skips model+params (engine-lifetime constants):
                 # the describe walk stays O(per-tick args), not O(params)
                 self._ds_prefill.observe(*chunk_args[2:])
-                cache, last = _in_mesh(self.mesh, self._paged_chunk, *chunk_args)
+                cache, last, self._prefill_touched = _in_mesh(
+                    self.mesh, self._paged_chunk, *chunk_args
+                )
         except CompileFamilyExceeded:
             # strict-mode sanitizer trip: the whole point is the readable
             # signature listing — it must reach the test harness, not be
@@ -2055,7 +2107,7 @@ class ServingEngine:
                          active=self.active_count, spec=bool(self.draft_k),
                          loops=self.cfg.n_loops,
                          pages_in_use=self.slots.pool.in_use,
-                         table_pages=table_pages):
+                         table_pages=table_pages) as step_span:
                 if self._chaos is not None:
                     self._chaos.on_tick(self._tick)
                 # one batched push of every block-table change this tick
@@ -2075,6 +2127,13 @@ class ServingEngine:
                             self._gen_mask,
                             self._rngs,
                         )
+                        if self._counts_routing:
+                            # the rows that decode, on the device; sent
+                            # again only when a slot joins or leaves
+                            live = tuple(act is not None for act in self._active)
+                            if live != self._decoding[0]:
+                                self._decoding = (live, jnp.asarray(live, jnp.bool_))
+                            fused_args += (self._decoding[1],)
                         # skip model (0) + params (2) — engine-lifetime
                         # constants; sampling statics + cache/logits/mask/rng
                         # shapes remain
@@ -2086,7 +2145,7 @@ class ServingEngine:
                             self._ds_paged.observe(
                                 fused_args[4], 1 + self.draft_k
                             )
-                        token, self._last_logits, self.slots.cache, self._gen_mask, self._rngs, bad = _in_mesh(
+                        token, self._last_logits, self.slots.cache, self._gen_mask, self._rngs, bad, routing = _in_mesh(
                             self.mesh, self._fused, *fused_args
                         )
                         if self._chaos is not None:
@@ -2100,7 +2159,19 @@ class ServingEngine:
                             bad = _in_mesh(self.mesh, nonfinite_rows, self._last_logits)
                     with tr.span("device_wait", "engine", tick=tick_idx):
                         # graftlint: allow[host-sync-in-hot-path] reason=THE designed per-tick sync — one coalesced device_get of token + poison mask (PR 2's one-sync budget); every other read rides it
-                        tokens, bad_rows = jax.device_get((token, bad))
+                        tokens, bad_rows, routing, prefill_touched = jax.device_get(
+                            (token, bad, routing, self._prefill_touched)
+                        )
+                    if prefill_touched is not None:
+                        self._prefill_touched = None
+                        step_span.note(prefill_experts_touched=int(prefill_touched))
+                    if routing is not None:
+                        routed, load_max, touched = (int(n) for n in routing)
+                        self.stats["moe_tokens_routed"] += routed
+                        self.stats["moe_expert_load_max"] += load_max
+                        self.stats["moe_expert_load_mean"] += routed / self.cfg.n_experts
+                        step_span.note(experts_touched=touched, moe_routed=routed,
+                                       moe_load_max=load_max)
                     blocks = [[int(t)] for t in tokens.tolist()]
                     n_emits = [1] * self.n_slots
         except CompileFamilyExceeded:
@@ -3279,7 +3350,12 @@ class ServingEngine:
             ),
             # is the paged-attention kernel compiled into the decode
             # program (vs the gather fallback)?
-            "kernel_paged_attention": int(self._paged_kernel),
+            "kernel_paged_attention": int(
+                self._paged_kernel and not self.cfg.latent_attention
+            ),
+            "kernel_latent_attention": int(
+                self._paged_kernel and self.cfg.latent_attention
+            ),
             # disaggregation / migration gauges
             "role": self.role,
             "free_pages": self.free_pages,
@@ -3322,6 +3398,7 @@ class ServingEngine:
             "page_faults", "pages_reclaimed", "preemptions",
             "page_waits", "loop_passes",
             "kernel_pages_live", "kernel_pages_table",
+            "moe_tokens_routed", "moe_expert_load_max", "moe_expert_load_mean",
             "spec_ticks", "draft_tokens", "accepted_tokens",
             "migrations_out", "migrations_in", "migration_failures",
             "prefill_handoffs", "import_replayed_tokens",
@@ -3374,6 +3451,12 @@ class ServingEngine:
              "KV pages of the rows' live extents, summed over decode ticks"),
             ("kernel_pages_table",
              "Block-table entries handed to decode ticks (slots x blocks)"),
+            ("moe_tokens_routed",
+             "(row, expert) pairs routed by decode ticks, over the routed layers"),
+            ("moe_expert_load_max",
+             "Rows of the busiest expert, summed over decode ticks and routed layers"),
+            ("moe_expert_load_mean",
+             "Mean rows an expert, summed over decode ticks and routed layers"),
             ("spec_ticks", "Speculative decode ticks"),
             ("draft_tokens", "Draft tokens proposed"),
             ("accepted_tokens", "Draft tokens accepted by verify"),

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from zero_transformer_tpu.inference.generate import init_cache
+from zero_transformer_tpu.models.gpt import kv_pool_wire_heads
 
 # cache leaves that hold POSITIONS, not K/V data; widened per-slot.
 # (cache_index: per-layer attention write position; decode_pos: the learned-
@@ -46,8 +47,12 @@ INDEX_LEAVES = ("cache_index", "decode_pos")
 # allocated, shared, copied on write, banked by the prefix index and
 # shipped in a span — carries every entry of its positions. The int32
 # per-row page map is its own leaf and has no pass axis: a token sits in
-# one page at one position whatever the pass.
-POOL_LEAVES = ("cached_key", "cached_value", "key_scale", "value_scale")
+# one page at one position whatever the pass. A latent-attention model keeps
+# ONE pool instead, a latent row a position (``models.mla``): the same page
+# axis, the same unit.
+POOL_LEAVES = (
+    "cached_key", "cached_value", "key_scale", "value_scale", "cached_latent",
+)
 TABLE_LEAF = "block_table"
 _PAGE_AXIS_FROM_END = 3
 
@@ -402,7 +407,8 @@ class PagedKVCache:
         # dtype)}. On the wire a page is [(L,) page, KVH, D | 1] — the heads
         # split back out of the pool's merged lane axis, on HOST arrays
         # (free), so replicas keep exchanging the payloads they always have
-        kvh = model.cfg.kv_heads
+        # (a latent row is one head of all its lanes)
+        kvh = kv_pool_wire_heads(model.cfg)
         self.wire_leaves: Dict[str, Tuple[str, Tuple[int, ...], Any]] = {}
         for path, leaf in jax.tree_util.tree_leaves_with_path(self.cache):
             if _leaf_name(path) in POOL_LEAVES:
