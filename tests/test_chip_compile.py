@@ -215,13 +215,14 @@ def _cfg_580m_cut(int8: bool, scan: bool):
 
 
 def _serving_programs(one_chip, monkeypatch, cfg, cache_len=CACHE_LEN, n_pages=N_PAGES,
-                      held=False):
+                      held=False, rows=None):
     """``cfg`` at a benchmark cell's engine shapes — 16 slots, page 16,
     chunk 64, the cell's cache length and pool — as the engine's own jitted
     decode step and paged chunk prefill, compiled for the described chip,
     from the tree a checkpoint gives or (``held``) from the serving form
     the engine holds of it. Returns their optimised HLO and the number of
-    pool leaves."""
+    pool leaves; the prefill program computes ``rows`` rows: by default
+    the engine's ``PREFILL_ROWS``."""
     from zero_transformer_tpu.inference.generate import decode_model, serving_params
     from zero_transformer_tpu.inference.sampling import SamplingConfig
     from zero_transformer_tpu.serving import engine as eng
@@ -268,11 +269,11 @@ def _serving_programs(one_chip, monkeypatch, cfg, cache_len=CACHE_LEN, n_pages=N
         sds((N_SLOTS, V), jnp.float32), cache, sds((N_SLOTS, V), jnp.bool_),
         sds((N_SLOTS, 2), jnp.uint32),
     ).compile().as_text()
-    rows = sds((N_SLOTS,), jnp.int32)
+    rows = rows or eng.PREFILL_ROWS
     prefill = jax.jit(eng._paged_chunk_prefill_impl, static_argnums=(0,)).lower(
-        model, params, cache, sds((N_SLOTS, CHUNK), jnp.int32), rows, rows,
-        sds((N_SLOTS,), jnp.bool_), sds((N_SLOTS, cache_len // PAGE), jnp.int32),
-        rows,
+        model, params, cache, sds((rows, CHUNK), jnp.int32),
+        sds((rows,), jnp.int32), sds((rows,), jnp.int32), sds((rows,), jnp.int32),
+        sds((N_SLOTS, cache_len // PAGE), jnp.int32), sds((N_SLOTS,), jnp.int32),
     ).compile().as_text()
     return decode, prefill, n_pools
 
@@ -313,6 +314,35 @@ def test_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch, int8, 
     on_kv = [op for op, _, kv, _ in _pool_ops(prefill) if kv and op not in PREFETCH]
     assert set(on_kv) <= {"scatter", "copy", "copy-start"}, on_kv
     assert len(on_kv) - on_kv.count("scatter") == n_kv, on_kv
+
+
+@pytest.mark.parametrize("rows", [2, N_SLOTS], ids=["engine", "every_slot"])
+def test_the_prefill_program_gathers_the_rows_it_is_handed(one_chip, monkeypatch, rows):
+    """The 580M cell's chunk-prefill program at the engine's row count
+    (``PREFILL_ROWS``): it holds the one whole-pool copy per K/V leaf that
+    not donating forces, and no value of the program is
+    ``[16, 2048, ...]``-shaped or holds a row's 2,048 cached positions
+    sixteen times: the gather, its heads-first re-layout and the attention
+    over it are of the rows it is handed. (Handed every slot it is the
+    control: the same search finds its ``[16, .., 2048, ..]``.)"""
+    import re
+
+    from zero_transformer_tpu.serving import engine as eng
+
+    assert eng.PREFILL_ROWS == 2
+    _, hlo, n_pools = _serving_programs(
+        one_chip, monkeypatch, _cfg_580m_cut(False, True), rows=rows)
+    shapes = set(re.findall(r"[a-z0-9]+\[(\d+(?:,\d+)+)\]", hlo))
+    # shapes that lead with the slot count and carry the cache length
+    whole_slot_rows = sorted(
+        dims for dims in shapes
+        if dims.split(",")[0] == str(N_SLOTS) and str(CACHE_LEN) in dims.split(",")[1:]
+    )
+    on_kv = [op for op, _, kv, _ in _pool_ops(hlo) if kv and op not in PREFETCH]
+    assert set(on_kv) <= {"scatter", "copy", "copy-start"}, on_kv
+    assert len(on_kv) - on_kv.count("scatter") == n_pools, on_kv
+    assert f"[{rows},{CACHE_LEN},12,128]" in hlo  # the gathered rows
+    assert bool(whole_slot_rows) == (rows == N_SLOTS), whole_slot_rows
 
 
 def _weight_reads(hlo: str, cfg):

@@ -29,6 +29,7 @@ from zero_transformer_tpu.inference.generate import (
 )
 from zero_transformer_tpu.inference.sampling import SamplingConfig
 from zero_transformer_tpu.models import Transformer
+from zero_transformer_tpu.serving.slots import POOL_LEAVES, _leaf_name
 from zero_transformer_tpu.serving import (
     PagedPrefixIndex,
     PagePool,
@@ -238,8 +239,9 @@ def test_learned_positions_chunked_parity():
 
 def test_batched_admission_single_install_dispatch(cfg, params, reference):
     """N free slots + N queued prompts admit as ONE batch: every prompt
-    progresses through the same chunk dispatches and completion installs
-    coalesce — and each trajectory still matches generate()."""
+    progresses through the same ticks' chunk dispatches (two slots to a
+    dispatch) and a dispatch's completion installs coalesce — and each
+    trajectory still matches generate()."""
     engine = make_engine(cfg, params, n_slots=4, prefill_chunk=8)
     prompts = [_prompt(9, offset=i * 11) for i in range(4)]
     handles = [
@@ -254,6 +256,74 @@ def test_batched_admission_single_install_dispatch(cfg, params, reference):
     engine.run_until_idle()
     for i, (p, h) in enumerate(zip(prompts, handles)):
         assert h.tokens == reference(p, i, max_new=6)
+
+
+def _chunk_spans(engine):
+    return [
+        attrs for _, _, name, _, _, attrs in engine.tracer.spans()
+        if name == "prefill_chunk"
+    ]
+
+
+@pytest.mark.parametrize("n", [1, 3, 5, 16])
+def test_simultaneous_admissions_prefill_two_rows_to_a_dispatch(cfg, params, reference, n):
+    """The chunk program computes the rows that prefill: ``n`` prompts
+    admitted in one tick of a 16-slot engine go through the ONE
+    [PREFILL_ROWS, chunk] program two at a time (1, 2, 3 and 8 dispatches),
+    every trajectory still matches generate(), and the dispatch site has
+    seen one signature however many slots prefilled."""
+    engine = make_engine(cfg, params, n_slots=16, prefill_chunk=8)
+    assert engine.prefill_rows == 2
+    prompts = [_prompt(4 + i % 5, offset=7 * i) for i in range(n)]
+    handles = [
+        engine.submit(p, max_new_tokens=4, seed=i) for i, p in enumerate(prompts)
+    ]
+    engine.run_until_idle()
+    dispatches = -(-n // 2)
+    assert _chunk_spans(engine) == [
+        {"tick": 0, "slots": min(2, n - 2 * i), "rows": 2} for i in range(dispatches)
+    ]
+    for i, (p, h) in enumerate(zip(prompts, handles)):
+        assert h.status == "done", (h.status, h.error)
+        assert h.tokens == reference(p, i, max_new=4), f"request {i} garbled"
+    snap = engine.metrics_snapshot()
+    assert snap["prefill_rows_live"] == n == snap["prefill_chunks"]
+    assert snap["prefill_rows_computed"] == 2 * dispatches
+    site = engine._ds_prefill.snapshot()
+    assert site["distinct"] == site["max_entries"] == 1 and site["violations"] == 0
+    assert site["calls"] == dispatches
+
+
+def test_one_chunk_program_in_flight_at_a_time(cfg, params):
+    """Prefill-only ticks (a long prompt at an idle engine) and a burst's
+    later dispatches wait for nothing else: the engine itself waits for
+    the chunk program before, so no second pool-sized output is enqueued
+    while the first is still owed."""
+    engine = make_engine(cfg, params, n_slots=4, prefill_chunk=4)
+    program, ready = engine._paged_chunk, []
+
+    def watched(*args):
+        out = program(*args)
+        ready.append([last.is_ready() for last in previous])
+        previous.append(out[1])
+        return out
+
+    previous = []
+    engine._paged_chunk = watched
+    for i in range(3):
+        engine.submit(_prompt(13, offset=i), max_new_tokens=2, seed=i)
+    _drive_prefill_only(engine)  # 4 chunks x 2 dispatches a tick, no decode
+    assert len(ready) == 8 and all(all(r) for r in ready)
+
+
+def test_one_slot_engine_keeps_its_one_row(cfg, params, reference):
+    """The program never has more rows than the engine has slots."""
+    engine = make_engine(cfg, params, n_slots=1, prefill_chunk=8)
+    assert engine.prefill_rows == 1
+    handle = engine.submit(_prompt(11), max_new_tokens=4, seed=2)
+    engine.run_until_idle()
+    assert handle.tokens == reference(_prompt(11), 2, max_new=4)
+    assert [(a["slots"], a["rows"]) for a in _chunk_spans(engine)] == [(1, 1)] * 2
 
 
 def test_itl_attribution_excludes_prefill_ticks(cfg, params):
@@ -298,6 +368,66 @@ def test_prefill_fault_retires_only_the_chunk_slots(cfg, params, reference):
     engine.run_until_idle()
     assert retry.status == "done"
     assert retry.tokens == reference(_prompt(13, offset=50), 3)
+
+
+@pytest.mark.chaos
+def test_prefill_fault_in_a_bursts_second_dispatch_keeps_the_last_pool(cfg, params, reference):
+    """Three slots prefill in one tick of a 16-slot engine: two dispatches.
+    The SECOND faults: every slot still mid-prefill fails retryably (the
+    first dispatch's two, whose chunk ran, and the third), the cache the
+    engine holds is the one the first dispatch returned (its pools are not
+    the pre-tick arrays, and the fault replaced nothing), and the decoding
+    neighbours finish byte-identical to an undisturbed run."""
+    engine = make_engine(cfg, params, n_slots=16, prefill_chunk=4)
+    neighbors = [
+        engine.submit(_prompt(3, offset=9 * i), max_new_tokens=12, seed=i)
+        for i in range(2)
+    ]
+    for _ in range(4):
+        engine.step()
+    assert [(a["slots"], a["rows"]) for a in _chunk_spans(engine)] == [(2, 2)]
+    victims = [
+        engine.submit(_prompt(13, offset=50 + i), max_new_tokens=8, seed=3 + i)
+        for i in range(3)
+    ]
+    engine._admit()
+
+    def pools():
+        return [
+            leaf for path, leaf in jax.tree_util.tree_leaves_with_path(engine.slots.cache)
+            if _leaf_name(path) in POOL_LEAVES
+        ]
+
+    before, program, calls = pools(), engine._paged_chunk, []
+
+    def second_call_faults(*args):
+        calls.append(pools())
+        if len(calls) == 2:
+            raise RuntimeError("injected: the burst's second dispatch")
+        return program(*args)
+
+    engine._paged_chunk = second_call_faults
+    assert engine._prefill_tick()
+    engine._paged_chunk = program
+    assert len(calls) == 2 and all(a is b for a, b in zip(calls[0], before))
+    after_first = calls[1]
+    assert not any(a is b for a, b in zip(after_first, before))
+    assert all(a is b for a, b in zip(pools(), after_first))
+    assert engine.stats["prefill_rows_computed"] == 2 + 2  # the tick's first dispatch
+    assert engine.stats["prefill_faults"] == 1 and not engine._prefilling
+    for victim in victims:
+        assert victim.status == "failed" and victim.retryable and victim.tokens == []
+        assert "prefill chunk" in victim.error
+    engine.run_until_idle()
+    for i, neighbor in enumerate(neighbors):
+        assert neighbor.status == "done"
+        assert neighbor.tokens == reference(_prompt(3, offset=9 * i), i, max_new=12)
+    assert engine.stats["tick_faults"] == 0 and not engine._breaker.open
+    retry = engine.submit(_prompt(13, offset=50), max_new_tokens=8, seed=3)
+    engine.run_until_idle()
+    assert retry.status == "done"
+    assert retry.tokens == reference(_prompt(13, offset=50), 3)
+    assert [a["slots"] for a in _chunk_spans(engine)][-4:] == [1, 1, 1, 1]
 
 
 def test_decode_fault_mid_chunk_fails_prefilling_retryably(cfg, params, reference):

@@ -24,7 +24,9 @@ from zero_transformer_tpu.inference import (
 )
 from zero_transformer_tpu.parallel.sharding import unbox
 from zero_transformer_tpu.serving import engine as eng
-from zero_transformer_tpu.serving.slots import PagedKVCache
+from zero_transformer_tpu.serving.slots import (
+    INDEX_LEAVES, POOL_LEAVES, TABLE_LEAF, PagedKVCache, _leaf_name,
+)
 
 N_SLOTS, CACHE_LEN, PAGE, CHUNK = 2, 32, 4, 8
 N_BLOCKS = CACHE_LEN // PAGE
@@ -48,13 +50,13 @@ FAMILIES = {
 KEPT = ("ln_", "exit_gate", "router")
 
 
-def _model(param_dtype="float32", **kw):
+def _model(param_dtype="float32", n_slots=N_SLOTS, **kw):
     cfg = ModelConfig(
         name="tiny", vocab_size=128, d_model=64, n_layers=2, n_heads=4,
         max_seq_len=CACHE_LEN, dropout=0.0, compute_dtype="bfloat16",
         param_dtype=param_dtype, **kw,
     )
-    return decode_model(cfg, CACHE_LEN, kv_pages=(N_SLOTS * N_BLOCKS + 1, PAGE))
+    return decode_model(cfg, CACHE_LEN, kv_pages=(n_slots * N_BLOCKS + 1, PAGE))
 
 
 def _random_params(model, seed=0):
@@ -96,7 +98,7 @@ def _serve(model, params, steps=3):
         tokens[s, :n] = rng.integers(1, model.cfg.vocab_size, n)
     cache, last, _ = jax.jit(eng._paged_chunk_prefill_impl, static_argnums=(0,))(
         model, params, cache, jnp.asarray(tokens), jnp.zeros(N_SLOTS, jnp.int32),
-        jnp.asarray(lens), jnp.ones(N_SLOTS, jnp.bool_), jnp.asarray(table),
+        jnp.asarray(lens), jnp.arange(N_SLOTS, dtype=jnp.int32), jnp.asarray(table),
         jnp.asarray(lens),
     )
     out = [last]
@@ -162,6 +164,81 @@ def test_serving_form_is_bit_equal_and_keeps_what_is_read_in_float32(family):
         "casting every leaf to bfloat16 went unnoticed: the test cannot "
         "tell the serving form from a rounded tree"
     )
+
+
+ROW_SLOTS = 5
+
+
+@pytest.mark.parametrize("live", [(3,), (4, 0), (4, 0, 2)], ids=["1row", "2rows", "3rows"])
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_the_rows_that_prefill_are_bit_equal_to_the_whole_slot_program(family, live):
+    """The chunk-prefill program over the slots that prefill, ``PREFILL_ROWS``
+    to a dispatch (the last one padded; three slots are two dispatches, the
+    second on the cache the first returned), against ONE program over every
+    slot (row i = slot i, a slot that does not prefill a padded entry: what
+    the engine ran until PR 32): the logits rows it installs, every pool
+    page but the trash page, the table and the cursors are BIT-equal. Run on
+    a cache that already holds every slot's first chunk, so a neighbour's
+    pages are there to be damaged, and on the prompts' SECOND chunk, so the
+    rows attend over cached positions through their own table rows."""
+    S, R = ROW_SLOTS, eng.PREFILL_ROWS
+    assert R == 2
+    model = _model(n_slots=S, **FAMILIES[family])
+    cfg = model.cfg
+    params = serving_params(model, _random_params(model))
+    table = 1 + np.arange(S * N_BLOCKS, dtype=np.int32).reshape(S, N_BLOCKS)
+    rng = np.random.default_rng(5)
+    prompts = rng.integers(1, cfg.vocab_size, (S, 2 * CHUNK - 3)).astype(np.int32)
+    program = jax.jit(eng._paged_chunk_prefill_impl, static_argnums=(0,))
+
+    def chunk(cache, rows, start, index_after):
+        """``rows`` (slot ids, ``S`` a padded entry) through one chunk."""
+        rows = np.asarray(rows, np.int32)
+        real = rows < S
+        tokens = np.zeros((len(rows), CHUNK), np.int32)
+        window = prompts[rows[real], start:start + CHUNK]
+        tokens[real, :window.shape[1]] = window
+        return program(
+            model, params, cache, jnp.asarray(tokens),
+            jnp.asarray(np.where(real, start, 0).astype(np.int32)),
+            jnp.asarray(np.where(real, prompts.shape[1], 0).astype(np.int32)),
+            jnp.asarray(rows), jnp.asarray(table),
+            jnp.asarray(index_after, jnp.int32),
+        )
+
+    cache, _, _ = chunk(PagedKVCache(model, S).cache, range(S), 0, [CHUNK] * S)
+    after = [prompts.shape[1] if s in live else CHUNK for s in range(S)]
+    got_cache, got_last = cache, 0.0
+    for i in range(0, len(live), R):
+        group = list(live[i:i + R])
+        got_cache, last, _ = chunk(got_cache, group + [S] * (R - len(group)), CHUNK, after)
+        assert not np.asarray(last)[[s for s in range(S) if s not in group]].any()
+        got_last = got_last + np.asarray(last)
+    want_cache, want_last, _ = chunk(
+        cache, [s if s in live else S for s in range(S)], CHUNK, after)
+
+    assert got_last.shape == want_last.shape == (S, cfg.vocab_size)
+    assert np.isfinite(np.asarray(want_last)).all()
+    np.testing.assert_array_equal(np.asarray(got_last), np.asarray(want_last))
+    assert np.asarray(want_last)[list(live)].any(axis=1).all()
+    pools = 0
+    with_path = jax.tree_util.tree_leaves_with_path
+    for (path, got), (_, want), (_, before) in zip(
+            with_path(got_cache), with_path(want_cache), with_path(cache)):
+        name, leaf = jax.tree_util.keystr(path), _leaf_name(path)
+        assert got.shape == want.shape == before.shape, name
+        got, want = np.asarray(got), np.asarray(want)
+        if leaf in POOL_LEAVES:
+            page_axis = got.ndim - 3
+            got, want = (np.delete(x, 0, axis=page_axis) for x in (got, want))
+            pools += 1
+            # the chunk wrote the live rows' pages, and nobody else's
+            changed = np.delete(np.asarray(before), 0, axis=page_axis) != want
+            assert changed.any(), name
+        else:
+            assert leaf == TABLE_LEAF or leaf in INDEX_LEAVES, name
+        np.testing.assert_array_equal(got, want, err_msg=name)
+    assert pools >= 1
 
 
 @pytest.mark.parametrize("family", ["gpt_alibi_tied", "looped_sandwich_gate"])

@@ -2,7 +2,7 @@
 
 The repo's fixed-shape discipline (PR 4/6/8) says every jit dispatch site
 has a BOUNDED family of cache signatures: the engine's fused decode step is
-ONE program whatever the occupancy, the chunk prefill is ONE [S, C] program
+ONE program whatever the occupancy, the chunk prefill is ONE [R, C] program
 whatever the prompt mix, the trainer step is ONE program for the whole run.
 A regression (a shape that varies per request, a static arg that varies per
 tick) silently multiplies compiles and looks like "serving got slow".

@@ -12,14 +12,18 @@
   pages by refcount instead of copying bytes;
 - free slots admit queued requests, and the prompt prefills CHUNKED:
   ``prefill_chunk`` tokens per tick, written through the slot's block table
-  into the pool by one fixed-shape ``[n_slots, chunk]`` program
+  into the pool by one ``[rows, chunk]`` program
   (``_paged_chunk_prefill_impl``) that advances EVERY mid-prefill slot at
   once — so a long prompt never stalls active streams for its full prefill
   (the Sarathi-Serve interleaving), multiple queued prompts prefill as one
   batch (admission is inherently batched), and there is no per-prompt-length
-  compile. A chunk-aligned token-prefix index over page ids
-  (``serving/prefix_cache.py``) lets repeated system prompts skip straight
-  to the first novel chunk;
+  compile. The program computes ``PREFILL_ROWS`` rows, not ``n_slots``:
+  the slots that prefill this tick are handed to it that many at a time
+  (one dispatch for nearly every tick of a server under steady load, one
+  more for each further ``PREFILL_ROWS`` slots of a burst), so a prompt
+  arriving alone does not pay for every slot's row. A chunk-aligned
+  token-prefix index over page ids (``serving/prefix_cache.py``) lets
+  repeated system prompts skip straight to the first novel chunk;
 - every ``step()`` runs ONE fused decode step across all slots
   (``_fused_step_impl``) — padded and masked so the compiled program is
   identical whatever the occupancy — then retires slots that hit EOS, their
@@ -94,6 +98,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from zero_transformer_tpu.analysis.runtime import (
     CompileFamilyExceeded,
@@ -515,14 +520,30 @@ def _jit_fused_step():
 _FUSED_SHARED = _jit_fused_step()
 
 
-def _paged_chunk_prefill_impl(
-    model, params, cache, tokens, starts, true_lens, active, table, index_after
-):
-    """One prefill chunk for EVERY mid-prefill slot, written through each
-    slot's block table into the page pool — the fixed-shape [S, C] program
-    at the heart of chunked prefill + batched admission.
+# Rows of the chunk-prefill program (clipped to n_slots). Under steady load
+# nearly every prefill tick advances ONE slot and a few advance two (chat
+# cell: 95% / 4.5% of dispatches; PERF.md section 6, PR 32), so the program
+# is sized for those, and a tick with more slots prefilling dispatches it
+# again. One row count, because each one costs a trace of the whole model
+# at start-up (2.2 s for the looped 2.6B model on the benchmark's host,
+# 10% of that cell's set-up), and two rows, because a second row costs a
+# tenth of the first (the program's fixed part, the undonated pool copy and
+# one read of the weights, is most of it) while a second dispatch costs it
+# all again.
+PREFILL_ROWS = 2
 
-    Per row: ``tokens`` holds the prompt window at global positions
+
+def _paged_chunk_prefill_impl(
+    model, params, cache, tokens, starts, true_lens, rows, table, index_after
+):
+    """One prefill chunk for the ``R`` rows it is handed, written through
+    each row's block table into the page pool: the ``[R, C]`` program at
+    the heart of chunked prefill + batched admission. The engine hands
+    over slots that prefill this tick and no others, ``PREFILL_ROWS`` at a
+    time, the last dispatch padded.
+
+    ``rows [R]`` are the slot ids; ``tokens``, ``starts`` and ``true_lens``
+    are per ROW. ``tokens`` holds the prompt window at global positions
     ``[starts, starts + C)`` (zero-padded past the prompt; the host clamps
     ``starts`` to ``cache_len - C`` and re-sends earlier tokens in the
     window, whose K/V recompute bit-identically). The model's per-slot
@@ -530,34 +551,42 @@ def _paged_chunk_prefill_impl(
     per-row RoPE/ALiBi positions, causal masking against ``q_offset`` so
     real query positions never attend to the window's padded tail.
 
-    Rows NOT mid-prefill (parked or actively decoding) ride along because
-    the program's shape is fixed: they are routed to the TRASH page for
-    the duration of the apply (their table rows swap to zeros), so the
-    dispatch cannot touch their K/V at all, and index leaves are
-    overwritten wholesale afterwards from ``index_after`` — the host knows
-    every row's true cursor (fill for prefilling rows, prompt + emitted
-    for decoding rows, 0 for parked). ``table`` is the authoritative host
-    mirror; the apply never mutates it. The cache is deliberately NOT
-    donated: on a fault the engine keeps the pre-chunk pool and fails only
-    the prefilling slots (``_on_prefill_fault``).
+    The apply sees a cache STAGED to the R rows: the table leaf is
+    ``table[rows]`` and the index leaves are ``starts`` (the pools have no
+    row axis and are shared), so slots that do not prefill are not in the
+    program at all. A padded entry carries the row id ``n_slots``: its
+    table row reads as zeros (the TRASH page takes its writes), its start
+    and tokens are zero and its logits row is dropped. Afterwards the
+    table and index leaves are written back at their whole ``[n_slots]``
+    shapes from ``table`` (the authoritative host mirror; the apply never
+    mutates it) and ``index_after``: the host knows every row's true
+    cursor (fill for prefilling rows, prompt + emitted for decoding rows,
+    0 for parked). The cache is deliberately NOT donated: on a fault the
+    engine keeps the pre-chunk pool and fails only the prefilling slots
+    (``_on_prefill_fault``).
 
-    Returns ``(cache, last_logits, touched)`` where ``last_logits[s]`` is
-    the f32 logits row at the prompt's final position — meaningful only for
-    rows whose prefill completes in this chunk (``true_lens`` falls inside
-    the window); the engine installs exactly those rows. ``touched`` (None
-    but for a dropless routed model) counts the (layer, expert) pairs that
-    took any of the ``S * C`` rows the program computes whoever prefills:
-    what its grouped matmuls were handed."""
-    S, C = tokens.shape
+    Returns ``(cache, last_logits, touched)`` where ``last_logits`` is
+    ``[n_slots, V]`` whatever ``R`` (one shape for everything that
+    installs from it): ``last_logits[s]`` is the f32 logits row at the
+    prompt's final position for a slot ``s`` among ``rows`` and zeros
+    elsewhere, meaningful only for rows whose prefill completes in this
+    chunk (``true_lens`` falls inside the window); the engine installs
+    exactly those rows. ``touched`` (None but for a dropless routed model)
+    counts the (layer, expert) pairs that took any of the ``R * C`` rows
+    the program computes: what its grouped matmuls were handed."""
+    R, C = tokens.shape
+    S = table.shape[0]
     counts = model.cfg.moe_dispatch == "dropless"
+    staged_table = table.at[rows].get(mode="fill", fill_value=0)
 
     def pre(path, leaf):
         name = _leaf_name(path)
         if name == TABLE_LEAF:
-            routed = jnp.where(active[:, None], table, 0)
-            return jnp.broadcast_to(routed, leaf.shape).astype(leaf.dtype)
+            shape = leaf.shape[:-2] + staged_table.shape
+            return jnp.broadcast_to(staged_table, shape).astype(leaf.dtype)
         if name in INDEX_LEAVES:
-            return jnp.broadcast_to(starts, leaf.shape).astype(leaf.dtype)
+            shape = leaf.shape[:-1] + (R,)
+            return jnp.broadcast_to(starts, shape).astype(leaf.dtype)
         return leaf
 
     staged = jax.tree_util.tree_map_with_path(pre, cache)
@@ -565,7 +594,6 @@ def _paged_chunk_prefill_impl(
         {"params": params, "cache": staged}, tokens,
         mutable=["cache", "routing"] if counts else ["cache"],
     )
-    new_cache = vars_out["cache"]
     touched = None
     if counts:
         touched = sum(
@@ -575,21 +603,24 @@ def _paged_chunk_prefill_impl(
     last = jax.vmap(
         lambda row, i: jax.lax.dynamic_slice_in_dim(row, i, 1, axis=0)[0]
     )(logits, jnp.clip(true_lens - 1 - starts, 0, C - 1)).astype(jnp.float32)
+    last = jnp.zeros((S, last.shape[1]), jnp.float32).at[rows].set(last, mode="drop")
 
-    def post(path, leaf):
+    def post(path, before, after):
         name = _leaf_name(path)
         if name == TABLE_LEAF:
-            return jnp.broadcast_to(table, leaf.shape).astype(leaf.dtype)
+            return jnp.broadcast_to(table, before.shape).astype(before.dtype)
         if name in INDEX_LEAVES:
-            return jnp.broadcast_to(index_after, leaf.shape).astype(leaf.dtype)
-        return leaf
+            return jnp.broadcast_to(index_after, before.shape).astype(before.dtype)
+        return after
 
-    return jax.tree_util.tree_map_with_path(post, new_cache), last, touched
+    new_cache = jax.tree_util.tree_map_with_path(post, cache, vars_out["cache"])
+    return new_cache, last, touched
 
 
 # shared like _FUSED_SHARED: the static (model structure) compares equal
 # across engines, so warmup engines pre-pay this compile too. ONE compiled
-# program per (n_slots, chunk) whatever the prompt-length mix.
+# program per (n_slots, chunk) whatever the prompt-length mix and however
+# many slots prefill at once.
 _PAGED_CHUNK_SHARED = jax.jit(_paged_chunk_prefill_impl, static_argnums=(0,))
 
 
@@ -904,11 +935,14 @@ class ServingEngine:
         self._prefix_cache: Optional[PagedPrefixIndex] = self._make_prefix_cache()
         self._paged_chunk = _PAGED_CHUNK_SHARED
         self._spec = _SPEC_SHARED
+        # rows of the chunk-prefill program: the slots that prefill in a
+        # tick go through it this many at a time
+        self.prefill_rows = min(PREFILL_ROWS, n_slots)
         # compile-family sanitizer (analysis/runtime.py): each labeled jit
         # dispatch site declares the number of distinct cache signatures it
         # may legitimately produce over this engine's lifetime. The fixed-
         # shape discipline says ONE each — the fused decode step, the
-        # [S, C] chunk prefill, and the K-draft verify are all single
+        # [R, C] chunk prefill, and the K-draft verify are all single
         # programs whatever the occupancy/prompt mix. A second signature
         # means some per-request axis leaked into a shape or static
         # (strict mode raises listing the signatures; production warns).
@@ -947,6 +981,8 @@ class ServingEngine:
         # ... and so does its chunk-prefill program: the last chunk's count,
         # on the device until a decode tick's device_get takes it along
         self._prefill_touched = None
+        # the last chunk program's logits rows: ready when it has run
+        self._chunk_in_flight = None
         # did THIS tick run a prefill chunk? classifies the tick's ITL
         # samples for attribution
         self._prefill_work = False
@@ -1026,6 +1062,11 @@ class ServingEngine:
             "prefill_chunks": 0,
             "prefill_faults": 0,
             "expired_prefilling": 0,
+            # rows of the chunk-prefill program, summed over its dispatches:
+            # the slots that prefilled and the rows it computed (live /
+            # computed is the share of the program that was not padding)
+            "prefill_rows_live": 0,
+            "prefill_rows_computed": 0,
             # paged-KV counters: allocation pressure (a page fault = the
             # pool was empty and prefix-cache pages had to be reclaimed),
             # and the preemption of last resort when even reclaim failed
@@ -1655,12 +1696,40 @@ class ServingEngine:
 
     # ------------------------------------------------------- chunked prefill
 
+    def _chunk_args(self, group, windows, starts, lens, index_after) -> tuple:
+        """The chunk-prefill program's arguments for the slots ``group``
+        (at most ``prefill_rows`` of them; ``windows`` maps a slot to its
+        prompt window), padded to the program's rows. ``starts`` and
+        ``lens`` are per SLOT; the program's are per row."""
+        R, C, S = self.prefill_rows, self.prefill_chunk, self.n_slots
+        # a padded entry: row id n_slots (no slot), zero tokens, start 0
+        tokens = np.zeros((R, C), np.int32)
+        rows = np.full((R,), S, np.int32)
+        row_starts = np.zeros((R,), np.int32)
+        row_lens = np.zeros((R,), np.int32)
+        for i, slot in enumerate(group):
+            window = windows[slot]
+            tokens[i, : len(window)] = window
+            rows[i], row_starts[i], row_lens[i] = slot, starts[slot], lens[slot]
+        return (
+            self.model,
+            self.params,
+            self.slots.cache,
+            jnp.asarray(tokens),
+            jnp.asarray(row_starts),
+            jnp.asarray(row_lens),
+            jnp.asarray(rows),
+            jnp.asarray(self.slots.table),
+            index_after,
+        )
+
     # graftlint: hot-path
     # graftlint: supervised-seam
     def _prefill_tick(self) -> bool:
-        """Process ONE chunk for every mid-prefill slot in a single
-        fixed-shape [n_slots, chunk] dispatch, then install the slots whose
-        prompt completed (their decode starts this same tick).
+        """Process ONE chunk for every mid-prefill slot, ``prefill_rows``
+        slots to a [rows, chunk] dispatch (one dispatch unless a burst of
+        admissions prefills more slots than that at once), and install the
+        slots whose prompt completed (their decode starts this same tick).
         Supervised: a fault fails ONLY the
         prefilling slots — the chunk program does not donate the cache, so
         decoding slots keep their buffers and the tick proceeds to a
@@ -1669,7 +1738,7 @@ class ServingEngine:
             return False
         self._prefill_work = True
         C, L, S = self.prefill_chunk, self.cache_len, self.n_slots
-        tokens = [[0] * C for _ in range(S)]
+        windows: Dict[int, Sequence[int]] = {}  # slot -> its prompt window
         starts = [0] * S
         lens = [0] * S
         active = [False] * S
@@ -1695,8 +1764,7 @@ class ServingEngine:
             ):
                 faulted.append(slot)
                 continue
-            window = prompt[w : w + C]
-            tokens[slot][: len(window)] = [int(t) for t in window]
+            windows[slot] = prompt[w : w + C]
             starts[slot], lens[slot], active[slot] = w, len(prompt), True
         if faulted:
             # reservation-backed allocation makes this unreachable unless
@@ -1714,30 +1782,15 @@ class ServingEngine:
             self._event("page_preemption", slots=len(faulted), phase="prefill")
             if not self._prefilling:
                 return True
+        # every dispatch of the tick writes the cursors the tick ENDS with:
+        # nothing reads a cursor between them (a dispatch stages its own
+        # rows' from ``starts``), and a fault releases every mid-prefill slot
+        index_after = jnp.asarray(self._index_after(starts, lens, active), jnp.int32)
+        live = list(windows)
+        R = self.prefill_rows
         try:
-            # prefill_chunk covers an asynchronous dispatch: the chunk
-            # program's device time is waited for in this tick's decode_step
-            with self.tracer.span("prefill_chunk", "engine", tick=self._tick,
-                                  slots=sum(active)):
-                if self._chaos is not None:
-                    self._chaos.on_prefill_chunk(self._tick)
-                chunk_args = (
-                    self.model,
-                    self.params,
-                    self.slots.cache,
-                    jnp.asarray(tokens, jnp.int32),
-                    jnp.asarray(starts, jnp.int32),
-                    jnp.asarray(lens, jnp.int32),
-                    jnp.asarray(active, jnp.bool_),
-                    jnp.asarray(self.slots.table),
-                    jnp.asarray(self._index_after(starts, lens, active), jnp.int32),
-                )
-                # observe skips model+params (engine-lifetime constants):
-                # the describe walk stays O(per-tick args), not O(params)
-                self._ds_prefill.observe(*chunk_args[2:])
-                cache, last, self._prefill_touched = _in_mesh(
-                    self.mesh, self._paged_chunk, *chunk_args
-                )
+            for group in (live[i : i + R] for i in range(0, len(live), R)):
+                self._prefill_dispatch(group, windows, starts, lens, index_after)
         except CompileFamilyExceeded:
             # strict-mode sanitizer trip: the whole point is the readable
             # signature listing — it must reach the test harness, not be
@@ -1745,21 +1798,58 @@ class ServingEngine:
             raise
         except Exception as exc:
             self._on_prefill_fault(exc)
-            return True
+        return True
+
+    # graftlint: hot-path
+    def _prefill_dispatch(self, group, windows, starts, lens, index_after) -> None:
+        """One dispatch of the chunk program for the slots ``group``: adopt
+        the cache it returns, advance their jobs and install those whose
+        prompt completed. A fault propagates to ``_prefill_tick`` with the
+        engine still holding the cache of the last dispatch that returned.
+
+        ONE chunk program is in flight at a time: the cache is not donated,
+        so each program holds a pool-sized output from the moment it is
+        enqueued, and a host that runs ahead of the device (a tick's second
+        dispatch; prefill-only ticks, which wait for nothing: a long prompt
+        arriving at an idle engine) stacks them up until the device's
+        memory is full (GLM cell: 16.84e9 bytes of the chip's 16.91e9,
+        PERF.md section 6). In a tick that decodes the program before has
+        long run (the tick's device_get waited for it) and this costs
+        nothing."""
+        C = self.prefill_chunk
+        before, self._chunk_in_flight = self._chunk_in_flight, None
+        if before is not None:
+            # graftlint: allow[host-sync-in-hot-path] reason=waits only where the host would run ahead of the device (a burst's later dispatches, prefill-only ticks); bounds device memory at one pool copy in flight
+            jax.block_until_ready(before)
+        # prefill_chunk covers an asynchronous dispatch: the chunk
+        # program's device time is waited for in this tick's decode_step
+        with self.tracer.span("prefill_chunk", "engine", tick=self._tick,
+                              slots=len(group), rows=self.prefill_rows):
+            if self._chaos is not None:
+                self._chaos.on_prefill_chunk(self._tick)
+            chunk_args = self._chunk_args(group, windows, starts, lens, index_after)
+            # observe skips model+params (engine-lifetime constants):
+            # the describe walk stays O(per-tick args), not O(params)
+            self._ds_prefill.observe(*chunk_args[2:])
+            cache, last, self._prefill_touched = _in_mesh(
+                self.mesh, self._paged_chunk, *chunk_args
+            )
         self.slots.cache = cache
-        self.stats["prefill_chunks"] += sum(active)
+        self.stats["prefill_chunks"] += len(group)
+        self.stats["prefill_rows_live"] += len(group)
+        self.stats["prefill_rows_computed"] += self.prefill_rows
         completed = []
-        for slot, job in self._prefilling.items():
-            if active[slot]:
-                # ledger attribution: this request paid for one chunk row
-                # of the batched dispatch (sums to stats["prefill_chunks"])
-                job.handle.ledger["prefill_chunks"] += 1
+        for slot in group:
+            job = self._prefilling[slot]
+            # ledger attribution: this request paid for one chunk row
+            # of the batched dispatch (sums to stats["prefill_chunks"])
+            job.handle.ledger["prefill_chunks"] += 1
             job.fill = min(starts[slot] + C, lens[slot])
             if job.fill >= lens[slot]:
                 completed.append((slot, job))
+        self._chunk_in_flight = last
         if completed:
             self._install_completed(completed, last)
-        return True
 
     def _index_after(self, starts, lens, active) -> List[int]:
         """Every row's true post-chunk cursor, host-derived (the chunk
@@ -1823,18 +1913,16 @@ class ServingEngine:
                 self._detach_slot(slot, True)
                 self._migration_failed(handle, f"export failed: {exc!r}")
                 continue
-            import numpy as _np
-
             leaves = dict(span["leaves"])
-            leaves["carry/last_logits"] = _np.asarray(
-                rows[slot], _np.float32
+            leaves["carry/last_logits"] = np.asarray(
+                rows[slot], np.float32
             )
-            leaves["carry/gen_mask"] = _np.zeros(
-                (self.cfg.vocab_size,), _np.bool_
+            leaves["carry/gen_mask"] = np.zeros(
+                (self.cfg.vocab_size,), np.bool_
             )
             # graftlint: allow[host-sync-in-hot-path] reason=tiny PRNGKey materialization for the wire payload, handoff-only
             key_host = jax.device_get(jax.random.PRNGKey(handle.request.seed))
-            leaves["carry/rng"] = _np.asarray(key_host, _np.uint32)
+            leaves["carry/rng"] = np.asarray(key_host, np.uint32)
             payload = {
                 **self._stream_meta(
                     handle, list(handle.request.prompt),
@@ -3395,6 +3483,7 @@ class ServingEngine:
             "tick_faults", "poisoned_slots", "breaker_trips", "shed_infeasible",
             "rejected_draining", "drain_forced", "reloads", "reloads_rejected",
             "prefill_chunks", "prefill_faults", "expired_prefilling",
+            "prefill_rows_live", "prefill_rows_computed",
             "page_faults", "pages_reclaimed", "preemptions",
             "page_waits", "loop_passes",
             "kernel_pages_live", "kernel_pages_table",
@@ -3441,6 +3530,9 @@ class ServingEngine:
             ("reloads_rejected", "Hot weight reloads rejected"),
             ("prefill_chunks", "Chunk-prefill row dispatches"),
             ("prefill_faults", "Supervised chunk-prefill faults"),
+            ("prefill_rows_live", "Chunk-prefill program rows that prefilled a slot"),
+            ("prefill_rows_computed",
+             "Chunk-prefill program rows computed (padding included)"),
             ("page_faults", "Page-pool exhaustions that reclaimed prefix pages"),
             ("pages_reclaimed", "Prefix-cache pages reclaimed under pressure"),
             ("preemptions", "Requests preempted for KV pages (last resort)"),
