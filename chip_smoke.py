@@ -307,11 +307,16 @@ CELL_SHAPES = ((16, 12, 2048), (16, 16, 512))
 # (slots, heads, cached row's lanes, value lanes, cache_len) of the latent
 # serving cell: GLM-4.7-Flash's 20 heads over rows of 512 + 64 (+ 64) lanes
 LATENT_SHAPES = ((16, 20, 640, 512, 5120),)
+# (rows, heads, head_dim, d_state) of the state-space serving cell's decode
+# state update: granite-4.0-h-micro's 32 slots of 64 heads of 64 x 128
+SSM_SHAPES = ((32, 64, 64, 128),)
+SSM_ULPS = 4  # float32 ulps at the state's / y's own scale
 
 
 def kernels_phase(model: str = SERVE_MODEL, slots: int = 4,
                   cache_len: int = 1024, ragged_shapes: tuple = CELL_SHAPES,
                   latent_shapes: tuple = LATENT_SHAPES,
+                  ssm_shapes: tuple = SSM_SHAPES,
                   require_tpu: bool = True) -> dict:
     """The paged decode kernel as the chip's compiler built it, against the
     gather path it replaces, at the shapes the servers below decode with —
@@ -319,13 +324,15 @@ def kernels_phase(model: str = SERVE_MODEL, slots: int = 4,
     random-weight model that emits one token — and, the kernel's walk being
     bounded by each row's own length, at the benchmark cells' shapes with
     most rows a few pages long; and the latent decode kernel against ITS
-    gather path at the latent cell's shapes, to the same bar."""
+    gather path at the latent cell's shapes, to the same bar; and the decode
+    state-update kernel against its plain ``jax.numpy`` twin at the
+    state-space cell's shapes, a stale-state control far outside."""
     device = device_or_exit(require_tpu)
     import jax.numpy as jnp
 
     from zero_transformer_tpu.config import ServingConfig, model_config
     from zero_transformer_tpu.ops.pallas.parity import (
-        latent_vs_gather, paged_vs_gather,
+        latent_vs_gather, paged_vs_gather, ssm_update_vs_xla,
     )
 
     cfg = model_config(model)
@@ -356,9 +363,22 @@ def kernels_phase(model: str = SERVE_MODEL, slots: int = 4,
                 f"paged kernel outside {PAGED_ULPS} bf16 ulps of the gather "
                 f"path (or the control inside them): {case}"
             )
+    on_tpu = device["platform"] == "tpu"
+    ssm = [
+        ssm_update_vs_xla(rows=S, heads=H, head_dim=P, d_state=N, seed=SEED,
+                          interpret=not on_tpu, time_calls=20 if on_tpu else 0)
+        for S, H, P, N in ssm_shapes
+    ]
+    for case in ssm:
+        if not (case["finite"] and case["idle_rows_kept"] and case["other_layers_kept"]
+                and case["ulps"] <= SSM_ULPS < case["control_ulps"]):
+            raise RuntimeError(
+                f"state-update kernel outside {SSM_ULPS} float32 ulps of its "
+                f"jax.numpy twin (or the stale-state control inside them): {case}"
+            )
     return {"phase": "kernels", "ok": True, "device": device, "model": model,
             "paged_ulps_bar": PAGED_ULPS, "paged_vs_gather": cases,
-            "latent_vs_gather": latent}
+            "latent_vs_gather": latent, "ssm_update_vs_xla": ssm}
 
 
 def serve_child(model: str, params: Path, port: int, extra: list,

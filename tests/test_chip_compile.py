@@ -563,3 +563,127 @@ def test_flash_compiles_under_a_four_device_data_mesh(data_mesh, docs):
         text = jax.jit(grads).lower(qkv, qkv, qkv, ids).compile().as_text()
     assert text.count("tpu_custom_call") == 3
     assert "all-gather" not in text and "all-reduce" not in text
+
+
+# ---- recurrent state beside the pages (granite-4.0-h-micro's cell) ---------
+
+SSM_SLOTS, SSM_HEADS, SSM_P, SSM_N = 32, 64, 64, 128
+
+
+def test_state_update_kernel_compiles_at_the_cells_shapes(one_chip, monkeypatch):
+    """The decode state-update kernel at the state-space cell's shapes (32
+    slots, 64 heads of 64 x 128, the stacked state of 36 layers with a traced
+    layer index, donated): one Mosaic call, its state aliased to its output,
+    and no operation of the program makes a state-pool-sized value."""
+    from zero_transformer_tpu.ops.pallas import ssm_update as su
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("ZT_PALLAS_INTERPRET", raising=False)
+    assert su.supported(heads=SSM_HEADS, head_dim=SSM_P, d_state=SSM_N)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S, H, P, N = SSM_SLOTS, SSM_HEADS, SSM_P, SSM_N
+    text = jax.jit(su.ssm_update, donate_argnums=(0,)).lower(
+        sds((36, S, H, P, N)), sds((S, H, P)), sds((S, H)), sds((H,)), sds((S, N)),
+        sds((S, N)), sds((H,)), sds((S,), jnp.bool_), sds((), jnp.int32),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "ssm_state_update" in text
+    assert not _state_ops(text, 36)
+
+
+def _state_ops(hlo: str, layers: int, slots: int = SSM_SLOTS, in_place=()):
+    """Every operation of an optimised HLO module whose result is the whole
+    stacked SSM state ``f32[layers, slots, 64, 64, 128]`` and which is not
+    the state itself passing through (a parameter, a tuple's element, a
+    loop, the aliased kernel; ``in_place`` names further opcodes that
+    write into the buffer they are handed): ``[(opcode, name)]``."""
+    import re
+
+    shape = rf"f32\[{layers},{slots},{SSM_HEADS},{SSM_P},{SSM_N}\]"
+    free = {"parameter", "get-tuple-element", "tuple", "bitcast", "while", "conditional",
+            "call", "custom-call", *in_place}
+    out = []
+    for line in hlo.splitlines():
+        m = re.match(rf"\s*(?:ROOT )?%?([\w.\-]+) = {shape}\S* ([a-z][\w\-]*)\(", line)
+        if m and m.group(2) not in free:
+            out.append((m.group(2), m.group(1)))
+    return out
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "jnp_twin"])
+def test_hybrid_serving_programs_copy_the_state_pool_once(one_chip, monkeypatch, kernel):
+    """granite-4.0-h-micro's structure at its cell's engine shapes (32 slots
+    x 512, page 16, chunk 64, published widths; depth cut to two periods of
+    (mamba, attention) to keep the compile in seconds): the donated decode
+    program holds one state-update kernel a period's mamba block and the
+    paged kernel with 8 K/V heads under 32 query heads, and NO copy of the
+    stacked state; the chunk-prefill program, not donated by design, holds
+    exactly the one copy that forces, and scatters the rows' new state into
+    it in place. Where the kernel's gate says no, the ``jax.numpy`` step it
+    falls back to updates the stack in place too (a fusion rooted in a
+    dynamic-update-slice of the loop's carry): no copy either, and 2.3 ms a
+    decode program slower in the cell (PERF.md section 6, PR 33)."""
+    from zero_transformer_tpu.config import model_config
+    from zero_transformer_tpu.inference.generate import decode_model
+    from zero_transformer_tpu.inference.sampling import SamplingConfig
+    from zero_transformer_tpu.ops.attention import paged_kernel_supported
+    from zero_transformer_tpu.parallel.sharding import unbox
+    from zero_transformer_tpu.serving import engine as eng
+    from zero_transformer_tpu.serving.slots import _cache_struct, vectorize_index
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("ZT_PALLAS_INTERPRET", raising=False)
+    if not kernel:
+        from zero_transformer_tpu.ops.pallas import ssm_update as su
+
+        monkeypatch.setattr(su, "supported", lambda **kw: False)
+    cfg = model_config("granite_4_0_h_micro", n_layers=4, max_seq_len=512,
+                       layer_pattern=("mamba", "attention"))
+    S, cache_len = SSM_SLOTS, 512
+    assert paged_kernel_supported("auto", T=1, H=32, KVH=8, D=64, S=cache_len,
+                                  page_size=PAGE, dtype=jnp.bfloat16)
+    model = decode_model(cfg, cache_len, kv_pages=(S * cache_len // PAGE + 1, PAGE))
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(
+        lambda r: model.init(r, jnp.zeros((S, 1), jnp.int32)), jax.random.PRNGKey(0))
+    params = on_chip(unbox(shapes["params"]))
+    cache = on_chip(jax.eval_shape(lambda: vectorize_index(
+        jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), _cache_struct(model, S)), S)))
+    assert cache["ssm_state"].shape == (2, S, SSM_HEADS, SSM_P, SSM_N)
+    V = cfg.vocab_size
+    # (jax keys a trace by the function under the jit: the two cases wrap
+    # the step anew, or the second would be handed the first's program)
+    decode = jax.jit(lambda *a: eng._fused_step_impl(*a), static_argnums=(0, 1),
+                     donate_argnums=(3, 4, 5, 6)).lower(
+        model, SamplingConfig(greedy=True, repetition_penalty=1.0), params,
+        sds((S, V), jnp.float32), cache, sds((S, V), jnp.bool_), sds((S, 2), jnp.uint32),
+        sds((S,), jnp.bool_),
+    ).compile().as_text()
+    R = eng.PREFILL_ROWS
+    prefill = jax.jit(lambda *a: eng._paged_chunk_prefill_impl(*a), static_argnums=(0,)).lower(
+        model, params, cache, sds((R, CHUNK), jnp.int32), sds((R,), jnp.int32),
+        sds((R,), jnp.int32), sds((R,), jnp.int32),
+        sds((S, cache_len // PAGE), jnp.int32), sds((S,), jnp.int32),
+    ).compile().as_text()
+    # a scanned period's body holds one mamba and one attention block
+    kernels = 2 if kernel else 1
+    assert decode.count("tpu_custom_call") == kernels
+    assert decode.count('custom_call_target="tpu_custom_call"') == kernels
+    assert ("ssm_state_update" in decode) == kernel and "paged_attention" in decode
+    moved = _state_ops(decode, 2, in_place=() if kernel else ("dynamic-update-slice", "fusion"))
+    assert not moved, moved
+    # (the rows' new state is scattered into the copy by a fusion or a
+    # short loop of in-place updates)
+    copies = _state_ops(prefill, 2, in_place=("scatter", "dynamic-update-slice", "fusion"))
+    assert [op for op, _ in copies] == ["copy"], copies
+    assert "ssm_state_update" not in prefill  # a chunk is the chunked form

@@ -91,9 +91,13 @@ def test_kernels_phase_holds_the_paged_kernel_to_its_bar(monkeypatch):
     # ragged at a shape with three buckets and two staged groups (page 16)
     # and the latent kernel at a small row (96 latent + 16 key lanes of 128)
     kw = dict(slots=2, cache_len=64, ragged_shapes=((4, 4, 384),),
-              latent_shapes=((4, 5, 128, 96, 384),), require_tpu=False)
+              latent_shapes=((4, 5, 128, 96, 384),), ssm_shapes=((5, 8, 16, 128),),
+              require_tpu=False)
     got = chip_smoke.kernels_phase("test", **kw)
     assert got["ok"] and len(got["paged_vs_gather"]) == 8
+    (ssm,) = got["ssm_update_vs_xla"]
+    assert ssm["ulps"] <= chip_smoke.SSM_ULPS < 1000 < ssm["control_ulps"]
+    assert ssm["idle_rows_kept"] and ssm["other_layers_kept"] and "kernel_s" not in ssm
     assert [c["shape"]["T"] for c in got["latent_vs_gather"]] == [1, 5]
     for case in got["latent_vs_gather"]:
         assert case["ulps"] <= chip_smoke.PAGED_ULPS < case["control_ulps"]
@@ -106,6 +110,10 @@ def test_kernels_phase_holds_the_paged_kernel_to_its_bar(monkeypatch):
         assert case["ulps"] <= chip_smoke.PAGED_ULPS < case["control_ulps"]
     monkeypatch.setattr(chip_smoke, "PAGED_ULPS", 1e9)
     with pytest.raises(RuntimeError, match="paged kernel outside"):
+        chip_smoke.kernels_phase("test", **kw)
+    monkeypatch.setattr(chip_smoke, "PAGED_ULPS", 2)
+    monkeypatch.setattr(chip_smoke, "SSM_ULPS", 1e12)  # the stale control would pass
+    with pytest.raises(RuntimeError, match="state-update kernel outside"):
         chip_smoke.kernels_phase("test", **kw)
 
 

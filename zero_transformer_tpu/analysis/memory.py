@@ -58,7 +58,9 @@ def kv_bytes_per_token(m) -> int:
     serving pool is sized from: ``page_pool_tokens`` x this, beside the
     weights. Latent attention keeps ONE row an entry, the latent and the
     rotated key padded to whole lane tiles (``latent_row``): the bytes the
-    pool really holds."""
+    pool really holds. In a hybrid stack only the layers that attend keep
+    K/V (``kv_entries`` counts those); what its mamba layers keep does not
+    grow with the position: ``state_bytes_per_slot``."""
     if m.latent_attention:
         return m.kv_entries * m.latent_row * _dtype_bytes(m.compute_dtype)
     per_head = (
@@ -66,6 +68,17 @@ def kv_bytes_per_token(m) -> int:
         else m.head_width * _dtype_bytes(m.compute_dtype)
     )
     return 2 * m.kv_entries * m.kv_heads * per_head
+
+
+def state_bytes_per_slot(m) -> int:
+    """Bytes of recurrent state ONE serving slot keeps in model config
+    ``m``, whatever the request's length: a hybrid stack's mamba layers'
+    float32 SSM state and conv inputs (``ModelConfig.state_bytes_per_slot``);
+    0 for a stack that only attends. What a serving deployment pays a SLOT
+    beside ``kv_bytes_per_token`` a position: ``n_slots`` x this is the
+    state pool, and the chunk-prefill program's undonated copy of the cache
+    holds it once more while it runs."""
+    return m.state_bytes_per_slot
 
 
 def analytic_memory(

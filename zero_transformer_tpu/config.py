@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 from pathlib import Path
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import jax.numpy as jnp
 import yaml
@@ -46,7 +46,9 @@ class ModelConfig:
     max_seq_len: int = 32
     dropout: float = 0.0
     # "alibi" (train-short/test-long extrapolation, reference layers.py:17-44),
-    # "rope" (llama family), or "learned" (plain GPT-2).
+    # "rope" (llama family), "learned" (plain GPT-2), or "none" (no position
+    # encoding at all: a hybrid stack's attention reads order off its
+    # recurrent layers).
     position: str = "alibi"
     rope_theta: float = 10000.0
     n_kv_heads: Optional[int] = None  # GQA; None -> MHA
@@ -162,6 +164,25 @@ class ModelConfig:
     # RoPE pairs lanes (2i, 2i+1) instead of (i, i + D/2)
     rope_interleaved: bool = False
     norm_eps: float = 1e-6
+    # Hybrid stack (Mamba-2 state-space mixers among attention layers;
+    # models/mamba.py): ONE period of the stack, each entry "mamba" or
+    # "attention" (which MIXER the block has; every block keeps the dense
+    # MLP), repeated n_layers / len(layer_pattern) times. None = every block
+    # attends. A mamba block keeps a recurrent state a batch row, whatever
+    # the row's length, where an attention block keeps K/V a position.
+    layer_pattern: Optional[Tuple[str, ...]] = None
+    mamba_heads: int = 0  # SSM heads; inner width = mamba_heads * mamba_head_dim
+    mamba_head_dim: int = 64
+    mamba_state: int = 128  # state values a (head, channel): d_state
+    mamba_conv: int = 4  # causal depthwise conv taps over [x | B | C]
+    mamba_chunk: int = 256  # positions a step of the chunked scan
+    # scores = (q . k) * attention_scale; None -> 1 / sqrt(head_dim)
+    attention_scale: Optional[float] = None
+    # h0 = embedding_multiplier * Embed(tokens); each sublayer's output joins
+    # the residual stream times residual_multiplier; logits / logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     @property
     def kv_heads(self) -> int:
@@ -198,8 +219,61 @@ class ModelConfig:
     def layer_kind(self, i: int) -> str:
         """What block ``i`` of the stack is: "moe" (the routed layer of
         ``moe_dispatch``) or "dense" (the MLP; the ``moe_dense_layers``
-        leading blocks of a routed stack, every block of a dense one)."""
+        leading blocks of a routed stack, every block of a dense one); in a
+        hybrid stack which mixer it has before its MLP, "mamba" or
+        "attention", as ``layer_pattern`` repeats."""
+        if self.layer_pattern is not None:
+            return self.layer_pattern[i % len(self.layer_pattern)]
         return "moe" if self.n_experts > 0 and i >= self.moe_dense_layers else "dense"
+
+    @property
+    def hybrid(self) -> bool:
+        return self.layer_pattern is not None
+
+    @property
+    def recurrent(self) -> bool:
+        """Does a block keep a recurrent state (a hybrid stack with mamba
+        blocks)? Such a model's cache holds a state a batch row beside its
+        K/V, right only at the position the row has reached."""
+        return self.hybrid and "mamba" in self.layer_pattern
+
+    def layers_of(self, kind: str) -> int:
+        """Blocks of ``kind`` in the whole stack."""
+        return sum(self.layer_kind(i) == kind for i in range(self.n_layers))
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the causal conv runs over: ``[x | B | C]``."""
+        return self.mamba_inner + 2 * self.mamba_state
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of recurrent state ONE batch row (a serving slot) keeps,
+        whatever its length: a float32 SSM state ``[heads, head_dim,
+        state]`` and the conv's last ``mamba_conv - 1`` inputs at the
+        compute dtype, a mamba block. 0 for a stack that only attends."""
+        if not self.recurrent:
+            return 0
+        ssm = self.mamba_inner * self.mamba_state * 4
+        itemsize = jnp.dtype(resolve_dtype(self.compute_dtype)).itemsize
+        conv = (self.mamba_conv - 1) * self.mamba_conv_dim * itemsize
+        return self.layers_of("mamba") * (ssm + conv)
+
+    @property
+    def _mamba_params(self) -> int:
+        d, inner, h = self.d_model, self.mamba_inner, self.mamba_heads
+        conv = self.mamba_conv_dim
+        return (
+            d * (inner + conv + h)  # in_proj: [z | xBC | dt]
+            + conv * self.mamba_conv + conv  # depthwise taps and bias
+            + 3 * h  # dt_bias, A_log, D
+            + inner  # the gated norm's scale
+            + inner * d  # out_proj
+        )
 
     @property
     def _attention_params(self) -> int:
@@ -227,7 +301,8 @@ class ModelConfig:
         else:
             mlp = per * d * self.ff_dim
         norms = (4 if self.post_norm else 2) * d
-        return self._attention_params + mlp + norms
+        mixer = self._mamba_params if kind == "mamba" else self._attention_params
+        return mixer + mlp + norms
 
     @property
     def layer_params(self) -> int:
@@ -267,7 +342,10 @@ class ModelConfig:
 
     @property
     def kv_entries(self) -> int:
-        """K/V cache entries a token keeps: one per (pass, layer)."""
+        """K/V cache entries a token keeps: one per (pass, layer) that
+        attends."""
+        if self.hybrid:
+            return self.layers_of("attention")
         return self.n_loops * self.n_layers
 
     def __post_init__(self):
@@ -275,8 +353,12 @@ class ModelConfig:
             raise ValueError("d_model must be divisible by n_heads")
         if self.n_kv_heads is not None and self.n_heads % self.n_kv_heads:
             raise ValueError("n_heads must be divisible by n_kv_heads")
-        if self.position not in ("alibi", "rope", "learned"):
+        if self.position not in ("alibi", "rope", "learned", "none"):
             raise ValueError(f"invalid position {self.position!r}")
+        if self.layer_pattern is not None:
+            # a YAML list -> the hashable tuple a static jit argument needs
+            object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+            self._check_hybrid()
         if self.activation not in ("gelu", "swiglu"):
             raise ValueError(f"invalid activation {self.activation!r}")
         if self.norm not in ("layernorm", "rmsnorm"):
@@ -367,6 +449,47 @@ class ModelConfig:
             raise ValueError(f"invalid param_quant {self.param_quant!r}")
         resolve_dtype(self.param_dtype)
         resolve_dtype(self.compute_dtype)
+
+
+    def _check_hybrid(self) -> None:
+        pattern = self.layer_pattern
+        if not pattern or set(pattern) - {"mamba", "attention"}:
+            raise ValueError(
+                f"layer_pattern {pattern!r}: one period of 'mamba' / 'attention'"
+            )
+        if self.n_layers % len(pattern):
+            raise ValueError("n_layers must be a multiple of len(layer_pattern)")
+        if self.recurrent and (
+            self.mamba_heads < 1 or self.mamba_head_dim < 1 or self.mamba_state < 1
+            or self.mamba_conv < 2
+        ):
+            raise ValueError(
+                "a mamba block needs mamba_heads, mamba_head_dim, mamba_state "
+                ">= 1 and mamba_conv >= 2"
+            )
+        if not self.scan_layers:
+            raise ValueError(
+                "a hybrid stack (layer_pattern) needs scan_layers=True: it is "
+                "scanned over the periods of its pattern (unrolled it traces "
+                "and compiles twice as long for 3.5% of a decode tick: "
+                "PERF.md section 6, PR 33)"
+            )
+        if (
+            self.n_experts or self.latent_attention or self.n_loops > 1
+            or self.post_norm or self.doc_sep_token is not None
+            or self.param_quant != "none" or self.remat
+        ):
+            raise ValueError(
+                "a hybrid stack (layer_pattern) has dense MLPs and full-head "
+                "attention, one pass, no sandwich norms, packing, remat or "
+                "int8 weights"
+            )
+        if self.kv_cache_dtype != "auto" and self.recurrent:
+            raise ValueError(
+                "kv_cache_dtype='int8' is refused for a model with recurrent "
+                "state: the state is summed over the whole request and stays "
+                "float32"
+            )
 
 
 @dataclasses.dataclass(frozen=True)

@@ -31,6 +31,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.nn import initializers
 
 from zero_transformer_tpu.config import ModelConfig, resolve_dtype
+from zero_transformer_tpu.models.mamba import Mamba2Mixer, mamba_state_leaves
 from zero_transformer_tpu.models.mla import LatentAttention, latent_pool_leaves
 from zero_transformer_tpu.models.moe import DroplessMoE, MoEMLP
 from zero_transformer_tpu.parallel.sharding import (
@@ -381,6 +382,10 @@ class Attention(nn.Module):
                 pos = offset + jnp.arange(T, dtype=jnp.int32)
             q = apply_rope(q, pos, cfg.rope_theta)
             k = apply_rope(k, pos, cfg.rope_theta)  # cache stores rotated keys
+        if cfg.attention_scale is not None:
+            # every attention path scales by 1 / sqrt(D): the query carries
+            # the rest (granite's 1/64 at D 64 is a factor of 1/8, exact)
+            q = q * jnp.asarray(cfg.attention_scale * D ** 0.5, q.dtype)
 
         if use_cache:
             if paged:
@@ -615,7 +620,16 @@ class Block(nn.Module):
     ``expert_counts`` ``[B, n_experts]`` into the ``routing`` collection
     where the caller makes it mutable: how many of each batch row's
     positions it sent to each expert (the engine's decode step sums them
-    over the rows that decode)."""
+    over the rows that decode).
+
+    In a hybrid stack (``cfg.layer_pattern``) ``kind`` names the MIXER,
+    "mamba" (``models.mamba.Mamba2Mixer``, which keeps a recurrent state a
+    row) or "attention", before the dense MLP; ``layer`` is then the block's
+    place among the blocks of its kind (the entry of the stacked state or
+    K/V pool it reads and writes), and ``valid`` ``[B]`` how many of the
+    window's positions are real (``models.mamba``). ``cfg.
+    residual_multiplier`` scales each sublayer's output as it joins the
+    residual stream."""
 
     cfg: ModelConfig
     deterministic: bool = True
@@ -626,7 +640,7 @@ class Block(nn.Module):
     kind: Optional[str] = None
 
     @nn.compact
-    def __call__(self, carry, layer=None, step=None):
+    def __call__(self, carry, layer=None, step=None, valid=None):
         cfg = self.cfg
         # packed-sequence models thread the document ids as a third carry
         # element (constant through the layer scan); the decode path never
@@ -640,16 +654,23 @@ class Block(nn.Module):
         else:
             x, aux = carry
         kind = self.kind or cfg.layer_kind(0)
-        attn = (LatentAttention if cfg.latent_attention else Attention)(
-            cfg, self.deterministic, self.decode, self.cache_len, self.mesh,
-            self.kv_pages, name="attn"
-        )(
-            _norm(cfg, x.dtype, "ln_attn")(x), doc_ids, pools, layer, step
-        )
+        if kind == "mamba":
+            attn = Mamba2Mixer(cfg, self.decode, name="mamba")(
+                _norm(cfg, x.dtype, "ln_attn")(x), valid, pools, layer
+            )
+        else:
+            attn = (LatentAttention if cfg.latent_attention else Attention)(
+                cfg, self.deterministic, self.decode, self.cache_len, self.mesh,
+                self.kv_pages, name="attn"
+            )(
+                _norm(cfg, x.dtype, "ln_attn")(x), doc_ids, pools, layer, step
+            )
         if pools is not None:
             attn, pools = attn
         if cfg.post_norm:
             attn = _norm(cfg, x.dtype, "ln_attn_post")(attn)
+        if cfg.residual_multiplier != 1.0:
+            attn = attn * jnp.asarray(cfg.residual_multiplier, attn.dtype)
         x = x + attn
         # pin the residual stream: batch/seq sharded, replicated over tensor
         # (Megatron layout) — GSPMD must not invent another layout for it
@@ -671,6 +692,8 @@ class Block(nn.Module):
             )
         if cfg.post_norm:
             mo = _norm(cfg, x.dtype, "ln_mlp_post")(mo)
+        if cfg.residual_multiplier != 1.0:
+            mo = mo * jnp.asarray(cfg.residual_multiplier, mo.dtype)
         x = x + mo
         if layer_aux is not None:
             aux = aux + layer_aux
@@ -678,6 +701,35 @@ class Block(nn.Module):
         if packed:
             return (x, aux, doc_ids), None
         return ((x, aux) if pools is None else (x, aux, pools)), None
+
+
+class Period(nn.Module):
+    """One period of a hybrid stack (``cfg.layer_pattern``), its blocks
+    unrolled, each its own kind: what ``scan_layers`` scans over, so the
+    program holds one period's blocks whatever the depth. ``period`` is the
+    period's index (None without a stacked cache): block ``j``'s entry in
+    the stacked state or K/V pool of its kind is ``period x (blocks of the
+    kind a period) + (blocks of the kind before j)``."""
+
+    cfg: ModelConfig
+    deterministic: bool = True
+    decode: bool = False
+    cache_len: Optional[int] = None
+    mesh: Optional[Any] = None
+    kv_pages: Optional[Tuple[int, int]] = None
+
+    @nn.compact
+    def __call__(self, carry, period=None, valid=None):
+        pattern = self.cfg.layer_pattern
+        for j, kind in enumerate(pattern):
+            entry = None
+            if period is not None:
+                entry = period * pattern.count(kind) + pattern[:j].count(kind)
+            carry, _ = Block(
+                self.cfg, self.deterministic, self.decode, self.cache_len,
+                self.mesh, self.kv_pages, kind, name=f"block_{j}",
+            )(carry, entry, None, valid)
+        return carry, None
 
 
 class Transformer(nn.Module):
@@ -703,7 +755,11 @@ class Transformer(nn.Module):
         x: jax.Array,
         labels: Optional[jax.Array] = None,
         train: bool = False,
+        valid: Optional[jax.Array] = None,
     ) -> Union[jax.Array, Tuple[jax.Array, jax.Array]]:
+        """``valid`` ``[B]`` int32 (a model with recurrent state, through
+        its cache): how many of each row's ``T`` positions are real; the
+        rest leave the row's state as it was (``models.mamba``)."""
         cfg = self.cfg
         dtype = resolve_dtype(cfg.compute_dtype)
         param_dtype = resolve_dtype(cfg.param_dtype)
@@ -759,6 +815,8 @@ class Transformer(nn.Module):
             # table, so the vocab-parallel logits matmul is unaffected.
             table = replicate_activation(jnp.asarray(embed.embedding, dtype))
             h = jnp.take(table, x, axis=0)
+        if cfg.embedding_multiplier != 1.0:
+            h = h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
         h = constrain_activation(h, "batch", "seq", "embed")
 
         if cfg.position == "learned":
@@ -824,25 +882,47 @@ class Transformer(nn.Module):
                     cfg, self.kv_pages, dtype
                 ).items()
             }
+            if cfg.recurrent:
+                # a hybrid stack's recurrent state rides the carry beside
+                # the pool, stacked over its mamba layers, a row a batch row
+                pool_vars.update({
+                    name: self.variable(
+                        "cache", name, jnp.zeros,
+                        (cfg.layers_of("mamba"),) + shape, dt,
+                    )
+                    for name, (shape, dt) in mamba_state_leaves(cfg, B, dtype).items()
+                })
             layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
             carry = carry + ({n: v.value for n, v in pool_vars.items()},)
-        if cfg.scan_layers:
+        members = (cfg, not train, self.decode, self.cache_len, self.mesh,
+                   self.kv_pages)
+        if cfg.hybrid:
+            # scanned over the PERIODS of the pattern (``scan_layers`` is
+            # required of a hybrid stack), a period's blocks unrolled; a
+            # block's cache entry is its place among its kind
+            periods = cfg.n_layers // len(cfg.layer_pattern)
+            stack = nn.scan(
+                Period,
+                variable_axes={"params": 0, "cache": 0},
+                split_rngs={"params": True, "dropout": True},
+                in_axes=(0, nn.broadcast),
+                length=periods,
+                metadata_params={nn.PARTITION_NAME: "layers"},
+            )(*members, name="periods")
+            layers = jnp.arange(periods, dtype=jnp.int32) if pool_vars else None
+        elif cfg.scan_layers:
             stack = nn.scan(
                 block_cls,
                 variable_axes={"params": 0, "cache": 0},
                 split_rngs={"params": True, "dropout": True},
                 length=cfg.n_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
-            )(cfg, not train, self.decode, self.cache_len, self.mesh,
-              self.kv_pages, name="blocks")
+            )(*members, name="blocks")
         else:
             # unrolled, each block is its own kind (``cfg.layer_kind``): the
             # only stack whose layers may differ
             blocks = [
-                block_cls(
-                    cfg, not train, self.decode, self.cache_len, self.mesh,
-                    self.kv_pages, cfg.layer_kind(i), name=f"block_{i}",
-                )
+                block_cls(*members, cfg.layer_kind(i), name=f"block_{i}")
                 for i in range(cfg.n_layers)
             ]
         ln_f = _norm(cfg, h.dtype, "ln_f")
@@ -864,7 +944,9 @@ class Transformer(nn.Module):
         for t in range(cfg.n_loops):
             step = t if looped else None
             with jax.named_scope("loop_pass") if looped else contextlib.nullcontext():
-                if cfg.scan_layers:
+                if cfg.hybrid:
+                    carry, _ = stack(carry, layers, valid)
+                elif cfg.scan_layers:
                     steps = jnp.full((cfg.n_layers,), t, jnp.int32) if looped else None
                     carry, _ = stack(carry, layers, steps)
                 else:
@@ -907,6 +989,8 @@ class Transformer(nn.Module):
                 if cfg.tie_embeddings
                 else jnp.asarray(head.kernel, dtype)
             )
+            if cfg.logits_scaling != 1.0:
+                h = h / jnp.asarray(cfg.logits_scaling, h.dtype)
             loss = chunked_next_token_loss(
                 h, w_dv, labels, cfg.loss_chunk, ignore_index=ignore
             )
@@ -915,6 +999,8 @@ class Transformer(nn.Module):
             return None, loss
 
         logits = embed.attend(h) if cfg.tie_embeddings else head(h)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
 
         if labels is None:
             return logits

@@ -17,7 +17,9 @@ Cases:
   take + attention in one compiled program.
 
 ``latent_vs_gather`` is ``paged_vs_gather``'s twin for the latent decode
-kernel (``chip_smoke.py``'s ``kernels`` phase, ``tests``).
+kernel (``chip_smoke.py``'s ``kernels`` phase, ``tests``);
+``ssm_update_vs_xla`` holds the decode state-update kernel to its plain
+``jax.numpy`` twin.
 """
 from __future__ import annotations
 
@@ -245,3 +247,64 @@ def latent_vs_gather(
         "max_abs_diff": diff, "ulps": diff / ulp,
         "control_ulps": float(jnp.max(jnp.abs(control - ref))) / ulp,
     }
+
+
+def ssm_update_vs_xla(
+    *, rows: int, heads: int, head_dim: int, d_state: int, layers: int = 2,
+    seed: int = 0, interpret: bool = False, time_calls: int = 0,
+) -> dict:
+    """The decode state-update kernel (``ops.pallas.ssm_update``) against
+    its plain ``jax.numpy`` twin on a stacked state with a traced layer
+    index, some rows not decoding, both under jit with the state donated:
+    the new state and ``y`` in float32 ulps at their own scale. The control
+    is a STALE state: the same step from the state one step earlier, which
+    must land far outside. With ``time_calls`` also the mean seconds of a
+    call of each, waited for (a number for a TPU only)."""
+    import time
+
+    from zero_transformer_tpu.ops.pallas import ssm_update as su
+
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    f32 = jnp.float32
+    state = jax.random.normal(ks[0], (layers, rows, heads, head_dim, d_state), f32)
+    x = jax.random.normal(ks[1], (rows, heads, head_dim), f32)
+    dt = jax.nn.softplus(jax.random.normal(ks[2], (rows, heads), f32))
+    A = -jnp.exp(jax.random.normal(ks[3], (heads,), f32))
+    Bm = jax.random.normal(ks[4], (rows, d_state), f32)
+    Cm = jax.random.normal(ks[5], (rows, d_state), f32)
+    D = jax.random.normal(ks[6], (heads,), f32)
+    live = jax.random.bernoulli(ks[7], 0.75, (rows,)).at[0].set(True)
+    layer = jnp.int32(layers - 1)
+    small = (x, dt, A, Bm, Cm, D, live, layer)
+    reference = jax.jit(
+        lambda st, *a: su.ssm_update_reference(st, *a), donate_argnums=(0,))
+    kernel = jax.jit(
+        lambda st, *a: su.ssm_update(st, *a, interpret=interpret), donate_argnums=(0,))
+    y_ref, new_ref = reference(state + 0.0, *small)
+    y, new = kernel(state + 0.0, *small)
+    # the stale control: the state as it was one step earlier (decayed back)
+    y_stale, _ = reference(state * 0.5, *small)
+
+    def ulps(a, b):
+        scale = float(jnp.finfo(f32).eps) * float(jnp.max(jnp.abs(b)))
+        return float(jnp.max(jnp.abs(a - b))) / scale
+
+    out = {
+        "shape": {"rows": rows, "heads": heads, "head_dim": head_dim,
+                  "d_state": d_state, "layers": layers},
+        "finite": bool(jnp.all(jnp.isfinite(y)) & jnp.all(jnp.isfinite(new))),
+        "ulps": max(ulps(y, y_ref), ulps(new, new_ref)),
+        "state_ulps": ulps(new, new_ref), "y_ulps": ulps(y, y_ref),
+        "idle_rows_kept": bool(jnp.all(jnp.where(
+            live[:, None, None, None], True, new[layers - 1] == state[layers - 1]))),
+        "other_layers_kept": bool(jnp.all(new[: layers - 1] == state[: layers - 1])),
+        "control_ulps": ulps(y_stale, y_ref),
+    }
+    for name, fn in (("kernel_s", kernel), ("xla_s", reference)) if time_calls else ():
+        st = jax.block_until_ready(fn(state + 0.0, *small)[1])
+        t0 = time.perf_counter()
+        for _ in range(time_calls):
+            _, st = fn(st, *small)
+        jax.block_until_ready(st)
+        out[name] = (time.perf_counter() - t0) / time_calls
+    return out

@@ -197,6 +197,11 @@ def make_overlap_zero_step(
     cfg = model.cfg
     if not cfg.scan_layers:
         raise ValueError("overlap_comm requires scan_layers=True")
+    if cfg.hybrid:
+        raise ValueError(
+            "overlap_comm builds one kind of block a layer; a hybrid stack "
+            "(layer_pattern) is scanned by period"
+        )
     if zero_stage < 1:
         raise ValueError("overlap_comm requires zero_stage >= 1")
     acc_dt = _accum_dtype(grad_accum_dtype)

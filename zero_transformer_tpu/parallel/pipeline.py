@@ -140,12 +140,18 @@ def check_supported(cfg) -> None:
     """The stage builder lays ``n_layers`` blocks over the pipe ranks ONCE
     and applies its own final norm and head: a looped stack (``n_loops`` >
     1: every pass would have to travel the ranks again) or an exit gate
-    would be dropped in silence and a one-pass model trained. Refuse."""
+    would be dropped in silence and a one-pass model trained; a hybrid
+    stack's mamba blocks would be built as attention blocks. Refuse."""
     if cfg.n_loops > 1 or cfg.exit_gate:
         raise NotImplementedError(
             f"pipeline parallelism runs the layer stack once: n_loops="
             f"{cfg.n_loops}, exit_gate={cfg.exit_gate} is not supported on a "
             "mesh with a pipe axis (use data/fsdp/tensor axes for a looped model)"
+        )
+    if cfg.hybrid:
+        raise NotImplementedError(
+            "pipeline parallelism builds one kind of block a layer: a hybrid "
+            "stack (layer_pattern) would be trained as an attention-only model"
         )
 
 

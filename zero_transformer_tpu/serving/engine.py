@@ -151,6 +151,7 @@ from zero_transformer_tpu.serving.resilience import (
 )
 from zero_transformer_tpu.serving.slots import (
     INDEX_LEAVES,
+    STATE_LEAVES,
     TABLE_LEAF,
     PagedKVCache,
     _leaf_name,
@@ -474,19 +475,24 @@ def _forward_only_impl(model, params, token, cache, decoding=None):
     here) so the healthy path pays one dispatch per tick, not two, and the
     [S] mask rides the same device_get as the tokens.
 
-    With ``decoding`` (``[S]`` bool: the rows whose token someone is
-    waiting for; a dropless routed model's engine passes it) also the
-    routed layers' ``expert_counts`` of this apply, summed over those rows
-    and reduced here to three int32: (row, expert) pairs routed, the
+    ``decoding`` is ``[S]`` bool: the rows whose token someone is waiting
+    for. The engine of a model with recurrent state passes it and the model
+    advances the state of those rows alone. A dropless routed model's
+    engine passes it and gets also the routed layers' ``expert_counts`` of
+    this apply, summed over those rows and reduced here to three int32: (row, expert) pairs routed, the
     busiest expert's rows summed over the layers, and the (layer, expert)
     pairs that took any row."""
+    counts = decoding is not None and model.cfg.moe_dispatch == "dropless"
+    # a model with recurrent state is told which rows decode: the others
+    # (parked, mid-prefill) keep their state bit for bit
+    told = {"valid": decoding.astype(jnp.int32)} if model.cfg.recurrent else {}
     logits, vars_out = model.apply(
         {"params": params, "cache": cache}, token[:, None],
-        mutable=["cache"] if decoding is None else ["cache", "routing"],
+        mutable=["cache", "routing"] if counts else ["cache"], **told,
     )
     new_logits = logits[:, -1, :].astype(jnp.float32)
     routing = None
-    if decoding is not None:
+    if counts:
         load = jnp.stack([
             jnp.sum(jnp.where(decoding[:, None], counts, 0), axis=0)
             for counts in jax.tree.leaves(vars_out["routing"])
@@ -561,7 +567,13 @@ def _paged_chunk_prefill_impl(
     shapes from ``table`` (the authoritative host mirror; the apply never
     mutates it) and ``index_after``: the host knows every row's true
     cursor (fill for prefilling rows, prompt + emitted for decoding rows,
-    0 for parked). The cache is deliberately NOT donated: on a fault the
+    0 for parked). A model's recurrent state (``STATE_LEAVES``,
+    ``[n_layers, n_slots, ...]``) is staged to the R rows likewise, a first
+    chunk's (``starts`` 0) as zeros; the model is told each row's count of
+    REAL tokens in the window (``valid``: the padded tail leaves the state
+    as it was), and the rows' new state goes back into their slots. For
+    such a model the host never re-sends a token (``cache_len`` is a whole
+    number of chunks, so no window is clamped). The cache is deliberately NOT donated: on a fault the
     engine keeps the pre-chunk pool and fails only the prefilling slots
     (``_on_prefill_fault``).
 
@@ -578,6 +590,9 @@ def _paged_chunk_prefill_impl(
     S = table.shape[0]
     counts = model.cfg.moe_dispatch == "dropless"
     staged_table = table.at[rows].get(mode="fill", fill_value=0)
+    # recurrent state: each row's REAL tokens in the window move it, the
+    # padded tail does not (a padded entry has none)
+    told = {"valid": jnp.clip(true_lens - starts, 0, C)} if model.cfg.recurrent else {}
 
     def pre(path, leaf):
         name = _leaf_name(path)
@@ -587,12 +602,19 @@ def _paged_chunk_prefill_impl(
         if name in INDEX_LEAVES:
             shape = leaf.shape[:-1] + (R,)
             return jnp.broadcast_to(starts, shape).astype(leaf.dtype)
+        if name in STATE_LEAVES:
+            # [n_layers, n_slots, ...] -> the R rows' state; a prompt's
+            # FIRST chunk reads zeros in place of what the slot's last
+            # request left (there is no reset program)
+            mine = leaf.at[:, rows].get(mode="fill", fill_value=0)
+            fresh = (starts == 0).reshape((1, R) + (1,) * (leaf.ndim - 2))
+            return jnp.where(fresh, jnp.zeros((), leaf.dtype), mine)
         return leaf
 
     staged = jax.tree_util.tree_map_with_path(pre, cache)
     logits, vars_out = model.apply(
         {"params": params, "cache": staged}, tokens,
-        mutable=["cache", "routing"] if counts else ["cache"],
+        mutable=["cache", "routing"] if counts else ["cache"], **told,
     )
     touched = None
     if counts:
@@ -611,6 +633,10 @@ def _paged_chunk_prefill_impl(
             return jnp.broadcast_to(table, before.shape).astype(before.dtype)
         if name in INDEX_LEAVES:
             return jnp.broadcast_to(index_after, before.shape).astype(before.dtype)
+        if name in STATE_LEAVES:
+            # the rows' new state back into their slots; a padded entry
+            # (row id n_slots) writes nothing
+            return before.at[:, rows].set(after, mode="drop")
         return after
 
     new_cache = jax.tree_util.tree_map_with_path(post, cache, vars_out["cache"])
@@ -873,6 +899,32 @@ class ServingEngine:
         self.draft_fn = draft_fn or ngram_propose
         if role not in ROLES:
             raise ValueError(f"role must be one of {ROLES}, got {role!r}")
+        # A model with recurrent state (cfg.layer_pattern with "mamba"
+        # blocks): a slot's state sits beside its K/V pages and is right
+        # only at the position the slot has reached. What would need the
+        # state at ANOTHER position is refused by name (docs/SERVING.md).
+        self._has_state = cfg.recurrent
+        self._state_bytes_per_slot = cfg.state_bytes_per_slot
+        if self._has_state:
+            if draft_k:
+                raise ValueError(
+                    "draft_k > 0 is refused for a model with recurrent "
+                    "state: a rejected draft would have to roll the state "
+                    "back (state_refusals: speculation)"
+                )
+            if self.cache_len % self.prefill_chunk:
+                raise ValueError(
+                    f"cache_len ({self.cache_len}) must be a multiple of "
+                    f"prefill_chunk ({self.prefill_chunk}) for a model with "
+                    "recurrent state: a clamped last window re-sends tokens, "
+                    "and a recurrence that sees a token twice is wrong"
+                )
+            if role != "mixed":
+                raise ValueError(
+                    "role must be 'mixed' for a model with recurrent state: "
+                    "a page span without its state is half a request "
+                    "(state_refusals: page_span)"
+                )
         if role == "prefill" and draft_k:
             raise ValueError(
                 "role='prefill' replicas never decode; draft_k must be 0"
@@ -931,7 +983,10 @@ class ServingEngine:
         # slot -> _PrefillJob for slots mid-chunked-prefill (acquired, not
         # yet decoding); only the tick thread touches it
         self._prefilling: Dict[int, _PrefillJob] = {}
-        self._prefix_cache_chunks = prefix_cache_chunks
+        # a banked page is reusable only with the state AT its boundary:
+        # a model with recurrent state gets no prefix index (said once, in
+        # the prefix_cache_refused event at the end of construction)
+        self._prefix_cache_chunks = 0 if self._has_state else prefix_cache_chunks
         self._prefix_cache: Optional[PagedPrefixIndex] = self._make_prefix_cache()
         self._paged_chunk = _PAGED_CHUNK_SHARED
         self._spec = _SPEC_SHARED
@@ -977,6 +1032,9 @@ class ServingEngine:
         # a dropless routed model's decode step also counts what its
         # routed layers sent where (``_forward_only_impl``)
         self._counts_routing = cfg.moe_dispatch == "dropless"
+        # the rows that decode, on the device: what a routed model's counts
+        # are summed over and what a model with recurrent state advances
+        self._sends_decoding = self._counts_routing or self._has_state
         self._decoding: Tuple[Optional[tuple], Any] = (None, None)
         # ... and so does its chunk-prefill program: the last chunk's count,
         # on the device until a decode tick's device_get takes it along
@@ -1091,6 +1149,11 @@ class ServingEngine:
             "moe_tokens_routed": 0,
             "moe_expert_load_max": 0,
             "moe_expert_load_mean": 0.0,
+            # a model with recurrent state: first chunks (a slot's state
+            # read as zeros), and what was refused for it, by reason
+            "state_resets": 0,
+            "state_refusals_prefix_cache": 0,
+            "state_refusals_page_span": 0,
             # speculation counters: acceptance_rate = accepted / drafted
             "spec_ticks": 0,
             "draft_tokens": 0,
@@ -1197,6 +1260,13 @@ class ServingEngine:
         self._itl_decode = self._h_itl_decode
         self._register_exports()
         self._started = self.now()
+        if self._has_state and prefix_cache_chunks:
+            self.stats["state_refusals_prefix_cache"] += 1
+            self._event(
+                "prefix_cache_refused", asked=prefix_cache_chunks,
+                reason="recurrent state: a banked page is reusable only "
+                       "with the state at its boundary; no index is built",
+            )
 
     # ----------------------------------------------------- device-state build
 
@@ -1838,6 +1908,8 @@ class ServingEngine:
         self.stats["prefill_chunks"] += len(group)
         self.stats["prefill_rows_live"] += len(group)
         self.stats["prefill_rows_computed"] += self.prefill_rows
+        if self._has_state:
+            self.stats["state_resets"] += sum(starts[s] == 0 for s in group)
         completed = []
         for slot in group:
             job = self._prefilling[slot]
@@ -2215,13 +2287,23 @@ class ServingEngine:
                             self._gen_mask,
                             self._rngs,
                         )
-                        if self._counts_routing:
+                        if self._sends_decoding:
                             # the rows that decode, on the device; sent
                             # again only when a slot joins or leaves
                             live = tuple(act is not None for act in self._active)
                             if live != self._decoding[0]:
                                 self._decoding = (live, jnp.asarray(live, jnp.bool_))
                             fused_args += (self._decoding[1],)
+                        if self._has_state:
+                            # rows whose state this tick advances, the
+                            # bytes that moves (each state in and out), and
+                            # the slots that hold one (the gauge's count)
+                            step_span.note(
+                                state_rows=self.active_count,
+                                state_bytes=2 * self.active_count
+                                * self._state_bytes_per_slot,
+                                state_rows_in_use=self.state_rows_in_use,
+                            )
                         # skip model (0) + params (2) — engine-lifetime
                         # constants; sampling statics + cache/logits/mask/rng
                         # shapes remain
@@ -2485,6 +2567,8 @@ class ServingEngine:
         # snapshot under the GIL (list() of a dict/list is one C-level op)
         # — the tick thread mutates both containers concurrently, and bare
         # iteration from this HTTP thread could see "changed size"
+        if self._refuse_page_span("request_migration"):
+            return False
         active = list(self._active)
         prefilling = list(self._prefilling.values())
         found = any(
@@ -2499,6 +2583,8 @@ class ServingEngine:
     def request_migrate_all(self, target: str) -> int:
         """Migrate EVERY live stream to ``target`` (scale-down / drain
         upgrade). Returns how many streams were tagged."""
+        if self._refuse_page_span("request_migrate_all"):
+            return 0
         n = sum(1 for a in list(self._active) if a is not None) + len(
             self._prefilling
         )
@@ -2506,6 +2592,23 @@ class ServingEngine:
             with self._lock:
                 self._migrate_requests["*"] = target
         return n
+
+    @property
+    def state_rows_in_use(self) -> int:
+        """Slots whose recurrent state a request holds: decoding or
+        mid-prefill (0 for a model that keeps none)."""
+        return self.active_count + len(self._prefilling) if self._has_state else 0
+
+    def _refuse_page_span(self, what: str) -> bool:
+        """A model with recurrent state exports and imports no stream: the
+        wire format carries pages, and a span without its state is half a
+        request. Counted and said; the caller reports 'not found' /
+        rejects, and the router falls back to re-dispatch-and-recompute."""
+        if not self._has_state:
+            return False
+        self.stats["state_refusals_page_span"] += 1
+        self._event("page_span_refused", what=what, reason="recurrent state")
+        return True
 
     # graftlint: hot-path
     def _service_migrations(self) -> None:
@@ -2741,6 +2844,8 @@ class ServingEngine:
         # payload must become a clean retryable rejection here, never a
         # KeyError on the tick thread (which would abort the whole engine)
         structural = self._validate_import_payload(payload)
+        if self._refuse_page_span("import_stream"):
+            structural = "this model keeps recurrent state: page spans are refused"
         if structural is not None:
             handle = RequestHandle(
                 Request([0], 1), next(self._ids), now,
@@ -3428,6 +3533,12 @@ class ServingEngine:
             # stack keeps n_loops a layer): the number to size
             # page_pool_tokens with
             "kv_bytes_per_token": kv_bytes_per_token(self.cfg),
+            # recurrent state beside the pages (0 for a model that only
+            # attends): what a slot keeps whatever its length, the pool of
+            # all slots, and the slots a request holds now
+            "state_bytes_per_slot": self._state_bytes_per_slot,
+            "state_pool_bytes": self.slots.state_pool_bytes,
+            "state_rows_in_use": self.state_rows_in_use,
             # the weights as held (the serving form) and how many of those
             # bytes a conversion at load made
             **self._weights_bytes,
@@ -3488,6 +3599,8 @@ class ServingEngine:
             "page_waits", "loop_passes",
             "kernel_pages_live", "kernel_pages_table",
             "moe_tokens_routed", "moe_expert_load_max", "moe_expert_load_mean",
+            "state_resets", "state_refusals_prefix_cache",
+            "state_refusals_page_span",
             "spec_ticks", "draft_tokens", "accepted_tokens",
             "migrations_out", "migrations_in", "migration_failures",
             "prefill_handoffs", "import_replayed_tokens",
@@ -3549,6 +3662,12 @@ class ServingEngine:
              "Rows of the busiest expert, summed over decode ticks and routed layers"),
             ("moe_expert_load_mean",
              "Mean rows an expert, summed over decode ticks and routed layers"),
+            ("state_resets",
+             "First prefill chunks: a slot's recurrent state read as zeros"),
+            ("state_refusals_prefix_cache",
+             "Prefix indexes asked for and not built: recurrent state"),
+            ("state_refusals_page_span",
+             "Stream exports / imports refused: recurrent state"),
             ("spec_ticks", "Speculative decode ticks"),
             ("draft_tokens", "Draft tokens proposed"),
             ("accepted_tokens", "Draft tokens accepted by verify"),
@@ -3589,6 +3708,16 @@ class ServingEngine:
         reg.gauge_func(
             "serve_prefilling_slots", "Slots mid-chunked-prefill",
             lambda: len(self._prefilling),
+        )
+        reg.gauge_func(
+            "serve_state_pool_bytes",
+            "Bytes of recurrent state the cache holds beside its pages",
+            lambda: self.slots.state_pool_bytes,
+        )
+        reg.gauge_func(
+            "serve_state_rows_in_use",
+            "Slots whose recurrent state a request holds (decoding + prefilling)",
+            lambda: self.state_rows_in_use,
         )
         reg.gauge_func(
             "serve_slots", "Configured decode slots", lambda: self.n_slots

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from zero_transformer_tpu.inference.generate import init_cache
 from zero_transformer_tpu.models.gpt import kv_pool_wire_heads
+from zero_transformer_tpu.models.mamba import STATE_LEAVES
 
 # cache leaves that hold POSITIONS, not K/V data; widened per-slot.
 # (cache_index: per-layer attention write position; decode_pos: the learned-
@@ -55,6 +56,17 @@ POOL_LEAVES = (
 )
 TABLE_LEAF = "block_table"
 _PAGE_AXIS_FROM_END = 3
+
+# ``STATE_LEAVES`` (``models.mamba``: ``ssm_state``, ``conv_state``) are the
+# third class: a mamba layer's RECURRENT state, addressed by SLOT and by
+# nothing else: stacked ``[n_mamba_layers, n_slots, ...]`` at the top of the
+# cache tree, no page axis, no position axis. A slot's state is right only
+# at the position the slot has reached, so no page operation touches it
+# (gather, scatter, copy-on-write, the prefix index and the wire format go
+# by ``POOL_LEAVES``), a span of a model that has one is refused, and a
+# released slot's is left as it is: the chunk-prefill program reads a
+# request's first chunk's as zeros (``serving.engine``, which indexes the
+# slot axis, 1).
 
 
 def _leaf_name(path) -> str:
@@ -403,6 +415,12 @@ class PagedKVCache:
         )
         self._free: List[int] = list(range(n_slots))
         self.cow_copies = 0
+        # bytes of recurrent state the cache holds beside its pages
+        self.state_pool_bytes = sum(
+            leaf.nbytes
+            for path, leaf in jax.tree_util.tree_leaves_with_path(self.cache)
+            if _leaf_name(path) in STATE_LEAVES
+        )
         # page-span geometry: {wire key: (cache key, per-page wire shape,
         # dtype)}. On the wire a page is [(L,) page, KVH, D | 1] — the heads
         # split back out of the pool's merged lane axis, on HOST arrays
@@ -553,6 +571,7 @@ class PagedKVCache:
         included) + the block-table fragment geometry. Read-only — the
         slot keeps its pages and refcounts are untouched, so an export
         followed by a failed ship leaves the source stream intact."""
+        self._no_state("export_page_span")
         n_blocks = self.blocks_for(n_tokens)
         if n_blocks > self.alloc_blocks[slot]:
             raise ValueError(
@@ -588,6 +607,7 @@ class PagedKVCache:
         Imported pages are ordinary refcounted pool pages (ref 1, owned by
         the slot): bank/share them and the standard copy-on-write guard
         protects any post-import write to a shared page."""
+        self._no_state("import_page_span")
         if self.alloc_blocks[slot] != 0:
             raise ValueError("import_page_span needs an empty slot")
         # graftlint: allow[host-sync-in-hot-path] reason=wire-payload fields are host ints (json header), never device values
@@ -644,6 +664,14 @@ class PagedKVCache:
         self.alloc_blocks[slot] = n_blocks
         self.tables_dirty = True
         return True
+
+    def _no_state(self, what: str) -> None:
+        if self.state_pool_bytes:
+            raise ValueError(
+                f"{what} is refused for a model with recurrent state: the "
+                "wire format carries pages, and a span without the slot's "
+                "state is half a request"
+            )
 
     def set_cursor(self, slot: int, value: int) -> None:
         """Set the slot's fill cursor in every index leaf (import install:
