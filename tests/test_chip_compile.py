@@ -199,6 +199,11 @@ def test_paged_kernel_takes_the_cells_pools_where_they_lie(one_chip, H, n_blocks
     assert not made, made
 
 
+def _aliases(hlo: str) -> int:
+    """How many outputs of a compiled program alias one of its inputs."""
+    return hlo.split("input_output_alias={", 1)[1].split("}, entry", 1)[0].count("-alias")
+
+
 def _cfg_580m_cut(int8: bool, scan: bool):
     """The 580M serving model's structure (d 1536, 12 heads of 128, float32
     weights, bf16 compute; depth cut to keep the compile in seconds)."""
@@ -270,7 +275,7 @@ def _serving_programs(one_chip, monkeypatch, cfg, cache_len=CACHE_LEN, n_pages=N
         sds((N_SLOTS, 2), jnp.uint32),
     ).compile().as_text()
     rows = rows or eng.PREFILL_ROWS
-    prefill = jax.jit(eng._paged_chunk_prefill_impl, static_argnums=(0,)).lower(
+    prefill = eng._jit_paged_chunk().lower(
         model, params, cache, sds((rows, CHUNK), jnp.int32),
         sds((rows,), jnp.int32), sds((rows,), jnp.int32), sds((rows,), jnp.int32),
         sds((N_SLOTS, cache_len // PAGE), jnp.int32), sds((N_SLOTS,), jnp.int32),
@@ -282,15 +287,16 @@ def _serving_programs(one_chip, monkeypatch, cfg, cache_len=CACHE_LEN, n_pages=N
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
 def test_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch, int8, scan):
     """The counter of "the pool has ONE layout from allocation to kernel and
-    every program updates it in place". In the donated decode program the
-    only operations with a K/V-pool-sized result are the in-place scatters,
-    and every pool leaf aliases its input; the chunk-prefill program, NOT
-    donated by design (a prefill fault keeps the pre-chunk pool), holds
-    exactly the one whole-pool copy per K/V leaf that this forces. Neither
-    slices a pool out of anything, and the layer loop's body holds scatters
-    alone. On the parent commit (pool scanned over as xs/ys, declared
+    every program updates it in place". In the decode program AND in the
+    chunk-prefill program, each as the engine jits it (the cache donated),
+    the only operations with a K/V-pool-sized result are the in-place
+    scatters, and every pool leaf aliases its input. Neither slices a pool
+    out of anything, and the layer loop's body holds scatters alone. On the
+    parent commit of PR 25 (pool scanned over as xs/ys, declared
     [.., KVH, D]) the decode program held 8 copies, 2 re-layouts and 2
-    slices of the pool per layer.
+    slices of the pool per layer; until PR 34 the chunk-prefill program,
+    not donated, held one whole-pool copy per K/V leaf (two thirds of its
+    time in the chat cell).
 
     The int8 scale pools (12 lanes of f32) are held to "never sliced"
     alone: the chip's default layout for so narrow an array is not the
@@ -300,10 +306,8 @@ def test_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch, int8, 
     there and back)."""
     decode, prefill, n_pools = _serving_programs(
         one_chip, monkeypatch, _cfg_580m_cut(int8, scan))
-    n_kv = n_pools // 2 if int8 else n_pools
     assert decode.count("tpu_custom_call") >= 1  # the paged kernel is on the path
-    aliased = decode.split("input_output_alias={", 1)[1].split("}, entry", 1)[0]
-    assert aliased.count("-alias") >= n_pools
+    assert _aliases(decode) >= n_pools and _aliases(prefill) >= n_pools
 
     for ops in (_pool_ops(decode), _pool_ops(prefill)):
         sliced = {"dynamic-slice", "dynamic-update-slice", "gather", "AllocateBuffer"}
@@ -312,19 +316,19 @@ def test_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch, int8, 
     on_kv = [op for op, _, kv, _ in _pool_ops(decode) if kv]
     assert set(on_kv) == {"scatter"}, on_kv
     on_kv = [op for op, _, kv, _ in _pool_ops(prefill) if kv and op not in PREFETCH]
-    assert set(on_kv) <= {"scatter", "copy", "copy-start"}, on_kv
-    assert len(on_kv) - on_kv.count("scatter") == n_kv, on_kv
+    assert set(on_kv) == {"scatter"}, on_kv
 
 
 @pytest.mark.parametrize("rows", [2, N_SLOTS], ids=["engine", "every_slot"])
 def test_the_prefill_program_gathers_the_rows_it_is_handed(one_chip, monkeypatch, rows):
     """The 580M cell's chunk-prefill program at the engine's row count
-    (``PREFILL_ROWS``): it holds the one whole-pool copy per K/V leaf that
-    not donating forces, and no value of the program is
-    ``[16, 2048, ...]``-shaped or holds a row's 2,048 cached positions
-    sixteen times: the gather, its heads-first re-layout and the attention
-    over it are of the rows it is handed. (Handed every slot it is the
-    control: the same search finds its ``[16, .., 2048, ..]``.)"""
+    (``PREFILL_ROWS``), as the engine jits it: every pool leaf aliases its
+    input and nothing K/V-pool-sized is made but the in-place scatters, and
+    no value of the program is ``[16, 2048, ...]``-shaped or holds a row's
+    2,048 cached positions sixteen times: the gather, its heads-first
+    re-layout and the attention over it are of the rows it is handed.
+    (Handed every slot it is the control: the same search finds its
+    ``[16, .., 2048, ..]``.)"""
     import re
 
     from zero_transformer_tpu.serving import engine as eng
@@ -339,8 +343,8 @@ def test_the_prefill_program_gathers_the_rows_it_is_handed(one_chip, monkeypatch
         if dims.split(",")[0] == str(N_SLOTS) and str(CACHE_LEN) in dims.split(",")[1:]
     )
     on_kv = [op for op, _, kv, _ in _pool_ops(hlo) if kv and op not in PREFETCH]
-    assert set(on_kv) <= {"scatter", "copy", "copy-start"}, on_kv
-    assert len(on_kv) - on_kv.count("scatter") == n_pools, on_kv
+    assert set(on_kv) == {"scatter"}, on_kv
+    assert _aliases(hlo) >= n_pools
     assert f"[{rows},{CACHE_LEN},12,128]" in hlo  # the gathered rows
     assert bool(whole_slot_rows) == (rows == N_SLOTS), whole_slot_rows
 
@@ -396,8 +400,8 @@ def test_looped_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch,
     over a 2,560-token pool. The pass axis changes nothing of what the 580M
     case allows: the stacked pool [n_loops * n_layers, ...] (unrolled: each
     layer's own [n_loops, ...]) rides the layer loops, every pass's scatter
-    is in place, decode aliases every pool leaf and holds nothing else
-    pool-sized, prefill the one copy a pool that not donating forces."""
+    is in place, and decode and chunk prefill alike alias every pool leaf
+    and hold nothing else pool-sized."""
     from zero_transformer_tpu.config import ModelConfig
 
     cfg = ModelConfig(
@@ -415,8 +419,7 @@ def test_looped_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch,
     # the paged kernel is on the path once a (pass, layer) unless the passes'
     # layer loops stay rolled: at least once a pass
     assert decode.count("tpu_custom_call") >= 4
-    aliased = decode.split("input_output_alias={", 1)[1].split("}, entry", 1)[0]
-    assert aliased.count("-alias") >= n_pools
+    assert _aliases(decode) >= n_pools and _aliases(prefill) >= n_pools
 
     def ops(hlo):
         return _pool_ops(hlo, LOOP_N_PAGES, PAGE, 16 * 128)
@@ -435,8 +438,7 @@ def test_looped_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch,
     on_kv = [op for op, _, kv, _ in ops(decode) if kv]
     assert set(on_kv) == {"scatter"}, on_kv
     on_kv = [op for op, _, kv, _ in ops(prefill) if kv and op not in PREFETCH]
-    assert set(on_kv) <= {"scatter", "copy", "copy-start"}, on_kv
-    assert len(on_kv) - on_kv.count("scatter") == n_pools, on_kv
+    assert set(on_kv) == {"scatter"}, on_kv
 
 
 GLM_CACHE_LEN, GLM_N_PAGES, GLM_ROW = 5120, 81920 // PAGE + 1, 640  # 5121 = 9 x 569
@@ -477,9 +479,8 @@ def test_latent_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch)
     slots x 5,120 over an 81,920-token pool of latent rows. What the K/V
     cases allow and no more: the stack is unrolled, so each block keeps its
     own pool [5121, 16, 640], the latent kernel and XLA's grouped matmuls are
-    on the decode path, decode aliases the pool and holds nothing else
-    pool-sized but its in-place scatters, prefill the one copy that not
-    donating forces."""
+    on the decode path, and decode and chunk prefill alike alias every pool
+    and hold nothing else pool-sized but their in-place scatters."""
     from zero_transformer_tpu.config import model_config
 
     cfg = model_config("glm_4_7_flash_7l", n_layers=3, attention_impl="auto")
@@ -488,8 +489,7 @@ def test_latent_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch)
     assert n_pools == 3
     assert "latent_paged_attention" in decode and "latent_paged_attention" not in prefill
     assert "ragged-dot" in decode and "ragged-dot" in prefill
-    aliased = decode.split("input_output_alias={", 1)[1].split("}, entry", 1)[0]
-    assert aliased.count("-alias") >= n_pools
+    assert _aliases(decode) >= n_pools and _aliases(prefill) >= n_pools
 
     def ops(hlo):
         return _pool_ops(hlo, GLM_N_PAGES, PAGE, GLM_ROW)
@@ -501,8 +501,7 @@ def test_latent_serving_programs_never_copy_the_page_pool(one_chip, monkeypatch)
     on_kv = [op for op, _, kv, _ in ops(decode) if kv]
     assert set(on_kv) == {"scatter"}, on_kv
     on_kv = [op for op, _, kv, _ in ops(prefill) if kv and op not in PREFETCH]
-    assert set(on_kv) <= {"scatter", "copy", "copy-start"}, on_kv
-    assert len(on_kv) - on_kv.count("scatter") == n_pools, on_kv
+    assert set(on_kv) == {"scatter"}, on_kv
 
 
 def _flash_grads(docs: bool, entry=flash.flash_attention):
@@ -614,15 +613,17 @@ def _state_ops(hlo: str, layers: int, slots: int = SSM_SLOTS, in_place=()):
 
 
 @pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "jnp_twin"])
-def test_hybrid_serving_programs_copy_the_state_pool_once(one_chip, monkeypatch, kernel):
+def test_hybrid_serving_programs_never_copy_the_state_pool(one_chip, monkeypatch, kernel):
     """granite-4.0-h-micro's structure at its cell's engine shapes (32 slots
     x 512, page 16, chunk 64, published widths; depth cut to two periods of
-    (mamba, attention) to keep the compile in seconds): the donated decode
-    program holds one state-update kernel a period's mamba block and the
-    paged kernel with 8 K/V heads under 32 query heads, and NO copy of the
-    stacked state; the chunk-prefill program, not donated by design, holds
-    exactly the one copy that forces, and scatters the rows' new state into
-    it in place. Where the kernel's gate says no, the ``jax.numpy`` step it
+    (mamba, attention) to keep the compile in seconds), both programs as
+    the engine jits them (the cache donated): the decode program holds one
+    state-update kernel a period's mamba block and the paged kernel with 8
+    K/V heads under 32 query heads, and NO copy of the stacked state; the
+    chunk-prefill program aliases every pool and state leaf and sets the
+    rows' new state into the stack it was handed, in place: no copy either
+    (until PR 34 it held one, the longest operation of the cell's
+    capture). Where the kernel's gate says no, the ``jax.numpy`` step it
     falls back to updates the stack in place too (a fusion rooted in a
     dynamic-update-slice of the loop's carry): no copy either, and 2.3 ms a
     decode program slower in the cell (PERF.md section 6, PR 33)."""
@@ -632,7 +633,9 @@ def test_hybrid_serving_programs_copy_the_state_pool_once(one_chip, monkeypatch,
     from zero_transformer_tpu.ops.attention import paged_kernel_supported
     from zero_transformer_tpu.parallel.sharding import unbox
     from zero_transformer_tpu.serving import engine as eng
-    from zero_transformer_tpu.serving.slots import _cache_struct, vectorize_index
+    from zero_transformer_tpu.serving.slots import (
+        POOL_LEAVES, STATE_LEAVES, _cache_struct, _leaf_name, vectorize_index,
+    )
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.delenv("ZT_PALLAS_INTERPRET", raising=False)
@@ -661,16 +664,15 @@ def test_hybrid_serving_programs_copy_the_state_pool_once(one_chip, monkeypatch,
         jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), _cache_struct(model, S)), S)))
     assert cache["ssm_state"].shape == (2, S, SSM_HEADS, SSM_P, SSM_N)
     V = cfg.vocab_size
-    # (jax keys a trace by the function under the jit: the two cases wrap
-    # the step anew, or the second would be handed the first's program)
-    decode = jax.jit(lambda *a: eng._fused_step_impl(*a), static_argnums=(0, 1),
-                     donate_argnums=(3, 4, 5, 6)).lower(
+    # (jax keys a trace by the function under the jit: the two cases ask
+    # for a trace of their own, or the second would be handed the first's)
+    decode = eng._jit_fused_step(fresh=True).lower(
         model, SamplingConfig(greedy=True, repetition_penalty=1.0), params,
         sds((S, V), jnp.float32), cache, sds((S, V), jnp.bool_), sds((S, 2), jnp.uint32),
         sds((S,), jnp.bool_),
     ).compile().as_text()
     R = eng.PREFILL_ROWS
-    prefill = jax.jit(lambda *a: eng._paged_chunk_prefill_impl(*a), static_argnums=(0,)).lower(
+    prefill = eng._jit_paged_chunk(fresh=True).lower(
         model, params, cache, sds((R, CHUNK), jnp.int32), sds((R,), jnp.int32),
         sds((R,), jnp.int32), sds((R,), jnp.int32),
         sds((S, cache_len // PAGE), jnp.int32), sds((S,), jnp.int32),
@@ -682,8 +684,11 @@ def test_hybrid_serving_programs_copy_the_state_pool_once(one_chip, monkeypatch,
     assert ("ssm_state_update" in decode) == kernel and "paged_attention" in decode
     moved = _state_ops(decode, 2, in_place=() if kernel else ("dynamic-update-slice", "fusion"))
     assert not moved, moved
-    # (the rows' new state is scattered into the copy by a fusion or a
-    # short loop of in-place updates)
+    # (the rows' new state is scattered into the stack it was handed by a
+    # fusion or a short loop of in-place updates)
     copies = _state_ops(prefill, 2, in_place=("scatter", "dynamic-update-slice", "fusion"))
-    assert [op for op, _ in copies] == ["copy"], copies
+    assert not copies, copies
+    carried = sum(_leaf_name(p) in POOL_LEAVES + STATE_LEAVES
+                  for p, _ in jax.tree_util.tree_leaves_with_path(cache))
+    assert carried == 4 and _aliases(prefill) >= carried  # K, V, SSM and conv state
     assert "ssm_state_update" not in prefill  # a chunk is the chunked form

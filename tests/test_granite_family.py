@@ -363,12 +363,9 @@ def test_state_controls_are_far_outside(family, monkeypatch, control):
 
     monkeypatch.setattr(Transformer, "apply", blind)
     # the shared jitted programs hold the healthy trace, and jax keys a
-    # trace by the function under the jit: wrap both anew
-    monkeypatch.setattr(eng, "_FUSED_SHARED", jax.jit(
-        lambda *a: eng._fused_step_impl(*a), static_argnums=(0, 1),
-        donate_argnums=(3, 4, 5, 6)))
-    monkeypatch.setattr(eng, "_PAGED_CHUNK_SHARED", jax.jit(
-        lambda *a: eng._paged_chunk_prefill_impl(*a), static_argnums=(0,)))
+    # trace by the function under the jit: the engine's own jits, traced anew
+    monkeypatch.setattr(eng, "_FUSED_SHARED", eng._jit_fused_step(fresh=True))
+    monkeypatch.setattr(eng, "_PAGED_CHUNK_SHARED", eng._jit_paged_chunk(fresh=True))
     engine = _engine(cfg, params)
     prompts = _prompts()
     handles, rows = _serve(engine, prompts)

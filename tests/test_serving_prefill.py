@@ -297,8 +297,9 @@ def test_simultaneous_admissions_prefill_two_rows_to_a_dispatch(cfg, params, ref
 def test_one_chunk_program_in_flight_at_a_time(cfg, params):
     """Prefill-only ticks (a long prompt at an idle engine) and a burst's
     later dispatches wait for nothing else: the engine itself waits for
-    the chunk program before, so no second pool-sized output is enqueued
-    while the first is still owed."""
+    the chunk program before, so the host runs at most one chunk program
+    ahead of the device (the cache is donated, so it is the run-ahead this
+    bounds, no longer a stack of pool copies)."""
     engine = make_engine(cfg, params, n_slots=4, prefill_chunk=4)
     program, ready = engine._paged_chunk, []
 
@@ -345,7 +346,8 @@ def test_itl_attribution_excludes_prefill_ticks(cfg, params):
 
 @pytest.mark.chaos
 def test_prefill_fault_retires_only_the_chunk_slots(cfg, params, reference):
-    """A fault during a prefill chunk fails ONLY the mid-prefill slot
+    """A fault during a prefill chunk, raised BEFORE the program is handed
+    the cache it donates (the chaos hook), fails ONLY the mid-prefill slot
     (retryably): the decoding neighbor's trajectory is byte-identical to an
     undisturbed run, the breaker never opens, and the freed slot serves a
     retry cleanly."""
@@ -373,7 +375,8 @@ def test_prefill_fault_retires_only_the_chunk_slots(cfg, params, reference):
 @pytest.mark.chaos
 def test_prefill_fault_in_a_bursts_second_dispatch_keeps_the_last_pool(cfg, params, reference):
     """Three slots prefill in one tick of a 16-slot engine: two dispatches.
-    The SECOND faults: every slot still mid-prefill fails retryably (the
+    The SECOND faults before it is handed the cache (which the first
+    consumed and replaced): every slot still mid-prefill fails retryably (the
     first dispatch's two, whose chunk ran, and the third), the cache the
     engine holds is the one the first dispatch returned (its pools are not
     the pre-tick arrays, and the fault replaced nothing), and the decoding
@@ -428,6 +431,95 @@ def test_prefill_fault_in_a_bursts_second_dispatch_keeps_the_last_pool(cfg, para
     assert retry.status == "done"
     assert retry.tokens == reference(_prompt(13, offset=50), 3)
     assert [a["slots"] for a in _chunk_spans(engine)][-4:] == [1, 1, 1, 1]
+
+
+def _pools(cache):
+    return [
+        leaf for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
+        if _leaf_name(path) in POOL_LEAVES
+    ]
+
+
+def test_chunk_dispatch_consumes_the_cache_it_is_handed(cfg, params, reference):
+    """The chunk program is jitted with its cache donated: after every
+    dispatch the pools the engine held before it are DELETED (nothing
+    pool-sized is kept beside the program's output; the table and cursor
+    leaves, which the program overwrites without reading, are not handed
+    over at all), the engine holds the program's output, and what is served
+    is byte-identical to ``generate()``."""
+    engine = make_engine(cfg, params, n_slots=4, prefill_chunk=4)
+    prompts = [_prompt(13, offset=7 * i) for i in range(3)]
+    handles = [engine.submit(p, max_new_tokens=6, seed=i) for i, p in enumerate(prompts)]
+    engine._admit()
+    program, handed = engine._paged_chunk, []
+
+    def watched(*args):
+        handed.append(_pools(args[2]))
+        return program(*args)
+
+    engine._paged_chunk = watched
+    held = _pools(engine.slots.cache)
+    assert engine._prefill_tick()  # three slots: two dispatches
+    assert len(handed) == 2 and all(a is b for a, b in zip(handed[0], held))
+    for pools in handed:
+        assert len(pools) == 2 and all(leaf.is_deleted() for leaf in pools)
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(engine.slots.cache))
+    engine._paged_chunk = program
+    engine.run_until_idle()
+    for i, (p, h) in enumerate(zip(prompts, handles)):
+        assert h.status == "done", (h.status, h.error)
+        assert h.tokens == reference(p, i, max_new=6)
+    assert engine.stats["prefill_faults"] == engine.stats["tick_faults"] == 0
+
+
+@pytest.mark.chaos
+def test_prefill_fault_after_the_hand_over_is_the_ticks(cfg, params, reference):
+    """A chunk dispatch that raises AFTER its program consumed the donated
+    cache: the pools the engine holds are gone, so the fault is the tick's.
+    Every decoding and every prefilling request fails retryably, the
+    breaker is fed, the device state is rebuilt, ``prefill_faults_escalated``
+    counts it, and a retry of each request is byte-identical to an
+    undisturbed run."""
+    engine = make_engine(cfg, params, n_slots=4, prefill_chunk=4)
+    asks = [(_prompt(3, offset=9 * i), 12, i) for i in range(2)]
+    asks += [(_prompt(13, offset=50 + i), 8, 3 + i) for i in range(2)]
+    decoding = [engine.submit(p, max_new_tokens=n, seed=s) for p, n, s in asks[:2]]
+    for _ in range(4):
+        engine.step()
+    assert all(h.status == "running" and h.tokens for h in decoding)
+    prefilling = [engine.submit(p, max_new_tokens=n, seed=s) for p, n, s in asks[2:]]
+    program = engine._paged_chunk
+
+    def faults_after_the_call(*args):
+        program(*args)
+        raise RuntimeError("injected: after the hand-over")
+
+    engine._paged_chunk = faults_after_the_call
+    held = _pools(engine.slots.cache)
+    assert engine.step()
+    engine._paged_chunk = program
+    assert all(leaf.is_deleted() for leaf in held)
+    for handle in decoding + prefilling:
+        assert handle.status == "failed" and handle.retryable
+        assert "after the hand-over" in handle.error
+    assert all(h.tokens == [] for h in prefilling)
+    snap = engine.metrics_snapshot()
+    assert snap["tick_faults"] == 1 and snap["prefill_faults_escalated"] == 1
+    assert snap["prefill_faults"] == 1
+    assert "serve_prefill_faults_escalated_total 1" in engine.prometheus_text()
+    events = [(name, fields) for _, name, fields in engine.flight.events()]
+    names = [name for name, _ in events]
+    assert names.count("engine_rebuilt") == 1 and names.count("tick_fault") == 1
+    (fault,) = [f for name, f in events if name == "prefill_fault"]
+    assert fault["escalated"] is True and fault["slots_failed"] == 4
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(engine.slots.cache))
+    assert not engine._prefilling and engine.active_count == 0
+    retries = [engine.submit(p, max_new_tokens=n, seed=s) for p, n, s in asks]
+    engine.run_until_idle()
+    for (p, n, s), retry in zip(asks, retries):
+        assert retry.status == "done", (retry.status, retry.error)
+        assert retry.tokens == reference(p, s, max_new=n)
+    assert engine.stats["tick_faults"] == 1 and not engine._breaker.open
 
 
 def test_decode_fault_mid_chunk_fails_prefilling_retryably(cfg, params, reference):

@@ -76,8 +76,7 @@ def state_bytes_per_slot(m) -> int:
     float32 SSM state and conv inputs (``ModelConfig.state_bytes_per_slot``);
     0 for a stack that only attends. What a serving deployment pays a SLOT
     beside ``kv_bytes_per_token`` a position: ``n_slots`` x this is the
-    state pool, and the chunk-prefill program's undonated copy of the cache
-    holds it once more while it runs."""
+    state pool (held once: both serving programs update it in place)."""
     return m.state_bytes_per_slot
 
 

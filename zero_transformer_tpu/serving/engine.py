@@ -85,6 +85,7 @@ Observability (``obs/`` owns the primitives — docs/OBSERVABILITY.md):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import math
 import queue as queue_mod
@@ -516,8 +517,21 @@ def _fused_step_impl(
     return token, new_logits, cache, gen_mask, rngs, bad, routing
 
 
-def _jit_fused_step():
-    return jax.jit(_fused_step_impl, static_argnums=(0, 1), donate_argnums=(3, 4, 5, 6))
+def _traced_anew(fn):
+    """``fn`` under another identity and its own name. jax keys a trace by
+    the function under the jit, so a caller that has patched what the trace
+    reads (a test's broken control) gets a trace of its own; the name stays
+    because a capture's readers find a program by its XLA module's."""
+    @functools.wraps(fn)
+    def again(*args):
+        return fn(*args)
+    return again
+
+
+def _jit_fused_step(fresh: bool = False):
+    """The decode step as the engine jits it (``fresh``: ``_traced_anew``)."""
+    fn = _traced_anew(_fused_step_impl) if fresh else _fused_step_impl
+    return jax.jit(fn, static_argnums=(0, 1), donate_argnums=(3, 4, 5, 6))
 
 
 # one process-wide compiled step shared by every engine (warmup engines in
@@ -533,9 +547,10 @@ _FUSED_SHARED = _jit_fused_step()
 # again. One row count, because each one costs a trace of the whole model
 # at start-up (2.2 s for the looped 2.6B model on the benchmark's host,
 # 10% of that cell's set-up), and two rows, because a second row costs a
-# tenth of the first (the program's fixed part, the undonated pool copy and
-# one read of the weights, is most of it) while a second dispatch costs it
-# all again.
+# tenth of the first (the program's fixed part, one read of the weights, is
+# most of it) while a second dispatch costs it all again. Measured when the
+# fixed part also held an undonated copy of the pool (gone since PR 34): the
+# row count is due a re-measurement, PERF.md section 5 has the new split.
 PREFILL_ROWS = 2
 
 
@@ -573,9 +588,13 @@ def _paged_chunk_prefill_impl(
     REAL tokens in the window (``valid``: the padded tail leaves the state
     as it was), and the rows' new state goes back into their slots. For
     such a model the host never re-sends a token (``cache_len`` is a whole
-    number of chunks, so no window is clamped). The cache is deliberately NOT donated: on a fault the
-    engine keeps the pre-chunk pool and fails only the prefilling slots
-    (``_on_prefill_fault``).
+    number of chunks, so no window is clamped).
+
+    The engine jits this with the cache DONATED (``_jit_paged_chunk``):
+    every pool and state leaf aliases its output, so the rows' pages are
+    scattered and the rows' state set in place, and no pool-sized value is
+    made. What a fault of the dispatch then costs is ``_prefill_tick``'s
+    rule.
 
     Returns ``(cache, last_logits, touched)`` where ``last_logits`` is
     ``[n_slots, V]`` whatever ``R`` (one shape for everything that
@@ -643,11 +662,21 @@ def _paged_chunk_prefill_impl(
     return new_cache, last, touched
 
 
+def _jit_paged_chunk(fresh: bool = False):
+    """The chunk-prefill program as the engine jits it: the cache
+    (positional 2, after the static model) donated, like the decode step's
+    (``fresh``: ``_traced_anew``). Not wrapped in a lambda: a capture's
+    readers find the program as the XLA module
+    ``jit__paged_chunk_prefill_impl``."""
+    fn = _traced_anew(_paged_chunk_prefill_impl) if fresh else _paged_chunk_prefill_impl
+    return jax.jit(fn, static_argnums=(0,), donate_argnums=(2,))
+
+
 # shared like _FUSED_SHARED: the static (model structure) compares equal
 # across engines, so warmup engines pre-pay this compile too. ONE compiled
 # program per (n_slots, chunk) whatever the prompt-length mix and however
 # many slots prefill at once.
-_PAGED_CHUNK_SHARED = jax.jit(_paged_chunk_prefill_impl, static_argnums=(0,))
+_PAGED_CHUNK_SHARED = _jit_paged_chunk()
 
 
 def _spec_step_impl(
@@ -988,6 +1017,8 @@ class ServingEngine:
         # the prefix_cache_refused event at the end of construction)
         self._prefix_cache_chunks = 0 if self._has_state else prefix_cache_chunks
         self._prefix_cache: Optional[PagedPrefixIndex] = self._make_prefix_cache()
+        # (the name the dispatch calls: declared to the donation-safety rule)
+        # graftlint: donates[2]
         self._paged_chunk = _PAGED_CHUNK_SHARED
         self._spec = _SPEC_SHARED
         # rows of the chunk-prefill program: the slots that prefill in a
@@ -1119,6 +1150,7 @@ class ServingEngine:
             # prefill-path counters (chunked prefill / prefix cache)
             "prefill_chunks": 0,
             "prefill_faults": 0,
+            "prefill_faults_escalated": 0,
             "expired_prefilling": 0,
             # rows of the chunk-prefill program, summed over its dispatches:
             # the slots that prefilled and the rows it computed (live /
@@ -1800,10 +1832,21 @@ class ServingEngine:
         slots to a [rows, chunk] dispatch (one dispatch unless a burst of
         admissions prefills more slots than that at once), and install the
         slots whose prompt completed (their decode starts this same tick).
-        Supervised: a fault fails ONLY the
-        prefilling slots — the chunk program does not donate the cache, so
-        decoding slots keep their buffers and the tick proceeds to a
-        normal fused decode."""
+
+        Supervised, and THE FAULT RULE of a chunk dispatch (the other
+        places point here). The chunk program donates the cache it is
+        handed, as the decode step does, so what a fault costs is decided
+        by what the engine can observe afterwards. BEFORE the hand-over
+        (the chaos hook, building the arguments, the dispatch sanitizer, a
+        trace or compile error: the engine's cache leaves are all alive):
+        only the prefilling slots fail, decoding slots keep their buffers
+        and the tick proceeds to a normal fused decode
+        (``_on_prefill_fault``; no rebuild, no breaker). AFTER it (a leaf
+        of the cache ``is_deleted()``: the faulted call consumed the
+        pools): the fault is the tick's: ``_on_tick_fault`` fails every
+        active and mid-prefill slot retryably, feeds the breaker and
+        rebuilds the device state; ``prefill_faults_escalated`` counts how
+        often the merged fault domain was paid for."""
         if not self._prefilling:
             return False
         self._prefill_work = True
@@ -1867,29 +1910,41 @@ class ServingEngine:
             # classified as a prefill fault and fed to the breaker
             raise
         except Exception as exc:
-            self._on_prefill_fault(exc)
+            if any(leaf.is_deleted() for leaf in jax.tree.leaves(self.slots.cache)):
+                self.stats["prefill_faults"] += 1
+                self.stats["prefill_faults_escalated"] += 1
+                self._event("prefill_fault", error=repr(exc), escalated=True,
+                            slots_failed=self.active_count + len(self._prefilling))
+                # ring entry first, as for a decode tick's fault: a breaker
+                # trip's dump must hold the tick that tripped it
+                self.flight.tick({
+                    "tick": self._tick, "fault": True, "error": repr(exc),
+                    "queued": len(self._queue),
+                })
+                self._on_tick_fault(exc)
+            else:
+                self._on_prefill_fault(exc)
         return True
 
     # graftlint: hot-path
     def _prefill_dispatch(self, group, windows, starts, lens, index_after) -> None:
         """One dispatch of the chunk program for the slots ``group``: adopt
         the cache it returns, advance their jobs and install those whose
-        prompt completed. A fault propagates to ``_prefill_tick`` with the
-        engine still holding the cache of the last dispatch that returned.
+        prompt completed. A fault propagates to ``_prefill_tick``, whose
+        rule asks whether the call had consumed the cache it was handed
+        (donated) or the engine still holds the last dispatch's.
 
-        ONE chunk program is in flight at a time: the cache is not donated,
-        so each program holds a pool-sized output from the moment it is
-        enqueued, and a host that runs ahead of the device (a tick's second
-        dispatch; prefill-only ticks, which wait for nothing: a long prompt
-        arriving at an idle engine) stacks them up until the device's
-        memory is full (GLM cell: 16.84e9 bytes of the chip's 16.91e9,
-        PERF.md section 6). In a tick that decodes the program before has
-        long run (the tick's device_get waited for it) and this costs
-        nothing."""
+        ONE chunk program is in flight at a time: the wait bounds how far
+        the host runs ahead of the device (a tick's second dispatch;
+        prefill-only ticks, which wait for nothing: a long prompt arriving
+        at an idle engine). It bounds no memory: the cache is donated, so
+        a program in flight holds no pool-sized output. In a tick that
+        decodes the program before has long run (the tick's device_get
+        waited for it) and this costs nothing."""
         C = self.prefill_chunk
         before, self._chunk_in_flight = self._chunk_in_flight, None
         if before is not None:
-            # graftlint: allow[host-sync-in-hot-path] reason=waits only where the host would run ahead of the device (a burst's later dispatches, prefill-only ticks); bounds device memory at one pool copy in flight
+            # graftlint: allow[host-sync-in-hot-path] reason=waits only where the host would run ahead of the device (a burst's later dispatches, prefill-only ticks); bounds the run-ahead at one chunk program in flight
             jax.block_until_ready(before)
         # prefill_chunk covers an asynchronous dispatch: the chunk
         # program's device time is waited for in this tick's decode_step
@@ -2090,13 +2145,14 @@ class ServingEngine:
                 )
 
     def _on_prefill_fault(self, exc: Exception) -> None:
-        """A chunk-prefill dispatch failed: fail ONLY the slots mid-prefill
-        (retryable error to those clients) and keep everything else — the
-        chunk program never donates the cache, so the pre-chunk buffers
-        (including every decoding slot's rows) are intact and nothing needs
-        a rebuild. Unlike decode faults this does not feed the breaker:
-        blast radius is per-request and bounded, and the shared decode
-        executable was never implicated."""
+        """A chunk-prefill dispatch failed BEFORE it was handed the cache
+        (``_prefill_tick``'s rule: every cache leaf is alive): fail ONLY
+        the slots mid-prefill (retryable error to those clients) and keep
+        everything else: the buffers the engine holds (including every
+        decoding slot's rows) are intact and nothing needs a rebuild.
+        Unlike decode faults this does not feed the breaker: blast radius
+        is per-request and bounded, and the shared decode executable was
+        never implicated."""
         self.stats["prefill_faults"] += 1
         now = self.now()
         failed = sorted(self._prefilling)
@@ -3208,6 +3264,7 @@ class ServingEngine:
         self._gen_mask = jnp.zeros((self.n_slots, V), jnp.bool_)
         self._rngs = jnp.stack([jax.random.PRNGKey(0)] * self.n_slots)
         self._veto = jnp.full((self.n_slots,), -1, jnp.int32)
+        self._prefill_touched = self._chunk_in_flight = None
         self._active = [None] * self.n_slots
         self._prefilling.clear()
         if self._prefix_cache is not None:
@@ -3593,7 +3650,8 @@ class ServingEngine:
             "peak_occupancy", "peak_queue_depth",
             "tick_faults", "poisoned_slots", "breaker_trips", "shed_infeasible",
             "rejected_draining", "drain_forced", "reloads", "reloads_rejected",
-            "prefill_chunks", "prefill_faults", "expired_prefilling",
+            "prefill_chunks", "prefill_faults", "prefill_faults_escalated",
+            "expired_prefilling",
             "prefill_rows_live", "prefill_rows_computed",
             "page_faults", "pages_reclaimed", "preemptions",
             "page_waits", "loop_passes",
@@ -3643,6 +3701,8 @@ class ServingEngine:
             ("reloads_rejected", "Hot weight reloads rejected"),
             ("prefill_chunks", "Chunk-prefill row dispatches"),
             ("prefill_faults", "Supervised chunk-prefill faults"),
+            ("prefill_faults_escalated",
+             "Chunk-prefill faults that had consumed the donated cache (tick faults)"),
             ("prefill_rows_live", "Chunk-prefill program rows that prefilled a slot"),
             ("prefill_rows_computed",
              "Chunk-prefill program rows computed (padding included)"),

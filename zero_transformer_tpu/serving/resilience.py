@@ -255,8 +255,11 @@ class ServeFault(Fault):
       0-based) — sigterm/slow_tick fire once at the first tick >= step;
       tick_fault / prefill_fault / nan_logits fire for ``duration``
       consecutive ticks. A prefill_fault raises inside the CHUNK-prefill
-      dispatch (before the fused decode), proving the engine fails only
-      the mid-prefill slots and leaves decoding neighbors untouched.
+      dispatch (before the fused decode) and BEFORE the chunk program is
+      handed the cache it donates, proving that such a fault fails only
+      the mid-prefill slots and leaves decoding neighbors untouched (one
+      that had consumed the cache is the tick's: the rule is
+      ``ServingEngine._prefill_tick``'s).
       "slow_client" is a CONSUMER fault: the server's SSE pump stalls for
       ``duration`` seconds mid-stream (a reader that stopped draining its
       socket), proving the bounded emit buffer finishes the stalled
@@ -314,8 +317,8 @@ class ServingChaosMonkey(ChaosMonkey):
 
     def on_prefill_chunk(self, tick: int) -> None:
         """Called at the top of a supervised chunk-prefill dispatch: a
-        "prefill_fault" in its window raises here, through the exact path
-        a real mid-chunk blow-up (OOM, bad artifact math) takes."""
+        "prefill_fault" in its window raises here, before the program is
+        called: the path of a fault on the host's side of the dispatch."""
         for f in self._of_kind("prefill_fault"):
             if f.step <= tick < f.step + int(f.duration):
                 if not f.fired:
