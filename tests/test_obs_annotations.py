@@ -118,10 +118,10 @@ def test_disabled_tracer_records_and_annotates_nothing(monkeypatch):
 
 
 def test_disabled_engine_tracer_annotates_nothing(cfg, params, tmp_path):
-    engine = make_engine(cfg, params, trace=False)
+    engine = make_engine(cfg, params, n_slots=4, trace=False)
     profiling.start_trace(tmp_path)
     try:
-        drive(engine, n=1)
+        drive(engine)  # a burst: an enabled tracer would write install and chunk_wait too
     finally:
         jax.profiler.stop_trace()
     assert len(engine.tracer) == 0
@@ -133,8 +133,10 @@ def test_disabled_engine_tracer_annotates_nothing(cfg, params, tmp_path):
 
 def test_capture_holds_the_tick_tree_on_both_clocks(cfg, params, tmp_path):
     """A real capture, Python tracing off, around a tiny engine run: the
-    host plane holds engine/tick > engine/schedule, engine/decode_step >
-    engine/dispatch + engine/device_wait, engine/emit with the ring's own
+    host plane holds engine/tick > engine/schedule, engine/prefill >
+    engine/chunk_wait + engine/prefill_chunk + engine/install,
+    engine/decode_step > engine/dispatch + engine/device_wait, engine/emit
+    with the ring's own
     ``tick`` values, and every tick's children cover >= 95% of it (what is
     left is span overhead and the release of the step's arrays at return:
     some 40 us, so the ticks here are made a few milliseconds long)."""
@@ -160,8 +162,11 @@ def test_capture_holds_the_tick_tree_on_both_clocks(cfg, params, tmp_path):
     ring = {}
     for s in engine.tracer.by_track("engine"):
         ring.setdefault(s[NAME], {})[s[ATTRS]["tick"]] = s
-    assert set(ring) == {"tick", "schedule", "prefill", "prefill_chunk", "grow_pages",
-                         "decode_step", "dispatch", "device_wait", "emit"}
+    # three requests, two rows a dispatch: the first tick dispatches twice,
+    # installs after each and waits for the first program before the second
+    assert set(ring) == {"tick", "schedule", "prefill", "chunk_wait", "prefill_chunk",
+                         "install", "grow_pages", "decode_step", "dispatch", "device_wait",
+                         "emit"}
     decode_ticks = set(ring["decode_step"])
     assert decode_ticks and decode_ticks <= set(ring["tick"])
     # same names, same tick values on the profiler's side
@@ -182,6 +187,17 @@ def test_capture_holds_the_tick_tree_on_both_clocks(cfg, params, tmp_path):
         assert d[1] <= w[0]  # the wait follows the dispatch
     for tick in ring["prefill_chunk"]:
         assert inside("prefill_chunk", "prefill", tick) and inside("prefill", "tick", tick)
+    # the admission's two spans are on the profiler's side too, inside prefill;
+    # an install follows a dispatch and lies over none
+    assert ring["install"] and ring["chunk_wait"]
+    for name in ("install", "chunk_wait"):
+        for tick in ring[name]:
+            assert inside(name, "prefill", tick), (name, tick)
+    for tick in ring["install"]:
+        chunks = by_name["prefill_chunk"][tick]
+        for s, e in by_name["install"][tick]:
+            assert any(ce <= s for _, ce in chunks)
+            assert not any(cs < e and ce > s for cs, ce in chunks)
 
     # ring side: children cover each tick; a tick is its children plus self time
     for tick, root in ring["tick"].items():

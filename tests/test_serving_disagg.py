@@ -425,6 +425,9 @@ def test_prefill_handoff_and_role_contracts(cfg, params, reference):
     assert cont.status == "done" and cont.tokens == expect
     assert pre.stats["prefill_handoffs"] == 1
     assert dst.stats["prefill_chunks"] == 0  # decode never re-prefilled
+    # the hand-off is the prefill replica's ``install`` span, and says so
+    installs = [s[5] for s in pre.tracer.by_track("engine") if s[2] == "install"]
+    assert [(a["slots"], a.get("shipped")) for a in installs] == [(1, 1)]
 
     bare = pre.submit(prompt, max_new_tokens=4)
     assert bare.status == "rejected" and "prefill_to" in bare.error

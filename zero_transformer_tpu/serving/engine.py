@@ -1944,8 +1944,10 @@ class ServingEngine:
         C = self.prefill_chunk
         before, self._chunk_in_flight = self._chunk_in_flight, None
         if before is not None:
-            # graftlint: allow[host-sync-in-hot-path] reason=waits only where the host would run ahead of the device (a burst's later dispatches, prefill-only ticks); bounds the run-ahead at one chunk program in flight
-            jax.block_until_ready(before)
+            # the tick thread's one wait for the device beside device_wait
+            with self.tracer.span("chunk_wait", "engine", tick=self._tick):
+                # graftlint: allow[host-sync-in-hot-path] reason=waits only where the host would run ahead of the device (a burst's later dispatches, prefill-only ticks); bounds the run-ahead at one chunk program in flight
+                jax.block_until_ready(before)
         # prefill_chunk covers an asynchronous dispatch: the chunk
         # program's device time is waited for in this tick's decode_step
         with self.tracer.span("prefill_chunk", "engine", tick=self._tick,
@@ -2072,51 +2074,56 @@ class ServingEngine:
         """Move slots whose prefill just finished into the decode set (one
         coalesced install), then bank their chunk-aligned prefix spans so
         the NEXT prompt sharing the prefix skips them. Completions whose
-        request names a decode target (``prefill_to``) ship instead."""
-        ship = [
-            (s, j) for s, j in completed
-            if j.handle.request.prefill_to is not None
-        ]
-        if ship:
-            self._handoff_completed(ship, last_rows)
-            completed = [
+        request names a decode target (``prefill_to``) ship instead.
+        All of it is the ``install`` span: the admission's host work and
+        its small launches, after the chunk program's dispatch."""
+        with self.tracer.span("install", "engine", tick=self._tick,
+                              slots=len(completed)) as install_span:
+            ship = [
                 (s, j) for s, j in completed
-                if j.handle.request.prefill_to is None
+                if j.handle.request.prefill_to is not None
             ]
-            if not completed:
-                return
-        mask = [False] * self.n_slots
-        zero_key = jnp.zeros((2,), jnp.uint32)
-        keys = [zero_key] * self.n_slots
-        for slot, job in completed:
-            mask[slot] = True
-            keys[slot] = jax.random.PRNGKey(job.handle.request.seed)
-        self._last_logits, self._gen_mask, self._rngs = _in_mesh(
-            self.mesh,
-            _install_rows,
-            self._last_logits,
-            self._gen_mask,
-            self._rngs,
-            jnp.asarray(mask, jnp.bool_),
-            last_rows,
-            jnp.stack(keys),
-        )
-        if self.draft_k:
-            # fresh request, fresh rejection-rule carry
-            self._veto = jnp.where(
-                jnp.asarray(mask, jnp.bool_), -1, self._veto
+            if ship:
+                install_span.note(shipped=len(ship))
+                self._handoff_completed(ship, last_rows)
+                completed = [
+                    (s, j) for s, j in completed
+                    if j.handle.request.prefill_to is None
+                ]
+                if not completed:
+                    return
+            mask = [False] * self.n_slots
+            zero_key = jnp.zeros((2,), jnp.uint32)
+            keys = [zero_key] * self.n_slots
+            for slot, job in completed:
+                mask[slot] = True
+                keys[slot] = jax.random.PRNGKey(job.handle.request.seed)
+            self._last_logits, self._gen_mask, self._rngs = _in_mesh(
+                self.mesh,
+                _install_rows,
+                self._last_logits,
+                self._gen_mask,
+                self._rngs,
+                jnp.asarray(mask, jnp.bool_),
+                last_rows,
+                jnp.stack(keys),
             )
-        t_done = self.now()
-        for slot, job in completed:
-            del self._prefilling[slot]
-            job.handle.prefill_done_at = t_done
-            if job.handle.admitted_at is not None:
-                self._h_prefill.observe(t_done - job.handle.admitted_at)
-            self._active[slot] = _ActiveSlot(job.handle)
-            self.stats["peak_occupancy"] = max(
-                self.stats["peak_occupancy"], self.active_count
-            )
-            self._bank_prefix(slot, job.handle)
+            if self.draft_k:
+                # fresh request, fresh rejection-rule carry
+                self._veto = jnp.where(
+                    jnp.asarray(mask, jnp.bool_), -1, self._veto
+                )
+            t_done = self.now()
+            for slot, job in completed:
+                del self._prefilling[slot]
+                job.handle.prefill_done_at = t_done
+                if job.handle.admitted_at is not None:
+                    self._h_prefill.observe(t_done - job.handle.admitted_at)
+                self._active[slot] = _ActiveSlot(job.handle)
+                self.stats["peak_occupancy"] = max(
+                    self.stats["peak_occupancy"], self.active_count
+                )
+                self._bank_prefix(slot, job.handle)
 
     def _bank_prefix(self, slot: int, handle: RequestHandle) -> None:
         """Bank a completed prefill's chunk-aligned prefix pages so the
@@ -2252,9 +2259,9 @@ class ServingEngine:
         self._profiler.poll(self._tick)
         # the tick's span tree (live spans: the ring on the engine's clock,
         # "engine/<name>" annotations on the profiler's while a capture is
-        # open):  tick > schedule, prefill > prefill_chunk, grow_pages,
-        # decode_step > dispatch + device_wait, emit. What the children do
-        # not cover is the tick's self time.
+        # open):  tick > schedule, prefill > chunk_wait + prefill_chunk +
+        # install, grow_pages, decode_step > dispatch + device_wait, emit.
+        # What the children do not cover is the tick's self time.
         with self.tracer.span("tick", "engine", tick=self._tick) as tick_span:
             return self._run_tick(tick_span)
 
@@ -2278,8 +2285,14 @@ class ServingEngine:
                 sched_span.discard()
         ran_prefill = False
         if self._prefilling:
+            rows_before = self.stats["prefill_rows_computed"]
             with tr.span("prefill", "engine", tick=tick_idx):
                 ran_prefill = self._prefill_tick()
+            # the chunk program's dispatches this tick, from the counter each
+            # one already keeps: a chunk tick is told by its ``tick`` record
+            chunks = (self.stats["prefill_rows_computed"] - rows_before) // self.prefill_rows
+            if chunks:
+                tick_span.note(chunks=chunks)
         if self.active_count:
             with tr.span("grow_pages", "engine", tick=tick_idx):
                 self._grow_decode_pages()
