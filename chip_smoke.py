@@ -332,7 +332,7 @@ def kernels_phase(model: str = SERVE_MODEL, slots: int = 4,
 
     from zero_transformer_tpu.config import ServingConfig, model_config
     from zero_transformer_tpu.ops.pallas.parity import (
-        latent_vs_gather, paged_vs_gather, ssm_update_vs_xla,
+        SSM_LIVE, latent_vs_gather, paged_vs_gather, ssm_update_vs_xla,
     )
 
     cfg = model_config(model)
@@ -365,9 +365,10 @@ def kernels_phase(model: str = SERVE_MODEL, slots: int = 4,
             )
     on_tpu = device["platform"] == "tpu"
     ssm = [
-        ssm_update_vs_xla(rows=S, heads=H, head_dim=P, d_state=N, seed=SEED,
-                          interpret=not on_tpu, time_calls=20 if on_tpu else 0)
-        for S, H, P, N in ssm_shapes
+        ssm_update_vs_xla(rows=S, heads=H, head_dim=P, d_state=N, live=live,
+                          seed=SEED, interpret=not on_tpu,
+                          time_calls=20 if on_tpu else 0)
+        for S, H, P, N in ssm_shapes for live in SSM_LIVE
     ]
     for case in ssm:
         if not (case["finite"] and case["idle_rows_kept"] and case["other_layers_kept"]

@@ -612,6 +612,15 @@ def _state_ops(hlo: str, layers: int, slots: int = SSM_SLOTS, in_place=()):
     return out
 
 
+def _computation_of(hlo: str, kernel: str) -> str:
+    """The text of the optimised HLO computation that calls ``kernel``."""
+    import re
+
+    (block,) = [b for b in re.split(r"\n(?=\S)", hlo)
+                if re.search(rf"%{kernel}[\w.]* = .* custom-call\(", b)]
+    return block
+
+
 @pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "jnp_twin"])
 def test_hybrid_serving_programs_never_copy_the_state_pool(one_chip, monkeypatch, kernel):
     """granite-4.0-h-micro's structure at its cell's engine shapes (32 slots
@@ -684,6 +693,11 @@ def test_hybrid_serving_programs_never_copy_the_state_pool(one_chip, monkeypatch
     assert ("ssm_state_update" in decode) == kernel and "paged_attention" in decode
     moved = _state_ops(decode, 2, in_place=() if kernel else ("dynamic-update-slice", "fusion"))
     assert not moved, moved
+    if kernel:
+        # the kernel's live rows are compacted ONCE, before the period loop
+        # (``models.mamba.step_rows``): its body builds no prefix sum
+        body = _computation_of(decode, "ssm_state_update")
+        assert "reduce-window" not in body
     # (the rows' new state is scattered into the stack it was handed by a
     # fusion or a short loop of in-place updates)
     copies = _state_ops(prefill, 2, in_place=("scatter", "dynamic-update-slice", "fusion"))

@@ -95,9 +95,12 @@ def test_kernels_phase_holds_the_paged_kernel_to_its_bar(monkeypatch):
               require_tpu=False)
     got = chip_smoke.kernels_phase("test", **kw)
     assert got["ok"] and len(got["paged_vs_gather"]) == 8
-    (ssm,) = got["ssm_update_vs_xla"]
-    assert ssm["ulps"] <= chip_smoke.SSM_ULPS < 1000 < ssm["control_ulps"]
-    assert ssm["idle_rows_kept"] and ssm["other_layers_kept"] and "kernel_s" not in ssm
+    # every pattern of decoding rows, none and all among them
+    ssm = got["ssm_update_vs_xla"]
+    assert [c["live_rows"] for c in ssm] == [0, 1, 1, 2, 2, 5]
+    for case in ssm:
+        assert case["ulps"] <= chip_smoke.SSM_ULPS < 1000 < case["control_ulps"]
+        assert case["idle_rows_kept"] and case["other_layers_kept"] and "kernel_s" not in case
     assert [c["shape"]["T"] for c in got["latent_vs_gather"]] == [1, 5]
     for case in got["latent_vs_gather"]:
         assert case["ulps"] <= chip_smoke.PAGED_ULPS < case["control_ulps"]

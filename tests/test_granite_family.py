@@ -310,6 +310,10 @@ def test_engine_chunked_prefill_then_decode_with_state_beside_the_pages(
     assert all(a["state_rows_in_use"] >= a["state_rows"] for a in steps)
     assert any(a["state_rows_in_use"] > a["state_rows"] for a in steps)
     assert all(a["state_bytes"] == 2 * a["active"] * cfg.state_bytes_per_slot for a in steps)
+    # the slots the state update left in HBM: the parked and the prefilling
+    assert all(a["state_rows_skipped"] == 4 - a["state_rows"] for a in steps)
+    assert snap["state_rows_skipped"] == sum(a["state_rows_skipped"] for a in steps) > 0
+    assert "serve_state_rows_skipped_total" in engine.prometheus_text()
     # a tick in which a slot was mid-prefill while another decoded
     assert any(a["active"] == 1 for a in steps) and any(a["active"] == 3 for a in steps)
 

@@ -14,6 +14,7 @@ import pytest
 from zero_transformer_tpu.config import model_config
 from zero_transformer_tpu.inference.generate import decode_model
 from zero_transformer_tpu.ops.pallas import ssm_update as su
+from zero_transformer_tpu.ops.pallas.parity import SSM_LIVE, live_pattern
 from zero_transformer_tpu.serving import slots as slots_mod
 from zero_transformer_tpu.serving.slots import PagedKVCache, STATE_LEAVES
 
@@ -104,11 +105,18 @@ def test_gate():
     assert su.head_block(4, 32, 16) == 4 and su.head_block(24, 64, 128) == 24
 
 
-@pytest.mark.parametrize("S,H,P,N,L", [
-    (3, 4, 32, 16, None), (4, 8, 8, 128, 3), (2, 64, 64, 128, 2), (5, 16, 16, 128, None)],
-    ids=["test_size", "stacked", "published_widths", "odd_rows"])
-def test_interpret_kernel_is_the_jnp_path_in_place(S, H, P, N, L):
+@pytest.mark.parametrize("S,H,P,N,L,pattern", [
+    *((3, 4, 32, 16, None, p) for p in SSM_LIVE), *((4, 8, 8, 128, 3, p) for p in SSM_LIVE),
+    (2, 64, 64, 128, 2, "random"), (5, 16, 16, 128, None, "random")],
+    ids=[*(f"test_size-{p}" for p in SSM_LIVE), *(f"stacked-{p}" for p in SSM_LIVE),
+         "published_widths", "odd_rows"])
+def test_interpret_kernel_is_the_jnp_path_in_place(S, H, P, N, L, pattern):
+    """Whatever rows decode, the grid visits those alone: they are the
+    reference's step, every other row's state is the one it had (never
+    moved), its ``y`` zero, and the stack's other layers are untouched."""
     state, x, dt, A, Bm, Cm, D, live = _inputs(S, H, P, N, L, seed=S)
+    if pattern != "random":  # (the seeded 0.6 mask ``_inputs`` draws)
+        live = live_pattern(pattern, S, None)
     layer = None if L is None else jnp.int32(L - 1)
     y_ref, new_ref = su.ssm_update_reference(state, x, dt, A, Bm, Cm, D, live, layer)
     y, new = jax.jit(
@@ -127,6 +135,19 @@ def test_interpret_kernel_is_the_jnp_path_in_place(S, H, P, N, L):
     assert not np.any(np.asarray(y)[~rows])
     if L is not None:  # the other layers of the stack: untouched
         assert bool(jnp.all(new[: L - 1] == state[: L - 1]))
+
+
+@pytest.mark.parametrize("live,order,n_live", [
+    ([0, 0, 0, 0], [0, 0, 0, 0], 0), ([1, 1, 1, 1], [0, 1, 2, 3], 4),
+    ([0, 0, 0, 1], [3, 3, 3, 3], 1), ([1, 0, 0, 0], [0, 0, 0, 0], 1),
+    ([0, 1, 0, 1, 0], [1, 3, 3, 3, 3], 2), ([1, 0, 1, 1, 0, 0, 1], [0, 2, 3, 6, 6, 6, 6], 4)],
+    ids=["none", "all", "last", "first", "alternate", "scattered"])
+def test_live_order_puts_the_live_rows_first(live, order, n_live):
+    """The live rows in order, then the last of them again (row 0 with
+    none): the grid's steps past them stay on the last live block."""
+    got_order, got_n = su.live_order(jnp.asarray(live, jnp.bool_))
+    assert got_order.dtype == jnp.int32 and got_n.dtype == jnp.int32
+    assert got_order.tolist() == order and got_n.tolist() == [n_live]
 
 
 def test_every_row_is_live_without_a_mask():

@@ -31,7 +31,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.nn import initializers
 
 from zero_transformer_tpu.config import ModelConfig, resolve_dtype
-from zero_transformer_tpu.models.mamba import Mamba2Mixer, mamba_state_leaves
+from zero_transformer_tpu.models.mamba import Mamba2Mixer, mamba_state_leaves, step_rows
 from zero_transformer_tpu.models.mla import LatentAttention, latent_pool_leaves
 from zero_transformer_tpu.models.moe import DroplessMoE, MoEMLP
 from zero_transformer_tpu.parallel.sharding import (
@@ -627,9 +627,10 @@ class Block(nn.Module):
     row) or "attention", before the dense MLP; ``layer`` is then the block's
     place among the blocks of its kind (the entry of the stacked state or
     K/V pool it reads and writes), and ``valid`` ``[B]`` how many of the
-    window's positions are real (``models.mamba``). ``cfg.
-    residual_multiplier`` scales each sublayer's output as it joins the
-    residual stream."""
+    window's positions are real (``models.mamba``), ``rows`` a decode
+    step's live rows compacted once for every mamba layer
+    (``models.mamba.step_rows``). ``cfg.residual_multiplier`` scales each
+    sublayer's output as it joins the residual stream."""
 
     cfg: ModelConfig
     deterministic: bool = True
@@ -640,7 +641,7 @@ class Block(nn.Module):
     kind: Optional[str] = None
 
     @nn.compact
-    def __call__(self, carry, layer=None, step=None, valid=None):
+    def __call__(self, carry, layer=None, step=None, valid=None, rows=None):
         cfg = self.cfg
         # packed-sequence models thread the document ids as a third carry
         # element (constant through the layer scan); the decode path never
@@ -656,7 +657,7 @@ class Block(nn.Module):
         kind = self.kind or cfg.layer_kind(0)
         if kind == "mamba":
             attn = Mamba2Mixer(cfg, self.decode, name="mamba")(
-                _norm(cfg, x.dtype, "ln_attn")(x), valid, pools, layer
+                _norm(cfg, x.dtype, "ln_attn")(x), valid, pools, layer, rows
             )
         else:
             attn = (LatentAttention if cfg.latent_attention else Attention)(
@@ -719,7 +720,7 @@ class Period(nn.Module):
     kv_pages: Optional[Tuple[int, int]] = None
 
     @nn.compact
-    def __call__(self, carry, period=None, valid=None):
+    def __call__(self, carry, period=None, valid=None, rows=None):
         pattern = self.cfg.layer_pattern
         for j, kind in enumerate(pattern):
             entry = None
@@ -728,7 +729,7 @@ class Period(nn.Module):
             carry, _ = Block(
                 self.cfg, self.deterministic, self.decode, self.cache_len,
                 self.mesh, self.kv_pages, kind, name=f"block_{j}",
-            )(carry, entry, None, valid)
+            )(carry, entry, None, valid, rows)
         return carry, None
 
 
@@ -905,7 +906,7 @@ class Transformer(nn.Module):
                 Period,
                 variable_axes={"params": 0, "cache": 0},
                 split_rngs={"params": True, "dropout": True},
-                in_axes=(0, nn.broadcast),
+                in_axes=(0, nn.broadcast, nn.broadcast),
                 length=periods,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(*members, name="periods")
@@ -932,6 +933,8 @@ class Transformer(nn.Module):
         # entries. The final norm closes every pass: the next one starts
         # from the normed state.
         looped = cfg.n_loops > 1
+        # a decode step's live rows, compacted once for every mamba layer
+        rows = step_rows(valid) if cfg.hybrid and T == 1 and valid is not None else None
         states, gates = [], []
         if cfg.exit_gate:
             gate = nn.Dense(
@@ -945,7 +948,7 @@ class Transformer(nn.Module):
             step = t if looped else None
             with jax.named_scope("loop_pass") if looped else contextlib.nullcontext():
                 if cfg.hybrid:
-                    carry, _ = stack(carry, layers, valid)
+                    carry, _ = stack(carry, layers, valid, rows)
                 elif cfg.scan_layers:
                     steps = jnp.full((cfg.n_layers,), t, jnp.int32) if looped else None
                     carry, _ = stack(carry, layers, steps)

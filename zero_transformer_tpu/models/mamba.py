@@ -136,25 +136,35 @@ def ssd_scan(x, dt, A, Bm, Cm, D, h0, chunk: int):
     return y[:, :T], h
 
 
-def ssm_step(state, x, dt, A, Bm, Cm, D, live, layer):
+def step_rows(valid):
+    """``ops.pallas.ssm_update.live_order`` of the rows a decode step
+    advances (``valid > 0``), for the caller to build ONCE and hand every
+    mamba layer: inside the stack's loop XLA builds it again a layer."""
+    from zero_transformer_tpu.ops.pallas.ssm_update import live_order
+
+    return live_order(valid > 0)
+
+
+def ssm_step(state, x, dt, A, Bm, Cm, D, live, layer, rows=None):
     """One decode step for every row, the state (a stack when ``layer`` is
     given) updated in place: the Pallas kernel where its gate accepts the
-    shape, the same step in ``jax.numpy`` elsewhere."""
+    shape, the same step in ``jax.numpy`` elsewhere. ``rows``: ``live``
+    compacted (``step_rows``), where the caller has it."""
     from zero_transformer_tpu.ops.pallas import ssm_update as su
 
     _, H, P, N = state.shape[-4:]
     if su.supported(heads=H, head_dim=P, d_state=N, dtype=state.dtype):
         from zero_transformer_tpu.parallel.sharding import shard_kernel
 
-        def local(state, x, dt, A, Bm, Cm, D, live, lyr):
-            y, new = su.ssm_update(
+        def local(state, x, dt, A, Bm, Cm, D, live, lyr, order, n_live):
+            return su.ssm_update(
                 state, x, dt, A, Bm, Cm, D, live,
-                None if layer is None else lyr[0],
+                None if layer is None else lyr[0], rows=(order, n_live),
             )
-            return y, new
 
         lyr = jnp.asarray(0 if layer is None else layer, jnp.int32).reshape(1)
-        args = (state, x, dt, A, Bm, Cm, D, live.astype(jnp.int32), lyr)
+        order, n_live = su.live_order(live) if rows is None else rows
+        args = (state, x, dt, A, Bm, Cm, D, live.astype(jnp.int32), lyr, order, n_live)
         # on a mesh every device takes every operand whole (the state is
         # replicated: it has no head axis the tensor axis is known to divide)
         whole = [(None,) * a.ndim for a in args]
@@ -180,7 +190,9 @@ class Mamba2Mixer(nn.Module):
     stack's carry, which holds the stacked state leaves) and this layer's
     place among the mamba layers, it reads and writes the stack at
     ``layer`` and returns ``(out, pools)``; without, a decode model keeps
-    its own per-layer leaves. ``valid``: see the module docstring."""
+    its own per-layer leaves. ``valid``: see the module docstring; ``rows``
+    (a decode step's, ``step_rows``) is ``valid`` compacted for the state
+    update, built once for the stack."""
 
     cfg: ModelConfig
     decode: bool = False
@@ -192,6 +204,7 @@ class Mamba2Mixer(nn.Module):
         valid: Optional[jax.Array] = None,
         pools: Optional[dict] = None,
         layer: Optional[jax.Array] = None,
+        rows: Optional[tuple] = None,
     ):
         cfg = self.cfg
         dtype = x.dtype
@@ -269,7 +282,7 @@ class Mamba2Mixer(nn.Module):
             if use_cache and T == 1:
                 live = jnp.ones((B,), jnp.bool_) if valid is None else valid > 0
                 y, h = ssm_step(
-                    h0, xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], D, live, layer
+                    h0, xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], D, live, layer, rows
                 )
                 y = y[:, None]
             else:

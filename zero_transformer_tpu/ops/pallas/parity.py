@@ -249,17 +249,38 @@ def latent_vs_gather(
     }
 
 
+# which rows decode in ``ssm_update_vs_xla``: the grid visits those alone
+# ("random": 7 of every 16 rows at random, 14 of 32 as in the state-space
+# cell at its rate)
+SSM_LIVE = ("none", "first", "last", "alternate", "random", "all")
+
+
+def live_pattern(pattern: str, rows: int, key) -> jax.Array:
+    """``[rows]`` bool: the rows of ``pattern`` (one of ``SSM_LIVE``)."""
+    idx = jnp.arange(rows)
+    if pattern == "random":
+        return jnp.zeros((rows,), bool).at[
+            jax.random.permutation(key, rows)[: rows * 7 // 16]].set(True)
+    return {"none": idx < 0, "first": idx == 0, "last": idx == rows - 1,
+            "alternate": idx % 2 == 1, "all": idx >= 0}[pattern]
+
+
 def ssm_update_vs_xla(
     *, rows: int, heads: int, head_dim: int, d_state: int, layers: int = 2,
-    seed: int = 0, interpret: bool = False, time_calls: int = 0,
+    live: str = "random", seed: int = 0, interpret: bool = False,
+    time_calls: int = 0,
 ) -> dict:
     """The decode state-update kernel (``ops.pallas.ssm_update``) against
     its plain ``jax.numpy`` twin on a stacked state with a traced layer
-    index, some rows not decoding, both under jit with the state donated:
-    the new state and ``y`` in float32 ulps at their own scale. The control
-    is a STALE state: the same step from the state one step earlier, which
-    must land far outside. With ``time_calls`` also the mean seconds of a
-    call of each, waited for (a number for a TPU only)."""
+    index, the rows of the ``live`` pattern (``SSM_LIVE``) decoding, both
+    under jit with the state donated: the new state and ``y`` in float32
+    ulps at their own scale, and whether every row that does not decode and
+    every other layer kept its state to the bit (on the chip the only check
+    that the grid, which visits the decoding rows alone, writes back no
+    block it did not fill). The control is a STALE state: the same step over
+    every row from the state one step earlier, which must land far outside.
+    With ``time_calls`` also the mean seconds of a call of each, waited for
+    (a number for a TPU only)."""
     import time
 
     from zero_transformer_tpu.ops.pallas import ssm_update as su
@@ -273,32 +294,36 @@ def ssm_update_vs_xla(
     Bm = jax.random.normal(ks[4], (rows, d_state), f32)
     Cm = jax.random.normal(ks[5], (rows, d_state), f32)
     D = jax.random.normal(ks[6], (heads,), f32)
-    live = jax.random.bernoulli(ks[7], 0.75, (rows,)).at[0].set(True)
+    mask = live_pattern(live, rows, ks[7])
     layer = jnp.int32(layers - 1)
-    small = (x, dt, A, Bm, Cm, D, live, layer)
+    small = (x, dt, A, Bm, Cm, D, mask, layer)
     reference = jax.jit(
         lambda st, *a: su.ssm_update_reference(st, *a), donate_argnums=(0,))
     kernel = jax.jit(
         lambda st, *a: su.ssm_update(st, *a, interpret=interpret), donate_argnums=(0,))
     y_ref, new_ref = reference(state + 0.0, *small)
     y, new = kernel(state + 0.0, *small)
-    # the stale control: the state as it was one step earlier (decayed back)
-    y_stale, _ = reference(state * 0.5, *small)
+    # the stale control: every row's step from the state as it was one step
+    # earlier (decayed back)
+    every = small[:6] + (jnp.ones((rows,), bool), layer)
+    y_stale, _ = reference(state * 0.5, *every)
+    y_every, _ = reference(state + 0.0, *every)
 
     def ulps(a, b):
-        scale = float(jnp.finfo(f32).eps) * float(jnp.max(jnp.abs(b)))
-        return float(jnp.max(jnp.abs(a - b))) / scale
+        diff = float(jnp.max(jnp.abs(a - b)))
+        return diff and diff / (float(jnp.finfo(f32).eps) * float(jnp.max(jnp.abs(b))))
 
     out = {
         "shape": {"rows": rows, "heads": heads, "head_dim": head_dim,
                   "d_state": d_state, "layers": layers},
+        "live": live, "live_rows": int(jnp.sum(mask)),
         "finite": bool(jnp.all(jnp.isfinite(y)) & jnp.all(jnp.isfinite(new))),
         "ulps": max(ulps(y, y_ref), ulps(new, new_ref)),
         "state_ulps": ulps(new, new_ref), "y_ulps": ulps(y, y_ref),
         "idle_rows_kept": bool(jnp.all(jnp.where(
-            live[:, None, None, None], True, new[layers - 1] == state[layers - 1]))),
+            mask[:, None, None, None], True, new[layers - 1] == state[layers - 1]))),
         "other_layers_kept": bool(jnp.all(new[: layers - 1] == state[: layers - 1])),
-        "control_ulps": ulps(y_stale, y_ref),
+        "control_ulps": ulps(y_stale, y_every),
     }
     for name, fn in (("kernel_s", kernel), ("xla_s", reference)) if time_calls else ():
         st = jax.block_until_ready(fn(state + 0.0, *small)[1])

@@ -16,12 +16,20 @@ nothing slices a state-pool-sized value out of the carry or copies it back
 (a custom call's operands are whole buffers: PERF.md section 6, PR 31).
 
 Grid ``(rows, heads / block)``; each step moves one ``[block, head_dim,
-d_state]`` tile in and out through the pipeline. ``live`` (a prefetched
-``[rows]`` int32) says which rows decode this tick: a parked or mid-prefill
-row's tile goes through UNCHANGED (bit for bit: it is copied, not
-multiplied), its ``y`` is zero. The tile still crosses the chip's memory
-bus, which the roofline reader counts against the kernel: it reckons the
-decoding rows alone.
+d_state]`` tile in and out through the pipeline, and the grid visits the
+rows that decode this tick ALONE. ``live`` (``[rows]``) is compacted by
+``live_order`` into ``order`` (the live rows, then the last of them
+repeated) and ``n_live``, both prefetched (``models.mamba.step_rows`` builds
+them once a decode program, before the layer loop); step ``(s, h)`` maps
+every operand to row ``order[s]``. A parked or mid-prefill row is never
+DMA'd: its state stays in the aliased HBM buffer, bit for bit, and its ``y``
+is zeroed outside the kernel. The steps past ``n_live`` map to the SAME
+block as the last live step (its row, the last head block), so the pipeline
+fetches and writes back nothing for them, and their body does nothing: the
+block they hold is that step's computed output. The pipeline writes back every block
+it visits, filled or not: with no row live every step maps to one block,
+which the first step copies through. With every row live the grid is the
+all-rows grid it replaced.
 
 What it buys, measured in the serving cell (PERF.md section 6, PR 33): the
 decode program of granite-4.0-h-micro at 32 slots takes 19.04 ms with this
@@ -29,7 +37,9 @@ kernel and 21.32 ms with the ``jax.numpy`` step below in its place (63 us a
 layer; XLA updates the carry in place too, so neither copies the state).
 Timed ALONE, one layer, the two are equal (339 against 333 us): the
 difference is what the step's neighbours in a whole program cost XLA's
-fusions, so measure it there.
+fusions, so measure it there. Visiting the decoding rows alone (PR 37, the
+same cell, some 12 of 32 rows decoding): 77.96 us a call against 217.2, the
+decode program 14.01 ms against 18.74.
 
 ``ssm_update_reference`` is the same step in plain ``jax.numpy``: what the
 CPU runs, what a refused shape falls back to (``supported``), and what the
@@ -48,6 +58,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -118,21 +129,39 @@ def ssm_update_reference(state, x, dt, A, Bm, Cm, D, live=None, layer=None):
     return y, (new if layer is None else state.at[layer].set(new))
 
 
-def _kernel(layer_ref, live_ref, h_ref, da_ref, dtx_ref, b_ref, c_ref, dx_ref,
-            o_ref, y_ref):
+def live_order(live: jax.Array):
+    """``(order [rows], n_live [1])`` int32 from ``live`` ``[rows]``: the live
+    rows in ascending order, then the last of them repeated (row 0 with
+    none), and how many are live: grid step ``s`` visits row ``order[s]``.
+    One-hot sums, not a sort: a few small fusions of ``rows x rows``."""
+    live = live.astype(jnp.int32)
+    n_live = jnp.sum(live)
+    rows = jnp.arange(live.shape[0], dtype=jnp.int32)
+    # the k-th live row is the one with k live rows before it; past the
+    # last, the last
+    k = jnp.minimum(rows, jnp.maximum(n_live - 1, 0))
+    seen = jnp.cumsum(live) - live
+    hit = (live[None, :] > 0) & (seen[None, :] == k[:, None])
+    order = jnp.sum(jnp.where(hit, rows[None, :], 0), axis=1)
+    return order.astype(jnp.int32), n_live.reshape(1)
+
+
+def _kernel(layer_ref, order_ref, n_live_ref, h_ref, da_ref, dtx_ref, b_ref,
+            c_ref, dx_ref, o_ref, y_ref):
     """One ``[hb, P, N]`` tile of one row. ``da`` ``[hb, 1]`` is ``exp(dt
     A)``, ``dtx`` ``[hb, P]`` is ``dt * x``, ``dx`` ``D * x``."""
-    del layer_ref
-    row = pl.program_id(0)
+    del layer_ref, order_ref
+    step, n_live = pl.program_id(0), n_live_ref[0]
 
-    @pl.when(live_ref[row] != 0)
+    @pl.when(step < n_live)
     def _step():
         new = da_ref[0][:, :, None] * h_ref[0, 0] \
             + dtx_ref[0][:, :, None] * b_ref[0][None, :, :]
         o_ref[0, 0] = new
         y_ref[0] = jnp.sum(new * c_ref[0][None, :, :], axis=-1) + dx_ref[0]
 
-    @pl.when(live_ref[row] == 0)
+    # no row live: the one block every step holds goes back as it came
+    @pl.when((n_live == 0) & (step == 0) & (pl.program_id(1) == 0))
     def _keep():
         o_ref[0, 0] = h_ref[0, 0]
         y_ref[0] = jnp.zeros_like(y_ref[0])
@@ -142,11 +171,13 @@ def _kernel(layer_ref, live_ref, h_ref, da_ref, dtx_ref, b_ref, c_ref, dx_ref,
 def ssm_update(
     state: jax.Array, x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     Cm: jax.Array, D: jax.Array, live: Optional[jax.Array] = None,
-    layer: Optional[jax.Array] = None, *, interpret: bool = False,
+    layer: Optional[jax.Array] = None, *, rows: Optional[tuple] = None,
+    interpret: bool = False,
 ):
     """``ssm_update_reference`` as a Pallas kernel, the state aliased to the
     output: with ``layer`` the caller's stack is updated in place at that
-    layer (donate it, or carry it through a loop)."""
+    layer (donate it, or carry it through a loop). ``rows`` is
+    ``live_order(live)`` where the caller built it once for many calls."""
     stacked = layer is not None
     if not stacked:  # an unstacked state is a stack of one: moves no byte
         layer, state = 0, state[None]
@@ -157,6 +188,7 @@ def ssm_update(
     hb = head_block(H, P, N)
     lyr = jnp.asarray(layer, jnp.int32).reshape(1)
     live = jnp.ones((S,), jnp.int32) if live is None else live.astype(jnp.int32)
+    order, n_live = live_order(live) if rows is None else rows
     f32 = jnp.float32
     x, dt = x.astype(f32), dt.astype(f32)
     da = jnp.exp(dt * A.astype(f32))[..., None]  # [S, H, 1]
@@ -164,19 +196,27 @@ def ssm_update(
     dx = D.astype(f32)[:, None] * x
     kernel_traces[KERNEL_NAME] += 1
 
+    def heads(s, h, n_live):
+        """Step ``(s, h)``'s head block: past the live rows, the last (the
+        last live step's block, or row 0's last with none)."""
+        return lax.select(s < n_live[0], h, H // hb - 1)
+
     def per_head(width):
-        return pl.BlockSpec((1, hb, width), lambda s, h, lyr, live: (s, h, 0))
+        def index(s, h, lyr, order, n_live):
+            return order[s], heads(s, h, n_live), 0
+        return pl.BlockSpec((1, hb, width), index)
 
     def per_row():
-        return pl.BlockSpec((1, 1, N), lambda s, h, lyr, live: (s, 0, 0))
+        return pl.BlockSpec((1, 1, N), lambda s, h, lyr, order, n_live: (order[s], 0, 0))
 
-    tile = pl.BlockSpec(
-        (1, 1, hb, P, N), lambda s, h, lyr, live: (lyr[0], s, h, 0, 0)
-    )
+    def tile_index(s, h, lyr, order, n_live):
+        return lyr[0], order[s], heads(s, h, n_live), 0, 0
+
+    tile = pl.BlockSpec((1, 1, hb, P, N), tile_index)
     new, y = pl.pallas_call(
         _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(S, H // hb),
             in_specs=[tile, per_head(1), per_head(P), per_row(),
                       per_row(), per_head(P)],
@@ -186,14 +226,18 @@ def ssm_update(
             jax.ShapeDtypeStruct(state.shape, state.dtype),
             jax.ShapeDtypeStruct((S, H, P), f32),
         ],
-        # operand 2 (after the two prefetched scalars) is the state
-        input_output_aliases={2: 0},
+        # operand 3 (after the three prefetched scalars) is the state
+        input_output_aliases={3: 0},
         name=KERNEL_NAME,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            # a step past the live rows holds the block its predecessor
+            # computed: the steps run in order
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem_bytes(heads=H, head_dim=P, d_state=N),
         ),
         interpret=interpret,
-    )(lyr, live, state, da, dtx, Bm.astype(f32)[:, None, :],
+    )(lyr, order, n_live, state, da, dtx, Bm.astype(f32)[:, None, :],
       Cm.astype(f32)[:, None, :], dx)
+    # the rows the grid did not visit hold no y: zero, as the reference's
+    y = jnp.where(live[:, None, None] > 0, y, 0.0)
     return y, (new if stacked else new[0])

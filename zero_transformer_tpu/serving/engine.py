@@ -1182,8 +1182,10 @@ class ServingEngine:
             "moe_expert_load_max": 0,
             "moe_expert_load_mean": 0.0,
             # a model with recurrent state: first chunks (a slot's state
-            # read as zeros), and what was refused for it, by reason
+            # read as zeros), the slots decode ticks' state update did not
+            # move (summed over ticks), and what was refused, by reason
             "state_resets": 0,
+            "state_rows_skipped": 0,
             "state_refusals_prefix_cache": 0,
             "state_refusals_page_span": 0,
             # speculation counters: acceptance_rate = accepted / drafted
@@ -2365,12 +2367,17 @@ class ServingEngine:
                             fused_args += (self._decoding[1],)
                         if self._has_state:
                             # rows whose state this tick advances, the
-                            # bytes that moves (each state in and out), and
-                            # the slots that hold one (the gauge's count)
+                            # bytes the state update moves (those rows'
+                            # state in and out: it visits no other row),
+                            # the slots it leaves in HBM untouched, and the
+                            # slots that hold one (the gauge's count)
+                            skipped = self.n_slots - self.active_count
+                            self.stats["state_rows_skipped"] += skipped
                             step_span.note(
                                 state_rows=self.active_count,
                                 state_bytes=2 * self.active_count
                                 * self._state_bytes_per_slot,
+                                state_rows_skipped=skipped,
                                 state_rows_in_use=self.state_rows_in_use,
                             )
                         # skip model (0) + params (2) — engine-lifetime
@@ -3670,7 +3677,7 @@ class ServingEngine:
             "page_waits", "loop_passes",
             "kernel_pages_live", "kernel_pages_table",
             "moe_tokens_routed", "moe_expert_load_max", "moe_expert_load_mean",
-            "state_resets", "state_refusals_prefix_cache",
+            "state_resets", "state_rows_skipped", "state_refusals_prefix_cache",
             "state_refusals_page_span",
             "spec_ticks", "draft_tokens", "accepted_tokens",
             "migrations_out", "migrations_in", "migration_failures",
@@ -3737,6 +3744,8 @@ class ServingEngine:
              "Mean rows an expert, summed over decode ticks and routed layers"),
             ("state_resets",
              "First prefill chunks: a slot's recurrent state read as zeros"),
+            ("state_rows_skipped",
+             "Slots whose recurrent state decode ticks left in HBM, unmoved"),
             ("state_refusals_prefix_cache",
              "Prefix indexes asked for and not built: recurrent state"),
             ("state_refusals_page_span",
