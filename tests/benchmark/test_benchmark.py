@@ -327,6 +327,24 @@ def test_serve_run_is_correct_only_with_served_tokens_unaltered(monkeypatch, fau
         assert stats["not_first"] >= 0.1 and stats["p90"] > 3 * limits["served_logit_gap_p90"]
 
 
+def test_serve_mfu_of_a_throughput_cell_is_serve_mfu():
+    # two ticks of 10 ms in the window, one request of 3 prompt and 2 served
+    # tokens (4 positions): 4 x (2 x 100 + 8 x 2.5) = 880 operations over 20 ms
+    ctx = {"spans": [(0, "engine", "tick", 1.00, 1.01, None),
+                     (1, "engine", "tick", 1.02, 1.03, None)],
+           "records": [{"prompt": [1, 2, 3], "tokens": [4, 5], "token_times": [1.01, 1.03]}],
+           "t0": 0.5, "t_end": 2.0, "active_params": 100, "attention_flops_per_position": 8,
+           "peak": {"flops_per_s": 1e6}, "chips": 1}
+    read = harness.load_reader("serve_mfu.throughput").read
+    assert read(ctx) == harness.load_reader("serve_mfu").read(ctx)
+    assert read(ctx) == pytest.approx(100.0 * 4 * arith.forward_flops(100, 8, 2.5) / (0.02 * 1e6))
+    assert read({}) is None
+    declared = next(m for m in BENCH["per_layer"] if m["name"] == "serve_mfu.throughput")
+    assert declared["moves"] == "serve_tokens_per_s"
+    throughput = next(m for m in BENCH["end_to_end"] if m["name"] == "serve_tokens_per_s")
+    assert set(declared["workloads"]) <= set(throughput["workloads"])
+
+
 def test_a_mix_that_names_no_gap_statistic_is_refused():
     drv = harness.load_driver("serve_open_loop")
     assert drv.gap_statistics([]) == {"tokens": 0, "max": None, "p90": None, "mean": None,

@@ -159,15 +159,18 @@ def test_cell_and_traffic_are_as_the_issue_names_them(cell):
             "serve_mfu", "engine_tick_ms_p50", "engine_host_ms_per_tick",
             "engine_device_wait_ms_p50", "queue_wait_ms_p95", "ttft_p95_ms",
             "decode_program_ms_p50", "prefill_program_ms_p50", "device_idle_share.serve",
-            "device_idle_attributed.serve"} == names
+            "device_idle_attributed.serve"} <= names
     # full-head bytes would read four times what 8 K/V heads hold
+    assert "paged_attention_roofline" not in names
     assert {m["name"] for m in cell["end_to_end"]} >= {"itl_p95_ms", "setup_s"}
+    # the cell's own entries, found by name: later PRs append configurations,
+    # cells and metrics wherever the list ends
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    assert len(bench["workloads"]) == 5 and all(w["chips"] == 1 for w in bench["workloads"])
-    assert bench["workloads"][-1]["name"] == CELL and bench["configs"][-1]["name"] == "granite_4_0_h_micro"
-    new = {m["name"]: m for m in bench["per_layer"][-3:]}
-    assert set(new) == {"ssm_state_update_roofline", "ssm_decode_bandwidth_share",
-                        "state_rows_in_use_p50"}
+    entry = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert entry["chips"] == 1 and entry["config"] == "granite_4_0_h_micro"
+    new = {m["name"]: m for m in bench["per_layer"] if m["name"] in
+           {"ssm_state_update_roofline", "ssm_decode_bandwidth_share", "state_rows_in_use_p50"}}
+    assert len(new) == 3
     assert all(m["workloads"] == [CELL] for m in new.values())
     assert new["state_rows_in_use_p50"]["moves"] == "serve_tokens_per_s"
 
