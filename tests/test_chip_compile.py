@@ -706,3 +706,117 @@ def test_hybrid_serving_programs_never_copy_the_state_pool(one_chip, monkeypatch
                   for p, _ in jax.tree_util.tree_leaves_with_path(cache))
     assert carried == 4 and _aliases(prefill) >= carried  # K, V, SSM and conv state
     assert "ssm_state_update" not in prefill  # a chunk is the chunked form
+
+
+# ---- a matrix state a head beside latent pages (Kimi-Linear's cell) --------
+
+KDA_SLOTS, KDA_HEADS, KDA_D = 128, 32, 128
+
+
+def test_kda_state_update_kernel_compiles_at_the_cells_shapes(one_chip, monkeypatch):
+    """The KDA decode state-update kernel at the Kimi-Linear cell's shapes
+    (128 slots, 32 heads of 128 x 128, the stacked state of 6 layers with a
+    traced layer index, donated): one Mosaic call, its state aliased to its
+    output, and no operation of the program makes a state-pool-sized value."""
+    from zero_transformer_tpu.ops.pallas import kda_update as ku
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("ZT_PALLAS_INTERPRET", raising=False)
+    assert ku.supported(heads=KDA_HEADS, head_dim=KDA_D)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S, H, D = KDA_SLOTS, KDA_HEADS, KDA_D
+    text = jax.jit(ku.kda_update, donate_argnums=(0,)).lower(
+        sds((6, S, H, D, D)), sds((S, H, D)), sds((S, H, D)), sds((S, H, D)),
+        sds((S, H, D)), sds((S, H)), sds((S,), jnp.bool_), sds((), jnp.int32),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "kda_state_update" in text
+    assert not _kda_state_ops(text, 6)
+
+
+def _kda_state_ops(hlo: str, layers: int, in_place=()):
+    """``_state_ops`` for the stacked KDA state ``f32[layers, 128, 32, 128,
+    128]``."""
+    import re
+
+    shape = rf"f32\[{layers},{KDA_SLOTS},{KDA_HEADS},{KDA_D},{KDA_D}\]"
+    free = {"parameter", "get-tuple-element", "tuple", "bitcast", "while", "conditional",
+            "call", "custom-call", *in_place}
+    out = []
+    for line in hlo.splitlines():
+        m = re.match(rf"\s*(?:ROOT )?%?([\w.\-]+) = {shape}\S* ([a-z][\w\-]*)\(", line)
+        if m and m.group(2) not in free:
+            out.append((m.group(2), m.group(1)))
+    return out
+
+
+def test_kimi_serving_programs_never_copy_the_state_or_the_latent_pool(one_chip, monkeypatch):
+    """Kimi-Linear-48B-A3B's structure at its cell's engine shapes (128
+    slots x 3,072, page 16, chunk 64, published widths and 32 held experts;
+    depth cut to one period, KDA KDA KDA MLA, the first layer dense, to keep
+    the compile in seconds), both programs as the engine jits them (the
+    cache donated): ONE cache tree, the stacked KDA state beside the stacked
+    latent pool; the decode program holds a state-update kernel a KDA block
+    and the latent kernel, and no copy of the state; the chunk-prefill
+    program aliases every pool and state leaf and runs the chunked form
+    (its triangular solve), not the kernel."""
+    import json
+    from pathlib import Path
+
+    from benchmark import harness
+    from zero_transformer_tpu.inference.generate import decode_model
+    from zero_transformer_tpu.inference.sampling import SamplingConfig
+    from zero_transformer_tpu.parallel.sharding import unbox
+    from zero_transformer_tpu.serving import engine as eng
+    from zero_transformer_tpu.serving.slots import (
+        POOL_LEAVES, STATE_LEAVES, _cache_struct, _leaf_name, vectorize_index,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("ZT_PALLAS_INTERPRET", raising=False)
+    root = Path(__file__).resolve().parents[1]
+    config = json.loads((root / "benchmark/configs/kimi_linear_48b_a3b.json").read_text())
+    cfg = harness.model_config(config, {"n_layers": 4, "dropout": 0.0})
+    S, cache_len = KDA_SLOTS, 3072
+    model = decode_model(cfg, cache_len, kv_pages=(S * cache_len // PAGE + 1, PAGE))
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(
+        lambda r: model.init(r, jnp.zeros((S, 1), jnp.int32)), jax.random.PRNGKey(0))
+    params = on_chip(unbox(shapes["params"]))
+    cache = on_chip(jax.eval_shape(lambda: vectorize_index(
+        jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), _cache_struct(model, S)), S)))
+    assert cache["ssm_state"].shape == (3, S, KDA_HEADS, KDA_D, KDA_D)
+    assert cache["cached_latent"].shape == (1, S * cache_len // PAGE + 1, PAGE, 640)
+    V = cfg.vocab_size
+    decode = eng._jit_fused_step(fresh=True).lower(
+        model, SamplingConfig(greedy=True, repetition_penalty=1.0), params,
+        sds((S, V), jnp.float32), cache, sds((S, V), jnp.bool_), sds((S, 2), jnp.uint32),
+        sds((S,), jnp.bool_),
+    ).compile().as_text()
+    R = eng.PREFILL_ROWS
+    prefill = eng._jit_paged_chunk(fresh=True).lower(
+        model, params, cache, sds((R, CHUNK), jnp.int32), sds((R,), jnp.int32),
+        sds((R,), jnp.int32), sds((R,), jnp.int32),
+        sds((S, cache_len // PAGE), jnp.int32), sds((S,), jnp.int32),
+    ).compile().as_text()
+    import re
+
+    assert len(re.findall(r"%kda_state_update[\w.]* = ", decode)) == 3
+    assert "latent_paged_attention" in decode
+    assert not _kda_state_ops(decode, 3), _kda_state_ops(decode, 3)
+    copies = _kda_state_ops(prefill, 3, in_place=("scatter", "dynamic-update-slice", "fusion"))
+    assert not copies, copies
+    carried = sum(_leaf_name(p) in POOL_LEAVES + STATE_LEAVES
+                  for p, _ in jax.tree_util.tree_leaves_with_path(cache))
+    assert carried == 3 and _aliases(prefill) >= carried  # latent pool, state, conv state
+    assert "kda_state_update" not in prefill  # a chunk is the chunked form

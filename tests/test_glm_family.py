@@ -308,7 +308,7 @@ def test_published_size_counts_and_latent_bytes():
     (dict(moe_dense_layers=4), "moe_dense_layers"),
     (dict(n_experts=0), "needs n_experts"),
     (dict(scan_layers=True), "scan_layers=False"),
-    (dict(q_lora_rank=None), "needs q_lora_rank"),
+    (dict(q_lora_rank=0), "needs q_lora_rank"),
 ])
 def test_configuration_refuses_what_the_family_has_not(over, match):
     import dataclasses

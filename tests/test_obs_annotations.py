@@ -139,8 +139,11 @@ def test_capture_holds_the_tick_tree_on_both_clocks(cfg, params, tmp_path):
     with the ring's own
     ``tick`` values, and every tick's children cover >= 95% of it (what is
     left is span overhead and the release of the step's arrays at return:
-    some 40 us, so the ticks here are made a few milliseconds long)."""
-    engine = make_engine(cfg, params, n_slots=8, cache_len=1024)
+    some 60-90 us). The ticks here are made some 15 ms long (16 slots over
+    4,096 positions): at 8 x 1,024 they were 3.5 ms, and one stall of the
+    worker process of 0.2 ms outside the children (seen in a quiet run;
+    six workers share the machine under xdist) left a tick at 94.7%."""
+    engine = make_engine(cfg, params, n_slots=16, cache_len=4096)
     drive(engine, n=1)  # compile outside the capture
     engine.tracer = obs.Tracer(capacity=65536, clock=engine.now)
     t_open = time.monotonic()

@@ -149,13 +149,19 @@ class ModelConfig:
     # leading layers that keep the dense MLP (width ``d_ff``) in a routed
     # model (dropless, so unrolled: block i is dense where i < this)
     moe_dense_layers: int = 0
+    # the routed experts THIS chip holds, ids [0, moe_experts_held), of
+    # ``n_experts`` routed over (an expert-parallel share: the router, its
+    # bias and the shared expert whole); None holds them all
+    moe_experts_held: Optional[int] = None
     # Latent attention (MLA, DeepSeek-V2/V3), set by ``kv_lora_rank`` with all
-    # five widths: queries through a rank ``q_lora_rank`` bottleneck; keys and values up-projected from ONE normed
+    # five widths: queries through a rank ``q_lora_rank`` bottleneck (None:
+    # one direct projection); keys and values up-projected from ONE normed
     # latent row of ``kv_lora_rank`` values a position, plus one rotated key of
     # ``qk_rope_head_dim`` shared by all heads. A head's query and key are
     # ``qk_nope_head_dim + qk_rope_head_dim`` wide (= ``head_dim``), its value
     # ``v_head_dim``. The cache holds the latent row and the rotated key only
-    # (models/mla.py). None = full multi-head / grouped attention.
+    # (models/mla.py). None = full multi-head / grouped attention. With
+    # ``position`` "none" the rope lanes are kept and rotated by nothing.
     kv_lora_rank: Optional[int] = None
     q_lora_rank: Optional[int] = None
     qk_nope_head_dim: Optional[int] = None
@@ -164,18 +170,23 @@ class ModelConfig:
     # RoPE pairs lanes (2i, 2i+1) instead of (i, i + D/2)
     rope_interleaved: bool = False
     norm_eps: float = 1e-6
-    # Hybrid stack (Mamba-2 state-space mixers among attention layers;
-    # models/mamba.py): ONE period of the stack, each entry "mamba" or
-    # "attention" (which MIXER the block has; every block keeps the dense
-    # MLP), repeated n_layers / len(layer_pattern) times. None = every block
-    # attends. A mamba block keeps a recurrent state a batch row, whatever
-    # the row's length, where an attention block keeps K/V a position.
+    # Hybrid stack (recurrent mixers among attention layers): ONE period of
+    # the stack, each entry "mamba" (Mamba-2, models/mamba.py), "kda" (Kimi
+    # Delta Attention, models/kda.py) or "attention": which MIXER the block
+    # has, repeated n_layers / len(layer_pattern) times. Its MLP is the
+    # dense one, or, in a routed (dropless) stack, ``layer_kind``'s. None =
+    # every block attends. A recurrent block keeps a state a batch row,
+    # whatever the row's length, where an attention block keeps K/V a
+    # position.
     layer_pattern: Optional[Tuple[str, ...]] = None
     mamba_heads: int = 0  # SSM heads; inner width = mamba_heads * mamba_head_dim
     mamba_head_dim: int = 64
     mamba_state: int = 128  # state values a (head, channel): d_state
     mamba_conv: int = 4  # causal depthwise conv taps over [x | B | C]
     mamba_chunk: int = 256  # positions a step of the chunked scan
+    kda_heads: int = 0  # KDA heads; q, k, v are each kda_heads * kda_head_dim wide
+    kda_head_dim: int = 128  # key = value width of a head, and the gates' low rank
+    kda_conv: int = 4  # causal depthwise conv taps over q, k and v
     # scores = (q . k) * attention_scale; None -> 1 / sqrt(head_dim)
     attention_scale: Optional[float] = None
     # h0 = embedding_multiplier * Embed(tokens); each sublayer's output joins
@@ -216,26 +227,45 @@ class ModelConfig:
     def moe_ff_dim(self) -> int:
         return self.moe_d_ff if self.moe_d_ff is not None else self.ff_dim
 
-    def layer_kind(self, i: int) -> str:
-        """What block ``i`` of the stack is: "moe" (the routed layer of
-        ``moe_dispatch``) or "dense" (the MLP; the ``moe_dense_layers``
-        leading blocks of a routed stack, every block of a dense one); in a
-        hybrid stack which mixer it has before its MLP, "mamba" or
-        "attention", as ``layer_pattern`` repeats."""
+    def mlp_kind(self, i: int) -> str:
+        """Block ``i``'s MLP: "moe" (the routed layer of ``moe_dispatch``)
+        or "dense" (the MLP; the ``moe_dense_layers`` leading blocks of a
+        routed stack, every block of a dense one)."""
+        return "moe" if self.n_experts > 0 and i >= self.moe_dense_layers else "dense"
+
+    def mixer_kind(self, i: int) -> str:
+        """Block ``i``'s mixer: "attention", or in a hybrid stack as
+        ``layer_pattern`` repeats ("mamba", "kda" or "attention")."""
         if self.layer_pattern is not None:
             return self.layer_pattern[i % len(self.layer_pattern)]
-        return "moe" if self.n_experts > 0 and i >= self.moe_dense_layers else "dense"
+        return "attention"
+
+    def layer_kind(self, i: int) -> str:
+        """What block ``i`` of the stack is: in a hybrid stack its mixer
+        (``mixer_kind``), else its MLP (``mlp_kind``)."""
+        if self.layer_pattern is not None:
+            return self.mixer_kind(i)
+        return self.mlp_kind(i)
 
     @property
     def hybrid(self) -> bool:
         return self.layer_pattern is not None
 
     @property
+    def recurrent_kind(self) -> Optional[str]:
+        """The recurrent mixer of a hybrid stack ("mamba" or "kda"; a
+        stack has one at most), None for a stack that only attends."""
+        if not self.hybrid:
+            return None
+        kinds = set(self.layer_pattern) - {"attention"}
+        return kinds.pop() if len(kinds) == 1 else None
+
+    @property
     def recurrent(self) -> bool:
         """Does a block keep a recurrent state (a hybrid stack with mamba
-        blocks)? Such a model's cache holds a state a batch row beside its
-        K/V, right only at the position the row has reached."""
-        return self.hybrid and "mamba" in self.layer_pattern
+        or kda blocks)? Such a model's cache holds a state a batch row
+        beside its K/V, right only at the position the row has reached."""
+        return self.recurrent_kind is not None
 
     def layers_of(self, kind: str) -> int:
         """Blocks of ``kind`` in the whole stack."""
@@ -251,17 +281,27 @@ class ModelConfig:
         return self.mamba_inner + 2 * self.mamba_state
 
     @property
+    def kda_inner(self) -> int:
+        return self.kda_heads * self.kda_head_dim
+
+    @property
     def state_bytes_per_slot(self) -> int:
         """Bytes of recurrent state ONE batch row (a serving slot) keeps,
-        whatever its length: a float32 SSM state ``[heads, head_dim,
-        state]`` and the conv's last ``mamba_conv - 1`` inputs at the
-        compute dtype, a mamba block. 0 for a stack that only attends."""
-        if not self.recurrent:
+        whatever its length: a float32 state (a mamba block's ``[heads,
+        head_dim, state]``, a kda block's ``[heads, head_dim, head_dim]``)
+        and the conv's last taps - 1 inputs at the compute dtype. 0 for a
+        stack that only attends."""
+        kind = self.recurrent_kind
+        if kind is None:
             return 0
-        ssm = self.mamba_inner * self.mamba_state * 4
         itemsize = jnp.dtype(resolve_dtype(self.compute_dtype)).itemsize
-        conv = (self.mamba_conv - 1) * self.mamba_conv_dim * itemsize
-        return self.layers_of("mamba") * (ssm + conv)
+        if kind == "kda":
+            state = self.kda_inner * self.kda_head_dim * 4
+            conv = (self.kda_conv - 1) * 3 * self.kda_inner * itemsize
+        else:
+            state = self.mamba_inner * self.mamba_state * 4
+            conv = (self.mamba_conv - 1) * self.mamba_conv_dim * itemsize
+        return self.layers_of(kind) * (state + conv)
 
     @property
     def _mamba_params(self) -> int:
@@ -276,47 +316,67 @@ class ModelConfig:
         )
 
     @property
+    def _kda_params(self) -> int:
+        d, inner, h, r = self.d_model, self.kda_inner, self.kda_heads, self.kda_head_dim
+        return (
+            4 * d * inner  # q, k, v and out projections
+            + 3 * inner * self.kda_conv  # depthwise taps over q, k, v
+            + 2 * (d * r + r * inner)  # the decay's and the output gate's low ranks
+            + d * h + h  # beta's projection; A_log
+            + inner + r  # dt_bias; the output norm's scale
+        )
+
+    @property
     def _attention_params(self) -> int:
         d, h = self.d_model, self.n_heads
         if self.latent_attention:
             r, rq = self.kv_lora_rank, self.q_lora_rank
             qk, rope = self.head_width, self.qk_rope_head_dim
-            q = d * rq + rq + rq * h * qk
+            q = d * h * qk if rq is None else d * rq + rq + rq * h * qk
             kv = d * (r + rope) + r + r * h * (self.qk_nope_head_dim + self.v_head_dim)
             return q + kv + h * self.v_head_dim * d
         kv, hd = self.kv_heads, self.head_width
         return d * h * hd + 2 * d * kv * hd + h * hd * d
 
-    def _layer_params(self, kind: str, active: bool = False) -> int:
-        """Parameters of ONE block of a kind (matrices, norm scales, router);
-        with ``active``, of a routed block only what one token is multiplied
-        by: ``moe_top_k`` of its experts."""
+    @property
+    def experts_held(self) -> int:
+        """Routed experts this model holds (``moe_experts_held``, else all)."""
+        if self.moe_experts_held is None:
+            return self.n_experts
+        return self.moe_experts_held
+
+    def _layer_params(self, i: int, active: bool = False) -> int:
+        """Parameters of block ``i`` (matrices, norm scales, router); with
+        ``active``, of a routed block only what one token is multiplied
+        by: ``moe_top_k`` of its experts, as many as fall among the held
+        ones on average (all of them when every expert is held)."""
         d = self.d_model
         per = 3 if self.activation == "swiglu" else 2
-        if kind == "moe":
-            e = self.moe_top_k if active else self.n_experts
+        if self.mlp_kind(i) == "moe":
+            held = self.experts_held
+            e = round(self.moe_top_k * held / self.n_experts) if active else held
             mlp = per * d * self.moe_ff_dim * (e + self.moe_shared_experts) + d * self.n_experts
             if self.moe_dispatch == "dropless":
                 mlp += self.n_experts  # the selection bias
         else:
             mlp = per * d * self.ff_dim
         norms = (4 if self.post_norm else 2) * d
-        mixer = self._mamba_params if kind == "mamba" else self._attention_params
+        mixer = {"mamba": self._mamba_params, "kda": self._kda_params}.get(
+            self.mixer_kind(i), self._attention_params
+        )
         return mixer + mlp + norms
 
     @property
     def layer_params(self) -> int:
         """Parameters of ONE block (matrices and norm scales); of a stack
         with leading dense layers, of a routed block."""
-        return self._layer_params(self.layer_kind(self.n_layers - 1))
+        return self._layer_params(self.n_layers - 1)
 
     def _stack_params(self, active: bool) -> int:
         d, v = self.d_model, self.vocab_size
         embed = v * d * (1 if self.tie_embeddings else 2)
         gate = d + 1 if self.exit_gate else 0
-        layers = sum(
-            self._layer_params(self.layer_kind(i), active) for i in range(self.n_layers)
-        )
+        layers = sum(self._layer_params(i, active) for i in range(self.n_layers))
         return layers + embed + d + gate
 
     @property
@@ -414,23 +474,34 @@ class ModelConfig:
             )
         if not 0 <= self.moe_dense_layers < self.n_layers:
             raise ValueError("moe_dense_layers must lie in [0, n_layers)")
+        if self.moe_experts_held is not None and not (
+            dropless and 1 <= self.moe_experts_held <= self.n_experts
+        ):
+            raise ValueError(
+                "moe_experts_held belongs to moe_dispatch='dropless' and lies "
+                "in [1, n_experts]"
+            )
         if self.latent_attention:
-            widths = (self.q_lora_rank, self.qk_nope_head_dim,
-                      self.qk_rope_head_dim, self.v_head_dim)
+            widths = (self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)
             if None in widths or self.qk_rope_head_dim % 2:
                 raise ValueError(
-                    "latent attention needs q_lora_rank, qk_nope_head_dim, an "
-                    "even qk_rope_head_dim and v_head_dim"
+                    "latent attention needs qk_nope_head_dim, an even "
+                    "qk_rope_head_dim and v_head_dim"
+                )
+            if self.q_lora_rank is not None and self.q_lora_rank < 1:
+                raise ValueError(
+                    "latent attention needs q_lora_rank >= 1, or None for a "
+                    "direct query projection"
                 )
             if self.head_width != self.qk_nope_head_dim + self.qk_rope_head_dim:
                 raise ValueError(
                     "head_dim must be qk_nope_head_dim + qk_rope_head_dim "
                     "(the width of a query and key head)"
                 )
-            if self.position != "rope" or self.n_kv_heads is not None:
+            if self.position not in ("rope", "none") or self.n_kv_heads is not None:
                 raise ValueError(
-                    "latent attention is RoPE on its own shared key; it has "
-                    "no kv heads to group"
+                    "latent attention is RoPE (or no position at all) on its "
+                    "own shared key; it has no kv heads to group"
                 )
             if self.kv_cache_dtype != "auto" or self.param_quant != "none" or self.n_loops > 1:
                 raise ValueError(
@@ -453,13 +524,19 @@ class ModelConfig:
 
     def _check_hybrid(self) -> None:
         pattern = self.layer_pattern
-        if not pattern or set(pattern) - {"mamba", "attention"}:
+        if not pattern or set(pattern) - {"mamba", "kda", "attention"}:
             raise ValueError(
-                f"layer_pattern {pattern!r}: one period of 'mamba' / 'attention'"
+                f"layer_pattern {pattern!r}: one period of 'mamba' / 'kda' / "
+                "'attention'"
+            )
+        if {"mamba", "kda"} <= set(pattern):
+            raise ValueError(
+                "a hybrid stack has one kind of recurrent mixer: 'mamba' and "
+                "'kda' blocks would share the cache's state leaves"
             )
         if self.n_layers % len(pattern):
             raise ValueError("n_layers must be a multiple of len(layer_pattern)")
-        if self.recurrent and (
+        if "mamba" in pattern and (
             self.mamba_heads < 1 or self.mamba_head_dim < 1 or self.mamba_state < 1
             or self.mamba_conv < 2
         ):
@@ -467,22 +544,32 @@ class ModelConfig:
                 "a mamba block needs mamba_heads, mamba_head_dim, mamba_state "
                 ">= 1 and mamba_conv >= 2"
             )
-        if not self.scan_layers:
+        if "kda" in pattern and (
+            self.kda_heads < 1 or self.kda_head_dim < 1 or self.kda_conv < 2
+        ):
             raise ValueError(
-                "a hybrid stack (layer_pattern) needs scan_layers=True: it is "
-                "scanned over the periods of its pattern (unrolled it traces "
-                "and compiles twice as long for 3.5% of a decode tick: "
-                "PERF.md section 6, PR 33)"
+                "a kda block needs kda_heads, kda_head_dim >= 1 and kda_conv >= 2"
+            )
+        if self.scan_layers and (self.n_experts or self.latent_attention):
+            raise ValueError(
+                "a scanned hybrid stack (layer_pattern) has dense MLPs and "
+                "full-head attention; one with routed (dropless) layers is "
+                "unrolled (scan_layers=False), each block told its kind"
+            )
+        if not self.scan_layers and not (self.n_experts and self.moe_dispatch == "dropless"):
+            raise ValueError(
+                "a hybrid stack (layer_pattern) without routed layers needs "
+                "scan_layers=True: it is scanned over the periods of its "
+                "pattern (unrolled it traces and compiles twice as long for "
+                "3.5% of a decode tick: PERF.md section 6, PR 33)"
             )
         if (
-            self.n_experts or self.latent_attention or self.n_loops > 1
-            or self.post_norm or self.doc_sep_token is not None
+            self.n_loops > 1 or self.post_norm or self.doc_sep_token is not None
             or self.param_quant != "none" or self.remat
         ):
             raise ValueError(
-                "a hybrid stack (layer_pattern) has dense MLPs and full-head "
-                "attention, one pass, no sandwich norms, packing, remat or "
-                "int8 weights"
+                "a hybrid stack (layer_pattern) has one pass, no sandwich "
+                "norms, packing, remat or int8 weights"
             )
         if self.kv_cache_dtype != "auto" and self.recurrent:
             raise ValueError(

@@ -31,6 +31,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.nn import initializers
 
 from zero_transformer_tpu.config import ModelConfig, resolve_dtype
+from zero_transformer_tpu.models.kda import KDAMixer, kda_state_leaves
 from zero_transformer_tpu.models.mamba import Mamba2Mixer, mamba_state_leaves, step_rows
 from zero_transformer_tpu.models.mla import LatentAttention, latent_pool_leaves
 from zero_transformer_tpu.models.moe import DroplessMoE, MoEMLP
@@ -623,14 +624,15 @@ class Block(nn.Module):
     over the rows that decode).
 
     In a hybrid stack (``cfg.layer_pattern``) ``kind`` names the MIXER,
-    "mamba" (``models.mamba.Mamba2Mixer``, which keeps a recurrent state a
-    row) or "attention", before the dense MLP; ``layer`` is then the block's
-    place among the blocks of its kind (the entry of the stacked state or
-    K/V pool it reads and writes), and ``valid`` ``[B]`` how many of the
-    window's positions are real (``models.mamba``), ``rows`` a decode
-    step's live rows compacted once for every mamba layer
-    (``models.mamba.step_rows``). ``cfg.residual_multiplier`` scales each
-    sublayer's output as it joins the residual stream."""
+    "mamba" (``models.mamba.Mamba2Mixer``) or "kda" (``models.kda.KDAMixer``),
+    each of which keeps a recurrent state a row, or "attention", and ``mlp``
+    the MLP after it (``cfg.mlp_kind``: None is the dense one); ``layer`` is
+    then the block's place among the blocks of its kind (the entry of the
+    stacked state or K/V pool it reads and writes), and ``valid`` ``[B]``
+    how many of the window's positions are real (``models.mamba``),
+    ``rows`` a decode step's live rows compacted once for every recurrent
+    layer (``models.mamba.step_rows``). ``cfg.residual_multiplier`` scales
+    each sublayer's output as it joins the residual stream."""
 
     cfg: ModelConfig
     deterministic: bool = True
@@ -639,6 +641,7 @@ class Block(nn.Module):
     mesh: Optional[Any] = None
     kv_pages: Optional[Tuple[int, int]] = None
     kind: Optional[str] = None
+    mlp: Optional[str] = None
 
     @nn.compact
     def __call__(self, carry, layer=None, step=None, valid=None, rows=None):
@@ -655,8 +658,9 @@ class Block(nn.Module):
         else:
             x, aux = carry
         kind = self.kind or cfg.layer_kind(0)
-        if kind == "mamba":
-            attn = Mamba2Mixer(cfg, self.decode, name="mamba")(
+        if kind in ("mamba", "kda"):
+            mixer = Mamba2Mixer if kind == "mamba" else KDAMixer
+            attn = mixer(cfg, self.decode, name=kind)(
                 _norm(cfg, x.dtype, "ln_attn")(x), valid, pools, layer, rows
             )
         else:
@@ -676,13 +680,14 @@ class Block(nn.Module):
         # pin the residual stream: batch/seq sharded, replicated over tensor
         # (Megatron layout) — GSPMD must not invent another layout for it
         x = constrain_activation(x, "batch", "seq", "embed")
-        if kind == "moe" and cfg.moe_dispatch == "dropless":
+        mlp = self.mlp or kind
+        if mlp == "moe" and cfg.moe_dispatch == "dropless":
             layer_aux = None  # no auxiliary loss: the bias balances the load
             mo, counts = DroplessMoE(cfg, name="moe")(
                 _norm(cfg, x.dtype, "ln_mlp")(x)
             )
             self.sow("routing", "expert_counts", counts)
-        elif kind == "moe":
+        elif mlp == "moe":
             mo, layer_aux = MoEMLP(cfg, self.deterministic, name="moe")(
                 _norm(cfg, x.dtype, "ln_mlp")(x)
             )
@@ -867,14 +872,16 @@ class Transformer(nn.Module):
             doc_ids = doc_ids_from_tokens(x, cfg.doc_sep_token)
         carry = (h, aux, doc_ids) if packed else (h, aux)
         layers = pool_vars = None
-        if cfg.scan_layers and self.decode and self.kv_pages is not None:
+        if (cfg.scan_layers or cfg.hybrid) and self.decode and self.kv_pages is not None:
             # the paged K/V pool rides the layer loop's CARRY, stacked
             # [n_loops * n_layers, ...] and indexed by its entry inside
             # Attention.
             # Scanned over like the rest of the cache (`variable_axes`), it
             # would enter the loop as one buffer and leave as another:
             # every layer would slice its pool out of the first and copy
-            # it into the second, whatever the caller donates.
+            # it into the second, whatever the caller donates. (An unrolled
+            # hybrid stack keeps the same stacked leaves, each block told
+            # its entry: one cache tree, whichever way the stack is built.)
             pool_vars = {
                 name: self.variable(
                     "cache", name, jnp.zeros, (cfg.kv_entries,) + shape, dt
@@ -885,22 +892,25 @@ class Transformer(nn.Module):
             }
             if cfg.recurrent:
                 # a hybrid stack's recurrent state rides the carry beside
-                # the pool, stacked over its mamba layers, a row a batch row
+                # the pool, stacked over its recurrent layers, a row a
+                # batch row
+                kind = cfg.recurrent_kind
+                leaves = (mamba_state_leaves if kind == "mamba" else kda_state_leaves)(
+                    cfg, B, dtype
+                )
                 pool_vars.update({
                     name: self.variable(
-                        "cache", name, jnp.zeros,
-                        (cfg.layers_of("mamba"),) + shape, dt,
+                        "cache", name, jnp.zeros, (cfg.layers_of(kind),) + shape, dt,
                     )
-                    for name, (shape, dt) in mamba_state_leaves(cfg, B, dtype).items()
+                    for name, (shape, dt) in leaves.items()
                 })
             layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
             carry = carry + ({n: v.value for n, v in pool_vars.items()},)
         members = (cfg, not train, self.decode, self.cache_len, self.mesh,
                    self.kv_pages)
-        if cfg.hybrid:
-            # scanned over the PERIODS of the pattern (``scan_layers`` is
-            # required of a hybrid stack), a period's blocks unrolled; a
-            # block's cache entry is its place among its kind
+        if cfg.hybrid and cfg.scan_layers:
+            # scanned over the PERIODS of the pattern, a period's blocks
+            # unrolled; a block's cache entry is its place among its kind
             periods = cfg.n_layers // len(cfg.layer_pattern)
             stack = nn.scan(
                 Period,
@@ -920,10 +930,14 @@ class Transformer(nn.Module):
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(*members, name="blocks")
         else:
-            # unrolled, each block is its own kind (``cfg.layer_kind``): the
-            # only stack whose layers may differ
+            # unrolled, each block is its own kind (``cfg.layer_kind``; in a
+            # routed hybrid stack its mixer and its MLP): the only stack
+            # whose MLPs may differ
             blocks = [
-                block_cls(*members, cfg.layer_kind(i), name=f"block_{i}")
+                block_cls(
+                    *members, cfg.layer_kind(i),
+                    cfg.mlp_kind(i) if cfg.hybrid else None, name=f"block_{i}",
+                )
                 for i in range(cfg.n_layers)
             ]
         ln_f = _norm(cfg, h.dtype, "ln_f")
@@ -947,11 +961,20 @@ class Transformer(nn.Module):
         for t in range(cfg.n_loops):
             step = t if looped else None
             with jax.named_scope("loop_pass") if looped else contextlib.nullcontext():
-                if cfg.hybrid:
+                if cfg.hybrid and cfg.scan_layers:
                     carry, _ = stack(carry, layers, valid, rows)
                 elif cfg.scan_layers:
                     steps = jnp.full((cfg.n_layers,), t, jnp.int32) if looped else None
                     carry, _ = stack(carry, layers, steps)
+                elif cfg.hybrid:
+                    # a block's entry in the stacked cache: its place among
+                    # the blocks of its mixer's kind
+                    for i, block in enumerate(blocks):
+                        kind = cfg.layer_kind(i)
+                        entry = None
+                        if pool_vars is not None:
+                            entry = sum(cfg.layer_kind(j) == kind for j in range(i))
+                        carry, _ = block(carry, entry, None, valid, rows)
                 else:
                     for block in blocks:
                         carry, _ = block(carry, None, step)

@@ -10,6 +10,10 @@ Per position the layer keeps ONE row, not a key and a value a head:
     score_h        = (q_nope_h . k_nope_h + q_pe_h . k_pe) / sqrt(nope + rope)
     out            = concat_h(softmax(score_h) v_h) W_o
 
+With ``q_lora_rank`` None the query is ONE direct projection, ``q_h = x
+W_q,h``; with ``position`` "none" the rope lanes are kept and rotated by
+nothing (Kimi-Linear's ``mla_use_nope``).
+
 **The cache holds the normed ``c_kv`` and the rotated ``k_pe`` only**, in one
 row of ``cfg.latent_row`` lanes (``[c_kv | k_pe | 0...]``, whole lane tiles),
 paged exactly as K/V pages are: a pool ``[n_pages, page, row]`` (stacked
@@ -83,7 +87,7 @@ class _Matrix(nn.Module):
 
 
 class LatentAttention(nn.Module):
-    """Causal latent attention with RoPE and a latent-row cache. Same call
+    """Causal latent attention with RoPE (or none) and a latent-row cache. Same call
     contract as ``models.gpt.Attention`` (``pools`` / ``layer``: the stacked
     pool riding the layer loop's carry, indexed in place)."""
 
@@ -118,8 +122,11 @@ class LatentAttention(nn.Module):
             )
 
         with jax.named_scope("mla_project"):
-            c_q = rms("q_a_norm")(matrix(d, rq, ("embed", None), "q_a")(x))
-            q = matrix(rq, H * qk, (None, "qheads"), "q_b")(c_q).reshape(B, T, H, qk)
+            if rq is None:
+                q = matrix(d, H * qk, ("embed", "qheads"), "q")(x).reshape(B, T, H, qk)
+            else:
+                c_q = rms("q_a_norm")(matrix(d, rq, ("embed", None), "q_a")(x))
+                q = matrix(rq, H * qk, (None, "qheads"), "q_b")(c_q).reshape(B, T, H, qk)
             kv = matrix(d, r + rope, ("embed", None), "kv_a")(x)
             c_kv = rms("kv_norm")(kv[..., :r])
             k_pe = kv[..., r:].reshape(B, T, 1, rope)
@@ -166,8 +173,9 @@ class LatentAttention(nn.Module):
         else:
             pos = offset + jnp.arange(T, dtype=jnp.int32)
         q_nope, q_pe = q[..., :nope], q[..., nope:]
-        q_pe = apply_rope(q_pe, pos, cfg.rope_theta, cfg.rope_interleaved)
-        k_pe = apply_rope(k_pe, pos, cfg.rope_theta, cfg.rope_interleaved)
+        if cfg.position == "rope":
+            q_pe = apply_rope(q_pe, pos, cfg.rope_theta, cfg.rope_interleaved)
+            k_pe = apply_rope(k_pe, pos, cfg.rope_theta, cfg.rope_interleaved)
 
         if not use_cache:
             # the naive form: full heads of every position

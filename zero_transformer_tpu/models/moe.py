@@ -245,8 +245,8 @@ class DroplessMoE(nn.Module):
     """The routed layer of ``cfg.moe_dispatch == "dropless"``: returns
     ``(output, expert_counts)``.
 
-    ``experts = (lo, hi)`` says which experts THIS module holds (None: all
-    ``cfg.n_experts``). It routes every row over all of them, computes the
+    ``experts = (lo, hi)`` says which experts THIS module holds (None: the
+    configuration's share, the first ``cfg.experts_held``). It routes every row over all of them, computes the
     part of its own — expert weights ``[hi - lo, ...]`` — and, with
     ``shared``, the shared expert's: the parts of modules that split the
     experts between them (one of them holding the shared expert) add up to
@@ -268,7 +268,7 @@ class DroplessMoE(nn.Module):
         param_dtype = resolve_dtype(cfg.param_dtype)
         B, T, d = x.shape
         E, k, f = cfg.n_experts, cfg.moe_top_k, cfg.moe_ff_dim
-        lo, hi = self.experts or (0, E)
+        lo, hi = self.experts or (0, cfg.experts_held)
         held = hi - lo
         N = B * T
         rows = x.reshape(N, d)

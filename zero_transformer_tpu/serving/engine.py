@@ -482,7 +482,8 @@ def _forward_only_impl(model, params, token, cache, decoding=None):
     engine passes it and gets also the routed layers' ``expert_counts`` of
     this apply, summed over those rows and reduced here to three int32: (row, expert) pairs routed, the
     busiest expert's rows summed over the layers, and the (layer, expert)
-    pairs that took any row."""
+    pairs that took any row, among the experts the model HOLDS (all of
+    them but under ``moe_experts_held``: what its grouped matmuls read)."""
     counts = decoding is not None and model.cfg.moe_dispatch == "dropless"
     # a model with recurrent state is told which rows decode: the others
     # (parked, mid-prefill) keep their state bit for bit
@@ -498,8 +499,10 @@ def _forward_only_impl(model, params, token, cache, decoding=None):
             jnp.sum(jnp.where(decoding[:, None], counts, 0), axis=0)
             for counts in jax.tree.leaves(vars_out["routing"])
         ])  # [routed layers, n_experts]
+        held = model.cfg.moe_experts_held
+        mine = load if held is None else load[:, :held]
         routing = jnp.stack([
-            jnp.sum(load), jnp.sum(jnp.max(load, axis=1)), jnp.sum(load > 0)
+            jnp.sum(load), jnp.sum(jnp.max(load, axis=1)), jnp.sum(mine > 0)
         ]).astype(jnp.int32)
     return new_logits, vars_out["cache"], nonfinite_rows(new_logits), routing
 
@@ -603,8 +606,9 @@ def _paged_chunk_prefill_impl(
     elsewhere, meaningful only for rows whose prefill completes in this
     chunk (``true_lens`` falls inside the window); the engine installs
     exactly those rows. ``touched`` (None but for a dropless routed model)
-    counts the (layer, expert) pairs that took any of the ``R * C`` rows
-    the program computes: what its grouped matmuls were handed."""
+    counts the (layer, expert) pairs among the held experts that took any
+    of the ``R * C`` rows the program computes: what its grouped matmuls
+    were handed."""
     R, C = tokens.shape
     S = table.shape[0]
     counts = model.cfg.moe_dispatch == "dropless"
@@ -637,8 +641,10 @@ def _paged_chunk_prefill_impl(
     )
     touched = None
     if counts:
+        held = model.cfg.moe_experts_held
         touched = sum(
-            jnp.sum(jnp.sum(c, axis=0) > 0) for c in jax.tree.leaves(vars_out["routing"])
+            jnp.sum(jnp.sum(c if held is None else c[:, :held], axis=0) > 0)
+            for c in jax.tree.leaves(vars_out["routing"])
         ).astype(jnp.int32)
 
     last = jax.vmap(
