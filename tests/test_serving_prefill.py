@@ -29,6 +29,7 @@ from zero_transformer_tpu.inference.generate import (
 )
 from zero_transformer_tpu.inference.sampling import SamplingConfig
 from zero_transformer_tpu.models import Transformer
+from zero_transformer_tpu.serving import engine as engine_mod
 from zero_transformer_tpu.serving.slots import POOL_LEAVES, _leaf_name
 from zero_transformer_tpu.serving import (
     PagedPrefixIndex,
@@ -256,6 +257,138 @@ def test_batched_admission_single_install_dispatch(cfg, params, reference):
     engine.run_until_idle()
     for i, (p, h) in enumerate(zip(prompts, handles)):
         assert h.tokens == reference(p, i, max_new=6)
+
+
+SEEDS = [0, 1, 2**31 - 1, 2**31, 2**32, 2**32 + 7, 2**63 - 1,
+         -1, -(2**31), -(2**31) - 1, -(2**63)]
+
+
+@pytest.mark.parametrize("x64", [False, True], ids=["x32", "x64"])
+@pytest.mark.parametrize("seed", SEEDS)
+def test_host_key_is_prngkey_bit_for_bit(seed, x64):
+    """The install's host-built key is what ``PRNGKey(seed)`` gives on the
+    device, for every seed a ``Request`` takes: past 2**31 and 2**32, and
+    negative, under 32- and 64-bit JAX."""
+    with jax.enable_x64(x64):
+        want = np.asarray(jax.random.key_data(jax.random.PRNGKey(seed)))
+        got = engine_mod._host_key(seed)
+    assert got.dtype == np.uint32 and got.shape == want.shape
+    np.testing.assert_array_equal(got, want)
+
+
+def test_host_key_follows_another_prng_impl():
+    """Under another PRNG implementation the key has another width: the
+    helper gives that implementation's key, not a threefry one."""
+    with jax.default_prng_impl("rbg"):
+        want = np.asarray(jax.random.key_data(jax.random.PRNGKey(2**32 + 7)))
+        got = engine_mod._host_key(2**32 + 7)
+    assert want.shape == (4,)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("seed", [2**63, -(2**63) - 1])
+def test_host_key_refuses_what_prngkey_refuses(seed):
+    with pytest.raises(OverflowError):
+        jax.random.PRNGKey(seed)
+    with pytest.raises(OverflowError):
+        engine_mod._host_key(seed)
+
+
+@jax.jit
+def _install_rows_per_slot_keys(last_logits, gen_mask, rngs, mask, logits_rows, keys):
+    m = mask[:, None]
+    return (
+        jnp.where(m, logits_rows, last_logits),
+        jnp.where(m, jnp.zeros_like(gen_mask), gen_mask),
+        jnp.where(m, keys, rngs),
+    )
+
+
+def _install_per_slot_device_keys(engine):
+    """The install this one replaced, kept as the reference: one device
+    key a completed slot, ``jnp.stack``ed over all ``n_slots`` rows."""
+
+    def install(completed, last_rows):
+        mask = [False] * engine.n_slots
+        keys = [jnp.zeros((2,), jnp.uint32)] * engine.n_slots
+        for slot, job in completed:
+            mask[slot] = True
+            keys[slot] = jax.random.PRNGKey(job.handle.request.seed)
+        engine._last_logits, engine._gen_mask, engine._rngs = (
+            _install_rows_per_slot_keys(
+                engine._last_logits, engine._gen_mask, engine._rngs,
+                jnp.asarray(mask, jnp.bool_), last_rows, jnp.stack(keys),
+            )
+        )
+        t_done = engine.now()
+        for slot, job in completed:
+            del engine._prefilling[slot]
+            job.handle.prefill_done_at = t_done
+            engine._active[slot] = engine_mod._ActiveSlot(job.handle)
+            engine._bank_prefix(slot, job.handle)
+
+    return install
+
+
+@pytest.mark.parametrize("greedy", [False, True], ids=["sampled", "greedy"])
+def test_install_is_one_launch_of_host_arrays_at_128_slots(
+    cfg, params, greedy, monkeypatch
+):
+    """Several requests finish prefill in one tick of a 128-slot engine:
+    each ``_install_completed`` makes ONE ``_install_rows`` call, handed
+    its mask and keys as numpy arrays, with no device key made on the
+    way; the token streams equal the per-slot device-key install's."""
+    sampling = SamplingConfig(greedy=True) if greedy else SAMPLING
+    prompts = [_prompt(5 + i % 7, offset=13 * i) for i in range(7)]
+    seeds = [3, 2**31, 2**32 + 7, 2**63 - 1, -5, 11, 2**40]
+
+    def serve(engine):
+        handles = [
+            engine.submit(p, max_new_tokens=6, seed=s)
+            for p, s in zip(prompts, seeds)
+        ]
+        engine.run_until_idle()
+        assert all(h.status == "done" for h in handles)
+        return [h.tokens for h in handles]
+
+    reference = make_engine(cfg, params, n_slots=128, prefill_chunk=8,
+                            sampling=sampling)
+    reference._install_completed = _install_per_slot_device_keys(reference)
+    want = serve(reference)
+
+    engine = make_engine(cfg, params, n_slots=128, prefill_chunk=8,
+                         sampling=sampling)
+    calls, installs = [], []
+    real_rows, real_install = engine_mod._install_rows, engine._install_completed
+
+    def rows(*args):
+        calls.append(args)
+        return real_rows(*args)
+
+    def install(completed, last_rows):
+        installs.append((len(calls), len(completed)))
+        real_install(completed, last_rows)
+        assert len(calls) == installs[-1][0] + 1
+
+    def no_device_key(*args, **kw):
+        raise AssertionError("a device key was made in the install")
+
+    monkeypatch.setattr(engine_mod, "_install_rows", rows)
+    monkeypatch.setattr(engine, "_install_completed", install)
+    monkeypatch.setattr(jax.random, "PRNGKey", no_device_key)
+    monkeypatch.setattr(jnp, "stack", no_device_key)
+    got = serve(engine)
+    monkeypatch.undo()
+
+    assert got == want
+    assert len(installs) == len(calls) == 4  # two rows a chunk dispatch
+    assert sorted(n for _, n in installs) == [1, 2, 2, 2]
+    for (_, n), args in zip(installs, calls):
+        mask, keys = args[4], args[6]
+        assert isinstance(mask, np.ndarray) and mask.dtype == np.bool_
+        assert isinstance(keys, np.ndarray) and keys.dtype == np.uint32
+        assert mask.shape == (128,) and keys.shape == (128, 2)
+        assert mask.sum() == n and not keys[~mask].any()
 
 
 def _chunk_spans(engine):

@@ -812,18 +812,32 @@ def _jit_spec_step():
 _SPEC_SHARED = _jit_spec_step()
 
 
+def _host_key(seed: int) -> np.ndarray:
+    """``jax.random.key_data(jax.random.PRNGKey(seed))``, bit for bit, made
+    on the host with no device launch. The threefry seed is the seed's
+    two's-complement words (high, low); 32-bit JAX first wraps the seed to
+    int32, so its high word is 0. A seed outside int64 raises as
+    ``PRNGKey`` does; another PRNG implementation asks the device."""
+    if jax.config.jax_default_prng_impl != "threefry2x32":
+        return np.asarray(jax.random.key_data(jax.random.PRNGKey(seed)))
+    word = (int(np.int64(seed)) + jax.config.jax_random_seed_offset) % 2**64
+    high = word >> 32 if jax.config.jax_enable_x64 else 0
+    return np.array([high, word & 0xFFFFFFFF], np.uint32)
+
+
 @jax.jit
-def _install_rows(last_logits, gen_mask, rngs, mask, logits_rows, keys):
+def _install_rows(last_logits, gen_mask, rngs, veto, mask, logits_rows, keys):
     """Install every completed prefill in ONE dispatch: rows under ``mask``
-    get their prefill logits, a cleared penalty mask, and a fresh rng
-    chain; other rows pass through untouched. Replaces the per-request
-    ``dynamic_update_slice`` install — admission cost no longer scales
-    dispatches with the number of requests admitted in a tick."""
+    get their prefill logits, a cleared penalty mask, a fresh rng chain
+    and no vetoed draft; other rows pass through untouched. ``mask`` and
+    ``keys`` come from the host, so an admission is this one launch
+    whatever the slot count."""
     m = mask[:, None]
     return (
         jnp.where(m, logits_rows, last_logits),
         jnp.where(m, jnp.zeros_like(gen_mask), gen_mask),
         jnp.where(m, keys, rngs),
+        jnp.where(mask, -1, veto),
     )
 
 
@@ -1072,7 +1086,7 @@ class ServingEngine:
         # the rows that decode, on the device: what a routed model's counts
         # are summed over and what a model with recurrent state advances
         self._sends_decoding = self._counts_routing or self._has_state
-        self._decoding: Tuple[Optional[tuple], Any] = (None, None)
+        self._decoding: Tuple[Optional[list], Any] = (None, None)
         # ... and so does its chunk-prefill program: the last chunk's count,
         # on the device until a decode tick's device_get takes it along
         self._prefill_touched = None
@@ -1906,7 +1920,7 @@ class ServingEngine:
         # every dispatch of the tick writes the cursors the tick ENDS with:
         # nothing reads a cursor between them (a dispatch stages its own
         # rows' from ``starts``), and a fault releases every mid-prefill slot
-        index_after = jnp.asarray(self._index_after(starts, lens, active), jnp.int32)
+        index_after = jnp.asarray(self._index_after(starts, lens, active))
         live = list(windows)
         R = self.prefill_rows
         try:
@@ -1988,12 +2002,13 @@ class ServingEngine:
         if completed:
             self._install_completed(completed, last)
 
-    def _index_after(self, starts, lens, active) -> List[int]:
+    def _index_after(self, starts, lens, active) -> np.ndarray:
         """Every row's true post-chunk cursor, host-derived (the chunk
         program overwrites index leaves wholesale): mid-prefill rows
         advance their fill, decoding rows sit at prompt + emitted, parked
-        rows at zero."""
-        out = [0] * self.n_slots
+        rows at zero. An int32 host array: it reaches the device as a
+        transfer, not a conversion launched there."""
+        out = np.zeros((self.n_slots,), np.int32)
         C = self.prefill_chunk
         for slot in range(self.n_slots):
             if active[slot]:
@@ -2029,7 +2044,7 @@ class ServingEngine:
         true_len - 1, PRNGKey(seed), cleared mask/veto), so the handed-off
         stream is byte-identical to having decoded here — with zero
         recomputed tokens."""
-        # graftlint: allow[host-sync-in-hot-path] reason=THE designed handoff sync — one device_get of the shipping rows' logits (and seeds' keys), only on prefill-role completions
+        # graftlint: allow[host-sync-in-hot-path] reason=THE designed handoff sync — one device_get of the shipping rows' logits, only on prefill-role completions
         rows = jax.device_get(last_rows)
         now = self.now()
         for slot, job in ship:
@@ -2057,9 +2072,7 @@ class ServingEngine:
             leaves["carry/gen_mask"] = np.zeros(
                 (self.cfg.vocab_size,), np.bool_
             )
-            # graftlint: allow[host-sync-in-hot-path] reason=tiny PRNGKey materialization for the wire payload, handoff-only
-            key_host = jax.device_get(jax.random.PRNGKey(handle.request.seed))
-            leaves["carry/rng"] = np.asarray(key_host, np.uint32)
+            leaves["carry/rng"] = _host_key(handle.request.seed)
             payload = {
                 **self._stream_meta(
                     handle, list(handle.request.prompt),
@@ -2100,27 +2113,23 @@ class ServingEngine:
                 ]
                 if not completed:
                     return
-            mask = [False] * self.n_slots
-            zero_key = jnp.zeros((2,), jnp.uint32)
-            keys = [zero_key] * self.n_slots
+            mask = np.zeros((self.n_slots,), np.bool_)
+            keys = np.zeros(self._rngs.shape, np.uint32)
             for slot, job in completed:
                 mask[slot] = True
-                keys[slot] = jax.random.PRNGKey(job.handle.request.seed)
-            self._last_logits, self._gen_mask, self._rngs = _in_mesh(
+                keys[slot] = _host_key(job.handle.request.seed)
+            (self._last_logits, self._gen_mask, self._rngs,
+             self._veto) = _in_mesh(
                 self.mesh,
                 _install_rows,
                 self._last_logits,
                 self._gen_mask,
                 self._rngs,
-                jnp.asarray(mask, jnp.bool_),
+                self._veto,
+                mask,
                 last_rows,
-                jnp.stack(keys),
+                keys,
             )
-            if self.draft_k:
-                # fresh request, fresh rejection-rule carry
-                self._veto = jnp.where(
-                    jnp.asarray(mask, jnp.bool_), -1, self._veto
-                )
             t_done = self.now()
             for slot, job in completed:
                 del self._prefilling[slot]
@@ -2366,10 +2375,13 @@ class ServingEngine:
                         )
                         if self._sends_decoding:
                             # the rows that decode, on the device; sent
-                            # again only when a slot joins or leaves
-                            live = tuple(act is not None for act in self._active)
+                            # again only when a slot joins or leaves, as a
+                            # host array (a transfer, no launch)
+                            live = list(act is not None for act in self._active)
                             if live != self._decoding[0]:
-                                self._decoding = (live, jnp.asarray(live, jnp.bool_))
+                                self._decoding = (
+                                    live, jnp.asarray(np.array(live, np.bool_))
+                                )
                             fused_args += (self._decoding[1],)
                         if self._has_state:
                             # rows whose state this tick advances, the
